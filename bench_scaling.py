@@ -200,6 +200,11 @@ if __name__ == "__main__":
                              "next to each measurement — the predicted-vs-"
                              "measured validation of the cost model")
     args = parser.parse_args()
+    from csed_514_project_distributed_training_using_pytorch_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     if args.sweep_global_batch is not None:
         run_batch_sweep(args.sweep_global_batch or [256, 1024, 4096],
                         args.max_train_examples, args.timed_epochs)

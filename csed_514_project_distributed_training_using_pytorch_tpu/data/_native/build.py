@@ -14,9 +14,15 @@ SOURCE = os.path.join(_DIR, "loader.cc")
 LIBRARY = os.path.join(_DIR, "libnativeloader.so")
 
 
-def build(force: bool = False, quiet: bool = True) -> str | None:
-    """Compile loader.cc → libnativeloader.so if stale/missing. Returns the library path, or
-    None when the toolchain is unavailable or compilation fails (callers fall back to numpy).
+class BuildFailed(RuntimeError):
+    """g++ was found and ran, and did not produce the library (compile error or
+    timeout) — distinct from a machine with no toolchain, which is not an error."""
+
+
+def build(force: bool = False) -> str | None:
+    """Compile loader.cc → libnativeloader.so if stale/missing. Returns the library path,
+    or None when there is no ``g++`` to run (callers fall back to numpy). A compiler that
+    ran and failed raises :class:`BuildFailed` with its stderr.
     """
     if not force and os.path.exists(LIBRARY):
         try:
@@ -33,12 +39,14 @@ def build(force: bool = False, quiet: bool = True) -> str | None:
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
         if proc.returncode != 0:
-            if not quiet:
-                raise RuntimeError(f"native loader build failed:\n{proc.stderr}")
-            return None
+            raise BuildFailed(f"native loader build failed:\n{proc.stderr}")
         os.replace(tmp, LIBRARY)
-    except (OSError, subprocess.TimeoutExpired):
-        return None
+    except FileNotFoundError:
+        return None                       # no g++ on this machine
+    except subprocess.TimeoutExpired as e:
+        raise BuildFailed(f"native loader build timed out after {e.timeout}s") from e
+    except OSError as e:                  # e.g. a read-only install directory
+        raise BuildFailed(f"native loader build failed: {e}") from e
     finally:
         if os.path.exists(tmp):
             try:
@@ -49,5 +57,5 @@ def build(force: bool = False, quiet: bool = True) -> str | None:
 
 
 if __name__ == "__main__":
-    path = build(force=True, quiet=False)
+    path = build(force=True)
     print(f"built {path}")

@@ -7,15 +7,19 @@ that native substrate rebuilt first-party for the TPU framework — IDX parsing,
 normalization, batch gather, and a threaded prefetching batch queue — compiled on demand from
 ``_native/loader.cc`` and reached over a C ABI (ctypes; pybind11 intentionally not required).
 
-Every entry point degrades gracefully: if the toolchain or library is unavailable,
-``available()`` is False and callers (``data.mnist``, ``data.loader``) use their pure-numpy
-paths, which are bit-exact equivalents (asserted by tests/test_native.py).
+If the toolchain or library is unavailable, ``available()`` is False and callers
+(``data.mnist``, ``data.loader``) use their pure-numpy paths, which are bit-exact
+equivalents (asserted by tests/test_native.py). Which path a process got is never silent:
+the first load prints one ``data.native: ...`` line to stderr and ``status()`` returns the
+same string — ``"native"``, or ``"numpy (<why>)"`` where a why starting ``build failed``
+means a compiler ran and failed, which ``chip_smoke.py`` treats as an error.
 """
 
 from __future__ import annotations
 
 import ctypes
 import os
+import sys
 from typing import Iterator
 
 import numpy as np
@@ -23,25 +27,33 @@ import numpy as np
 from csed_514_project_distributed_training_using_pytorch_tpu.data._native import build
 
 _lib: ctypes.CDLL | None = None
-_lib_tried = False
+_status: str | None = None      # None until the first _load()
 
 _DISABLE_ENV = "CSED514_TPU_NO_NATIVE"
 
 
 def _load() -> ctypes.CDLL | None:
-    global _lib, _lib_tried
-    if _lib_tried:
-        return _lib
-    _lib_tried = True
+    global _lib, _status
+    if _status is None:
+        _lib, _status = _open_library()
+        print(f"data.native: {_status}", file=sys.stderr, flush=True)
+    return _lib
+
+
+def _open_library() -> tuple[ctypes.CDLL | None, str]:
+    """``(library or None, status string)`` — see the module docstring."""
     if os.environ.get(_DISABLE_ENV):
-        return None
-    path = build.build()
+        return None, f"numpy ({_DISABLE_ENV} set)"
+    try:
+        path = build.build()
+    except build.BuildFailed as e:
+        return None, f"numpy (build failed: {str(e).strip().splitlines()[-1]})"
     if path is None:
-        return None
+        return None, "numpy (no g++ to build the loader)"
     try:
         lib = ctypes.CDLL(path)
-    except OSError:
-        return None
+    except OSError as e:
+        return None, f"numpy (build failed: dlopen {e})"
 
     c_ll, c_int, c_float = ctypes.c_longlong, ctypes.c_int, ctypes.c_float
     p_u8 = ctypes.POINTER(ctypes.c_ubyte)
@@ -70,14 +82,20 @@ def _load() -> ctypes.CDLL | None:
     lib.nl_abi_version.restype = c_int
 
     if lib.nl_abi_version() != 1:
-        return None
-    _lib = lib
-    return _lib
+        return None, (f"numpy (build failed: ABI version "
+                      f"{lib.nl_abi_version()} != 1 — stale library?)")
+    return lib, "native"
 
 
 def available() -> bool:
     """True when the native library is built and loadable."""
     return _load() is not None
+
+
+def status() -> str:
+    """Which loader this process uses and why (loads on first call)."""
+    _load()
+    return _status
 
 
 def _as_ptr(a: np.ndarray, ctype):

@@ -18,9 +18,11 @@ Two implementations, one contract:
   structure of ``decode_step_slots``'s attention block. The CPU/tier-1 path
   and the numerics oracle.
 - ``paged_attend`` — the Pallas kernel (compiled on TPU, interpret mode
-  elsewhere, same ``_interpret`` gate as the flash kernels). Online softmax
-  changes the reduction ORDER, so the kernel is pinned allclose-tight (not
-  bitwise) against the reference in ``tests/test_paged_attention.py``;
+  elsewhere, same ``_interpret`` gate as the flash kernels; first compiled by
+  Mosaic in PR 21 — v5e, jax 0.9.0 — at the engine's G=2, R=4, D=128, page 64
+  geometry, fp32 and int8+scales, which ``chip_smoke.py`` re-checks). Online
+  softmax changes the reduction ORDER, so the kernel is pinned allclose-tight
+  (not bitwise) against the reference in ``tests/test_paged_attention.py``;
   the engine's default paged path stays on the gather adapters, which ARE
   bitwise, and opts into the kernel per-platform.
 
@@ -84,7 +86,7 @@ def paged_attend_reference(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 
 def _paged_kernel(*refs, groups, rep, head_dim, page_size, p_max, window,
                   quantized):
-    # Scalar-prefetch operands come first: the flat page table [B·P_max] and
+    # Scalar-prefetch operands come first: the page table [B, P_max] and
     # the positions t [B]. Then q [1, H, D] (H = G·R), the pool page blocks
     # [ps, G·D] (k, v[, k_scale, v_scale [ps, G]]), the out ref [1, H, D],
     # and the online-softmax scratch (acc [H, D], m [H, 1], l [H, 1] — f32

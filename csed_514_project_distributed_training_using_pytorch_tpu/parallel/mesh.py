@@ -79,16 +79,7 @@ def initialize_cluster(coordinator_address: str | None = None,
     multi_host = coordinator_address is not None or len(slice_hosts) > 1
     # Check the distributed-runtime state directly: touching jax.process_count() here would
     # initialize the local XLA backend first, after which jax.distributed.initialize raises.
-    if multi_host and not _distributed_is_initialized():
-        # Older jax (0.4.x) CPU backends reject multiprocess computations unless the
-        # gloo collectives implementation is selected BEFORE backend init; newer jax
-        # defaults to gloo and drops the option — hence feature-detected, best-effort.
-        if "cpu" in (os.environ.get("JAX_PLATFORMS", "")
-                     + str(jax.config.jax_platforms or "")):
-            try:
-                jax.config.update("jax_cpu_collectives_implementation", "gloo")
-            except Exception:
-                pass
+    if multi_host and not jax.distributed.is_initialized():
         kwargs = {}
         if initialization_timeout is not None:
             kwargs["initialization_timeout"] = initialization_timeout
@@ -107,20 +98,6 @@ def initialize_cluster(coordinator_address: str | None = None,
                 f"peer is up and reachable (≙ a hung init_process_group in the "
                 f"reference, src/train_dist.py:146)") from e
     return process_info()
-
-
-def _distributed_is_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` with a fallback for jax versions that
-    predate it (0.4.x): the distributed runtime is up iff the global coordination
-    client exists — checked WITHOUT touching jax.process_count(), which would
-    initialize the local backend first (see the call site)."""
-    if hasattr(jax.distributed, "is_initialized"):
-        return jax.distributed.is_initialized()
-    try:
-        from jax._src.distributed import global_state
-        return global_state.client is not None
-    except Exception:       # pragma: no cover - last resort: assume uninitialized
-        return False
 
 
 def process_info() -> ProcessInfo:

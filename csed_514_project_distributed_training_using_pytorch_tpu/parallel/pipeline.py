@@ -30,11 +30,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from csed_514_project_distributed_training_using_pytorch_tpu.parallel._compat import (
-    shard_map,
-)
 
 
 def stack_stage_params(stage_param_list):

@@ -31,11 +31,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from csed_514_project_distributed_training_using_pytorch_tpu.parallel._compat import (
-    shard_map,
-)
 
 from csed_514_project_distributed_training_using_pytorch_tpu.ops.attention import (
     MASK_VALUE,

@@ -43,11 +43,8 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh
-from csed_514_project_distributed_training_using_pytorch_tpu.parallel._compat import (
-    shard_map,
-)
 
 from csed_514_project_distributed_training_using_pytorch_tpu import ops
 from csed_514_project_distributed_training_using_pytorch_tpu.parallel.ring_attention import (

@@ -752,6 +752,14 @@ def serve_forever(args) -> int:
     if args.echo:
         engine = server = _EchoServer(args, tracer if tracer.enabled else None)
     else:
+        from csed_514_project_distributed_training_using_pytorch_tpu.utils.compile_cache import (
+            enable_compile_cache,
+        )
+
+        # Every restart and every sibling replica compiles the same program
+        # set; the directory reaches children through JAX_COMPILATION_CACHE_DIR
+        # in the Fleet env or is the fixed in-checkout default.
+        enable_compile_cache()
         engine, server = build_engine_server(args, trace=tracer)
         server.start()
 

@@ -656,6 +656,11 @@ def _run_epochs(config, state, mesh, epoch_fn, eval_fn, train_x, train_y, test_x
 
 
 if __name__ == "__main__":
+    from csed_514_project_distributed_training_using_pytorch_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     try:
         main(parse_config(ComposedConfig))
     except resilience.Preempted as e:

@@ -438,6 +438,11 @@ def main(config: DistributedConfig = DistributedConfig(), *,
 
 
 if __name__ == "__main__":
+    from csed_514_project_distributed_training_using_pytorch_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     try:
         main(parse_config(DistributedConfig))
     except resilience.Preempted as e:

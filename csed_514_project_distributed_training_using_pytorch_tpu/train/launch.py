@@ -8,8 +8,10 @@ command**; its cluster coordinates arrive via environment (``JAX_COORDINATOR_ADD
 ``JAX_NUM_PROCESSES``, ``JAX_PROCESS_ID``), which ``parallel.mesh.initialize_cluster`` reads.
 On a real TPU pod none of this is needed — slice metadata supplies everything — so this
 launcher's jobs are (a) multi-host *emulation* on one machine (N processes × M virtual CPU
-devices each — the fake-backend analog, SURVEY.md §4) and (b) documenting the env contract a
-non-TPU fleet runner must provide.
+devices each — the fake-backend analog, SURVEY.md §4), (b) N processes on ONE multi-chip
+host, each given its own chip(s) through libtpu's process-bounds variables (a chip belongs
+to one process; a layout the launcher cannot divide is refused before anything spawns), and
+(c) documenting the env contract a non-TPU fleet runner must provide.
 
 Usage (≙ running run1.py and run2.py on two VMs, but one command, no editing)::
 
@@ -45,21 +47,56 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
+# How one host's chips divide among co-hosted processes, in libtpu's own variables:
+# (processes, chips per process) -> (TPU_PROCESS_BOUNDS, TPU_CHIPS_PER_PROCESS_BOUNDS).
+# The row is the four-chip 2x2 v5e host this launcher was run on (PR 21), divided the
+# way jax's own multi-process TPU test harness divides it; a layout not listed is
+# refused at launch, because its children would each open every chip and fail (or
+# hang) inside backend init.
+_TPU_HOST_LAYOUTS = {
+    (4, 1): ("2,2,1", "1,1,1"),
+}
+
+
 def _child_env(base: dict, *, port: int, num_processes: int, process_id: int,
-               platform: str | None, devices_per_process: int) -> dict:
+               platform: str | None, devices_per_process: int,
+               tpu_ports: tuple[int, ...] = ()) -> dict:
+    """Environment of child ``process_id``: the rendezvous triple, the platform, and
+    the child's own devices — a virtual device count on CPU, its own chip(s) on an
+    accelerator (``tpu_ports``: one free local port per process for libtpu's
+    process mesh). Raises ``ValueError`` for a multi-process accelerator launch
+    whose chip division is not in ``_TPU_HOST_LAYOUTS``."""
     env = dict(base)
     env["JAX_COORDINATOR_ADDRESS"] = f"localhost:{port}"
     env["JAX_NUM_PROCESSES"] = str(num_processes)
     env["JAX_PROCESS_ID"] = str(process_id)
     if platform:
         env["JAX_PLATFORMS"] = platform
-    if (platform or env.get("JAX_PLATFORMS")) == "cpu":
+    if env.get("JAX_PLATFORMS") == "cpu":
         # Each emulated host owns its own virtual device set; replace any inherited count.
         flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
                        env.get("XLA_FLAGS", ""))
         env["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count={devices_per_process}"
         ).strip()
+    elif num_processes > 1:
+        layout = _TPU_HOST_LAYOUTS.get((num_processes, devices_per_process))
+        if layout is None:
+            known = ", ".join(f"{n} x {d}" for n, d in sorted(_TPU_HOST_LAYOUTS))
+            raise ValueError(
+                f"cannot give {num_processes} processes {devices_per_process} chip(s) "
+                f"each on this host: a chip belongs to one process, and the layouts "
+                f"this launcher knows how to divide are (processes x chips): {known}. "
+                f"Run one process over all chips, or pass --platform cpu for "
+                f"multi-process emulation")
+        first = process_id * devices_per_process
+        env["TPU_PROCESS_BOUNDS"], env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = layout
+        env["TPU_VISIBLE_CHIPS"] = ",".join(
+            str(first + k) for k in range(devices_per_process))
+        env["TPU_PROCESS_ADDRESSES"] = ",".join(f"localhost:{p}" for p in tpu_ports)
+        env["TPU_PROCESS_PORT"] = str(tpu_ports[process_id])
+        env["CLOUD_TPU_TASK_ID"] = str(process_id)
+        env["ALLOW_MULTIPLE_LIBTPU_LOAD"] = "1"
     return env
 
 
@@ -84,15 +121,16 @@ class Fleet:
         would break ``initialize_cluster``'s contiguous-rank contract)."""
         self.port = port or _free_port()
         base = dict(os.environ if env is None else env)
-        self.procs = [
-            subprocess.Popen(
-                [sys.executable, *command],
-                env=_child_env(base, port=self.port, num_processes=num_processes,
-                               process_id=process_id_base + i, platform=platform,
-                               devices_per_process=devices_per_process),
-            )
-            for i in range(num_processes)
-        ]
+        tpu_ports = [_free_port() for _ in range(num_processes)]
+        # Every child's env is built BEFORE the first spawn: a refused chip layout
+        # must not leave half a fleet running.
+        envs = [_child_env(base, port=self.port, num_processes=num_processes,
+                           process_id=process_id_base + i, platform=platform,
+                           devices_per_process=devices_per_process,
+                           tpu_ports=tpu_ports)
+                for i in range(num_processes)]
+        self.procs = [subprocess.Popen([sys.executable, *command], env=e)
+                      for e in envs]
         self._first_failure: int | None = None
 
     def poll(self) -> int | None:
@@ -178,7 +216,8 @@ def main(argv: list[str] | None = None) -> int:
     parser.add_argument("--platform", default=None,
                         help="force a JAX platform in children (e.g. cpu for emulation)")
     parser.add_argument("--devices-per-process", type=int, default=1,
-                        help="virtual devices per emulated host (cpu platform only)")
+                        help="devices each process owns: virtual devices per emulated "
+                             "host on cpu, chips per process on an accelerator")
     parser.add_argument("--port", type=int, default=None,
                         help="coordinator port (default: pick a free one)")
     parser.add_argument("--timeout", type=float, default=None,

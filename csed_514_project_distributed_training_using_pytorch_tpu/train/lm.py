@@ -550,6 +550,11 @@ def _run_epochs(config, state, mesh, epoch_fn, eval_fn, tokens_d, zeros_d, test_
 
 
 if __name__ == "__main__":
+    from csed_514_project_distributed_training_using_pytorch_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     try:
         main(parse_config(LMConfig))
     except resilience.Preempted as e:

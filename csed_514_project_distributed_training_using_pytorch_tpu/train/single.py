@@ -444,6 +444,11 @@ def main(config: SingleProcessConfig = SingleProcessConfig(), *,
 
 
 if __name__ == "__main__":
+    from csed_514_project_distributed_training_using_pytorch_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
     try:
         main(parse_config(SingleProcessConfig))
     except resilience.Preempted as e:

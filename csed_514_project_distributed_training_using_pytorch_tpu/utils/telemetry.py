@@ -252,8 +252,6 @@ def _compiled_cost_value(compiled, key: str) -> float | None:
         cost = compiled.cost_analysis()
     except Exception:
         return None
-    if isinstance(cost, (list, tuple)):      # older jax: one dict per partition
-        cost = cost[0] if cost else {}
     try:
         value = cost.get(key)
     except AttributeError:

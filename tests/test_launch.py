@@ -9,7 +9,8 @@ from csed_514_project_distributed_training_using_pytorch_tpu.train import launch
 class TestChildEnv:
     def test_rendezvous_env_contract(self):
         env = L._child_env({}, port=12345, num_processes=4, process_id=2,
-                           platform=None, devices_per_process=1)
+                           platform=None, devices_per_process=1,
+                           tpu_ports=(1, 2, 3, 4))
         assert env["JAX_COORDINATOR_ADDRESS"] == "localhost:12345"
         assert env["JAX_NUM_PROCESSES"] == "4"
         assert env["JAX_PROCESS_ID"] == "2"
@@ -32,9 +33,43 @@ class TestChildEnv:
 
     def test_non_cpu_platform_keeps_flags(self):
         base = {"XLA_FLAGS": "--keep-me"}
-        env = L._child_env(base, port=1, num_processes=2, process_id=0,
+        env = L._child_env(base, port=1, num_processes=1, process_id=0,
                            platform="tpu", devices_per_process=4)
         assert env["XLA_FLAGS"] == "--keep-me"
+        # One process owns every chip: nothing to divide, no libtpu bounds set.
+        assert not [k for k in env if k.startswith("TPU_")]
+
+    def test_accelerator_children_each_get_their_own_chip(self):
+        """Four processes on the four-chip host (PR 21): libtpu's process-bounds
+        variables give child i chip i and its own port in one shared address list —
+        without them every child opens all four chips and fails in backend init."""
+        ports = (9001, 9002, 9003, 9004)
+        envs = [L._child_env({"XLA_FLAGS": "--keep-me"}, port=1, num_processes=4,
+                             process_id=i, platform=None, devices_per_process=1,
+                             tpu_ports=ports) for i in range(4)]
+        assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+        assert [e["TPU_PROCESS_PORT"] for e in envs] == [str(p) for p in ports]
+        assert [e["CLOUD_TPU_TASK_ID"] for e in envs] == ["0", "1", "2", "3"]
+        for e in envs:
+            assert e["TPU_PROCESS_BOUNDS"] == "2,2,1"
+            assert e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+            assert e["TPU_PROCESS_ADDRESSES"] == ",".join(
+                f"localhost:{p}" for p in ports)
+            assert e["XLA_FLAGS"] == "--keep-me" and "JAX_PLATFORMS" not in e
+
+    @pytest.mark.parametrize("n,per", [(2, 1), (3, 1), (4, 2), (8, 1)])
+    def test_undividable_accelerator_layout_is_refused_with_a_reason(self, n, per):
+        with pytest.raises(ValueError, match="chip belongs to one process"):
+            L._child_env({}, port=1, num_processes=n, process_id=0, platform="tpu",
+                         devices_per_process=per, tpu_ports=tuple(range(n)))
+
+    def test_refused_layout_spawns_nothing(self, monkeypatch):
+        """The refusal happens at launch, for the whole fleet, before the first
+        child exists — not inside some child's backend init."""
+        monkeypatch.setattr(L.subprocess, "Popen",
+                            lambda *a, **k: pytest.fail("spawned a child"))
+        with pytest.raises(ValueError, match="--platform cpu"):
+            L.Fleet(["-c", "pass"], num_processes=2, platform="tpu")
 
 
 class TestCli:

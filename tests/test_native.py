@@ -203,3 +203,46 @@ class TestBatchLoaderIntegration:
             np.testing.assert_array_equal(bl, dataset.labels[local[s]])
             steps += 1
         assert steps == 9
+
+
+class TestLoaderVisibility:
+    """Which loader a process got is never silent (PR 21): ``status()`` names it, and
+    a compiler that RAN and failed is told apart from a machine without one."""
+
+    def test_status_names_the_loader_that_ran(self):
+        assert native.available()          # this image has g++; the suite needs it
+        assert native.status() == "native"
+
+    def _fresh_build(self, monkeypatch, tmp_path, run):
+        from csed_514_project_distributed_training_using_pytorch_tpu.data._native import (
+            build,
+        )
+        monkeypatch.setattr(build, "LIBRARY", str(tmp_path / "libnativeloader.so"))
+        monkeypatch.setattr(build.subprocess, "run", run)
+        return build
+
+    def test_failed_compile_is_typed_and_reported(self, monkeypatch, tmp_path):
+        import types
+
+        build = self._fresh_build(
+            monkeypatch, tmp_path,
+            lambda *a, **k: types.SimpleNamespace(returncode=1,
+                                                  stderr="loader.cc:1: error: boom"))
+        with pytest.raises(build.BuildFailed, match="boom"):
+            build.build()
+        lib, status = native._open_library()
+        assert lib is None and status == "numpy (build failed: loader.cc:1: error: boom)"
+        assert not list(tmp_path.iterdir())             # no half-written library
+
+    def test_missing_toolchain_is_not_a_failure(self, monkeypatch, tmp_path):
+        def no_gpp(*a, **k):
+            raise FileNotFoundError("g++")
+
+        build = self._fresh_build(monkeypatch, tmp_path, no_gpp)
+        assert build.build() is None
+        lib, status = native._open_library()
+        assert lib is None and status.startswith("numpy (no g++")
+
+    def test_disable_env_is_reported(self, monkeypatch):
+        monkeypatch.setenv("CSED514_TPU_NO_NATIVE", "1")
+        assert native._open_library() == (None, "numpy (CSED514_TPU_NO_NATIVE set)")

@@ -23,6 +23,16 @@ from csed_514_project_distributed_training_using_pytorch_tpu.ops.paged_attention
 )
 
 
+def _tol():
+    """Interpret mode (CPU) is exact to f32 round-off; compiled on the chip both
+    the kernel and the reference run their f32 matmuls as bf16 MXU passes and
+    differ at ~4e-3 (PR 21 chip run) — the TPU tolerance of
+    tests/test_pallas_attention.py."""
+    if jax.default_backend() == "tpu":
+        return dict(rtol=2e-2, atol=2e-2)
+    return dict(rtol=1e-5, atol=1e-5)
+
+
 def _setup(seed, *, b=3, g=2, rep=2, d=8, ps=4, s=16, quantized=False,
            shuffle=True):
     """Random pool + per-slot table covering the full context, with free
@@ -89,8 +99,7 @@ def test_kernel_matches_reference(window, quantized, rep):
     ref = paged_attend_reference(q, k_pool, v_pool, table, t, seq_len=16,
                                  window=window, **scales)
     out = paged_attend(q, k_pool, v_pool, table, t, window=window, **scales)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **_tol())
 
 
 def test_kernel_ignores_unmapped_pages():
@@ -115,5 +124,4 @@ def test_kernel_t_zero_and_t_max():
     t = jnp.asarray([0, 15], jnp.int32)
     ref = paged_attend_reference(q, k_pool, v_pool, table, t, seq_len=16)
     out = paged_attend(q, k_pool, v_pool, table, t)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **_tol())

@@ -12,8 +12,8 @@ what this checker caught on the tree it first ran against.
 
 Lazy (function-body) imports are the sanctioned escape: they defer the cost to
 the call that needs it, and the graph records but does not traverse them. A
-deliberately jax-reaching top-level import (the root package's env-gated
-platform-pin shim) carries a line pragma with its justification.
+deliberately jax-reaching top-level import would carry a line pragma with its
+justification; none does today.
 
 The finding points at the first import line in the DECLARED module whose edge
 begins the offending chain, and the message spells out the full chain — the

@@ -38,6 +38,11 @@ BACKEND_FREE = (
     "tools/serve_loadgen.py",
     "tools/trace_report.py",
     "tools/fleet_top.py",
+    # The chip smoke's parent runs its phases as serial children that each need
+    # the chip; a parent that reached jax at import could come to hold it.
+    "chip_smoke.py",
+    "train/launch.py",
+    "utils/compile_cache.py",
 )
 
 # Import targets that count as "the backend" for backend-purity.
@@ -100,8 +105,9 @@ RESOLVE_HELPERS: tuple[str, ...] = ()
 
 
 def package_relpath(graph, rule_path: str) -> str:
-    """Rule path -> repo-relative path (`tools/...` passes through unchanged)."""
-    if rule_path.startswith("tools/"):
+    """Rule path -> repo-relative path (`tools/...` and root-level scripts pass
+    through unchanged)."""
+    if rule_path.startswith("tools/") or "/" not in rule_path:
         return rule_path
     return f"{graph.package}/{rule_path}"
 

@@ -554,10 +554,11 @@ def main(argv: list[str] | None = None) -> int:
     f.add_argument("--echo-delay-s", type=float, default=0.0,
                    help="echo replicas: per-token sleep (keeps work in "
                         "flight so load actually accumulates)")
-    f.add_argument("--replica-platform", default="cpu",
-                   help="JAX_PLATFORMS for replica processes; '' = inherit "
-                        "the environment (e.g. to put each replica's engine "
-                        "on the accelerator)")
+    f.add_argument("--replica-platform", default="",
+                   help="JAX_PLATFORMS for replica processes; '' (default) = "
+                        "inherit the environment, so replicas land where "
+                        "this command would (the accelerator when there is "
+                        "one; the CPU tests export JAX_PLATFORMS=cpu)")
     f.add_argument("--router-max-pending", type=int, default=0,
                    help="router admission queue bound (0 = unbounded)")
     f.add_argument("--heartbeat-dir", default="",
@@ -795,7 +796,8 @@ def main(argv: list[str] | None = None) -> int:
         env = dict(os.environ)
         env["PYTHONPATH"] = (f"{repo_root}:{env['PYTHONPATH']}"
                              if env.get("PYTHONPATH") else repo_root)
-        if shard_tp * shard_dp > 1 and (args.replica_platform or "cpu") == "cpu":
+        if shard_tp * shard_dp > 1 and (
+                args.replica_platform or env.get("JAX_PLATFORMS")) == "cpu":
             # A CPU replica has one host device by default; grow it so the
             # tp*dp serve mesh has chips to land on (the same trick the test
             # suite uses — a multi-process CPU "mesh" of virtual devices).
@@ -867,7 +869,7 @@ def main(argv: list[str] | None = None) -> int:
         # recipe) — one owner, so the single-engine and fleet sides of an A/B
         # can never drift apart.
         if (shard_tp * shard_dp > 1
-                and os.environ.get("JAX_PLATFORMS", "cpu") == "cpu"):
+                and os.environ.get("JAX_PLATFORMS") == "cpu"):
             # Same trick as the fleet path, applied to OUR process: grow the
             # single host CPU device into tp*dp virtual chips. XLA reads the
             # flag at backend INITIALIZATION (first devices() call, inside
@@ -1238,5 +1240,19 @@ def main(argv: list[str] | None = None) -> int:
     return 0
 
 
+def _cli() -> int:
+    """Script entry: with ``--replicas 0`` THIS process compiles the engine, so
+    the persistent compile cache goes on first (fleet replicas enable their
+    own in ``serving/replica.py``; enabling imports jax but initializes no
+    backend). Kept out of :func:`main` so in-process callers keep jax's
+    default config."""
+    from csed_514_project_distributed_training_using_pytorch_tpu.utils.compile_cache import (
+        enable_compile_cache,
+    )
+
+    enable_compile_cache()
+    return main()
+
+
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(_cli())
