@@ -54,12 +54,18 @@ from csed_514_project_distributed_training_using_pytorch_tpu.train.step import (
 from csed_514_project_distributed_training_using_pytorch_tpu.utils import checkpoint
 from csed_514_project_distributed_training_using_pytorch_tpu.utils import metrics as M
 from csed_514_project_distributed_training_using_pytorch_tpu.utils import plotting
+from csed_514_project_distributed_training_using_pytorch_tpu.utils import profiling
 from csed_514_project_distributed_training_using_pytorch_tpu.utils.config import (
     LMConfig, parse_config,
 )
 from csed_514_project_distributed_training_using_pytorch_tpu.utils import (
     telemetry as T,
 )
+
+
+# The direct children of the epoch loop's `step("epoch", n)`, as `epoch/<name>` on a
+# trace and `<name>_s` in the `epoch` telemetry event.
+EPOCH_SPANS = ("data", "execute", "eval", "log", "emit", "guard", "checkpoint", "tick")
 
 
 def make_eval_nll_fn(model: lm_mod.TransformerLM, *, batch_size: int):
@@ -375,11 +381,12 @@ def main(config: LMConfig = LMConfig(), *,
         os.makedirs(config.results_dir, exist_ok=True)
 
     try:
-        state = _run_epochs(config, state, mesh, epoch_fn, eval_fn, tokens_d,
-                            zeros_d, test_d, dropout_rng, n_train, n_test, seq_len,
-                            steps_per_epoch, start_epoch, history, watch, saver,
-                            ckpt_path, gather, tele, compile_s, flops_per_step,
-                            rt, bytes_per_step, grt, loader)
+        with profiling.maybe_profile(config.profile, config.profile_dir):
+            state = _run_epochs(config, state, mesh, epoch_fn, eval_fn, tokens_d,
+                                zeros_d, test_d, dropout_rng, n_train, n_test,
+                                seq_len, steps_per_epoch, start_epoch, history,
+                                watch, saver, ckpt_path, gather, tele, compile_s,
+                                flops_per_step, rt, bytes_per_step, grt, loader)
     finally:
         # Drain the write-behind queue even on an exception/signal/preemption
         # mid-run — the queued per-epoch checkpoint is the resume artifact a killed
@@ -437,110 +444,143 @@ def _run_epochs(config, state, mesh, epoch_fn, eval_fn, tokens_d, zeros_d, test_
     best_step_s = None
     ckpt_store = (os.path.join(config.results_dir, "checkpoints")
                   if config.results_dir else "")
+    # Every statement of the loop body sits inside exactly one named span (README
+    # "Telemetry" has the table): a host event on the profiler's clock whenever a
+    # trace is being taken, and one `*_s` field of the `epoch` event. An earlier run
+    # in this process must not reach into this run's first period: start a fresh table.
+    profiling.drain()
+    if tele.enabled:
+        # estimate_mfu's first use imports utils.benchmarks and the distributed
+        # trainer with it (6-15 ms): paid here, not inside the first `epoch/emit`.
+        T.estimate_mfu(None, None)
     for epoch in range(start_epoch, config.epochs):
-        # heartbeat (with the previous boundary's param fingerprint) + armed
-        # faults; no-op off
-        rt.epoch_tick(state, epoch,
-                      fingerprint=grt.fingerprint if grt else None)
-        t_epoch = time.perf_counter()
-        stream_wait_s = stream_digest = None
-        if loader is not None:
-            # Streaming corpus feed (data/stream.py): the loader's
-            # (seed, epoch)-pure shard shuffle IS the permutation, already in
-            # batch order — refill the device token array and run the identity
-            # plan. Loader stall (shard IO, sha256, --data-throttle-s) lands in
-            # this epoch's data_s and therefore in goodput's data_wait.
-            epoch_np = loader.epoch_tokens(epoch)
-            stream_wait_s = loader.pop_wait_s()
-            stream_digest = zlib.crc32(epoch_np.tobytes())
-            tokens_d = dp.put_global(mesh, epoch_np, P())
-            plan = dp.put_global(
-                mesh,
-                np.arange(steps_per_epoch * config.batch_size, dtype=np.int32)
-                .reshape(steps_per_epoch, config.batch_size), P(None, "data"))
-        else:
-            # (seed, epoch)-keyed permutation — the parallel/sampler contract,
-            # so resumed runs replay exactly the epochs they missed.
-            perm = np.random.default_rng(
-                np.random.SeedSequence([config.seed, epoch])).permutation(n_train)
-            plan = dp.put_global(
-                mesh,
-                perm[:steps_per_epoch * config.batch_size].astype(np.int32)
-                .reshape(steps_per_epoch, config.batch_size), P(None, "data"))
-        data_s = time.perf_counter() - t_epoch
-        t_exec = time.perf_counter()
-        state, out = epoch_fn(state, tokens_d, zeros_d, plan, dropout_rng)
-        losses, epoch_health = out if config.health_stats else (out, None)
-        jax.block_until_ready(state.params)
-        train_loss = float(np.asarray(jax.device_get(losses)).mean())
-        execute_s = time.perf_counter() - t_exec
-        t_eval = time.perf_counter()
-        eval_params = state.ema if state.ema is not None else state.params
-        sum_nll = float(jax.device_get(eval_fn(eval_params, test_d)))
-        eval_s = time.perf_counter() - t_eval
-        val_nll = sum_nll / (n_test * seq_len)
-        examples = (epoch + 1) * steps_per_epoch * config.batch_size
-        history.record_train(examples, train_loss)
-        history.record_test(examples, val_nll)
-        M.log(f"Epoch {epoch}: train_loss: {train_loss:.4f}, "
-              f"val_nll/token: {val_nll:.4f}, val_ppl: {float(np.exp(val_nll)):.3f}, "
-              f"time_elapsed: {watch.elapsed():.2f}s")
-        if epoch_health is not None:
-            # SPMD-entered by every process (the norm program would deadlock a
-            # fleet if only process 0 ran it); emission below stays process-0 gated.
-            health_host = jax.device_get(epoch_health)
-            param_norm = T.global_l2_norm(state.params)
-        if tele.enabled:
-            step_s = execute_s / steps_per_epoch if steps_per_epoch else None
-            if step_s and (best_step_s is None or step_s < best_step_s):
-                best_step_s = step_s
-            tele.emit(T.epoch_event(
-                epoch, examples=steps_per_epoch * config.batch_size,
-                steps=steps_per_epoch, wall_s=time.perf_counter() - t_epoch,
-                execute_s=execute_s, eval_s=eval_s, data_s=data_s,
-                compile_s=compile_s, flops_per_step=flops_per_step,
-                train_loss=train_loss, val_loss=val_nll,
-                mfu=T.estimate_mfu(flops_per_step, step_s)["mfu"]))
-            if epoch_health is not None:
-                tele.emit(T.health_event(epoch, health_host, steps_per_epoch,
-                                         param_norm=param_norm))
-            if loader is not None:
-                # The stream ledger next to the epoch event: stall wall,
-                # next-epoch cursor (the one the checkpoint below stamps),
-                # and the epoch's token CRC — the bitwise pin the
-                # deterministic-resume tests compare across a kill.
-                tele.emit(T.data_event(
-                    epoch, batches=steps_per_epoch,
-                    sequences=steps_per_epoch * config.batch_size,
-                    wait_s=stream_wait_s, throttle_s=config.data_throttle_s,
-                    cursor=loader.cursor(epoch + 1, 0),
-                    stream_digest=stream_digest))
-        # Guard boundary: anomaly verdict fetch + event + cross-replica
-        # fingerprint, then the manifest health stamp for the versioned save.
-        stamp = grt.epoch_end(state, epoch, steps_per_epoch) if grt else None
-        if ckpt_path:
-            # Device-resident gathered state: the saver is process-0 gated and
-            # device_gets internally — non-0 processes must not pay a host fetch.
-            ck_state = gather(state)
-            saver.save_train_state(ckpt_path, ck_state)
-            if ckpt_store and config.keep_checkpoints:
-                # Versioned store (manifest + checksums + keep-last-N GC) for the
-                # supervisor's newest-HEALTHY resume scan. The cursor stamps the
-                # NEXT epoch's stream position into the manifest (DESIGN.md §26).
-                cursor = (loader.cursor(epoch + 1, 0) if loader is not None
-                          else {"version": 1, "kind": "epoch",
-                                "seed": config.seed, "epoch": epoch + 1,
-                                "batch": 0, "step": int(ck_state.step)})
-                checkpoint.save_versioned(ckpt_store, ck_state,
-                                          keep=config.keep_checkpoints, tele=tele,
-                                          health=stamp, cursor=cursor)
-        # Anomaly policy AFTER the stamped checkpoint is durable (raises
-        # Poisoned; __main__ exits 65).
-        if grt:
-            grt.check_poisoned(state)
-        # Cooperative preemption at the epoch boundary, with this epoch's
-        # checkpoint durable (raises Preempted; __main__ exits 75).
-        rt.check_preempt(epoch=epoch, state=state, checkpoint=ckpt_path, tele=tele)
+        with profiling.step("epoch", epoch):
+            with profiling.span("epoch/tick"):
+                # heartbeat (with the previous boundary's param fingerprint) + armed
+                # faults; no-op off
+                rt.epoch_tick(state, epoch,
+                              fingerprint=grt.fingerprint if grt else None)
+            with profiling.span("epoch/data"):
+                t_epoch = time.perf_counter()       # wall_s alone
+                stream_wait_s = stream_digest = None
+                if loader is not None:
+                    # Streaming corpus feed (data/stream.py): the loader's
+                    # (seed, epoch)-pure shard shuffle IS the permutation, already
+                    # in batch order — refill the device token array and run the
+                    # identity plan. Loader stall (shard IO, sha256,
+                    # --data-throttle-s) lands in this epoch's data_s and therefore
+                    # in goodput's data_wait.
+                    epoch_np = loader.epoch_tokens(epoch)
+                    stream_wait_s = loader.pop_wait_s()
+                    stream_digest = zlib.crc32(epoch_np.tobytes())
+                    tokens_d = dp.put_global(mesh, epoch_np, P())
+                    plan = dp.put_global(
+                        mesh,
+                        np.arange(steps_per_epoch * config.batch_size, dtype=np.int32)
+                        .reshape(steps_per_epoch, config.batch_size), P(None, "data"))
+                else:
+                    # (seed, epoch)-keyed permutation — the parallel/sampler
+                    # contract, so resumed runs replay exactly the epochs they missed.
+                    perm = np.random.default_rng(np.random.SeedSequence(
+                        [config.seed, epoch])).permutation(n_train)
+                    plan = dp.put_global(
+                        mesh,
+                        perm[:steps_per_epoch * config.batch_size].astype(np.int32)
+                        .reshape(steps_per_epoch, config.batch_size), P(None, "data"))
+            with profiling.span("epoch/execute"):
+                with profiling.span("execute/dispatch"):
+                    state, out = epoch_fn(state, tokens_d, zeros_d, plan, dropout_rng)
+                losses, epoch_health = out if config.health_stats else (out, None)
+                with profiling.span("execute/wait"):
+                    jax.block_until_ready(state.params)
+                with profiling.span("execute/loss_fetch"):
+                    train_loss = float(np.asarray(jax.device_get(losses)).mean())
+            with profiling.span("epoch/eval"):
+                eval_params = state.ema if state.ema is not None else state.params
+                sum_nll = float(jax.device_get(eval_fn(eval_params, test_d)))
+            with profiling.span("epoch/log"):
+                val_nll = sum_nll / (n_test * seq_len)
+                examples = (epoch + 1) * steps_per_epoch * config.batch_size
+                history.record_train(examples, train_loss)
+                history.record_test(examples, val_nll)
+                M.log(f"Epoch {epoch}: train_loss: {train_loss:.4f}, "
+                      f"val_nll/token: {val_nll:.4f}, "
+                      f"val_ppl: {float(np.exp(val_nll)):.3f}, "
+                      f"time_elapsed: {watch.elapsed():.2f}s")
+                if epoch_health is not None:
+                    # SPMD-entered by every process (the norm program would deadlock
+                    # a fleet if only process 0 ran it); emission below stays
+                    # process-0 gated.
+                    health_host = jax.device_get(epoch_health)
+                    param_norm = T.global_l2_norm(state.params)
+            with profiling.span("epoch/emit"):
+                if tele.enabled:
+                    # The event is emitted before its own iteration ends, so it
+                    # carries what is drained here: this iteration's head (tick,
+                    # data, execute, eval, log) and the previous one's tail (emit,
+                    # guard, checkpoint, tick). A span that did not run reads 0.0.
+                    spans, period_s = profiling.drain()
+                    span_s = {f"{name}_s": spans.get(f"epoch/{name}", 0.0)
+                              for name in EPOCH_SPANS}
+                    step_s = (span_s["execute_s"] / steps_per_epoch
+                              if steps_per_epoch else None)
+                    if step_s and (best_step_s is None or step_s < best_step_s):
+                        best_step_s = step_s
+                    tele.emit(T.epoch_event(
+                        epoch, examples=steps_per_epoch * config.batch_size,
+                        steps=steps_per_epoch, wall_s=time.perf_counter() - t_epoch,
+                        period_s=period_s, **span_s,
+                        compile_s=compile_s, flops_per_step=flops_per_step,
+                        train_loss=train_loss, val_loss=val_nll,
+                        mfu=T.estimate_mfu(flops_per_step, step_s)["mfu"]))
+                    if epoch_health is not None:
+                        tele.emit(T.health_event(epoch, health_host, steps_per_epoch,
+                                                 param_norm=param_norm))
+                    if loader is not None:
+                        # The stream ledger next to the epoch event: stall wall,
+                        # next-epoch cursor (the one the checkpoint below stamps),
+                        # and the epoch's token CRC — the bitwise pin the
+                        # deterministic-resume tests compare across a kill.
+                        tele.emit(T.data_event(
+                            epoch, batches=steps_per_epoch,
+                            sequences=steps_per_epoch * config.batch_size,
+                            wait_s=stream_wait_s, throttle_s=config.data_throttle_s,
+                            cursor=loader.cursor(epoch + 1, 0),
+                            stream_digest=stream_digest))
+            with profiling.span("epoch/guard"):
+                # Guard boundary: anomaly verdict fetch + event + cross-replica
+                # fingerprint, then the manifest health stamp for the versioned save.
+                stamp = grt.epoch_end(state, epoch, steps_per_epoch) if grt else None
+            with profiling.span("epoch/checkpoint"):
+                if ckpt_path:
+                    # Device-resident gathered state: the saver is process-0 gated
+                    # and device_gets internally — non-0 processes must not pay a
+                    # host fetch.
+                    ck_state = gather(state)
+                    saver.save_train_state(ckpt_path, ck_state)
+                    if ckpt_store and config.keep_checkpoints:
+                        # Versioned store (manifest + checksums + keep-last-N GC)
+                        # for the supervisor's newest-HEALTHY resume scan. The
+                        # cursor stamps the NEXT epoch's stream position into the
+                        # manifest (DESIGN.md §26).
+                        cursor = (loader.cursor(epoch + 1, 0) if loader is not None
+                                  else {"version": 1, "kind": "epoch",
+                                        "seed": config.seed, "epoch": epoch + 1,
+                                        "batch": 0, "step": int(ck_state.step)})
+                        checkpoint.save_versioned(ckpt_store, ck_state,
+                                                  keep=config.keep_checkpoints,
+                                                  tele=tele, health=stamp,
+                                                  cursor=cursor)
+            with profiling.span("epoch/guard"):
+                # Anomaly policy AFTER the stamped checkpoint is durable (raises
+                # Poisoned; __main__ exits 65).
+                if grt:
+                    grt.check_poisoned(state)
+            with profiling.span("epoch/tick"):
+                # Cooperative preemption at the epoch boundary, with this epoch's
+                # checkpoint durable (raises Preempted; __main__ exits 75).
+                rt.check_preempt(epoch=epoch, state=state, checkpoint=ckpt_path,
+                                 tele=tele)
     if tele.enabled and best_step_s is not None:
         # bytes_per_step is XLA's own bytes-accessed count for the compiled
         # step (byte-true under quantized dtypes): the mfu event carries the

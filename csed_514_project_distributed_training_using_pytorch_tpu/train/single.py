@@ -49,10 +49,7 @@ from csed_514_project_distributed_training_using_pytorch_tpu.utils.config import
 )
 from csed_514_project_distributed_training_using_pytorch_tpu.utils import metrics as M
 from csed_514_project_distributed_training_using_pytorch_tpu.utils import plotting
-from csed_514_project_distributed_training_using_pytorch_tpu.utils.profiling import (
-    annotate,
-    maybe_profile,
-)
+from csed_514_project_distributed_training_using_pytorch_tpu.utils import profiling
 from csed_514_project_distributed_training_using_pytorch_tpu.utils import (
     telemetry as T,
 )
@@ -360,8 +357,8 @@ def main(config: SingleProcessConfig = SingleProcessConfig(), *,
         train_epoch = train_epoch_host_pipeline
 
     try:
-        with maybe_profile(config.profile, config.profile_dir):
-            with annotate("eval"):
+        with profiling.maybe_profile(config.profile, config.profile_dir):
+            with profiling.span("eval"):
                 evaluate(state, 0)              # baseline eval, ≙ src/train.py:106
             best_step_s = None
             for epoch in range(1, config.n_epochs + 1):
@@ -370,12 +367,12 @@ def main(config: SingleProcessConfig = SingleProcessConfig(), *,
                 rt.epoch_tick(state, epoch, fingerprint=grt.fingerprint)
                 step_before = int(state.step)
                 t_epoch = time.perf_counter()
-                with annotate(f"train_epoch_{epoch}"):
+                with profiling.step("train_epoch", epoch):
                     state, epoch_health, times = train_epoch(state, epoch)
                 jax.block_until_ready(state.params)  # honest wall-clock (SURVEY.md §7c)
                 wall_s = time.perf_counter() - t_epoch
                 t_eval = time.perf_counter()
-                with annotate("eval"):
+                with profiling.span("eval"):
                     evaluate(state, epoch * n_train)
                 if epoch_health is not None:
                     # SPMD-entered by every process (the norm program would

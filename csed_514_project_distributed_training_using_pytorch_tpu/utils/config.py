@@ -446,6 +446,9 @@ class LMConfig:
                                         # SingleProcessConfig.telemetry); "" off
     health_stats: bool = False          # in-scan training-health accumulators (see
                                         # SingleProcessConfig.health_stats)
+    profile: bool = False               # jax.profiler capture around the epoch loop:
+                                        # the loop's `epoch/*` spans beside the device ops
+    profile_dir: str = "results/profile"
     max_train_examples: int = 0
     max_test_examples: int = 0
 

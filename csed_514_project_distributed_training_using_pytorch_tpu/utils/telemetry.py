@@ -15,7 +15,9 @@ structured layer every perf PR proves its numbers through:
   ``compile``    AOT compile timing of the epoch program (``jit(...).lower().compile()``)
                  plus its ``cost_analysis()`` FLOPs
   ``epoch``      per epoch: wall/execute/eval/data-feed seconds, examples/s,
-                 compile_s, flops_per_step, train/val loss
+                 compile_s, flops_per_step, train/val loss; from a loop of
+                 ``utils.profiling`` spans (``train/lm.py``) every other phase
+                 too (log/emit/guard/checkpoint/tick seconds and ``period_s``)
   ``health``     per epoch when ``--health-stats`` is on: grad-norm mean/max, loss
                  min/max/mean, param norm — accumulated INSIDE the compiled scan
                  (see ``train/step.py``), zero extra host syncs on the hot path
@@ -283,8 +285,11 @@ def aot_compile(jit_fn, *args) -> tuple[object | None, dict | None]:
     mix concrete arrays and ``jax.ShapeDtypeStruct``s. ``(None, None)`` when the
     callee has no ``.lower`` (the cached-sharding compile wrappers) or lowering
     fails — callers then fall back to the ordinary jit path with compile time
-    folded into the first epoch.
+    folded into the first epoch; the first line of the swallowed exception is
+    logged, so a program too large for the chip says ``RESOURCE_EXHAUSTED``.
     """
+    if not hasattr(jit_fn, "lower"):
+        return None, None
     try:
         t0 = time.perf_counter()
         lowered = jit_fn.lower(*args)
@@ -292,7 +297,9 @@ def aot_compile(jit_fn, *args) -> tuple[object | None, dict | None]:
         t0 = time.perf_counter()
         compiled = lowered.compile()
         compile_s = time.perf_counter() - t0
-    except Exception:
+    except Exception as e:
+        reason = (str(e).strip().splitlines() or [""])[0]
+        M.log(f"aot_compile: falling back to jit ({type(e).__name__}: {reason})")
         return None, None
     return compiled, {"lower_s": lower_s, "compile_s": compile_s,
                       "flops": compiled_flops(compiled),
@@ -323,12 +330,23 @@ def epoch_event(epoch: int, *, examples: int, steps: int | None = None,
                 eval_s: float | None = None, data_s: float | None = None,
                 compile_s: float | None = None, flops_per_step: float | None = None,
                 train_loss: float | None = None, val_loss: float | None = None,
-                mfu: float | None = None) -> dict:
+                mfu: float | None = None, log_s: float | None = None,
+                emit_s: float | None = None, guard_s: float | None = None,
+                checkpoint_s: float | None = None, tick_s: float | None = None,
+                period_s: float | None = None) -> dict:
     """Per-epoch phase-timing record. ``execute_s`` is device execution of the epoch
     program (closed by a host fetch, SURVEY.md §7c); ``wall_s`` the whole epoch
     including host work; ``data_s`` index-plan/feed construction; ``compile_s`` the
     AOT epoch-program compile (constant per run, repeated per event so each line is
-    self-contained)."""
+    self-contained).
+
+    ``log_s`` … ``tick_s`` are the other phases of a loop that names all of its time
+    with ``utils.profiling`` spans (``train/lm.py``; README "Telemetry" has the table),
+    and ``period_s`` the time since the previous event's drain that the ``*_s`` span
+    fields are pieces of. The event is emitted before its own iteration ends, so it
+    holds what was drained at its emit: ``emit_s``, ``guard_s``, ``checkpoint_s`` and
+    the boundary half of ``tick_s`` are the PREVIOUS iteration's tail. ``null`` from a
+    trainer whose loop has no such span."""
     ex = _finite(execute_s)
     return {
         "event": "epoch",
@@ -339,6 +357,12 @@ def epoch_event(epoch: int, *, examples: int, steps: int | None = None,
         "execute_s": ex,
         "eval_s": _finite(eval_s),
         "data_s": _finite(data_s),
+        "log_s": _finite(log_s),
+        "emit_s": _finite(emit_s),
+        "guard_s": _finite(guard_s),
+        "checkpoint_s": _finite(checkpoint_s),
+        "tick_s": _finite(tick_s),
+        "period_s": _finite(period_s),
         "compile_s": _finite(compile_s),
         "examples_per_s": _finite(examples / ex if ex else None),
         "steps_per_s": _finite(steps / ex if ex and steps else None),

@@ -116,6 +116,19 @@ def test_aot_compile_times_and_prices_a_jit_program():
     assert T.aot_compile(lambda x: x, jnp.ones(())) == (None, None)
 
 
+def test_aot_compile_says_why_it_fell_back(capsys):
+    """A program the compiler refuses (on the chip: RESOURCE_EXHAUSTED) degrades to
+    ``(None, None)`` as before, and the first line of the reason is logged."""
+    def refuses(x):
+        raise ValueError("RESOURCE_EXHAUSTED: Used 15.86G of 15.75G hbm\nsecond line")
+
+    assert T.aot_compile(jax.jit(refuses), jnp.ones(())) == (None, None)
+    out = capsys.readouterr().out
+    assert "aot_compile: falling back to jit (ValueError: RESOURCE_EXHAUSTED: Used " \
+           "15.86G of 15.75G hbm)" in out
+    assert "second line" not in out
+
+
 # ------------------------------------------------------- health-stats equivalence
 
 
