@@ -98,7 +98,9 @@ def main() -> int:
     parser = argparse.ArgumentParser()
     parser.add_argument("--out", default=None, help="also append JSONL here")
     parser.add_argument("--seq-lens", type=int, nargs="+", default=list(SEQ_LENS),
-                        help="sequence lengths to measure (must divide by 128); "
+                        help="sequence lengths to measure (one that does not divide "
+                             "by 128 is padded at the tail by the flash path, to a "
+                             "multiple of --block where given: the mask is causal); "
                              "small values make the tool drivable on CPU interpret mode")
     parser.add_argument("--plot", default=None,
                         help="also save the flash-vs-dense curve PNG here")

@@ -45,9 +45,10 @@ def main(argv=None) -> int:
     p.add_argument("--bf16", action=argparse.BooleanOptionalAction, default=True,
                    help="bfloat16 activations (f32 master weights) — the MXU dtype")
     p.add_argument("--flash", action=argparse.BooleanOptionalAction, default=False,
-                   help="measured-crossover attention dispatch (dense below "
-                        "FLASH_MIN_SEQ where dense is faster, Pallas flash at and "
-                        "above — the flag never regresses throughput)")
+                   help="attention dispatched by the size of the float32 score "
+                        "tensor (ops.dispatch_plan: dense while B*H*S*S*4 bytes stay "
+                        "on-chip, Pallas flash once they would go through HBM — the "
+                        "flag never regresses throughput)")
     args = p.parse_args(argv)
     _lg = args.large
     for name, small, large in (("d_model", 256, 1024), ("seq", 256, 2048),
@@ -86,16 +87,17 @@ def main(argv=None) -> int:
     flash_layout = None
     if args.flash:
         from csed_514_project_distributed_training_using_pytorch_tpu.ops.pallas_attention import (
-            _native_layout_default, dispatch_attention, dispatch_uses_flash,
-            native_mode,
+            dispatch_attention, dispatch_plan,
         )
         model_kwargs["attention_fn"] = dispatch_attention
         # Record what the dispatcher actually runs at this shape — a row labelled
         # "flash" must not have timed the dense path — and which LAYOUT the env
         # knobs select, so a capture file's name can't misstate what it timed.
-        attn_impl = "flash" if dispatch_uses_flash(s) else "dense"
-        flash_layout = (f"native-{native_mode(e // args.heads)}"
-                        if _native_layout_default() else "packed")
+        plan = dispatch_plan((b, s, args.heads, e // args.heads))
+        attn_impl = plan["impl"]
+        flash_layout = plan["layout"]      # None on the dense path
+        if flash_layout in ("strided", "unroll"):
+            flash_layout = f"native-{flash_layout}"
     model = TransformerClassifier(**model_kwargs)
 
     rng = np.random.default_rng(0)

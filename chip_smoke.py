@@ -301,11 +301,12 @@ def _check_flash() -> dict:
     )
     from csed_514_project_distributed_training_using_pytorch_tpu.ops.pallas_attention import (
         dispatch_attention,
-        dispatch_uses_flash,
+        dispatch_plan,
     )
 
     b, s, h, d = FLASH_SHAPE
-    _require(dispatch_uses_flash(s), f"dispatch_attention gives way to dense at S={s}")
+    _require(dispatch_plan(FLASH_SHAPE, causal=True)["impl"] == "flash",
+             f"dispatch_attention gives way to dense at {FLASH_SHAPE}")
     rng = np.random.default_rng(4)
     q, k, v = (jnp.asarray(rng.normal(size=(b, s, h, d)), jnp.bfloat16)
                for _ in range(3))

@@ -107,9 +107,10 @@ class TransformerLM(fnn.Module):
     dropout_rate: float = 0.0
     attention_fn: Callable = ops.full_attention
     attention_window: int = 0   # sliding-window causal attention over the pixel
-                                # stream (0 = full); composes with the DEFAULT dense
-                                # core only — the KV-cache decode path honors the
-                                # same window, keeping the decode-parity invariant
+                                # stream (0 = full); composes with the default dense
+                                # core and ``ops.dispatch_attention`` only — the
+                                # KV-cache decode path honors the same window,
+                                # keeping the decode-parity invariant
     rope: bool = False          # rotary position embeddings on q/k; when set, the
                                 # learned additive pos_embed is skipped (RoPE owns
                                 # position) — decode rotates its single position by
@@ -121,11 +122,12 @@ class TransformerLM(fnn.Module):
     def _attention_fn(self) -> Callable:
         if not self.attention_window:
             return self.attention_fn
-        if self.attention_fn is not ops.full_attention:
+        if self.attention_fn not in (ops.full_attention, ops.dispatch_attention):
             raise ValueError(
-                "attention_window composes with the default dense core only — "
-                "bake the window into your custom attention_fn instead")
-        return ops.attention.windowed_attention_fn(self.attention_window)
+                "attention_window composes with the dense core and the dispatcher "
+                "only — bake the window into your custom attention_fn instead")
+        ops.attention.validate_window(self.attention_window)
+        return functools.partial(self.attention_fn, window=self.attention_window)
 
     @fnn.compact
     def __call__(self, ids: jax.Array, *, deterministic: bool = True) -> jax.Array:

@@ -24,6 +24,7 @@ from csed_514_project_distributed_training_using_pytorch_tpu.ops.attention impor
 )
 from csed_514_project_distributed_training_using_pytorch_tpu.ops.pallas_attention import (
     dispatch_attention,
+    dispatch_plan,
     flash_attention,
 )
 from csed_514_project_distributed_training_using_pytorch_tpu.ops.initializers import (
@@ -46,6 +47,7 @@ __all__ = [
     "full_attention",
     "flash_attention",
     "dispatch_attention",
+    "dispatch_plan",
     "torch_kaiming_uniform",
     "torch_fan_in_uniform",
 ]

@@ -52,8 +52,9 @@ amortize grid/pipeline overhead per step against more VMEM per block; tune with
 Like the other Pallas modules: compiled on TPU, interpret mode elsewhere (the CPU test
 platform), numerics pinned against ``ops.attention.full_attention`` in
 ``tests/test_pallas_attention.py`` (hardware-gated Mosaic re-check included). Sequences
-must divide by the chosen ``block``; callers wanting odd lengths use the dense path
-(the transformer family's default).
+must divide by the chosen ``block``; ``flash_attention`` zero-pads a CAUSAL call of any
+other length at the tail (exact under the mask), a non-causal one takes the dense path
+(``dispatch_attention``).
 """
 
 from __future__ import annotations
@@ -82,7 +83,14 @@ BLOCK = 128            # base block rows (lane-aligned, MXU-shaped): the layout 
 MAX_AUTO_BLOCK = 1024  # r4 v5e sweep (bench_results/hw_r4/bench_attention_blocktune
                        # .jsonl): per-op time falls monotonically 128→1024 at every
                        # S >= 1024 (3.3× at S=2048), and 2048 hits the Mosaic
-                       # VMEM/compile wall — 1024 is the measured sweet spot
+                       # VMEM/compile wall — 1024 is the measured sweet spot. Below
+                       # it the same holds down to one whole-sequence block (PR 25,
+                       # bench_results/hw_pr25/bench_attention_dispatch_tpu.jsonl,
+                       # B16·H8·D128 bf16 causal fwd+bwd): at 784→pad, 896 at 896
+                       # 1.86 ms, 1024 at 1024 2.29, 512 at 1024 2.72, 256 at 1024
+                       # 4.97, 128 at 896 9.00; 640 1.06 against its divisor 128's
+                       # 3.99, 768 1.43 against 256's 2.76, 384 0.59 against 1.54;
+                       # under a window of 256 too (896 1.90; 512 2.89, 128 5.38)
 
 MAX_AUTO_BLOCK_WINDOWED = 512  # banded grids do O(S·(W+block)) work, so oversize
                                # blocks defeat NARROW bands: b512 beats b1024
@@ -99,10 +107,27 @@ WIDE_WINDOW = 4096             # smallest window the full MAX_AUTO_BLOCK cap is
                                # (256, 4096) — untested widths take the
                                # conservative side)
 
-FLASH_MIN_SEQ = 2048   # measured flash/dense crossover on TPU v5e (same capture),
-                       # windowed and not: dense wins 1.5-5× below (XLA keeps the
-                       # whole score tile on-chip), flash wins 4.1-6.9× at and
-                       # above (21× banded at S=8192 W=256)
+# The dense/flash crossover on TPU v5e (bf16 causal fwd+bwd; hw_r4/bench_attention_tpu
+# .jsonl at B = 1 and PR 25's bench_results/hw_pr25/bench_attention_dispatch_tpu.jsonl;
+# the table is in PERF.md §6). What dense costs follows the BYTES of its float32 score
+# tensor whatever S and D, in two steps as they stop fitting on-chip (128 MiB of VMEM):
+# 2.6-3.4 ns/KB at 33.5-39 MB, 5.4-6.5 at 59-100 MB (0.36-0.38 ms at 67 MB with S = 256,
+# 512 and 1024 alike), 14-19 at 134-315 MB. What flash costs is 3-4 µs a (batch, head)
+# program and kernel plus its S² work (4.0 µs at S = 256 with D = 32, 64 and 128 alike,
+# 5.7 at 512, 14.6 at 896).
+
+FLASH_MIN_SCORE_BYTES = 64 << 20        # B·H·S_q·S_k·4 per device: the smallest size
+                       # measured to win at every S from 512 up (1.05-1.36× at 67 MB,
+                       # 1.3× at 79-100, 2.6-4.8× at 134, 2.8-3.2× at 164-315, the
+                       # benchmark's cell); it loses at 33.5 and 39 MB (0.67×) and wins
+                       # at 59 (1.29×, S = 784): the crossover lies between
+
+FLASH_MIN_HEAD_SCORE_BYTES = 1 << 20    # S_q·S_k·4 of ONE (batch, head), = S 512: a
+                       # smaller tile never pays a program's fixed cost back (S = 256:
+                       # 0.39-0.55× at 67 MB, 0.85-1.05× at 134-268 MB, the r3 trainer's
+                       # shape; S = 384: 0.86× at 75 MB; S = 512: level at 67 MB,
+                       # 2.6-2.7× at 134). S = 384 above 128 MiB is untested and takes
+                       # the dense side
 
 
 NATIVE_BLOCK_ELEMS = 262144  # native-layout block·H·D cap (elements per operand
@@ -115,13 +140,15 @@ NATIVE_BLOCK_ELEMS = 262144  # native-layout block·H·D cap (elements per opera
 
 
 def auto_block(s: int, window: int = 0, native_hd: int | None = None) -> int:
-    """Largest lane-aligned block ≤ the measured per-regime cap that tiles ``s``
-    evenly — the measured-fastest choice per shape (see ``MAX_AUTO_BLOCK`` /
+    """``s`` itself when one block holds it (``MAX_AUTO_BLOCK``), else the largest
+    power-of-two block ≤ the measured per-regime cap that tiles ``s`` evenly — the
+    measured-fastest choice per shape (see ``MAX_AUTO_BLOCK`` /
     ``MAX_AUTO_BLOCK_WINDOWED``). ``native_hd`` (= H·D, the flat row width)
     caps the native layout's block·H·D VMEM product (``NATIVE_BLOCK_ELEMS``);
     packed callers leave it ``None``."""
     cap = (MAX_AUTO_BLOCK_WINDOWED if 0 < window < WIDE_WINDOW
            else MAX_AUTO_BLOCK)
+    whole = MAX_AUTO_BLOCK      # the longest sequence that rides in one block
     if native_hd is not None:
         if 128 * native_hd > NATIVE_BLOCK_ELEMS:
             # Even the smallest legal block would bust the measured scoped-vmem
@@ -130,7 +157,12 @@ def auto_block(s: int, window: int = 0, native_hd: int | None = None) -> int:
                 f"native-layout flash cannot tile heads*head_dim={native_hd}: "
                 f"128*{native_hd} exceeds the {NATIVE_BLOCK_ELEMS}-element "
                 f"VMEM envelope; use the packed layout for this shape")
-        cap = min(cap, NATIVE_BLOCK_ELEMS // native_hd)
+        whole = min(whole, NATIVE_BLOCK_ELEMS // native_hd)
+        cap = min(cap, whole)
+    if s % BLOCK == 0 and s <= whole:
+        # A sequence that fits one block takes one, banded or not: that short, a
+        # grid step's fixed cost outweighs what a band or a causal skip saves.
+        return s
     for b in (1024, 512, 256, 128):
         if b <= min(s, cap) and s % b == 0:
             return b
@@ -424,6 +456,8 @@ def _pallas_dispatch(kernel, lay, nq: int, steps: int, in_specs, out_specs,
     dispatch through here): traced offsets ride scalar prefetch
     (``PrefetchScalarGridSpec`` — the scalar is the first operand and reaches the
     index maps as their trailing arg), static paths use the plain grid."""
+    # The kernel's name on a device trace: flash_fwd, flash_dq, flash_dkv.
+    name = "flash" + kernel.func.__name__.removesuffix("_kernel")
     if dyn:
         return pl.pallas_call(
             kernel,
@@ -431,11 +465,11 @@ def _pallas_dispatch(kernel, lay, nq: int, steps: int, in_specs, out_specs,
                 num_scalar_prefetch=1, grid=lay.grid(nq, steps),
                 in_specs=in_specs, out_specs=out_specs,
                 scratch_shapes=scratch_shapes),
-            out_shape=out_shape, interpret=_interpret())
+            out_shape=out_shape, interpret=_interpret(), name=name)
     return pl.pallas_call(
         kernel, grid=lay.grid(nq, steps), in_specs=in_specs,
         out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch_shapes,
-        interpret=_interpret())
+        interpret=_interpret(), name=name)
 
 
 def _dispatch_block(body, qi, ki, bq, bk, in_range, *, causal: bool,
@@ -954,23 +988,30 @@ def flash_backward_blocks(qx, kx, vx, g, lse, delta, *, causal: bool,
 @functools.lru_cache(maxsize=None)
 def _make_op(causal: bool, block: int = BLOCK, window: int = 0,
              heads: int | None = None, per_head_grid: bool = False):
+    # The two halves are jitted, and this factory is cached: every layer of a
+    # model calls the same two functions, so a program traces and lowers the three
+    # kernels once, not once a layer (PR 25: the 24 Pallas calls of the 8-layer LM,
+    # lowered in each of three programs, put 10 s on a 42 s warm start).
+    kw = dict(causal=causal, block=block, window=window, heads=heads,
+              per_head_grid=per_head_grid)
+
+    @jax.jit
+    def flash_forward(q3, k3, v3):
+        return _flash_forward(q3, k3, v3, **kw)
+
+    @jax.jit
+    def flash_backward(res, g):
+        return _flash_backward(res, g, **kw)
+
     @jax.custom_vjp
     def op(q3, k3, v3):
-        out, _ = _flash_forward(q3, k3, v3, causal=causal, block=block,
-                                window=window, heads=heads,
-                                per_head_grid=per_head_grid)
-        return out
+        return flash_forward(q3, k3, v3)[0]
 
     def fwd(q3, k3, v3):
-        out, lse = _flash_forward(q3, k3, v3, causal=causal, block=block,
-                                  window=window, heads=heads,
-                                  per_head_grid=per_head_grid)
+        out, lse = flash_forward(q3, k3, v3)
         return out, (q3, k3, v3, out, lse)
 
-    def bwd(res, g):
-        return _flash_backward(res, g, causal=causal, block=block,
-                               window=window, heads=heads,
-                               per_head_grid=per_head_grid)
+    bwd = flash_backward
 
     op.defvjp(fwd, bwd)
     return op
@@ -1032,9 +1073,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     native_layout: bool | None = None) -> jax.Array:
     """Drop-in for ``ops.full_attention``: ``[B, S, H, D]`` → ``[B, S, H, D]``.
 
-    Requires ``S % block == 0`` with ``block`` a multiple of 128 (lane-aligned);
-    ``block=None`` (the default) picks the measured-fastest size for the shape via
-    ``auto_block``. Differentiable via the two-kernel flash backward; usable as the
+    Requires ``S % block == 0`` with ``block`` a multiple of 128 (lane-aligned), or
+    ``causal=True``: a causal call of any other length is zero-padded at the tail to
+    the next multiple of ``block``, run through the kernels and sliced, which the
+    mask makes exact. ``block=None`` (the default) picks the measured-fastest size
+    for the shape via ``auto_block``. Differentiable via the two-kernel flash backward; usable as the
     transformer family's ``attention_fn``. ``block`` is a pure performance knob
     (numerics are block-invariant — pinned in tests); tune it with
     ``bench_attention.py --block``. ``native_layout`` (default: the
@@ -1055,30 +1098,61 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     dominated at S ≥ 64k. Out-of-band blocks cost nothing: they are never stepped.
     """
     b, s, h, d = q.shape
+    validate_window(window)
+    layout, padded, block = _flash_plan(s, h, d, causal=causal, window=window,
+                                        block=block, native_layout=native_layout)
+    if padded != s:
+        # Exact under the causal mask: padded keys lie after every real query and
+        # are masked; padded query rows are sliced away, so their dout and Δ are
+        # zero and they add nothing to dk/dv. jnp.pad and the slice differentiate
+        # as themselves around the custom-VJP op.
+        q, k, v = (jnp.pad(x, ((0, 0), (0, padded - s), (0, 0), (0, 0)))
+                   for x in (q, k, v))
+    if layout == "packed":
+        op = _make_op(bool(causal), block, int(window or 0))
+        to3 = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, padded, d)
+        out = jnp.transpose(op(to3(q), to3(k), to3(v)).reshape(b, h, padded, d),
+                            (0, 2, 1, 3))
+    else:
+        # [B, S, H, D] → [B, S, H·D] is a free contiguous view (the repack the
+        # packed path pays is the S↔H transpose above, not this reshape).
+        op = _make_op(bool(causal), block, int(window or 0), heads=h,
+                      per_head_grid=layout == "strided")
+        flat = lambda x: x.reshape(b, padded, h * d)
+        out = op(flat(q), flat(k), flat(v)).reshape(b, padded, h, d)
+    return out[:, :s] if padded != s else out
+
+
+def _flash_plan(s: int, h: int, d: int, *, causal: bool, window: int | None,
+                block: int | None = None,
+                native_layout: bool | None = None) -> tuple[str, int, int]:
+    """``(layout, padded length, block)`` of a ``flash_attention`` call: the one
+    place its tiling is decided, for the op itself and for ``dispatch_plan``.
+
+    ``layout`` is ``"packed"``, ``"strided"`` or ``"unroll"`` (``native_layout=None``:
+    the ``FLASH_NATIVE_LAYOUT`` knob; ``native_mode`` picks between the two native
+    forms). The strided form keeps packed-size [block, D] refs, so it takes the
+    packed caps; only the all-heads unroll form pays the block·H·D envelope. A
+    geometry whose SMALLEST legal block (128·H·D) already busts that envelope can't
+    run native-unroll at any block — with ``block=None`` that is a layout
+    preference, not a user contract, so fall back to the packed layout (same math,
+    repacks paid) with a warning rather than dying at trace time; explicitly
+    requested blocks keep the hard error. The padded length is ``s`` itself when it
+    is lane-aligned or the call is not causal (only a causal mask makes tail
+    padding exact; ``_check_block`` refuses the rest), else the next multiple of
+    ``block`` (of 128 when ``auto_block`` is to choose)."""
     if native_layout is None:
         native_layout = _native_layout_default()
-    strided = native_layout and native_mode(d) == "strided"
-    if block is None:
-        # The strided form keeps packed-size [block, D] refs, so it takes the
-        # packed caps; only the all-heads unroll form pays the block·H·D
-        # envelope. A geometry whose SMALLEST legal block (128·H·D) already
-        # busts that envelope can't run native-unroll at any block — for the
-        # auto path that is a layout preference, not a user contract, so fall
-        # back to the packed layout (same math, repacks paid) with a warning
-        # rather than dying at trace time; explicitly requested blocks below
-        # keep the hard error.
-        if (native_layout and not strided
-                and 128 * h * d > NATIVE_BLOCK_ELEMS):
-            warnings.warn(
-                f"native-layout flash cannot tile heads*head_dim={h * d} "
-                f"(128*{h * d} exceeds the {NATIVE_BLOCK_ELEMS}-element VMEM "
-                f"envelope); falling back to the packed layout for this shape",
-                stacklevel=2)
-            native_layout = False
-        block = auto_block(s, int(window or 0),
-                           native_hd=h * d if native_layout and not strided
-                           else None)
-    elif native_layout and not strided and block * h * d > NATIVE_BLOCK_ELEMS:
+    layout = native_mode(d) if native_layout else "packed"
+    unroll_elems = h * d if layout == "unroll" else None
+    if unroll_elems and block is None and 128 * unroll_elems > NATIVE_BLOCK_ELEMS:
+        warnings.warn(
+            f"native-layout flash cannot tile heads*head_dim={h * d} "
+            f"(128*{h * d} exceeds the {NATIVE_BLOCK_ELEMS}-element VMEM "
+            f"envelope); falling back to the packed layout for this shape",
+            stacklevel=3)
+        layout, unroll_elems = "packed", None
+    if unroll_elems and block and block * unroll_elems > NATIVE_BLOCK_ELEMS:
         # Explicit blocks get the same VMEM envelope the auto path respects:
         # native-flat blocks hold all H heads, so block·H·D is the real
         # working-set knob and oversizing it is a Mosaic scoped-vmem compile
@@ -1087,39 +1161,57 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             f"native-layout flash needs block*heads*head_dim <= "
             f"{NATIVE_BLOCK_ELEMS} (got {block}*{h}*{d} = {block * h * d}); "
             f"pass a smaller block or use the packed layout")
-    _check_block(s, block)
-    validate_window(window)
-    if native_layout:
-        # [B, S, H, D] → [B, S, H·D] is a free contiguous view (the repack the
-        # packed path pays is the S↔H transpose below, not this reshape).
-        op = _make_op(bool(causal), int(block), int(window or 0), heads=h,
-                      per_head_grid=strided)
-        return op(q.reshape(b, s, h * d), k.reshape(b, s, h * d),
-                  v.reshape(b, s, h * d)).reshape(b, s, h, d)
-    op = _make_op(bool(causal), int(block), int(window or 0))
-    to3 = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, s, d)
-    out3 = op(to3(q), to3(k), to3(v))
-    return jnp.transpose(out3.reshape(b, h, s, d), (0, 2, 1, 3))
+    pad_to = block or BLOCK
+    padded = s if s % BLOCK == 0 or not causal else -(-s // pad_to) * pad_to
+    if block is None:
+        block = auto_block(padded, int(window or 0), native_hd=unroll_elems)
+    _check_block(padded, block)
+    return layout, padded, int(block)
 
 
-def dispatch_uses_flash(s: int) -> bool:
-    """The routing predicate behind ``dispatch_attention`` — exported so callers
-    labelling measurements (bench_transformer.py) can't desync from the dispatch."""
-    return s >= FLASH_MIN_SEQ and s % 128 == 0
+def dispatch_plan(shape, *, causal: bool = False, window: int | None = None,
+                  k_len: int | None = None) -> dict:
+    """What ``dispatch_attention`` does with a per-device ``[B, S, H, D]`` call, from
+    its shapes alone: ``{impl, score_bytes, seq_padded, block, layout}``. The one
+    routing predicate: the dispatcher runs what this returns, and callers that label
+    a measurement or a telemetry event (``train/lm.py``'s ``compile`` event,
+    ``bench_transformer.py``, ``chip_smoke.py``) read the same dict, so a label
+    cannot desync from the dispatch.
+
+    ``impl`` is ``"flash"`` when the float32 score tensor the dense core would
+    materialise (``B·H·S_q·S_k·4`` bytes, forward and again backward) reaches
+    ``FLASH_MIN_SCORE_BYTES``, one (batch, head)'s tile of it reaches
+    ``FLASH_MIN_HEAD_SCORE_BYTES``, and the kernels can run the call:
+    self-attention (``S_q == S_k``) at a 128-aligned S, or any S under a causal
+    mask (padded at the tail, ``seq_padded``). Everything else is ``"dense"``.
+    Under ``jit`` over a mesh the shapes a trace sees are global: callers there
+    hand the dispatcher per-device calls (``shard_map``) or keep the dense core
+    (``train/lm.py``)."""
+    b, s, h, d = shape
+    s_k = s if k_len is None else k_len
+    plan = {"impl": "dense", "score_bytes": 4 * b * h * s * s_k,
+            "seq_padded": None, "block": None, "layout": None}
+    if (plan["score_bytes"] >= FLASH_MIN_SCORE_BYTES
+            and 4 * s * s_k >= FLASH_MIN_HEAD_SCORE_BYTES
+            and s_k == s and (causal or s % BLOCK == 0)):
+        layout, padded, block = _flash_plan(s, h, d, causal=causal, window=window)
+        plan.update(impl="flash", seq_padded=padded, block=block, layout=layout)
+    return plan
 
 
 def dispatch_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                        causal: bool = False,
                        window: int | None = None) -> jax.Array:
     """``full_attention``-compatible attention that picks the measured-faster
-    implementation per shape: XLA's dense path below ``FLASH_MIN_SEQ`` (where the
-    whole score tile stays on-chip and dense wins 1.5-5× on v5e), the flash
-    kernels at and above it (4.7-6.9× the other way; the crossover was measured
-    windowed too — 4.1× at S=2048 W=256) — so enabling ``--flash-attention`` can
-    never regress throughput the way the r3 trainer capture did (45.96 vs 86.09
-    steps/s at S=256, ``bench_results/hw_r3/bench_transformer_flash_tpu.json``).
-    Shapes the kernels cannot tile (S not a multiple of 128) also take the dense
-    path."""
-    if not dispatch_uses_flash(q.shape[1]):
+    implementation per call (``dispatch_plan``): XLA's dense path while the float32
+    scores are small enough to stay on-chip, the flash kernels once they would go
+    through HBM — so a caller that passes this as its ``attention_fn`` can never
+    regress throughput the way the r3 trainer capture did with the kernels forced
+    on (45.96 vs 86.09 steps/s at S=256,
+    ``bench_results/hw_r3/bench_transformer_flash_tpu.json``). Calls the kernels
+    cannot run (cross-attention, a non-causal S that is not a multiple of 128)
+    take the dense path."""
+    plan = dispatch_plan(q.shape, causal=causal, window=window, k_len=k.shape[1])
+    if plan["impl"] == "dense":
         return full_attention(q, k, v, causal=causal, window=window)
     return flash_attention(q, k, v, causal=causal, window=window)

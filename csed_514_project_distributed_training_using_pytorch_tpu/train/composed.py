@@ -294,9 +294,10 @@ def main(config: ComposedConfig = ComposedConfig(), *,
                 f"seq_axis·BLOCK = {max(seq_size, 1)}·{pa.BLOCK}, got "
                 f"{config.seq_len} (e.g. --seq-len {max(seq_size, 1) * pa.BLOCK})")
         # Ring-of-flash under a seq axis (flash kernels on every hop, trainable custom
-        # VJP); the measured-crossover dispatcher otherwise (dense below
-        # FLASH_MIN_SEQ, flash at and above — the flag can never regress throughput;
-        # windowed/banded when requested).
+        # VJP); the measured-crossover dispatcher otherwise (dense while the float32
+        # scores stay on-chip, flash once they would go through HBM:
+        # ops.dispatch_plan — the flag can never regress throughput; windowed/banded
+        # when requested).
         if seq_size > 1:
             attention_fn = make_ring_attention_fn(
                 mesh, use_flash=True, window=config.attention_window)

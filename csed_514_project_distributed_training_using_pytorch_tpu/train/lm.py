@@ -37,7 +37,7 @@ from csed_514_project_distributed_training_using_pytorch_tpu.models import lm as
 from csed_514_project_distributed_training_using_pytorch_tpu.models import (
     validate_remat_policy,
 )
-from csed_514_project_distributed_training_using_pytorch_tpu import resilience
+from csed_514_project_distributed_training_using_pytorch_tpu import ops, resilience
 from csed_514_project_distributed_training_using_pytorch_tpu.ops import optim
 from csed_514_project_distributed_training_using_pytorch_tpu.train.guard import (
     GuardRuntime,
@@ -66,6 +66,20 @@ from csed_514_project_distributed_training_using_pytorch_tpu.utils import (
 # The direct children of the epoch loop's `step("epoch", n)`, as `epoch/<name>` on a
 # trace and `<name>_s` in the `epoch` telemetry event.
 EPOCH_SPANS = ("data", "execute", "eval", "log", "emit", "guard", "checkpoint", "tick")
+
+
+def _attention_plan(config: LMConfig, seq_len: int, world: int, *,
+                    dispatched: bool) -> dict:
+    """The ``compile`` event's ``attention`` field: what the step's attention call
+    gets, by the dispatcher's own predicate on the per-device microbatch; where the
+    model keeps the dense core (``dispatched`` false) the same keys say so."""
+    plan = ops.dispatch_plan(
+        (config.batch_size // world // config.grad_accum, seq_len,
+         config.num_heads, config.embed_dim // config.num_heads),
+        causal=True, window=config.attention_window)
+    if not dispatched:
+        plan.update(impl="dense", seq_padded=None, block=None, layout=None)
+    return plan
 
 
 def make_eval_nll_fn(model: lm_mod.TransformerLM, *, batch_size: int):
@@ -213,6 +227,13 @@ def main(config: LMConfig = LMConfig(), *,
         lm_kwargs["attention_fn"] = make_ring_attention_fn(
             mesh, use_zigzag=config.zigzag_attention,
             window=config.attention_window)
+    elif mesh.size == 1:
+        # One device: the core is picked per call from its shapes (dense while the
+        # float32 scores stay on-chip, the flash kernels once they would go through
+        # HBM: ops.dispatch_plan). On a mesh of several devices the dense core stays:
+        # jit's partitioner cannot split a Pallas call over the data axis, and no
+        # shard_map wraps it here yet.
+        lm_kwargs["attention_fn"] = ops.dispatch_attention
     # Fail fast on sampling knobs: generate() re-checks these, but its first call is
     # AFTER the full training loop — a bad flag must not cost the whole run.
     if not 0 <= config.top_k <= vocab + 1:
@@ -233,8 +254,7 @@ def main(config: LMConfig = LMConfig(), *,
     # window as a model field so the KV-cache decode mask applies the same band the
     # (possibly ring-windowed) training attention did — decode parity holds across
     # the mesh choice because attention has no window-dependent parameters.
-    from csed_514_project_distributed_training_using_pytorch_tpu import ops as _ops
-    decode_model = (model.clone(attention_fn=_ops.full_attention,
+    decode_model = (model.clone(attention_fn=ops.full_attention,
                                 attention_window=config.attention_window)
                     if seq_size > 1 else model)
     M.log(f"LM training: mesh {dict(mesh.shape)} on {info.process_count} process(es), "
@@ -370,8 +390,11 @@ def main(config: LMConfig = LMConfig(), *,
                 flops_per_step = aot["flops"] / steps_per_epoch
             if aot.get("bytes_accessed"):
                 bytes_per_step = aot["bytes_accessed"] / steps_per_epoch
+            attention = None if seq_size > 1 else _attention_plan(
+                config, seq_len, world, dispatched=mesh.size == 1)
             tele.emit(T.compile_event("epoch", aot,
-                                      steps_per_call=steps_per_epoch))
+                                      steps_per_call=steps_per_epoch,
+                                      attention=attention))
     history = M.MetricsHistory()
     saver = checkpoint.make_saver(config.async_checkpoint, tele=tele)
 

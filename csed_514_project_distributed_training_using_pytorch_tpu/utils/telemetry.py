@@ -306,8 +306,12 @@ def aot_compile(jit_fn, *args) -> tuple[object | None, dict | None]:
                       "bytes_accessed": compiled_bytes_accessed(compiled)}
 
 
-def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None) -> dict:
-    """The ``compile`` event for one AOT-timed program."""
+def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
+                  attention: dict | None = None) -> dict:
+    """The ``compile`` event for one AOT-timed program. ``attention``: which core the
+    program's attention calls get and why (``ops.dispatch_plan``'s dict: ``impl``,
+    ``score_bytes``, ``seq_padded``, ``block``, ``layout``), for the trainers that
+    route through the dispatcher."""
     flops = aot.get("flops")
     return {
         "event": "compile",
@@ -322,6 +326,7 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None)
         "bytes_accessed_per_step": _finite(
             aot["bytes_accessed"] / steps_per_call
             if aot.get("bytes_accessed") and steps_per_call else None),
+        "attention": attention,
     }
 
 

@@ -247,6 +247,11 @@ def test_auto_block_selection():
     assert auto_block(1024) == 1024
     assert auto_block(8192) == 1024      # capped at the measured sweet spot
     assert auto_block(1280) == 256       # largest divisor under the cap
+    # One block holds a sequence up to the cap, banded or not (PR 25 hw sweep:
+    # 896 at 896 beats the 128 that divides it 4.8x, 2.8x under a window of 256).
+    assert auto_block(896) == 896
+    assert auto_block(896, window=256) == 896
+    assert auto_block(640, native_hd=512) == 128   # the all-heads VMEM envelope holds
     # Windowed cap is W-dependent (r5 hw sweeps): narrow bands keep the 512
     # windowed cap; wide bands (W >= WIDE_WINDOW) amortize like the full walk.
     assert auto_block(8192, window=256) == 512
@@ -256,9 +261,10 @@ def test_auto_block_selection():
 
 
 def test_dispatch_attention_routes_by_crossover(monkeypatch):
-    """Below FLASH_MIN_SEQ (and for unaligned S) dispatch is exactly the dense
-    path; at and above it, the flash kernels (checked by matching each impl's own
-    output bit-for-bit, which also pins the routing)."""
+    """Under FLASH_MIN_SCORE_BYTES of float32 scores, or FLASH_MIN_HEAD_SCORE_BYTES
+    of them a (batch, head) (and for a non-causal unaligned S) dispatch is exactly
+    the dense path; at and above both, the flash kernels (checked by matching each impl's own output bit-for-bit, which also
+    pins the routing)."""
     import csed_514_project_distributed_training_using_pytorch_tpu.ops.pallas_attention as pa
 
     q, k, v = _qkv(s=256, seed=7)
@@ -269,7 +275,8 @@ def test_dispatch_attention_routes_by_crossover(monkeypatch):
     np.testing.assert_array_equal(
         np.asarray(pa.dispatch_attention(qo, ko, vo)),
         np.asarray(full_attention(qo, ko, vo)))
-    monkeypatch.setattr(pa, "FLASH_MIN_SEQ", 256)
+    monkeypatch.setattr(pa, "FLASH_MIN_SCORE_BYTES", 4 * 2 * 2 * 256 * 256)
+    monkeypatch.setattr(pa, "FLASH_MIN_HEAD_SCORE_BYTES", 4 * 256 * 256)
     np.testing.assert_array_equal(
         np.asarray(pa.dispatch_attention(q, k, v, causal=True)),
         np.asarray(flash_attention(q, k, v, causal=True)))
