@@ -17,6 +17,8 @@ from csed_514_project_distributed_training_using_pytorch_tpu.ops.nn import (
     dropout,
     dropout2d,
     layer_norm,
+    rms_norm,
+    swiglu,
     gelu,
 )
 from csed_514_project_distributed_training_using_pytorch_tpu.ops.attention import (
@@ -43,6 +45,8 @@ __all__ = [
     "dropout",
     "dropout2d",
     "layer_norm",
+    "rms_norm",
+    "swiglu",
     "gelu",
     "full_attention",
     "flash_attention",

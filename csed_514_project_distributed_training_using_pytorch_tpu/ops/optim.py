@@ -117,6 +117,21 @@ def adamw(learning_rate: float, *, b1: float = 0.9, b2: float = 0.999,
                                   "weight_decay": weight_decay})
 
 
+def freeze(optimizer: Optimizer, is_frozen: Callable) -> Optimizer:
+    """``optimizer`` with the leaves whose path ``is_frozen(path)`` names held as they
+    are: out of the update and of its weight decay (a constant that sits in the
+    parameter tree so that checkpoints and seeded weights carry it, such as an expert
+    layer's selection bias). Their moments stay whatever the gradient makes of them."""
+
+    def update(params, opt_state, grads, **kwargs):
+        new_params, new_state = optimizer.update(params, opt_state, grads, **kwargs)
+        return jax.tree_util.tree_map_with_path(
+            lambda path, old, new: old if is_frozen(path) else new,
+            params, new_params), new_state
+
+    return optimizer._replace(update=update)
+
+
 def make_optimizer(name: str, *, learning_rate: float, momentum: float,
                    weight_decay: float = 0.0) -> Optimizer:
     """CLI-name → ``Optimizer`` (the trainers' ``--optimizer`` surface)."""

@@ -1,8 +1,9 @@
 """Rotary position embeddings (RoPE) — relative positions by rotation.
 
 Beyond-parity op (the reference has no attention at all, reference
-``src/model.py:4-22``): the standard RoPE formulation — each head-dim pair
-``(2i, 2i+1)`` rotates by ``pos / base^(2i/D)`` radians — giving attention scores that
+``src/model.py:4-22``): RoPE in the half-split pairing — head dims ``i`` and
+``i + D/2`` rotate together by ``pos / base^(2i/D)`` radians (the pairing of the
+published decoders' checkpoints; ``base`` is their ``rope_theta``) — giving scores that
 depend only on RELATIVE query/key distance (``⟨R(p)q, R(p')k⟩`` is a function of
 ``p - p'``; pinned as the shift-invariance property in ``tests/test_rotary.py``).
 

@@ -33,6 +33,7 @@ from csed_514_project_distributed_training_using_pytorch_tpu.data import (
 from csed_514_project_distributed_training_using_pytorch_tpu.data import (
     stream as stream_mod,
 )
+from csed_514_project_distributed_training_using_pytorch_tpu.models import hybrid_lm
 from csed_514_project_distributed_training_using_pytorch_tpu.models import lm as lm_mod
 from csed_514_project_distributed_training_using_pytorch_tpu.models import (
     validate_remat_policy,
@@ -69,23 +70,28 @@ EPOCH_SPANS = ("data", "execute", "eval", "log", "emit", "guard", "checkpoint", 
 
 
 def _attention_plan(config: LMConfig, seq_len: int, world: int, *,
-                    dispatched: bool) -> dict:
+                    dispatched: bool, heads: int | None = None,
+                    head_dim: int | None = None) -> dict:
     """The ``compile`` event's ``attention`` field: what the step's attention call
     gets, by the dispatcher's own predicate on the per-device microbatch; where the
-    model keeps the dense core (``dispatched`` false) the same keys say so."""
+    model keeps the dense core (``dispatched`` false) the same keys say so. ``heads``
+    and ``head_dim`` are the flags' unless the model came from a file."""
+    heads = heads or config.num_heads
     plan = ops.dispatch_plan(
-        (config.batch_size // world // config.grad_accum, seq_len,
-         config.num_heads, config.embed_dim // config.num_heads),
+        (config.batch_size // world // config.grad_accum, seq_len, heads,
+         head_dim or config.embed_dim // heads),
         causal=True, window=config.attention_window)
     if not dispatched:
         plan.update(impl="dense", seq_padded=None, block=None, layout=None)
     return plan
 
 
-def make_eval_nll_fn(model: lm_mod.TransformerLM, *, batch_size: int):
+def make_eval_nll_fn(model, *, batch_size: int):
     """``evaluate(params, tokens) -> sum_nll`` — summed next-token NLL over the split
-    (divide by ``N·S`` for the mean; ``exp`` of that is perplexity), one scanned
-    program like the classifier's eval."""
+    (divide by ``N·S`` for the mean, ``N·(S-1)`` for a ``HybridLM``, which has no BOS;
+    ``exp`` of that is perplexity), one scanned program like the classifier's eval."""
+
+    hybrid = isinstance(model, hybrid_lm.HybridLM)
 
     def evaluate(params, tokens):
         n = tokens.shape[0]
@@ -95,6 +101,8 @@ def make_eval_nll_fn(model: lm_mod.TransformerLM, *, batch_size: int):
         xs = tokens.reshape((n // batch_size, batch_size) + tokens.shape[1:])
 
         def body(carry, batch):
+            if hybrid:
+                return carry + model.nll(params, batch)[0], None
             log_probs = model.apply({"params": params}, model.shift_right(batch))
             nll = -jnp.sum(jnp.take_along_axis(log_probs, batch[..., None], axis=-1))
             return carry + nll, None
@@ -125,6 +133,9 @@ def main(config: LMConfig = LMConfig(), *,
                             or config.num_heads % config.kv_heads):
         raise ValueError(f"--kv-heads {config.kv_heads} must be a positive divisor "
                          f"of --num-heads {config.num_heads}")
+    if config.model_config and not config.corpus:
+        raise ValueError("--model-config trains on a token corpus: pass --corpus DIR "
+                         "(tools/build_corpus.py) whose vocabulary is the file's")
     info = initialize_cluster()
     run_plan, plan_events = None, []
     if config.plan:
@@ -240,16 +251,33 @@ def main(config: LMConfig = LMConfig(), *,
         raise ValueError(f"top_k {config.top_k} outside [0, {vocab + 1}]")
     if not 0.0 < config.top_p <= 1.0:
         raise ValueError(f"top_p {config.top_p} outside (0, 1]")
-    model = lm_mod.TransformerLM(
-        vocab_size=vocab + 1, seq_len=seq_len,
-        embed_dim=config.embed_dim, num_layers=config.num_layers,
-        num_heads=config.num_heads, dropout_rate=config.dropout_rate,
-        num_kv_heads=config.kv_heads or None,
-        attention_window=(0 if seq_size > 1 else config.attention_window),
-        rope=config.rope,
-        dtype=jnp.bfloat16 if config.bf16 else jnp.float32, remat=config.remat,
-        remat_policy=config.remat_policy,
-        **lm_kwargs)
+    if config.model_config:
+        # A published architecture from its file (or one chip's share of it): the
+        # same loop, optimizer, telemetry and spans; only the model and its loss
+        # differ.
+        if mesh.size > 1 or config.attention_window:
+            # jit's partitioner cannot split the expert layer's (or the flash)
+            # Pallas calls over a data axis, and no shard_map wraps them here yet.
+            raise ValueError("--model-config trains on one device for now (--mesh "
+                             "data=1), and takes no --attention-window")
+        model = hybrid_lm.from_config_file(
+            config.model_config, vocab_size=vocab, seq_len=seq_len,
+            dtype=jnp.bfloat16 if config.bf16 else jnp.float32, remat=config.remat,
+            **lm_kwargs)
+    else:
+        model = lm_mod.TransformerLM(
+            vocab_size=vocab + 1, seq_len=seq_len,
+            embed_dim=config.embed_dim, num_layers=config.num_layers,
+            num_heads=config.num_heads, dropout_rate=config.dropout_rate,
+            num_kv_heads=config.kv_heads or None,
+            attention_window=(0 if seq_size > 1 else config.attention_window),
+            rope=config.rope,
+            dtype=jnp.bfloat16 if config.bf16 else jnp.float32, remat=config.remat,
+            remat_policy=config.remat_policy,
+            **lm_kwargs)
+    hybrid = isinstance(model, hybrid_lm.HybridLM)
+    # Targets a sequence: a HybridLM has no BOS, so its first token is context only.
+    targets_per_seq = seq_len - 1 if hybrid else seq_len
     # Decoding is single-chip (host params): restore the default core, and the
     # window as a model field so the KV-cache decode mask applies the same band the
     # (possibly ring-windowed) training attention did — decode parity holds across
@@ -258,7 +286,7 @@ def main(config: LMConfig = LMConfig(), *,
                                 attention_window=config.attention_window)
                     if seq_size > 1 else model)
     M.log(f"LM training: mesh {dict(mesh.shape)} on {info.process_count} process(es), "
-          f"batch {config.batch_size}, vocab {vocab}+BOS, "
+          f"batch {config.batch_size}, vocab {vocab}{'' if hybrid else '+BOS'}, "
           f"seq {seq_len}, data source: {data_source}")
     # Telemetry + resilience wiring live ABOVE the resume so the restore is recorded;
     # resilience hooks are flag-gated, host-side only (zero-cost when off).
@@ -282,6 +310,8 @@ def main(config: LMConfig = LMConfig(), *,
                                      learning_rate=config.learning_rate,
                                      momentum=config.momentum,
                                      weight_decay=config.weight_decay)
+    if hybrid:
+        optimizer = optim.freeze(optimizer, hybrid_lm.is_frozen)
     state = create_train_state(model, jax.random.PRNGKey(config.seed),
                                sample_input_shape=(1, seq_len),
                                optimizer=optimizer, ema=config.ema_decay > 0,
@@ -347,6 +377,8 @@ def main(config: LMConfig = LMConfig(), *,
 
     def lm_loss(params, xs, ys, rng):
         del ys  # the target stream IS the input stream, shifted inside the loss
+        if hybrid:      # (loss, rows that arrived at each held expert)
+            return model.loss(params, xs)
         return lm_mod.next_token_loss(model, params, xs, rng,
                                       deterministic=deterministic,
                                       label_smoothing=config.label_smoothing)
@@ -357,8 +389,10 @@ def main(config: LMConfig = LMConfig(), *,
                               optimizer=optimizer, lr_schedule=lr_schedule,
                               clip_grad_norm=config.clip_grad_norm,
                               ema_decay=config.ema_decay, loss_fn=lm_loss,
-                              with_metrics=health, guard=grt.spec)
-    epoch_fn = compile_lm_epoch(make_epoch_from_step(step_fn, health=health))
+                              with_metrics=health, guard=grt.spec,
+                              loss_has_aux=hybrid)
+    epoch_fn = compile_lm_epoch(make_epoch_from_step(step_fn, health=health,
+                                                     aux=hybrid))
     eval_fn = jax.jit(make_eval_nll_fn(model, batch_size=eval_batch))
 
     # Corpus mode: the device token array is REFILLED per epoch from the
@@ -391,10 +425,15 @@ def main(config: LMConfig = LMConfig(), *,
             if aot.get("bytes_accessed"):
                 bytes_per_step = aot["bytes_accessed"] / steps_per_epoch
             attention = None if seq_size > 1 else _attention_plan(
-                config, seq_len, world, dispatched=mesh.size == 1)
+                config, seq_len, world, dispatched=mesh.size == 1,
+                heads=model.num_attention_heads if hybrid else None,
+                head_dim=model.head_dim if hybrid else None)
+            experts = model.expert_plan(
+                config.batch_size // world // config.grad_accum * seq_len
+            ) if hybrid else None
             tele.emit(T.compile_event("epoch", aot,
                                       steps_per_call=steps_per_epoch,
-                                      attention=attention))
+                                      attention=attention, experts=experts))
     history = M.MetricsHistory()
     saver = checkpoint.make_saver(config.async_checkpoint, tele=tele)
 
@@ -407,7 +446,7 @@ def main(config: LMConfig = LMConfig(), *,
         with profiling.maybe_profile(config.profile, config.profile_dir):
             state = _run_epochs(config, state, mesh, epoch_fn, eval_fn, tokens_d,
                                 zeros_d, test_d, dropout_rng, n_train, n_test,
-                                seq_len, steps_per_epoch, start_epoch, history,
+                                targets_per_seq, steps_per_epoch, start_epoch, history,
                                 watch, saver, ckpt_path, gather, tele, compile_s,
                                 flops_per_step, rt, bytes_per_step, grt, loader)
     finally:
@@ -459,7 +498,8 @@ def main(config: LMConfig = LMConfig(), *,
 
 
 def _run_epochs(config, state, mesh, epoch_fn, eval_fn, tokens_d, zeros_d, test_d,
-                dropout_rng, n_train, n_test, seq_len, steps_per_epoch, start_epoch,
+                dropout_rng, n_train, n_test, targets_per_seq, steps_per_epoch,
+                start_epoch,
                 history, watch, saver, ckpt_path, gather, tele, compile_s,
                 flops_per_step, rt, bytes_per_step=None, grt=None, loader=None):
     """The LM trainer's epoch loop, split out so the caller can guarantee the
@@ -513,16 +553,23 @@ def _run_epochs(config, state, mesh, epoch_fn, eval_fn, tokens_d, zeros_d, test_
             with profiling.span("epoch/execute"):
                 with profiling.span("execute/dispatch"):
                     state, out = epoch_fn(state, tokens_d, zeros_d, plan, dropout_rng)
-                losses, epoch_health = out if config.health_stats else (out, None)
+                # (losses[, health][, the expert layers' arrived rows]): see
+                # train.step.make_epoch_from_step
+                out = out if isinstance(out, tuple) else (out,)
+                losses = out[0]
+                epoch_health = out[1] if config.health_stats else None
+                expert_counts = out[-1] if config.model_config else None
                 with profiling.span("execute/wait"):
                     jax.block_until_ready(state.params)
                 with profiling.span("execute/loss_fetch"):
                     train_loss = float(np.asarray(jax.device_get(losses)).mean())
+                    if expert_counts is not None:
+                        expert_counts = np.asarray(jax.device_get(expert_counts))
             with profiling.span("epoch/eval"):
                 eval_params = state.ema if state.ema is not None else state.params
                 sum_nll = float(jax.device_get(eval_fn(eval_params, test_d)))
             with profiling.span("epoch/log"):
-                val_nll = sum_nll / (n_test * seq_len)
+                val_nll = sum_nll / (n_test * targets_per_seq)
                 examples = (epoch + 1) * steps_per_epoch * config.batch_size
                 history.record_train(examples, train_loss)
                 history.record_test(examples, val_nll)
@@ -555,7 +602,8 @@ def _run_epochs(config, state, mesh, epoch_fn, eval_fn, tokens_d, zeros_d, test_
                         period_s=period_s, **span_s,
                         compile_s=compile_s, flops_per_step=flops_per_step,
                         train_loss=train_loss, val_loss=val_nll,
-                        mfu=T.estimate_mfu(flops_per_step, step_s)["mfu"]))
+                        mfu=T.estimate_mfu(flops_per_step, step_s)["mfu"],
+                        expert_counts=expert_counts))
                     if epoch_health is not None:
                         tele.emit(T.health_event(epoch, health_host, steps_per_epoch,
                                                  param_norm=param_norm))

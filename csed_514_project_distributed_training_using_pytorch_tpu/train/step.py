@@ -234,7 +234,8 @@ def make_train_step(model, *, learning_rate: float, momentum: float,
                     label_smoothing: float = 0.0,
                     loss_fn: Callable | None = None,
                     with_metrics: bool = False,
-                    guard: GuardSpec | None = None) -> Callable:
+                    guard: GuardSpec | None = None,
+                    loss_has_aux: bool = False) -> Callable:
     """Build ``step(state, images, labels, rng) -> (state, loss)``.
 
     The loss is the canonical ``nll(log_probs)`` formulation (see
@@ -283,6 +284,12 @@ def make_train_step(model, *, learning_rate: float, momentum: float,
     entirely (e.g. the LM's next-token loss, ``train/lm.py``) while keeping every
     other mechanism — grad-accum, clipping, schedules, optimizers — unchanged. Not
     supported with ``use_pallas`` (the fused kernels implement the standard loss).
+
+    ``loss_has_aux=True``: ``loss_fn`` returns ``(loss, aux)``, ``aux`` any pytree of
+    counters the forward pass hands out (the expert layers' arrived rows,
+    ``models/hybrid_lm.py``); the step then returns ``(state, (out, aux))`` with ``out``
+    what it would have returned without, and the scanned epoch stacks ``aux`` over
+    its steps beside the losses. Microbatches' ``aux`` add up.
 
     ``with_metrics=True`` changes the return to ``(state, (loss, grad_norm))``,
     where ``grad_norm`` is the PRE-clip global L2 norm of the (microbatch-averaged)
@@ -449,10 +456,15 @@ def make_train_step(model, *, learning_rate: float, momentum: float,
             return new_state, (loss, gnorm)
         return new_state, loss
 
+    value_and_grad = jax.value_and_grad(loss_fn, has_aux=loss_has_aux)
+
     def step(state: TrainState, images, labels, rng) -> tuple[TrainState, jax.Array]:
         step_rng = jax.random.fold_in(rng, state.step)
-        loss, grads = jax.value_and_grad(loss_fn)(state.params, images, labels, step_rng)
-        return apply_update(state, grads, loss)
+        loss, grads = value_and_grad(state.params, images, labels, step_rng)
+        if not loss_has_aux:
+            return apply_update(state, grads, loss)
+        new_state, out = apply_update(state, grads, loss[0])
+        return new_state, (out, loss[1])
 
     if grad_accum == 1:
         return step
@@ -469,17 +481,21 @@ def make_train_step(model, *, learning_rate: float, momentum: float,
         def body(carry, chunk):
             grads_sum, loss_sum = carry
             x, y, i = chunk
-            loss, grads = jax.value_and_grad(loss_fn)(
+            loss, grads = value_and_grad(
                 state.params, x, y, jax.random.fold_in(step_rng, i))
+            loss, aux = loss if loss_has_aux else (loss, None)
             return (jax.tree_util.tree_map(jnp.add, grads_sum, grads),
-                    loss_sum + loss), None
+                    loss_sum + loss), aux
 
         zeros = jax.tree_util.tree_map(jnp.zeros_like, state.params)
-        (grads_sum, loss_sum), _ = lax.scan(
+        (grads_sum, loss_sum), aux = lax.scan(
             body, (zeros, jnp.zeros((), jnp.float32)),
             (xs, ys, jnp.arange(grad_accum)))
         grads = jax.tree_util.tree_map(lambda g: g / grad_accum, grads_sum)
-        return apply_update(state, grads, loss_sum / grad_accum)
+        new_state, out = apply_update(state, grads, loss_sum / grad_accum)
+        if not loss_has_aux:
+            return new_state, out
+        return new_state, (out, jax.tree_util.tree_map(lambda a: a.sum(0), aux))
 
     return accum_step
 
@@ -534,22 +550,29 @@ def make_epoch_fn(model, *, learning_rate: float, momentum: float,
 
 
 def make_epoch_from_step(train_step: Callable, *, unroll: int = 1,
-                         pregather: bool = False, health: bool = False) -> Callable:
+                         pregather: bool = False, health: bool = False,
+                         aux: bool = False) -> Callable:
     """Wrap any ``step(state, images, labels, rng)`` into the scanned epoch program
     (same contract as ``make_epoch_fn`` — used for alternative step implementations,
     e.g. the LM trainer's next-token step, ``train/lm.py``).
 
     ``health=True`` expects a step built with ``with_metrics=True`` (returning
     ``(state, (loss, grad_norm))``), carries ``HealthStats`` through the scan, and
-    returns ``(state, (losses, health))``."""
+    returns ``(state, (losses, health))``.
+
+    ``aux=True`` expects a step built with ``loss_has_aux=True``; the epoch's second
+    result is then a tuple that ends in the steps' stacked ``aux``:
+    ``(losses, aux)``, or ``(losses, health, aux)`` with ``health``."""
 
     def epoch(state: TrainState, images, labels, idx_matrix, rng):
         def apply(carry, x, y):
-            if not health:
-                return train_step(carry, x, y, rng)
-            st, h = carry
-            st, (loss, gnorm) = train_step(st, x, y, rng)
-            return (st, update_health(h, loss, gnorm)), loss
+            st, h = carry if health else (carry, None)
+            st, out = train_step(st, x, y, rng)
+            out, extra = out if aux else (out, None)
+            if health:
+                loss, gnorm = out
+                st, out = (st, update_health(h, loss, gnorm)), loss
+            return st, ((out, extra) if aux else out)
 
         init = (state, init_health()) if health else state
 
@@ -570,9 +593,12 @@ def make_epoch_from_step(train_step: Callable, *, unroll: int = 1,
 
             out, losses = lax.scan(body, init, idx_matrix, unroll=unroll)
 
+        losses, extra = losses if aux else (losses, None)
         if health:
-            st, h = out
-            return st, (losses, h)
+            out, h = out
+            losses = (losses, h)
+        if aux:
+            losses = (*losses, extra) if health else (losses, extra)
         return out, losses
 
     return epoch

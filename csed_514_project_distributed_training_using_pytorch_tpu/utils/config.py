@@ -423,6 +423,13 @@ class LMConfig:
                                         # pixel streams; seq_len/vocab come from
                                         # corpus.json, the resume cursor from the
                                         # checkpoint manifest (DESIGN.md §26)
+    model_config: str = ""              # a published architecture's configuration
+                                        # file (models/hybrid_lm.py: layer_types,
+                                        # widths, this chip's share of the experts
+                                        # and the vocabulary); the model is built
+                                        # from it instead of from the pixel LM's
+                                        # --embed-dim/--num-layers/... and trains
+                                        # on --corpus
     data_throttle_s: float = 0.0        # per-batch streaming-loader brake (debug/
                                         # bench: proves goodput's data_wait is
                                         # actually measured); 0 off

@@ -81,6 +81,7 @@ import threading
 import time
 
 import jax
+import numpy as np
 
 from csed_514_project_distributed_training_using_pytorch_tpu.utils import metrics as M
 
@@ -307,11 +308,13 @@ def aot_compile(jit_fn, *args) -> tuple[object | None, dict | None]:
 
 
 def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
-                  attention: dict | None = None) -> dict:
+                  attention: dict | None = None, experts: dict | None = None) -> dict:
     """The ``compile`` event for one AOT-timed program. ``attention``: which core the
     program's attention calls get and why (``ops.dispatch_plan``'s dict: ``impl``,
     ``score_bytes``, ``seq_padded``, ``block``, ``layout``), for the trainers that
-    route through the dispatcher."""
+    route through the dispatcher. ``experts``: what a step asks of each sparse expert
+    layer (``ops.moe.expert_plan``: ``held``, ``row_bound``, ``rows_buffer``,
+    ``block``)."""
     flops = aot.get("flops")
     return {
         "event": "compile",
@@ -327,6 +330,7 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
             aot["bytes_accessed"] / steps_per_call
             if aot.get("bytes_accessed") and steps_per_call else None),
         "attention": attention,
+        "experts": experts,
     }
 
 
@@ -338,7 +342,7 @@ def epoch_event(epoch: int, *, examples: int, steps: int | None = None,
                 mfu: float | None = None, log_s: float | None = None,
                 emit_s: float | None = None, guard_s: float | None = None,
                 checkpoint_s: float | None = None, tick_s: float | None = None,
-                period_s: float | None = None) -> dict:
+                period_s: float | None = None, expert_counts=None) -> dict:
     """Per-epoch phase-timing record. ``execute_s`` is device execution of the epoch
     program (closed by a host fetch, SURVEY.md §7c); ``wall_s`` the whole epoch
     including host work; ``data_s`` index-plan/feed construction; ``compile_s`` the
@@ -351,8 +355,20 @@ def epoch_event(epoch: int, *, examples: int, steps: int | None = None,
     fields are pieces of. The event is emitted before its own iteration ends, so it
     holds what was drained at its emit: ``emit_s``, ``guard_s``, ``checkpoint_s`` and
     the boundary half of ``tick_s`` are the PREVIOUS iteration's tail. ``null`` from a
-    trainer whose loop has no such span."""
+    trainer whose loop has no such span.
+
+    ``expert_counts`` ``[steps, sparse layers, held experts]``: the rows that arrived
+    at each held expert, out of the epoch program with the losses. The event carries,
+    per step and sparse layer, their sum (``expert_rows``) and the smallest, mean and
+    largest count over the held experts; ``null`` for a model with no expert layer."""
     ex = _finite(execute_s)
+    experts = {f"expert_rows{suffix}": None for suffix in ("", "_min", "_mean", "_max")}
+    if expert_counts is not None:
+        counts = np.asarray(expert_counts)
+        experts = {"expert_rows": counts.sum(-1).tolist(),
+                   "expert_rows_min": counts.min(-1).tolist(),
+                   "expert_rows_mean": counts.mean(-1).tolist(),
+                   "expert_rows_max": counts.max(-1).tolist()}
     return {
         "event": "epoch",
         "epoch": int(epoch),
@@ -375,6 +391,7 @@ def epoch_event(epoch: int, *, examples: int, steps: int | None = None,
         "train_loss": _finite(train_loss),
         "val_loss": _finite(val_loss),
         "mfu": _finite(mfu),
+        **experts,
     }
 
 
