@@ -10,21 +10,36 @@ expert is computed, at any imbalance.
     route      s = sigmoid(u · W_r) in float32; the experts are the top-k of s + b
                (b enters the selection only); their weights are s_e / (Σ s_e + 1e-6)
     sort       the held assignments, grouped by expert; each expert's rows start on a
-               row tile, so a tile belongs to one expert; rows of the token array are
-               gathered into that order
+               row tile, so a tile belongs to one expert. ``moe_pack`` writes the token
+               array once as row-major 32-bit words (a row of a tiled ``[T, d]`` array
+               is not contiguous in HBM, a row of that copy is one DMA), and
+               ``moe_gather`` copies, tile by tile, the rows of the tiles that ARRIVED
+               into expert order
     experts    the grouped product, three Pallas kernels whose grid is the number of
-               row tiles that ARRIVED (a scalar the sort hands them), not the static
+               row tiles that arrived (a scalar the sort hands them), not the static
                bound of k·T rows: ``moe_ffn_fwd`` (W2 · (silu(W1 x) ⊙ W3 x) per tile,
                the hidden tile never leaving VMEM), ``moe_ffn_bwd`` (the same tile's
                input and routing-weight gradients) and ``moe_ffn_dw`` (the three
                weight gradients, accumulated over an expert's tiles)
-    combine    each token gathers its held assignments' rows, weighted
+    combine    the product kernels write their rows row-major too; ``moe_combine``
+               copies, for a tile of tokens at a time, the rows that ARRIVED for them
+               (an expert's rows are in token order, so those of a tile of tokens are
+               a range, which the sort hands over), sums each token's in float32,
+               weighted, and rounds once to the model's dtype
 
 Only gathers cross between token order and expert order, forward and backward (a
-TPU scatter-add walks its rows one by one): the backward of the combine is the
-gather of the sort, and the other way round. Buffers in expert order are sized for
-the bound (``k·T`` rows and a tile a held expert); what is computed and what the
-kernels read and write follows the rows that arrived.
+scatter-add would read and write every float32 sum once a row): the backward of the
+combine is the gather of the sort, and the other way round. What a crossing copies
+follows the rows that arrived (``num_tiles``, ``rows_of_tokens``), as the products do;
+what does not is one pass over the ``[T, d]`` token array on either side (``moe_pack``
+going in, the tiles ``moe_combine`` writes coming back). Every per-row and per-token
+fact a crossing needs is a scalar in SMEM (the token of a row, the assignment of a
+row, a tile's weights): a ``[T, k]`` operand of a kernel is padded to 128 lanes in HBM,
+and so is everything upstream that XLA gives its layout. Buffers in expert order are
+still sized for the bound (``k·T`` rows and a tile a held expert); their tiles past
+``num_tiles`` are never written and never read, a padding row inside an arrived tile
+is token 0's row going in, and a slot of a token no row came into is left out by a
+select, never by a product with 0.
 
 Expert weights are three leaves a layer, column-blocked by held expert: ``w1``,
 ``w3`` ``[d, n_held·f]`` and ``w2`` ``[f, n_held·d]``, so that a kernel's block index
@@ -43,6 +58,7 @@ from jax.experimental.pallas import tpu as pltpu
 ROW_TILE = 256          # rows of one expert a kernel step multiplies
 HIDDEN_TILE = 512       # columns of the hidden width a weight-gradient step owns
 VMEM_LIMIT = 100 * 2 ** 20   # the resident expert's three bf16 matrices, twice
+LANES, SUBLANES = 128, 8     # one tile of 32-bit words: what a row copy is aligned to
 
 
 def _interpret() -> bool:
@@ -71,25 +87,30 @@ def expert_plan(tokens: int, *, top_k: int, held: tuple[int, int],
                 block: int | None = None) -> dict:
     """The ``compile`` event's ``experts`` field: the held range, the static bound
     on rows (every token sending all of its ``top_k`` rows here), the rows of the
-    expert-order buffers (the bound and a tile a held expert) and the row tile."""
+    expert-order buffers (the bound and a tile a held expert), the row tile, and
+    what a crossing between the two orders moves: the row tiles that arrived."""
     tm = block or ROW_TILE
     bound = tokens * top_k
     return {"held": [held[0], held[0] + held[1]], "row_bound": bound,
-            "rows_buffer": (-(-bound // tm) + held[1]) * tm, "block": tm}
+            "rows_buffer": (-(-bound // tm) + held[1]) * tm, "block": tm,
+            "rows_moved": "arrived"}
 
 
 def _sort(experts: jax.Array, held: tuple[int, int], tm: int) -> dict:
     """Expert order from the router's choice. ``counts [n_held]``: rows that arrived
     at each held expert. Rows of expert ``e`` sit at ``seg_start[e] + rank``, each
     segment a whole number of tiles (an empty expert keeps one, all invalid, so
-    that its weight gradient is written)."""
+    that its weight gradient is written). ``rows_of_tokens [n_held, tiles + 1]``:
+    expert ``e``'s rows of the ``i``-th tile of ``tm`` tokens are ``[e, i]`` to
+    ``[e, i + 1]``."""
     first, n = held
     t, k = experts.shape
     a = t * k
     local = experts.reshape(a) - first
     is_held = (local >= 0) & (local < n)
     key = jnp.where(is_held, local, n)
-    running = jnp.cumsum((key[:, None] == jnp.arange(n)[None]).astype(jnp.int32), axis=0)
+    lands = (key[:, None] == jnp.arange(n)[None]).astype(jnp.int32)
+    running = jnp.cumsum(lands, axis=0)
     counts = running[-1]
     slot = jnp.minimum(key, n - 1)
     rank = jnp.take_along_axis(running, slot[:, None], axis=1)[:, 0] - 1
@@ -107,11 +128,89 @@ def _sort(experts: jax.Array, held: tuple[int, int], tm: int) -> dict:
     offset = rows - seg_start[of_row]
     valid = (offset < counts[of_row]) & (rows // tm < tile_end[-1])
     source = order[jnp.clip(unaligned[of_row] + offset, 0, a - 1)]
+    # an expert's rows are in token order: those of one tile of ``tm`` tokens are a range
+    token_tiles = -(-t // tm)
+    of_tile = jnp.pad(lands, ((0, token_tiles * tm * k - a), (0, 0))).reshape(
+        token_tiles, tm * k, n).sum(axis=1)
+    before = jnp.concatenate([jnp.zeros((1, n), jnp.int32), jnp.cumsum(of_tile, axis=0)])
     return {"counts": counts, "num_tiles": tile_end[-1].astype(jnp.int32),
+            "rows_of_tokens": (seg_start[None] + before).T.astype(jnp.int32),
             "tile_expert": tile_expert.astype(jnp.int32),
             "assignment_of_row": jnp.where(valid, source, 0).astype(jnp.int32),
-            "valid_row": valid, "pos": pos.reshape(t, k).astype(jnp.int32),
+            "token_of_row": jnp.where(valid, source // k, -1).astype(jnp.int32),
+            "pos": pos.reshape(t, k).astype(jnp.int32),
             "is_held": is_held.reshape(t, k)}
+
+
+# --------------------------------------------------------------------------------------
+# Row-major rows. A row of a ``[rows, d]`` array is not contiguous in HBM (tiles of 8 or
+# 16 rows), so what a crossing copies row by row is kept as 32-bit words ``[rows · r,
+# 128]`` in which row ``t`` is the ``r`` consecutive word rows from ``t · r``: whole
+# tiles, one DMA. bf16 packs two column blocks a word.
+# --------------------------------------------------------------------------------------
+
+
+def _word_rows(d: int, dtype) -> int:
+    """Word rows (of 128 lanes) that one row of ``d`` columns takes: whole tiles."""
+    words = -(-d // LANES) * LANES * jnp.dtype(dtype).itemsize // 4
+    return -(-words // (LANES * SUBLANES)) * SUBLANES
+
+
+def _column_blocks(d: int, dtype):
+    """``(word row, half, first column, columns)`` of every 128-column block."""
+    per = 4 // jnp.dtype(dtype).itemsize
+    return [(c // per, c % per, c * LANES, min(LANES, d - c * LANES))
+            for c in range(-(-d // LANES))]
+
+
+def _to_words(block, half, dtype):
+    """A ``[tm, 128]`` block's bits where its half of the 32-bit word is."""
+    if jnp.dtype(dtype).itemsize == 4:
+        return pltpu.bitcast(block, jnp.uint32)
+    bits = pltpu.bitcast(block.astype(jnp.float32), jnp.uint32)   # bf16: the high half
+    return bits & jnp.uint32(0xFFFF0000) if half else bits >> 16
+
+
+def _from_words(words, half, dtype):
+    if jnp.dtype(dtype).itemsize == 4:
+        return pltpu.bitcast(words, dtype)
+    bits = words & jnp.uint32(0xFFFF0000) if half else words << 16
+    return pltpu.bitcast(bits, jnp.float32).astype(dtype)
+
+
+def _lanes(block):
+    short = LANES - block.shape[1]
+    return jnp.pad(block, ((0, 0), (0, short))) if short else block
+
+
+def _store_row_major(o_ref, value, r: int):
+    """``value [tm, d]`` into ``o_ref [tm · r, 128]`` words, each row's ``r`` together."""
+    tm, d = value.shape
+    words = {}
+    for s, half, c0, n in _column_blocks(d, value.dtype):
+        w = _to_words(_lanes(value[:, c0:c0 + n]), half, value.dtype)
+        words[s] = words[s] | w if s in words else w
+    for s, w in words.items():
+        o_ref[pl.ds(s, tm, stride=r), :] = w
+
+
+def _each(lo, hi, body, by: int):
+    """``body(j)`` for ``lo <= j < hi``, ``by`` of them a turn of the loop and the rest
+    one by one (Mosaic unrolls a loop whole or not at all, and a turn's scalar work
+    does not overlap the next's)."""
+    whole = (hi - lo) // by
+
+    def turn(g, carry):
+        for u in range(by):
+            body(lo + g * by + u)
+        return carry
+
+    def one(j, carry):
+        body(j)
+        return carry
+
+    jax.lax.fori_loop(0, whole, turn, 0)
+    jax.lax.fori_loop(lo + whole * by, hi, one, 0)
 
 
 # --------------------------------------------------------------------------------------
@@ -124,20 +223,22 @@ def _dot(a, b, contract):
                                preferred_element_type=jnp.float32)
 
 
-def _fwd_kernel(te_ref, x_ref, w1_ref, w3_ref, w2_ref, y_ref):
+def _fwd_kernel(te_ref, x_ref, w1_ref, w3_ref, w2_ref, y_ref, *, r):
+    """One row tile's result, written row-major: only the combine reads it."""
     del te_ref
     x = x_ref[...]
     gate = _dot(x, w1_ref[...], ((1,), (0,)))
     up = _dot(x, w3_ref[...], ((1,), (0,)))
     hidden = (gate * jax.nn.sigmoid(gate) * up).astype(x.dtype)
-    y_ref[...] = _dot(hidden, w2_ref[...], ((1,), (0,))).astype(y_ref.dtype)
+    _store_row_major(y_ref, _dot(hidden, w2_ref[...], ((1,), (0,))).astype(x.dtype), r)
 
 
 def _bwd_kernel(te_ref, x_ref, g_ref, wr_ref, w1_ref, w3_ref, w2_ref,
-                dx_ref, dwr_ref, dgate_ref, dup_ref, hw_ref):
+                dx_ref, dwr_ref, dgate_ref, dup_ref, hw_ref, *, r):
     """One row tile's backward. ``wr``: the rows' routing weights (0 on an invalid
     row, which therefore adds nothing to any weight gradient). Writes the input
-    gradient, the routing weights' gradient, and what ``moe_ffn_dw`` multiplies."""
+    gradient (row-major, for the combine), the routing weights' gradient, and what
+    ``moe_ffn_dw`` multiplies."""
     del te_ref
     x, g, wr = x_ref[...], g_ref[...], wr_ref[...]
     gate = _dot(x, w1_ref[...], ((1,), (0,)))
@@ -153,8 +254,8 @@ def _bwd_kernel(te_ref, x_ref, g_ref, wr_ref, w1_ref, w3_ref, w2_ref,
     dgate_ref[...] = dgate
     dup_ref[...] = dup
     hw_ref[...] = (wr * hidden).astype(x.dtype)
-    dx_ref[...] = (_dot(dgate, w1_ref[...], ((1,), (1,)))
-                   + _dot(dup, w3_ref[...], ((1,), (1,)))).astype(dx_ref.dtype)
+    _store_row_major(dx_ref, (_dot(dgate, w1_ref[...], ((1,), (1,)))
+                              + _dot(dup, w3_ref[...], ((1,), (1,)))).astype(x.dtype), r)
 
 
 def _dw_kernel(te_ref, x_ref, g_ref, dgate_ref, dup_ref, hw_ref,
@@ -190,13 +291,19 @@ def _of_expert(shape):
     return pl.BlockSpec(shape, lambda i, te: (0, te[i]))
 
 
+def _row_major(tm: int, r: int):
+    """A row tile of an expert-order array kept row-major."""
+    return pl.BlockSpec((tm * r, LANES), lambda i, te: (i, 0))
+
+
 def _experts_fwd(sort, x_sorted, w1, w3, w2, tm):
-    d, f = x_sorted.shape[1], w2.shape[0]
+    (m, d), f = x_sorted.shape, w2.shape[0]
+    r = _word_rows(d, x_sorted.dtype)
     row = lambda width: pl.BlockSpec((tm, width), lambda i, te: (i, 0))
     return _call(
-        _fwd_kernel, "moe_ffn_fwd", (sort["num_tiles"],),
-        [row(d), _of_expert((d, f)), _of_expert((d, f)), _of_expert((f, d))], row(d),
-        jax.ShapeDtypeStruct(x_sorted.shape, x_sorted.dtype),
+        functools.partial(_fwd_kernel, r=r), "moe_ffn_fwd", (sort["num_tiles"],),
+        [row(d), _of_expert((d, f)), _of_expert((d, f)), _of_expert((f, d))],
+        _row_major(tm, r), jax.ShapeDtypeStruct((m * r, LANES), jnp.uint32),
     )(sort["tile_expert"], x_sorted, w1, w3, w2)
 
 
@@ -204,14 +311,15 @@ def _experts_bwd(sort, x_sorted, g_sorted, w_row, w1, w3, w2, tm):
     d, f = x_sorted.shape[1], w2.shape[0]
     row = lambda width: pl.BlockSpec((tm, width), lambda i, te: (i, 0))
     m, dt = x_sorted.shape[0], x_sorted.dtype
+    r = _word_rows(d, dt)
     hidden = jax.ShapeDtypeStruct((m, f), dt)
     dx, dwr, dgate, dup, hw = _call(
-        _bwd_kernel, "moe_ffn_bwd", (sort["num_tiles"],),
+        functools.partial(_bwd_kernel, r=r), "moe_ffn_bwd", (sort["num_tiles"],),
         [row(d), row(d), row(1), _of_expert((d, f)), _of_expert((d, f)),
          _of_expert((f, d))],
-        [row(d), row(1), row(f), row(f), row(f)],
-        [jax.ShapeDtypeStruct((m, d), dt), jax.ShapeDtypeStruct((m, 1), jnp.float32),
-         hidden, hidden, hidden],
+        [_row_major(tm, r), row(1), row(f), row(f), row(f)],
+        [jax.ShapeDtypeStruct((m * r, LANES), jnp.uint32),
+         jax.ShapeDtypeStruct((m, 1), jnp.float32), hidden, hidden, hidden],
     )(sort["tile_expert"], x_sorted, g_sorted, w_row, w1, w3, w2)
     fb = HIDDEN_TILE if f % HIDDEN_TILE == 0 else f
     nf = f // fb
@@ -228,58 +336,193 @@ def _experts_bwd(sort, x_sorted, g_sorted, w_row, w1, w3, w2, tm):
     return dx, dwr[:, 0], dw1, dw3, dw2
 
 
-def _from_rows(rows: jax.Array, sort: dict, weights: jax.Array | None) -> jax.Array:
+# --------------------------------------------------------------------------------------
+# The crossings between token order and expert order: row copies, a row tile or a tile
+# of tokens a grid step, driven by the sort's scalars (scalar prefetch).
+# --------------------------------------------------------------------------------------
+
+
+def _pack_kernel(x_ref, o_ref, *, r):
+    _store_row_major(o_ref, x_ref[...], r)
+
+
+def _gather_kernel(tok_ref, *refs, r):
+    """One row tile of expert order: its rows' tokens, copied row by row, from each of
+    ``n`` token-order arrays (row-major) into its own output."""
+    n = len(refs) // 3
+    sources, outs, bufs, sem = refs[:n], refs[n:2 * n], refs[2 * n:3 * n], refs[-1]
+    tm, d = outs[0].shape
+    i = pl.program_id(0)
+
+    def copies(j, token):
+        return [pltpu.make_async_copy(
+            x_hbm.at[pl.ds(pl.multiple_of(token * r, SUBLANES), r)],
+            buf.at[pl.ds(pl.multiple_of(j * r, SUBLANES), r)], sem)
+            for x_hbm, buf in zip(sources, bufs)]
+
+    _each(0, tm, lambda j: [c.start() for c in copies(j, tok_ref[i * tm + j])], by=8)
+    _each(0, tm, lambda j: [c.wait() for c in copies(j, 0)], by=8)
+    for o_ref, buf in zip(outs, bufs):
+        for s, half, c0, w in _column_blocks(d, o_ref.dtype):
+            block = _from_words(buf[pl.ds(s, tm, stride=r), :], half, o_ref.dtype)
+            o_ref[:, c0:c0 + w] = block[:, :w]
+
+
+def _combine_kernel(range_ref, assignment_ref, *refs, r, k, weighted, dtype):
+    """One tile of tokens: the rows of its held assignments, copied from expert order
+    row by row (every held expert's rows of these tokens are a range, ``range_ref``;
+    ``assignment_ref``: the assignment ``token · k + j`` of a row; ``weight_ref``: the
+    weights of this tile's assignments), and per token their float32 sum, weighted,
+    rounded once. ``came`` marks the slots a row came into: the others are left out by
+    a select."""
+    weight_ref = refs[1] if weighted else None       # this tile's [1, 1, tm · k], in SMEM
+    rows_hbm, (o_ref, buf, came, scale, sem) = refs[0], refs[-5:]
+    tm, d = o_ref.shape
+    i, steps = pl.program_id(0), pl.num_programs(0)
+    came[...] = jnp.zeros_like(came)
+
+    def copy(row, slot):
+        return pltpu.make_async_copy(
+            rows_hbm.at[pl.ds(pl.multiple_of(row * r, SUBLANES), r)],
+            buf.at[pl.ds(pl.multiple_of(slot * r, SUBLANES), r)], sem)
+
+    def of_expert(e, started):
+        lo = range_ref[e * (steps + 1) + i]
+        hi = range_ref[e * (steps + 1) + i + 1]
+
+        def start(row):
+            a = assignment_ref[row]
+            slot = (a % k) * tm + a // k - i * tm
+            copy(row, slot).start()
+            came[pl.ds(slot, 1), :] = jnp.ones((1, LANES), jnp.float32)
+            if weighted:
+                scale[pl.ds(slot, 1), :] = jnp.full(
+                    (1, LANES), weight_ref[0, 0, a - i * tm * k], jnp.float32)
+
+        _each(lo, hi, start, by=4)
+        return started + hi - lo
+
+    experts = range_ref.shape[0] // (steps + 1)
+    started = jax.lax.fori_loop(0, experts, of_expert, 0)
+    _each(0, started, lambda _: copy(0, 0).wait(), by=4)
+    for s, half, c0, n in _column_blocks(d, dtype):
+        total = jnp.zeros((tm, LANES), jnp.float32)
+        for a in range(k):
+            row = _from_words(buf[pl.ds(a * tm * r + s, tm, stride=r), :], half,
+                              dtype).astype(jnp.float32)
+            if weighted:
+                row = row * scale[a * tm:(a + 1) * tm, :]
+            total = total + jnp.where(came[a * tm:(a + 1) * tm, :] > 0, row, 0.0)
+        o_ref[:, c0:c0 + n] = total[:, :n].astype(o_ref.dtype)
+
+
+def _pack(x: jax.Array, tm: int) -> jax.Array:
+    """``[T, d]`` -> its row-major words ``[T · r, 128]``: one pass over ``x``."""
+    t, d = x.shape
+    r = _word_rows(d, x.dtype)
+    return pl.pallas_call(
+        functools.partial(_pack_kernel, r=r), name="moe_pack", interpret=_interpret(),
+        out_shape=jax.ShapeDtypeStruct((t * r, LANES), jnp.uint32), grid=(-(-t // tm),),
+        in_specs=[pl.BlockSpec((tm, d), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((tm * r, LANES), lambda i: (i, 0)))(x)
+
+
+def _to_rows(tokens: tuple, sort: dict, tm: int) -> list:
+    """Expert order from token order, for each of ``tokens`` (``[T, d]`` arrays of one
+    shape and dtype): ``moe_pack`` makes them row-major, ``moe_gather`` writes the row
+    tiles that arrived (a padding row of theirs gets token 0's row); the buffers' other
+    tiles stay unwritten, and nothing reads them."""
+    (_, d), dtype, n = tokens[0].shape, tokens[0].dtype, len(tokens)
+    r = _word_rows(d, dtype)
+    rows = sort["token_of_row"].shape[0]
+    return pl.pallas_call(
+        functools.partial(_gather_kernel, r=r), name="moe_gather",
+        interpret=_interpret(),
+        out_shape=[jax.ShapeDtypeStruct((rows, d), dtype)] * n,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(sort["num_tiles"],),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n,
+            out_specs=[pl.BlockSpec((tm, d), lambda i, tok: (i, 0))] * n,
+            scratch_shapes=[pltpu.VMEM((tm * r, LANES), jnp.uint32)] * n
+            + [pltpu.SemaphoreType.DMA(())]),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+    )(jnp.maximum(sort["token_of_row"], 0), *[_pack(x, tm) for x in tokens])
+
+
+def _from_rows(rows: jax.Array, sort: dict, weights: jax.Array | None, d: int, dtype,
+               tm: int) -> jax.Array:
     """Token order from expert order: each token's sum over its held assignments of
-    their row (times the assignment's weight, if given). One gather an assignment."""
-    out = 0.0
-    for j in range(sort["pos"].shape[1]):
-        row = jnp.take(rows, sort["pos"][:, j], axis=0).astype(jnp.float32)
-        keep = sort["is_held"][:, j]
-        scale = keep if weights is None else jnp.where(keep, weights[:, j], 0.0)
-        out = out + jnp.where(keep[:, None], row, 0.0) * scale[:, None]
-    return out
-
-
-def _to_rows(tokens: jax.Array, sort: dict, k: int) -> jax.Array:
-    return jnp.take(tokens, sort["assignment_of_row"] // k, axis=0)
+    their row (times the assignment's weight, if given), in float32, rounded once.
+    ``rows``: ``[·, d]`` of ``dtype`` kept row-major, as the product kernels write
+    it; ``moe_combine`` copies, for a tile of tokens at a time, the rows that arrived
+    for them. Everything it is told about a token is a scalar (a ``[T, k]`` operand
+    would have to be padded to 128 lanes in HBM, and its producers' with it)."""
+    tokens, k = sort["pos"].shape
+    r = _word_rows(d, dtype)
+    weighted = weights is not None
+    steps = -(-tokens // tm)
+    slot_rows = pltpu.VMEM((k * tm, LANES), jnp.float32)
+    of_tile = []
+    if weighted:        # a tile's weights: scalars, a step's at a time
+        flat = weights.astype(jnp.float32).reshape(-1)
+        of_tile = [jnp.pad(flat, (0, steps * tm * k - flat.shape[0])).reshape(steps, 1, tm * k)]
+    return pl.pallas_call(
+        functools.partial(_combine_kernel, r=r, k=k, weighted=weighted, dtype=dtype),
+        name="moe_combine", interpret=_interpret(),
+        out_shape=jax.ShapeDtypeStruct((tokens, d), dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(steps,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)]
+            + [pl.BlockSpec((1, 1, tm * k), lambda i, *_: (i, 0, 0),
+                            memory_space=pltpu.SMEM)] * weighted,
+            out_specs=pl.BlockSpec((tm, d), lambda i, *_: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((k * tm * r, LANES), jnp.uint32), slot_rows,
+                            slot_rows, pltpu.SemaphoreType.DMA(())]),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                                             vmem_limit_bytes=VMEM_LIMIT),
+    )(sort["rows_of_tokens"].reshape(-1), sort["assignment_of_row"], rows, *of_tile)
 
 
 @functools.lru_cache(maxsize=None)
 def _grouped_ffn(tm: int):
     """``ffn(x, weights, w1, w3, w2, sort) -> [T, d]`` with its hand-written
-    backward: recomputes the hidden tile instead of keeping ``[rows, f]``."""
+    backward: recomputes the hidden tile instead of keeping ``[rows, f]``. The two
+    halves are jitted and this factory is cached, as ``pallas_attention._make_op``'s
+    are: every sparse layer of a model calls the same two functions, so a program
+    traces and lowers the six kernels once, not once a layer and pass."""
 
     @jax.custom_vjp
     def ffn(x, weights, w1, w3, w2, sort):
         return forward(x, weights, w1, w3, w2, sort)[0]
 
+    @jax.jit
     def forward(x, weights, w1, w3, w2, sort):
         cast = lambda w: w.astype(x.dtype)
         with jax.named_scope("moe/sort"):
-            x_sorted = _to_rows(x, sort, weights.shape[1])
+            x_sorted, = _to_rows((x,), sort, tm)
         with jax.named_scope("moe/experts"):
             y_sorted = _experts_fwd(sort, x_sorted, cast(w1), cast(w3), cast(w2), tm)
         with jax.named_scope("moe/combine"):
-            out = _from_rows(y_sorted, sort, weights).astype(x.dtype)
+            out = _from_rows(y_sorted, sort, weights, x.shape[1], x.dtype, tm)
         return out, (x, weights, w1, w3, w2, sort)
 
+    @jax.jit
     def backward(residuals, dout):
         x, weights, w1, w3, w2, sort = residuals
-        cast = lambda w: w.astype(x.dtype)
-        k = weights.shape[1]
+        dtype, d = x.dtype, x.shape[1]
+        cast = lambda w: w.astype(dtype)
         with jax.named_scope("moe/combine"):
-            x_sorted = _to_rows(x, sort, k)
-            g_sorted = _to_rows(dout.astype(x.dtype), sort, k)
-            w_row = jnp.where(sort["valid_row"],
+            x_sorted, g_sorted = _to_rows((x, dout.astype(dtype)), sort, tm)
+            w_row = jnp.where(sort["token_of_row"] >= 0,
                               weights.reshape(-1)[sort["assignment_of_row"]], 0.0)
         with jax.named_scope("moe/experts"):
             dx_sorted, dw_row, dw1, dw3, dw2 = _experts_bwd(
                 sort, x_sorted, g_sorted, w_row[:, None].astype(jnp.float32),
                 cast(w1), cast(w3), cast(w2), tm)
         with jax.named_scope("moe/sort"):
-            dx = _from_rows(dx_sorted, sort, None).astype(x.dtype)
-            dweights = jnp.where(sort["is_held"],
-                                 jnp.take(dw_row, sort["pos"], axis=0), 0.0)
+            dx = _from_rows(dx_sorted, sort, None, d, dtype, tm)
+            dweights = jnp.where(sort["is_held"], dw_row.at[sort["pos"]].get(
+                mode="promise_in_bounds"), 0.0)
         return (dx, dweights.astype(weights.dtype), dw1.astype(w1.dtype),
                 dw3.astype(w3.dtype), dw2.astype(w2.dtype), None)
 
@@ -298,6 +541,8 @@ def held_experts_ffn(x: jax.Array, weights: jax.Array, experts: jax.Array,
     ``w2`` ``[f, n_held·d]``. Returns ``(out [T, d], counts [n_held] int32)``: the rows
     that arrived at each held expert, every one of them computed."""
     tm = block or ROW_TILE
+    if x.dtype not in (jnp.float32, jnp.bfloat16):
+        raise TypeError(f"the row-major copies pack float32 or bfloat16, not {x.dtype}")
     with jax.named_scope("moe/sort"):
         sort = _sort(experts, held, tm)
     counts = sort.pop("counts")

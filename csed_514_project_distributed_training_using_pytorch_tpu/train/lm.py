@@ -448,7 +448,8 @@ def main(config: LMConfig = LMConfig(), *,
                                 zeros_d, test_d, dropout_rng, n_train, n_test,
                                 targets_per_seq, steps_per_epoch, start_epoch, history,
                                 watch, saver, ckpt_path, gather, tele, compile_s,
-                                flops_per_step, rt, bytes_per_step, grt, loader)
+                                flops_per_step, rt, bytes_per_step, grt, loader,
+                                model.expert_plan(1)["block"] if hybrid else None)
     finally:
         # Drain the write-behind queue even on an exception/signal/preemption
         # mid-run — the queued per-epoch checkpoint is the resume artifact a killed
@@ -501,9 +502,11 @@ def _run_epochs(config, state, mesh, epoch_fn, eval_fn, tokens_d, zeros_d, test_
                 dropout_rng, n_train, n_test, targets_per_seq, steps_per_epoch,
                 start_epoch,
                 history, watch, saver, ckpt_path, gather, tele, compile_s,
-                flops_per_step, rt, bytes_per_step=None, grt=None, loader=None):
+                flops_per_step, rt, bytes_per_step=None, grt=None, loader=None,
+                expert_block=None):
     """The LM trainer's epoch loop, split out so the caller can guarantee the
-    async-checkpoint flush in a ``finally`` regardless of where the loop fails."""
+    async-checkpoint flush in a ``finally`` regardless of where the loop fails.
+    ``expert_block``: the expert layers' row tile (None for a model with none)."""
     best_step_s = None
     ckpt_store = (os.path.join(config.results_dir, "checkpoints")
                   if config.results_dir else "")
@@ -603,7 +606,8 @@ def _run_epochs(config, state, mesh, epoch_fn, eval_fn, tokens_d, zeros_d, test_
                         compile_s=compile_s, flops_per_step=flops_per_step,
                         train_loss=train_loss, val_loss=val_nll,
                         mfu=T.estimate_mfu(flops_per_step, step_s)["mfu"],
-                        expert_counts=expert_counts))
+                        expert_counts=expert_counts,
+                        expert_block=expert_block))
                     if epoch_health is not None:
                         tele.emit(T.health_event(epoch, health_host, steps_per_epoch,
                                                  param_norm=param_norm))

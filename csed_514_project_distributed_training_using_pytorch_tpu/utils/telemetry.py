@@ -314,7 +314,7 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
     ``score_bytes``, ``seq_padded``, ``block``, ``layout``), for the trainers that
     route through the dispatcher. ``experts``: what a step asks of each sparse expert
     layer (``ops.moe.expert_plan``: ``held``, ``row_bound``, ``rows_buffer``,
-    ``block``)."""
+    ``block``, ``rows_moved``)."""
     flops = aot.get("flops")
     return {
         "event": "compile",
@@ -342,7 +342,8 @@ def epoch_event(epoch: int, *, examples: int, steps: int | None = None,
                 mfu: float | None = None, log_s: float | None = None,
                 emit_s: float | None = None, guard_s: float | None = None,
                 checkpoint_s: float | None = None, tick_s: float | None = None,
-                period_s: float | None = None, expert_counts=None) -> dict:
+                period_s: float | None = None, expert_counts=None,
+                expert_block: int | None = None) -> dict:
     """Per-epoch phase-timing record. ``execute_s`` is device execution of the epoch
     program (closed by a host fetch, SURVEY.md §7c); ``wall_s`` the whole epoch
     including host work; ``data_s`` index-plan/feed construction; ``compile_s`` the
@@ -360,12 +361,18 @@ def epoch_event(epoch: int, *, examples: int, steps: int | None = None,
     ``expert_counts`` ``[steps, sparse layers, held experts]``: the rows that arrived
     at each held expert, out of the epoch program with the losses. The event carries,
     per step and sparse layer, their sum (``expert_rows``) and the smallest, mean and
-    largest count over the held experts; ``null`` for a model with no expert layer."""
+    largest count over the held experts, and ``expert_rows_moved``: the rows of the
+    row tiles that arrived (each held expert's count rounded up to whole tiles of
+    ``expert_block`` rows, one at least), which is what a crossing between token order
+    and expert order touches; ``null`` for a model with no expert layer."""
     ex = _finite(execute_s)
-    experts = {f"expert_rows{suffix}": None for suffix in ("", "_min", "_mean", "_max")}
+    experts = {f"expert_rows{suffix}": None
+               for suffix in ("", "_min", "_mean", "_max", "_moved")}
     if expert_counts is not None:
         counts = np.asarray(expert_counts)
+        tiles = np.maximum(1, -(-counts // expert_block))
         experts = {"expert_rows": counts.sum(-1).tolist(),
+                   "expert_rows_moved": (tiles * expert_block).sum(-1).tolist(),
                    "expert_rows_min": counts.min(-1).tolist(),
                    "expert_rows_mean": counts.mean(-1).tolist(),
                    "expert_rows_max": counts.max(-1).tolist()}

@@ -27,33 +27,74 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_expert_layer_kernels_compile_for_the_v5e_at_published_widths(one_chip, monkeypatch):
-    """``ops/moe.py`` forward and backward at LFM2-24B-A2B's widths (d 2048, expert
-    width 1536, 8 of 64 experts held, top-4): the three Pallas kernels are in the
-    program, with the whole of one expert's weights resident in VMEM."""
+KERNELS = ("moe_ffn_fwd", "moe_ffn_bwd", "moe_ffn_dw",
+           "moe_pack", "moe_gather", "moe_combine")
+D, F, ROUTER, HELD, K = 2048, 1536, 64, 8, 4        # LFM2-24B-A2B, chip 0 of 8
+
+
+@pytest.fixture(scope="module")
+def compiled_layer(one_chip):
+    """``ops/moe.py`` (route and the held experts, value and every gradient) compiled
+    at the published widths for ``tokens`` tokens: the program's text, once a size."""
     from csed_514_project_distributed_training_using_pytorch_tpu.ops import moe
-    monkeypatch.setattr(moe, "_interpret", lambda: False)
-    tokens, d, f, router, held, k = 4096, 2048, 1536, 64, 8, 4
-    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    texts = {}
 
-    def layer(u, router_kernel, bias, w1, w3, w2):
-        weights, experts = moe.route(u, router_kernel, bias, top_k=k)
-        out, counts = moe.held_experts_ffn(u, weights, experts, w1, w3, w2,
-                                           held=(0, held))
-        return jnp.sum(out.astype(jnp.float32)), counts
+    def compile_for(tokens):
+        if tokens in texts:
+            return texts[tokens]
+        spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    cache = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        compiled = jax.jit(jax.value_and_grad(layer, argnums=(0, 1, 3, 4, 5),
-                                              has_aux=True)).lower(
-            spec((tokens, d), jnp.bfloat16), spec((d, router), jnp.float32),
-            spec((router,), jnp.float32), spec((d, held * f), jnp.float32),
-            spec((d, held * f), jnp.float32), spec((f, held * d), jnp.float32)).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache)
-    text = compiled.as_text()
-    for name in ("moe_ffn_fwd", "moe_ffn_bwd", "moe_ffn_dw"):
-        assert f"%{name}" in text and "tpu_custom_call" in text, name
-    plan = moe.expert_plan(tokens, top_k=k, held=(0, held))
-    assert plan["rows_buffer"] == tokens * k + held * moe.ROW_TILE
+        def layer(u, router_kernel, bias, w1, w3, w2):
+            weights, experts = moe.route(u, router_kernel, bias, top_k=K)
+            out, counts = moe.held_experts_ffn(u, weights, experts, w1, w3, w2,
+                                               held=(0, HELD))
+            return jnp.sum(out.astype(jnp.float32)), counts
+
+        cache = jax.config.jax_enable_compilation_cache
+        interpret = moe._interpret
+        jax.config.update("jax_enable_compilation_cache", False)
+        moe._interpret = lambda: False
+        try:
+            texts[tokens] = jax.jit(jax.value_and_grad(
+                layer, argnums=(0, 1, 3, 4, 5), has_aux=True)).lower(
+                spec((tokens, D), jnp.bfloat16), spec((D, ROUTER), jnp.float32),
+                spec((ROUTER,), jnp.float32), spec((D, HELD * F), jnp.float32),
+                spec((D, HELD * F), jnp.float32), spec((F, HELD * D), jnp.float32)
+            ).compile().as_text()
+        finally:
+            moe._interpret = interpret
+            jax.config.update("jax_enable_compilation_cache", cache)
+        return texts[tokens]
+
+    return compile_for
+
+
+@pytest.mark.parametrize("tokens", [4096, 32768])
+def test_expert_layer_kernels_compile_for_the_v5e_at_published_widths(compiled_layer, tokens):
+    """Forward and backward at LFM2-24B-A2B's widths (d 2048, expert width 1536, 8 of
+    64 experts held, top-4), at 4,096 tokens and at the cell's 32,768: the three
+    product kernels (the whole of one expert's weights resident in VMEM) and the
+    three of the crossings are in the program."""
+    from csed_514_project_distributed_training_using_pytorch_tpu.ops import moe
+    text = compiled_layer(tokens)
+    assert "tpu_custom_call" in text
+    for name in KERNELS:
+        assert f"%{name}" in text, name
+    plan = moe.expert_plan(tokens, top_k=K, held=(0, HELD))
+    assert plan["rows_buffer"] == tokens * K + HELD * moe.ROW_TILE
+    assert plan["rows_moved"] == "arrived"
+
+
+@pytest.mark.parametrize("tokens", [4096, 32768])
+def test_no_crossing_is_left_to_xla_at_the_size_of_the_bound(compiled_layer, tokens):
+    """Outside the Pallas calls nothing gathers, selects or fills ``rows_buffer`` (or
+    ``k·T``) rows of width ``d``: the crossings move the row tiles that arrived."""
+    import re
+    from csed_514_project_distributed_training_using_pytorch_tpu.ops import moe
+    plan = moe.expert_plan(tokens, top_k=K, held=(0, HELD))
+    sized = re.compile(rf"\[({plan['rows_buffer']}|{plan['row_bound']}),{D}\]")
+    offenders = [line.strip()[:200] for line in compiled_layer(tokens).splitlines()
+                 if " = " in line and sized.search(line.split(" = ", 1)[1].split("(")[0])
+                 and "tpu_custom_call" not in line
+                 and not re.search(r"= \S+ (parameter|get-tuple-element|bitcast)\(", line)]
+    assert not offenders, offenders

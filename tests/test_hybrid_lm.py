@@ -108,7 +108,8 @@ def test_the_configuration_is_published_layers_1_to_5():
         full.param_shapes(), is_leaf=lambda x: isinstance(x, tuple)))
     assert count == 469_284_992 + 4 * 64        # ISSUE 26's count, and the four b
     assert full.expert_plan(4 * 8192) == {"held": [0, 8], "row_bound": 131072,
-                                          "rows_buffer": 133120, "block": 256}
+                                          "rows_buffer": 133120, "block": 256,
+                                          "rows_moved": "arrived"}
 
 
 def test_the_cells_attention_is_dispatched_to_the_flash_kernels():
@@ -204,6 +205,125 @@ def test_dropless_at_any_imbalance(case):
             width = g.shape[1] // 16
             g, w = (a[:, 4 * width:8 * width] for a in (g, w))
         np.testing.assert_allclose(g, w, atol=3e-5, err_msg=name)
+
+
+# (c') the crossings between token order and expert order --------------------------------
+
+
+def _loop_layer(x, weights, experts, w1, w3, w2, held, f=24, d=32):
+    """The held experts' part by a loop over experts, float32 throughout."""
+    first, count = held
+    x = x.astype(jnp.float32)
+    out = jnp.zeros_like(x)
+    for e in range(count):
+        gate, up = x @ w1[:, e * f:(e + 1) * f], x @ w3[:, e * f:(e + 1) * f]
+        y = (jax.nn.silu(gate) * up) @ w2[:, e * d:(e + 1) * d]
+        share = jnp.sum(jnp.where(experts == first + e, weights, 0.0), axis=1)
+        out = out + share[:, None] * y
+    return out
+
+
+def _crossing_case(case, t=40):
+    """``(experts [t, 4], held)``: hand-made routing over 16 experts, tiles of 8 rows.
+    Every token starts on experts 0..3, none of them held by ``(4, 4)``."""
+    experts = np.tile(np.arange(4, dtype=np.int32), (t, 1))
+    held = (4, 4)
+    if case == "everything arrives":
+        experts = (np.arange(t)[:, None] + 4 * np.arange(4)[None]) % 16
+        held = (0, 16)
+    elif case == "one held expert takes every row":
+        experts[:, 0] = 5
+    elif case == "counts on a tile edge":
+        experts[:16, 0], experts[16:24, 0] = 4, 6          # 16 and 8 rows
+    elif case == "counts one past a tile edge":
+        experts[:17, 0], experts[17:26, 0] = 4, 6          # 17 and 9 rows
+    elif case == "two and three held experts of one token":
+        experts[:, 0], experts[:, 1] = 4, 6
+        experts[::2, 2] = 7
+    else:
+        assert case == "nothing arrives"
+    return jnp.asarray(experts, jnp.int32), held
+
+
+CROSSING_CASES = ("nothing arrives", "everything arrives", "one held expert takes every row",
+                  "counts on a tile edge", "counts one past a tile edge",
+                  "two and three held experts of one token")
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+@pytest.mark.parametrize("case", CROSSING_CASES)
+def test_the_crossings_move_every_arrived_row_and_no_other(case, dtype):
+    """Forward and every gradient (x, routing weights, w1, w3, w2) against the loop, at
+    the edges of what ``moe_gather`` and ``moe_combine`` walk: the arrived row tiles."""
+    experts, held = _crossing_case(case)
+    t, f, d, n = experts.shape[0], 24, 32, held[1]
+    ks = jax.random.split(jax.random.PRNGKey(11), 6)
+    x = jax.random.normal(ks[0], (t, d)).astype(dtype)
+    weights = jax.nn.softmax(jax.random.normal(ks[1], (t, 4)), axis=-1)
+    w1, w3 = (jax.random.normal(ks[i], (d, n * f)) * 0.2 for i in (2, 3))
+    w2 = jax.random.normal(ks[4], (f, n * d)) * 0.2
+    target = jax.random.normal(ks[5], (t, d))
+
+    def program(x, weights, w1, w3, w2):
+        out, counts = moe.held_experts_ffn(x, weights, experts, w1, w3, w2,
+                                           held=held, block=8)
+        return jnp.sum(out.astype(jnp.float32) * target), (out, counts)
+
+    def loop(x, weights, w1, w3, w2):
+        out = _loop_layer(x, weights, experts, w1, w3, w2, held)
+        return jnp.sum(out * target), out
+
+    args = (x, weights, w1, w3, w2)
+    (_, (out, counts)), got = jax.value_and_grad(program, argnums=range(5), has_aux=True)(*args)
+    (_, want_out), want = jax.value_and_grad(loop, argnums=range(5), has_aux=True)(*args)
+    in_held = (np.asarray(experts) >= held[0]) & (np.asarray(experts) < held[0] + n)
+    assert out.dtype == dtype and int(counts.sum()) == int(in_held.sum())
+    np.testing.assert_array_equal(
+        counts, [(np.asarray(experts) == held[0] + e).sum() for e in range(n)])
+    untouched = ~in_held.any(axis=1)
+    assert float(jnp.abs(out[untouched].astype(jnp.float32)).max(initial=0.0)) == 0.0
+    assert float(jnp.abs(got[0][untouched].astype(jnp.float32)).max(initial=0.0)) == 0.0
+    assert float(jnp.abs(jnp.where(in_held, 0.0, got[1])).max()) == 0.0
+    tol = 3e-5 if dtype == jnp.float32 else 3e-2
+    pairs = [("out", out, want_out)] + list(zip(("x", "weights", "w1", "w3", "w2"), got, want))
+    for name, g, w in pairs:
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.isfinite(g).all(), name
+        assert np.abs(g - w).max() <= tol * (np.abs(w).max() + 1e-6), name
+
+
+def test_the_combine_sums_a_tokens_rows_in_float32_and_rounds_once():
+    """Token 3 holds two weighted rows, ``(1 + 2^-8) · 1`` and ``2^-8 · 1``: their
+    float32 sum ``1 + 2^-7`` is a bf16 number, while a bf16 running sum would round
+    the first to 1 and stay there. Token 5 holds one row; the others none, whatever
+    the rows they point at hold."""
+    tm, d, tokens, k = 8, 32, 16, 4
+    pos = np.zeros((tokens, k), np.int32)
+    is_held = np.zeros((tokens, k), bool)
+    pos[3, 0], pos[3, 2], pos[5, 1] = 0, 8, 1     # rows 0, 1 of tile 0; row 8 of tile 1
+    is_held[3, 0] = is_held[3, 2] = is_held[5, 1] = True
+    token_of_row = np.full(3 * tm, -1, np.int32)
+    token_of_row[[0, 1, 8]] = 3, 5, 3
+    assignment_of_row = np.zeros(3 * tm, np.int32)
+    assignment_of_row[[0, 1, 8]] = 3 * k + 0, 5 * k + 1, 3 * k + 2
+    sort = {"pos": jnp.asarray(pos), "is_held": jnp.asarray(is_held),
+            "token_of_row": jnp.asarray(token_of_row), "num_tiles": jnp.int32(2),
+            "assignment_of_row": jnp.asarray(assignment_of_row),
+            # two experts, two tiles of 8 tokens: their rows of tokens 0-7, of 8-15
+            "rows_of_tokens": jnp.asarray([[0, 2, 2], [8, 9, 9]], jnp.int32)}
+    rows = jnp.full((3 * tm, d), jnp.nan, jnp.bfloat16).at[jnp.asarray([0, 1, 8])].set(1.0)
+    weights = np.full((tokens, k), 0.25, np.float32)
+    weights[3, 0], weights[3, 2], weights[5, 1] = 1 + 2.0 ** -8, 2.0 ** -8, 0.5
+    out = moe._from_rows(moe._pack(rows, tm), sort, jnp.asarray(weights), d, jnp.bfloat16, tm)
+    want = np.zeros((tokens, d), np.float32)
+    want[3], want[5] = 1 + 2.0 ** -7, 0.5
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(out, np.float32), want)
+    # and the way there: the rows of tokens 3, 5 and 3 again, whatever the rest holds
+    x = jax.random.normal(jax.random.PRNGKey(0), (tokens, d)).astype(jnp.bfloat16)
+    there, = moe._to_rows((x,), sort, tm)
+    np.testing.assert_array_equal(np.asarray(there[jnp.asarray([0, 1, 8])], np.float32),
+                                  np.asarray(x[jnp.asarray([3, 5, 3])], np.float32))
 
 
 # (d) b moves the selection and not the weights -------------------------------------------
@@ -321,7 +441,8 @@ def test_the_events_carry_the_expert_layers_fields(trained):
     _, _, events = trained[0]
     compile_event = [e for e in events if e["event"] == "compile"][0]
     assert compile_event["experts"] == {"held": [0, 4], "row_bound": 8 * 64 * 4,
-                                        "rows_buffer": (8 + 4) * 256, "block": 256}
+                                        "rows_buffer": (8 + 4) * 256, "block": 256,
+                                        "rows_moved": "arrived"}
     assert compile_event["attention"]["impl"] == "dense"
     for event in (e for e in events if e["event"] == "epoch"):
         rows = np.asarray(event["expert_rows"])
@@ -329,6 +450,9 @@ def test_the_events_carry_the_expert_layers_fields(trained):
         lo, mean, hi = (np.asarray(event[f"expert_rows_{k}"]) for k in ("min", "mean", "max"))
         assert (lo <= mean).all() and (mean <= hi).all()
         np.testing.assert_allclose(mean * 4, rows)         # 4 experts held
+        moved = np.asarray(event["expert_rows_moved"])     # whole tiles, one at least
+        assert moved.shape == rows.shape and (moved % 256 == 0).all()
+        assert (moved >= np.maximum(rows, 4 * 256)).all() and (moved < rows + 4 * 256).all()
         assert 0 < rows.sum() < 4 * 8 * 64 * rows.size     # under the static bound
 
 
