@@ -118,10 +118,6 @@ def main() -> int:
                         default="float32",
                         help="q/k/v dtype; bfloat16 is the training dtype and runs "
                              "the kernels' matmuls at the MXU's native rate")
-    parser.add_argument("--native-layout", action="store_true",
-                        help="feed the kernels the model's [B,S,H,D] layout "
-                             "directly (no transpose repacks) — r5 measurement "
-                             "knob; rows carry native_layout: true")
     parser.add_argument("--batch", type=int, default=B)
     parser.add_argument("--heads", type=int, default=H)
     parser.add_argument("--head-dim", type=int, default=D,
@@ -158,14 +154,6 @@ def main() -> int:
                "dtype": args.dtype, "reps": REPS}
         if args.window is not None:
             row["window"] = args.window
-        if args.native_layout:
-            from csed_514_project_distributed_training_using_pytorch_tpu.ops.pallas_attention import (
-                native_mode,
-            )
-            row["native_layout"] = True
-            # Which native form the env knobs actually select at this head
-            # width — a capture file's name can't misstate what it timed.
-            row["native_mode"] = native_mode(d_hd)
         sweeping = args.block_sweep is not None
         blocks = (args.block_sweep if sweeping
                   else [args.block] if args.block is not None else [None])
@@ -180,8 +168,6 @@ def main() -> int:
                 flash_kw["block"] = blk
             if args.window is not None:
                 flash_kw["window"] = args.window
-            if args.native_layout:
-                flash_kw["native_layout"] = True
             flash = (ops.flash_attention if not flash_kw else
                      functools.partial(ops.flash_attention, **flash_kw))
             try:

@@ -84,20 +84,14 @@ def main(argv=None) -> int:
                         dropout_rate=0.0,
                         dtype=jnp.bfloat16 if args.bf16 else jnp.float32)
     attn_impl = "dense"
-    flash_layout = None
     if args.flash:
         from csed_514_project_distributed_training_using_pytorch_tpu.ops.pallas_attention import (
             dispatch_attention, dispatch_plan,
         )
         model_kwargs["attention_fn"] = dispatch_attention
         # Record what the dispatcher actually runs at this shape — a row labelled
-        # "flash" must not have timed the dense path — and which LAYOUT the env
-        # knobs select, so a capture file's name can't misstate what it timed.
-        plan = dispatch_plan((b, s, args.heads, e // args.heads))
-        attn_impl = plan["impl"]
-        flash_layout = plan["layout"]      # None on the dense path
-        if flash_layout in ("strided", "unroll"):
-            flash_layout = f"native-{flash_layout}"
+        # "flash" must not have timed the dense path.
+        attn_impl = dispatch_plan((b, s, args.heads, e // args.heads))["impl"]
     model = TransformerClassifier(**model_kwargs)
 
     rng = np.random.default_rng(0)
@@ -167,7 +161,6 @@ def main(argv=None) -> int:
         },
         "achieved_model_flops_per_s": round(achieved),
         "mfu_vs_bf16_peak": round(achieved / peak, 6) if peak else None,
-        "flash_layout": flash_layout,
         "final_train_loss": round(last_loss, 4),
     }))
     return 0

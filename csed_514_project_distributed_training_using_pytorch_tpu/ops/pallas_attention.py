@@ -8,15 +8,9 @@ exists. This is the intra-chip complement of the cross-chip ring attention in
 
 Kernel layout (FlashAttention-2 style, in the canonical Pallas-TPU grid formulation):
 
-- **Forward**: grid ``(B·H, S/BLOCK, S/BLOCK)`` in the packed ``[BH, S, D]`` layout; or,
-  for the native layouts that feed the model's ``[B, S, H, D]`` viewed flat (a free
-  reshape, no transpose repacks — ``_GridLayout``, r5): native-STRIDED at D%128==0
-  (the same packed grid and kernel bodies, with D-wide LANE-BLOCK index maps
-  ``(g//H, walk, g%H)`` addressing the flat operands) or native-UNROLL otherwise
-  (grid ``(B, S/BLOCK, S/BLOCK)``, all-heads blocks ``[BLOCK, H·D]``, a static head
-  unroll over per-head lane slices — Mosaic's last-two-dims tiling rules out a
-  per-head grid axis on rank-4 blocks, and sublane-sliced bf16 operands crash its
-  ``dot`` lowering, so heads ride the lane dim) — the innermost
+- **Forward**: grid ``(B·H, S/BLOCK, S/BLOCK)`` over operands packed ``[BH, S, D]``, the
+  one layout (two forms that fed the model's ``[B, S, H, D]`` viewed flat lost to it on
+  the chip at every recorded shape and are gone: DESIGN.md §9) — the innermost
   (fastest-varying) axis walks K/V blocks while the query block and the online-softmax
   accumulators ``(acc, m, l)`` persist in **VMEM scratch** across those steps
   (``@pl.when`` on the first/last K/V step initializes/finalizes them). Streaming and
@@ -60,8 +54,6 @@ other length at the tail (exact under the mask), a non-causal one takes the dens
 from __future__ import annotations
 
 import functools
-import os
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -130,36 +122,14 @@ FLASH_MIN_HEAD_SCORE_BYTES = 1 << 20    # S_q·S_k·4 of ONE (batch, head), = S 
                        # the dense side
 
 
-NATIVE_BLOCK_ELEMS = 262144  # native-layout block·H·D cap (elements per operand
-                             # block): native-flat blocks hold ALL H heads
-                             # ([block, H·D] refs), so the VMEM working set
-                             # scales with the product. Measured v5e envelope
-                             # (r5): 512·8·64 and 256·8·128 compile; 512·8·128
-                             # (524288) exceeds the 16 MB scoped-vmem limit by
-                             # 740 KB in the fwd kernel's AOT stack allocation
-
-
-def auto_block(s: int, window: int = 0, native_hd: int | None = None) -> int:
+def auto_block(s: int, window: int = 0) -> int:
     """``s`` itself when one block holds it (``MAX_AUTO_BLOCK``), else the largest
     power-of-two block ≤ the measured per-regime cap that tiles ``s`` evenly — the
     measured-fastest choice per shape (see ``MAX_AUTO_BLOCK`` /
-    ``MAX_AUTO_BLOCK_WINDOWED``). ``native_hd`` (= H·D, the flat row width)
-    caps the native layout's block·H·D VMEM product (``NATIVE_BLOCK_ELEMS``);
-    packed callers leave it ``None``."""
+    ``MAX_AUTO_BLOCK_WINDOWED``)."""
     cap = (MAX_AUTO_BLOCK_WINDOWED if 0 < window < WIDE_WINDOW
            else MAX_AUTO_BLOCK)
-    whole = MAX_AUTO_BLOCK      # the longest sequence that rides in one block
-    if native_hd is not None:
-        if 128 * native_hd > NATIVE_BLOCK_ELEMS:
-            # Even the smallest legal block would bust the measured scoped-vmem
-            # envelope — same failure the explicit-block check rejects.
-            raise ValueError(
-                f"native-layout flash cannot tile heads*head_dim={native_hd}: "
-                f"128*{native_hd} exceeds the {NATIVE_BLOCK_ELEMS}-element "
-                f"VMEM envelope; use the packed layout for this shape")
-        whole = min(whole, NATIVE_BLOCK_ELEMS // native_hd)
-        cap = min(cap, whole)
-    if s % BLOCK == 0 and s <= whole:
+    if s % BLOCK == 0 and s <= MAX_AUTO_BLOCK:
         # A sequence that fits one block takes one, banded or not: that short, a
         # grid step's fixed cost outweighs what a band or a causal skip saves.
         return s
@@ -279,160 +249,27 @@ def _elided_query_idx(nq: int, off_blocks: int, reach, *, causal: bool):
     return idx
 
 
-class _GridLayout:
-    """Grid/spec factory shared by the fwd/dq/dkv ``pallas_call``s for the two
-    operand layouts:
-
-    - packed ``[BH, S, D]`` (``heads=None``) — refs ``[block, D]`` — the ring
-      schedules' shard layout;
-    - native-flat ``[B, S, H·D]`` (``heads=H``) — refs ``[block, H·D]`` with
-      per-head LANE slices — the model's ``[B, S, H, D]`` viewed flat, which is
-      a free contiguous reshape, NOT the ``[B,S,H,D] ↔ [BH,S,D]`` transpose
-      repacks this layout exists to delete (11% of the r4 large-transformer
-      step, ``bench_results/hw_r4/profile_large``).
-
-    The flat form is forced by two Mosaic constraints the r5 chip runs hit
-    (interpret mode enforces neither): a per-head grid axis puts a size-1
-    block on the sublane (H) dim of a rank-4 block, which fails the
-    last-two-dims tiling rule; and keeping H as a ref dim makes the per-head
-    slice a SUBLANE slice, whose product feeding an MXU ``dot`` crashes the
-    bf16 Mosaic compile outright. Lane slices at D-granularity compile and
-    run for both dtypes. So both layouts share the rank-3 spec machinery —
-    grid ``(prefix, nq, steps)``, query-block axis at program_id(1), K/V-walk
-    axis at program_id(2) — and differ only in the kernels' static head unroll
-    (``_ref_heads``) and the lse spec, whose ``(1, block)`` trailing block dims
-    equal the array's (tiling-legal by equality).
-
-    When the head width is a whole number of 128-lane registers
-    (``D % 128 == 0``), ``per_head_grid=True`` selects a third form —
-    native-STRIDED: the same
-    flat ``[B, S, H·D]`` operands, but D-wide LANE BLOCKS addressed by index
-    maps ``(g // H, walk, g % H)`` on the packed ``(B·H, nq, steps)`` grid.
-    Kernels run their packed bodies (``heads=None`` — no unroll), refs are
-    ``[block, D]``, the lse keeps the packed ``[B·H, nq, 1, block]`` shape,
-    and VMEM per block matches the packed path — so the full measured
-    ``MAX_AUTO_BLOCK`` applies, not the all-heads ``NATIVE_BLOCK_ELEMS``
-    envelope. Zero repacks at packed-kernel efficiency; the price is a
-    D-strided HBM access pattern the grid pipeline overlaps."""
-
-    def __init__(self, shape, block: int, heads: int | None = None,
-                 per_head_grid: bool = False):
-        bh, s, last = shape
-        self.block, self.s = block, s
-        self.per_head_grid = per_head_grid
-        if per_head_grid:
-            if not heads or last % heads:
-                raise ValueError(
-                    f"per_head_grid needs heads dividing the flat width, got "
-                    f"{heads} over {last}")
-            self.heads = None              # kernels run their packed bodies
-            self.gh = heads                # grid-folded head count
-            self.prefix = (bh * heads,)
-            self.hd = last // heads        # per-head lane-block width
-        else:
-            self.heads = heads
-            self.gh = None
-            self.prefix = (bh,)
-            self.hd = last                 # D packed, H·D native-flat
-
-    def grid(self, nq: int, steps: int) -> tuple:
-        return self.prefix + (nq, steps)
-
-    def _spec(self, idx_fn, prefetch: bool):
-        """``idx_fn(i, j, *scalars)`` → S-block index. With ``prefetch`` the maps
-        take the scalar-prefetch ref as a trailing arg (the
-        ``PrefetchScalarGridSpec`` convention) — how a TRACED hop offset steers
-        a banded walk (r5; previously dynamic offsets forced the full walk).
-        Strided form: the grid's bh axis decomposes as (batch, head), and the
-        head picks the D-wide lane block of the flat operand."""
-        if self.per_head_grid:
-            gh = self.gh
-            if prefetch:
-                return pl.BlockSpec(
-                    (None, self.block, self.hd),
-                    lambda g, i, j, off: (g // gh, idx_fn(i, j, off), g % gh),
-                    memory_space=pltpu.VMEM)
-            return pl.BlockSpec((None, self.block, self.hd),
-                                lambda g, i, j: (g // gh, idx_fn(i, j), g % gh),
-                                memory_space=pltpu.VMEM)
-        if prefetch:
-            return pl.BlockSpec((None, self.block, self.hd),
-                                lambda b, i, j, off: (b, idx_fn(i, j, off), 0),
-                                memory_space=pltpu.VMEM)
-        return pl.BlockSpec((None, self.block, self.hd),
-                            lambda b, i, j: (b, idx_fn(i, j), 0),
+def _spec(tail: tuple, idx_fn, dyn: bool):
+    """The VMEM block ``tail`` of one (batch, head) of a packed operand at S-block
+    ``idx_fn(i, j, *scalars)``: ``(block, D)`` of a ``[BH, S, D]`` array, or the
+    statistics' ``(1, 1, block)`` of ``[BH, S/block, 1, block]`` (trailing block dims
+    equal to the array's, which Mosaic's last-two-dims rule admits). With ``dyn`` the
+    index map takes the scalar-prefetch ref as a trailing argument (the
+    ``PrefetchScalarGridSpec`` convention) — how a TRACED hop offset steers a banded
+    walk."""
+    zeros = (0,) * (len(tail) - 1)
+    if dyn:
+        return pl.BlockSpec((None,) + tail,
+                            lambda b, i, j, off: (b, idx_fn(i, j, off)) + zeros,
                             memory_space=pltpu.VMEM)
-
-    def row_spec(self, prefetch: bool = False):
-        return self._spec(lambda i, j, *_: i, prefetch)
-
-    def walk_spec(self, idx_fn, prefetch: bool = False):
-        return self._spec(idx_fn, prefetch)
-
-    def _lse_spec(self, idx_fn, prefetch: bool):
-        if self.heads:
-            if prefetch:
-                return pl.BlockSpec(
-                    (None, self.heads, 1, 1, self.block),
-                    lambda g, i, j, off: (g, 0, idx_fn(i, j, off), 0, 0),
-                    memory_space=pltpu.VMEM)
-            return pl.BlockSpec((None, self.heads, 1, 1, self.block),
-                                lambda g, i, j: (g, 0, idx_fn(i, j), 0, 0),
-                                memory_space=pltpu.VMEM)
-        if prefetch:
-            return pl.BlockSpec((None, 1, 1, self.block),
-                                lambda b, i, j, off: (b, idx_fn(i, j, off), 0, 0),
-                                memory_space=pltpu.VMEM)
-        return pl.BlockSpec((None, 1, 1, self.block),
-                            lambda b, i, j: (b, idx_fn(i, j), 0, 0),
-                            memory_space=pltpu.VMEM)
-
-    def lse_row_spec(self, prefetch: bool = False):
-        return self._lse_spec(lambda i, j, *_: i, prefetch)
-
-    def lse_walk_spec(self, idx_fn, prefetch: bool = False):
-        return self._lse_spec(idx_fn, prefetch)
-
-    def lse_shape(self, nq: int) -> tuple:
-        if self.heads:
-            return self.prefix + (self.heads, nq, 1, self.block)
-        return self.prefix + (nq, 1, self.block)
-
-    def out_shape(self, dtype):
-        if self.per_head_grid:        # the array stays flat [B, S, H·D]
-            return jax.ShapeDtypeStruct(
-                (self.prefix[0] // self.gh, self.s, self.hd * self.gh), dtype)
-        return jax.ShapeDtypeStruct((self.prefix[0], self.s, self.hd), dtype)
-
-    def acc(self, width: int):
-        """f32 VMEM scratch for a per-row accumulator of ``width`` columns:
-        ``[block, width]`` packed, head-leading ``[H, block, width]``
-        native-flat (leading-dim slices never cross the tiled trailing
-        dims)."""
-        if self.heads:
-            return pltpu.VMEM((self.heads, self.block, width), jnp.float32)
-        return pltpu.VMEM((self.block, width), jnp.float32)
+    return pl.BlockSpec((None,) + tail,
+                        lambda b, i, j: (b, idx_fn(i, j)) + zeros,
+                        memory_space=pltpu.VMEM)
 
 
-def _ref_heads(heads):
-    """Static head unroll: packed kernels (``heads=None``) run the body once on
-    the whole ref (``h is None``); native-flat kernels run it per head. A
-    Python loop over a STATIC bound — it unrolls at trace time, which Mosaic
-    requires."""
-    return range(heads) if heads else (None,)
-
-
-def _hslice(ref, h, d):
-    """Per-head ``[block, D]`` LANE slice of a ``[block, H·D]`` operand ref
-    (identity when packed)."""
-    return ref[:] if h is None else ref[:, h * d:(h + 1) * d]
-
-
-def _stat_col(ref, h):
-    """``[bq, 1]`` statistics column from an lse/delta ref (``[1, 1, block]``
-    packed, ``[H, 1, 1, block]`` native-flat)."""
-    row = ref[0] if h is None else ref[h, 0]
-    return jnp.transpose(row)
+def _row_idx(i, j, *_):
+    """The grid's own block axis: the operand a kernel holds across its walk."""
+    return i
 
 
 def _dyn_band_reach(window: int, block: int) -> int:
@@ -450,8 +287,8 @@ def _dyn_banded(window: int, nq: int, block: int) -> bool:
     return bool(window) and 2 * _dyn_band_reach(window, block) + 1 < nq
 
 
-def _pallas_dispatch(kernel, lay, nq: int, steps: int, in_specs, out_specs,
-                     out_shape, scratch_shapes, dyn: bool):
+def _pallas_dispatch(kernel, grid: tuple, in_specs, out_specs, out_shape,
+                     scratch_shapes, dyn: bool):
     """One owner for the dyn/static ``pallas_call`` shape (fwd, dq, and dkv all
     dispatch through here): traced offsets ride scalar prefetch
     (``PrefetchScalarGridSpec`` — the scalar is the first operand and reaches the
@@ -462,12 +299,12 @@ def _pallas_dispatch(kernel, lay, nq: int, steps: int, in_specs, out_specs,
         return pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1, grid=lay.grid(nq, steps),
+                num_scalar_prefetch=1, grid=grid,
                 in_specs=in_specs, out_specs=out_specs,
                 scratch_shapes=scratch_shapes),
             out_shape=out_shape, interpret=_interpret(), name=name)
     return pl.pallas_call(
-        kernel, grid=lay.grid(nq, steps), in_specs=in_specs,
+        kernel, grid=grid, in_specs=in_specs,
         out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch_shapes,
         interpret=_interpret(), name=name)
 
@@ -511,20 +348,13 @@ def _banded(window: int, causal: bool, nq: int, block: int) -> bool:
 
 
 def _fwd_kernel(*refs, scale, causal, num_steps, num_blocks,
-                band_base=None, window=0, q_offset=0, dyn_offset=False,
-                heads=None, head_dim=None):
+                band_base=None, window=0, q_offset=0, dyn_offset=False):
     # ``dyn_offset``: the hop offset arrives as a TRACED int32 scalar via scalar
     # prefetch (the first operand) instead of the static ``q_offset`` — the
     # zig-zag schedules' chunk-pair offsets are device-dependent. r5: scalar-
     # prefetch index maps let the SAME traced offset steer a banded walk
     # (``band_base`` set), so dynamic windowed callers no longer pay the full
-    # O((S/block)²) grid.
-    # Layouts: packed refs are [block, D] with [block, ...] scratch; native-flat
-    # refs are [block, H·D] with head-LEADING [H, block, ...] scratch, and the
-    # body unrolls a static head loop over per-head LANE slices (``_ref_heads``
-    # / ``_hslice``; ``heads``/``head_dim`` are static partial args). The
-    # visibility mask depends only on (query block, key block) positions, so it
-    # is hoisted out of the head loop.
+    # O((S/block)²) grid. Refs are [block, D], scratch [block, ...].
     if dyn_offset:
         off_ref, refs = refs[0], refs[1:]
         q_offset = off_ref[0]
@@ -555,30 +385,25 @@ def _fwd_kernel(*refs, scale, causal, num_steps, num_blocks,
         visible = (_visibility_mask(iq, j, bq, k_ref.shape[0], causal=causal,
                                     window=window, q_offset=q_offset)
                    if masked else None)
-        for h in _ref_heads(heads):
-            q = _hslice(q_ref, h, head_dim)                                # [bq, D]
-            k_blk = _hslice(k_ref, h, head_dim)                            # [bk, D]
-            s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            if masked:
-                s = jnp.where(visible, s, NEG)
-            m = m_ref[:] if h is None else m_ref[h]
-            l = l_ref[:] if h is None else l_ref[h]
-            m_blk = jnp.max(s, axis=1, keepdims=True)                      # [bq, 1]
-            m_new = jnp.maximum(m, m_blk)
-            p = jnp.exp(s - m_new)
-            if masked:
-                p = jnp.where(visible, p, 0.0)
-            corr = jnp.exp(m - m_new)
-            v_blk = _hslice(v_ref, h, head_dim)
-            acc = acc_ref[:] if h is None else acc_ref[h]
-            acc_new = acc * corr + jnp.dot(p.astype(v_blk.dtype), v_blk,
-                                           preferred_element_type=jnp.float32)
-            l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
-            if h is None:
-                acc_ref[:], m_ref[:], l_ref[:] = acc_new, m_new, l_new
-            else:
-                acc_ref[h], m_ref[h], l_ref[h] = acc_new, m_new, l_new
+        q = q_ref[:]                                                       # [bq, D]
+        k_blk = k_ref[:]                                                   # [bk, D]
+        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if masked:
+            s = jnp.where(visible, s, NEG)
+        m = m_ref[:]
+        l = l_ref[:]
+        m_blk = jnp.max(s, axis=1, keepdims=True)                          # [bq, 1]
+        m_new = jnp.maximum(m, m_blk)
+        p = jnp.exp(s - m_new)
+        if masked:
+            p = jnp.where(visible, p, 0.0)
+        corr = jnp.exp(m - m_new)
+        v_blk = v_ref[:]
+        acc_new = acc_ref[:] * corr + jnp.dot(p.astype(v_blk.dtype), v_blk,
+                                              preferred_element_type=jnp.float32)
+        l_new = l * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[:], m_ref[:], l_ref[:] = acc_new, m_new, l_new
 
     # Causal/banded: key blocks with no visible pair contribute nothing — no FLOPs
     # (and with the elided walks, no fetch either). Fully-visible INTERIOR blocks
@@ -589,29 +414,17 @@ def _fwd_kernel(*refs, scale, causal, num_steps, num_blocks,
 
     @pl.when(step == num_steps - 1)
     def _():
-        for h in _ref_heads(heads):
-            l_cur = l_ref[:] if h is None else l_ref[h]
-            l_safe = jnp.where(l_cur == 0.0, 1.0, l_cur)
-            acc = acc_ref[:] if h is None else acc_ref[h]
-            m_cur = m_ref[:] if h is None else m_ref[h]
-            lse = jnp.transpose(m_cur + jnp.log(l_safe))               # [1, bq]
-            if h is None:
-                o_ref[:] = (acc / l_safe).astype(o_ref.dtype)
-                lse_ref[:] = lse.reshape(1, 1, bq)
-            else:
-                o_ref[:, h * head_dim:(h + 1) * head_dim] = (
-                    acc / l_safe).astype(o_ref.dtype)
-                lse_ref[h] = lse.reshape(1, 1, bq)
+        l_cur = l_ref[:]
+        l_safe = jnp.where(l_cur == 0.0, 1.0, l_cur)
+        acc = acc_ref[:]
+        lse = jnp.transpose(m_ref[:] + jnp.log(l_safe))                    # [1, bq]
+        o_ref[:] = (acc / l_safe).astype(o_ref.dtype)
+        lse_ref[:] = lse.reshape(1, 1, bq)
 
 
 def _flash_forward(qx, kx, vx, *, causal: bool, block: int = BLOCK,
-                   window: int = 0, q_offset: int = 0, q_offset_dyn=None,
-                   heads: int | None = None, per_head_grid: bool = False):
-    """Packed [BH, S, D]³ → (out [BH, S, D], lse [BH, S/block, 1, block]), or —
-    with ``heads=H`` — native-flat [B, S, H·D]³ → (out [B, S, H·D],
-    lse [B, H, S/block, 1, block]); ``per_head_grid`` selects the
-    native-STRIDED form (packed grid + lane blocks over the flat operands,
-    packed-shape lse [B·H, S/block, 1, block]) (``_GridLayout``).
+                   window: int = 0, q_offset: int = 0, q_offset_dyn=None):
+    """Packed [BH, S, D]³ → (out [BH, S, D], lse [BH, S/block, 1, block]).
     ``q_offset`` (static, a multiple of ``block``) shifts query positions globally
     relative to the keys — the ring hop offset (see ``_visibility_mask``).
     ``q_offset_dyn`` (a traced int32 scalar, mutually exclusive with a nonzero
@@ -623,14 +436,7 @@ def _flash_forward(qx, kx, vx, *, causal: bool, block: int = BLOCK,
     offset need NOT be block-quantized: the dynamic band is one block wider
     (``_dyn_band_reach``) to absorb the sub-block remainder its floor-division
     steering discards."""
-    s = qx.shape[1]
-    if heads and qx.shape[-1] % heads:
-        raise ValueError(
-            f"native-flat operands need last dim divisible by heads, got "
-            f"{qx.shape[-1]} % {heads}")
-    d = qx.shape[-1] // (heads or 1)       # per-head width sets the softmax scale
-    lay = _GridLayout(qx.shape, block, heads, per_head_grid=per_head_grid)
-    unroll_heads = None if per_head_grid else heads
+    bh, s, d = qx.shape
     _check_block(s, block)
     _check_offset(q_offset, block)
     dyn = q_offset_dyn is not None
@@ -663,32 +469,23 @@ def _flash_forward(qx, kx, vx, *, causal: bool, block: int = BLOCK,
             key_idx = lambda i, j, *_: j
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                num_steps=num_steps, num_blocks=nq, band_base=base,
-                               window=window, q_offset=q_offset, dyn_offset=dyn,
-                               heads=unroll_heads, head_dim=d)
-    in_specs = [
-        lay.row_spec(prefetch=dyn),
-        lay.walk_spec(key_idx, prefetch=dyn),
-        lay.walk_spec(key_idx, prefetch=dyn),
-    ]
-    out_specs = [
-        lay.row_spec(prefetch=dyn),
-        # lse rides with (1, block) trailing dims equal to the array's,
-        # satisfying Mosaic's last-two-dims block constraint.
-        lay.lse_row_spec(prefetch=dyn),
-    ]
+                               window=window, q_offset=q_offset, dyn_offset=dyn)
+    row_spec = _spec((block, d), _row_idx, dyn)
+    walk_spec = _spec((block, d), key_idx, dyn)
     out_shape = [
-        lay.out_shape(qx.dtype),
-        jax.ShapeDtypeStruct(lay.lse_shape(nq), jnp.float32),
+        jax.ShapeDtypeStruct(qx.shape, qx.dtype),
+        jax.ShapeDtypeStruct((bh, nq, 1, block), jnp.float32),
     ]
     scratch_shapes = [
-        lay.acc(d),    # acc
-        lay.acc(1),    # running max m
-        lay.acc(1),    # running normalizer l
+        pltpu.VMEM((block, d), jnp.float32),    # acc
+        pltpu.VMEM((block, 1), jnp.float32),    # running max m
+        pltpu.VMEM((block, 1), jnp.float32),    # running normalizer l
     ]
     dyn_args = ((jnp.asarray(q_offset_dyn, jnp.int32).reshape(1),) if dyn else ())
-    out, lse = _pallas_dispatch(kernel, lay, nq, num_steps, in_specs, out_specs,
-                                out_shape, scratch_shapes, dyn)(
-        *dyn_args, qx, kx, vx)
+    out, lse = _pallas_dispatch(
+        kernel, (bh, nq, num_steps), [row_spec, walk_spec, walk_spec],
+        [row_spec, _spec((1, 1, block), _row_idx, dyn)], out_shape,
+        scratch_shapes, dyn)(*dyn_args, qx, kx, vx)
     return out, lse
 
 
@@ -698,8 +495,7 @@ def _flash_forward(qx, kx, vx, *, causal: bool, block: int = BLOCK,
 
 
 def _dq_kernel(*refs, scale, causal, num_steps, num_blocks,
-               band_base=None, window=0, q_offset=0, dyn_offset=False,
-               heads=None, head_dim=None):
+               band_base=None, window=0, q_offset=0, dyn_offset=False):
     if dyn_offset:                      # traced hop offset (see _fwd_kernel)
         off_ref, refs = refs[0], refs[1:]
         q_offset = off_ref[0]
@@ -725,46 +521,36 @@ def _dq_kernel(*refs, scale, causal, num_steps, num_blocks,
         visible = (_visibility_mask(iq, j, bq, k_ref.shape[0], causal=causal,
                                     window=window, q_offset=q_offset)
                    if masked else None)
-        for h in _ref_heads(heads):
-            q = _hslice(q_ref, h, head_dim)                       # [bq, D]
-            do = _hslice(do_ref, h, head_dim)                     # [bq, D]
-            lse = _stat_col(lse_ref, h)                           # [bq, 1]
-            delta = _stat_col(delta_ref, h)                       # [bq, 1]
-            k_blk = _hslice(k_ref, h, head_dim)
-            v_blk = _hslice(v_ref, h, head_dim)
-            s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            if masked:
-                s = jnp.where(visible, s, NEG)
-            p = jnp.exp(s - lse)                                  # [bq, bk]
-            if masked:
-                p = jnp.where(visible, p, 0.0)
-            dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = p * (dp - delta)
-            upd = jnp.dot(ds.astype(k_blk.dtype), k_blk,
-                          preferred_element_type=jnp.float32)
-            if h is None:
-                dq_acc_ref[:] = dq_acc_ref[:] + upd
-            else:
-                dq_acc_ref[h] = dq_acc_ref[h] + upd
+        q = q_ref[:]                                              # [bq, D]
+        do = do_ref[:]                                            # [bq, D]
+        lse = jnp.transpose(lse_ref[0])                           # [bq, 1]
+        delta = jnp.transpose(delta_ref[0])                       # [bq, 1]
+        k_blk = k_ref[:]
+        v_blk = v_ref[:]
+        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if masked:
+            s = jnp.where(visible, s, NEG)
+        p = jnp.exp(s - lse)                                      # [bq, bk]
+        if masked:
+            p = jnp.where(visible, p, 0.0)
+        dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta)
+        upd = jnp.dot(ds.astype(k_blk.dtype), k_blk,
+                      preferred_element_type=jnp.float32)
+        dq_acc_ref[:] = dq_acc_ref[:] + upd
 
     _dispatch_block(body, iq, j, bq, k_ref.shape[0], in_range, causal=causal,
                     window=window, q_offset=q_offset)
 
     @pl.when(step == num_steps - 1)
     def _():
-        for h in _ref_heads(heads):
-            if h is None:
-                dq_ref[:] = (dq_acc_ref[:] * scale).astype(dq_ref.dtype)
-            else:
-                dq_ref[:, h * head_dim:(h + 1) * head_dim] = (
-                    dq_acc_ref[h] * scale).astype(dq_ref.dtype)
+        dq_ref[:] = (dq_acc_ref[:] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(*refs, scale, causal, num_steps, num_blocks,
-                band_base=None, window=0, q_offset=0, dyn_offset=False,
-                heads=None, head_dim=None):
+                band_base=None, window=0, q_offset=0, dyn_offset=False):
     if dyn_offset:                      # traced hop offset (see _fwd_kernel)
         off_ref, refs = refs[0], refs[1:]
         q_offset = off_ref[0]
@@ -795,36 +581,31 @@ def _dkv_kernel(*refs, scale, causal, num_steps, num_blocks,
         visible = (_visibility_mask(i, ik, q_ref.shape[0], bk, causal=causal,
                                     window=window, q_offset=q_offset)
                    if masked else None)
-        for h in _ref_heads(heads):
-            k = _hslice(k_ref, h, head_dim)                       # [bk, D]
-            v = _hslice(v_ref, h, head_dim)                       # [bk, D]
-            q_blk = _hslice(q_ref, h, head_dim)                   # [bq, D]
-            do_blk = _hslice(do_ref, h, head_dim)
-            lse_blk = _stat_col(lse_ref, h)                       # [bq, 1]
-            delta_blk = _stat_col(delta_ref, h)                   # [bq, 1]
-            s = jax.lax.dot_general(q_blk, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            if masked:
-                s = jnp.where(visible, s, NEG)
-            p = jnp.exp(s - lse_blk)                              # [bq, bk]
-            if masked:
-                p = jnp.where(visible, p, 0.0)
-            # dv += pᵀ · do ; dk += dsᵀ · q
-            dv_upd = jax.lax.dot_general(
-                p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)               # [bk, D]
-            dp = jax.lax.dot_general(do_blk, v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = p * (dp - delta_blk)
-            dk_upd = jax.lax.dot_general(
-                ds.astype(q_blk.dtype), q_blk, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            if h is None:
-                dv_acc_ref[:] = dv_acc_ref[:] + dv_upd
-                dk_acc_ref[:] = dk_acc_ref[:] + dk_upd
-            else:
-                dv_acc_ref[h] = dv_acc_ref[h] + dv_upd
-                dk_acc_ref[h] = dk_acc_ref[h] + dk_upd
+        k = k_ref[:]                                              # [bk, D]
+        v = v_ref[:]                                              # [bk, D]
+        q_blk = q_ref[:]                                          # [bq, D]
+        do_blk = do_ref[:]
+        lse_blk = jnp.transpose(lse_ref[0])                       # [bq, 1]
+        delta_blk = jnp.transpose(delta_ref[0])                   # [bq, 1]
+        s = jax.lax.dot_general(q_blk, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if masked:
+            s = jnp.where(visible, s, NEG)
+        p = jnp.exp(s - lse_blk)                                  # [bq, bk]
+        if masked:
+            p = jnp.where(visible, p, 0.0)
+        # dv += pᵀ · do ; dk += dsᵀ · q
+        dv_upd = jax.lax.dot_general(
+            p.astype(do_blk.dtype), do_blk, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)                   # [bk, D]
+        dp = jax.lax.dot_general(do_blk, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_blk)
+        dk_upd = jax.lax.dot_general(
+            ds.astype(q_blk.dtype), q_blk, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dv_acc_ref[:] = dv_acc_ref[:] + dv_upd
+        dk_acc_ref[:] = dk_acc_ref[:] + dk_upd
 
     # Causal/banded: query blocks with no visible pair against this key block skip;
     # fully-visible interior blocks skip the mask chain (see _fwd_kernel).
@@ -833,54 +614,32 @@ def _dkv_kernel(*refs, scale, causal, num_steps, num_blocks,
 
     @pl.when(step == num_steps - 1)
     def _():
-        for h in _ref_heads(heads):
-            if h is None:
-                dk_ref[:] = (dk_acc_ref[:] * scale).astype(dk_ref.dtype)
-                dv_ref[:] = dv_acc_ref[:].astype(dv_ref.dtype)
-            else:
-                sl = slice(h * head_dim, (h + 1) * head_dim)
-                dk_ref[:, sl] = (dk_acc_ref[h] * scale).astype(dk_ref.dtype)
-                dv_ref[:, sl] = dv_acc_ref[h].astype(dv_ref.dtype)
+        dk_ref[:] = (dk_acc_ref[:] * scale).astype(dk_ref.dtype)
+        dv_ref[:] = dv_acc_ref[:].astype(dv_ref.dtype)
 
 
 def _flash_backward(res, g, *, causal: bool, block: int = BLOCK,
-                    window: int = 0, heads: int | None = None,
-                    per_head_grid: bool = False):
+                    window: int = 0):
     qx, kx, vx, out, lse = res
     gsz, s = qx.shape[0], qx.shape[1]
     nq = s // block
-    # Δ = rowsum(dout ∘ out) PER HEAD, reshaped to the lse layout — XLA fuses
-    # this small pass (and in the native layouts the [G,S,H]→[G,H,S] permute is
-    # D-free, so it is ~1/D the size of the operand repacks the layouts
-    # removed).
+    # Δ = rowsum(dout ∘ out), reshaped to the lse layout — XLA fuses this small
+    # pass.
     prod = g.astype(jnp.float32) * out.astype(jnp.float32)
-    if heads:
-        delta = jnp.sum(prod.reshape(gsz, s, heads, -1), axis=-1)  # [G, S, H]
-        delta = jnp.transpose(delta, (0, 2, 1))                    # [G, H, S]
-        if per_head_grid:   # packed-shape statistics on the folded (B·H) axis
-            delta = delta.reshape(gsz * heads, nq, 1, block)
-        else:
-            delta = delta.reshape(gsz, heads, nq, 1, block)
-    else:
-        delta = jnp.sum(prod, axis=-1).reshape(gsz, nq, 1, block)
+    delta = jnp.sum(prod, axis=-1).reshape(gsz, nq, 1, block)
     return flash_backward_blocks(qx, kx, vx, g, lse, delta, causal=causal,
-                                 block=block, window=window, heads=heads,
-                                 per_head_grid=per_head_grid)
+                                 block=block, window=window)
 
 
 def flash_backward_blocks(qx, kx, vx, g, lse, delta, *, causal: bool,
                           block: int = BLOCK, window: int = 0,
-                          q_offset: int = 0, q_offset_dyn=None,
-                          heads: int | None = None,
-                          per_head_grid: bool = False):
+                          q_offset: int = 0, q_offset_dyn=None):
     """One flash-backward pass of a query-block set against a key/value-block set,
     given the GLOBAL softmax statistics: ``(dq, dk, dv)`` contributions.
 
     Packed layout (the ring schedules' shard form): ``qx/g: [BH, Sq, D]``,
     ``kx/vx: [BH, Sk, D]`` with ``Sq == Sk``, ``lse/delta: [BH, Sq/BLOCK, 1,
-    BLOCK]``. Native-flat layout (the model form viewed ``[B, S, H·D]``, no
-    transpose repacks — ``heads=H``): ``lse/delta: [B, H, S/BLOCK, 1, BLOCK]``.
-    The statistics are of the FULL attention row (all
+    BLOCK]``. The statistics are of the FULL attention row (all
     keys, not just this block set): ``p = exp(q·kᵀ·scale − lse)`` then yields the
     true softmax coefficients restricted to these keys, so the returned
     contributions sum exactly over block sets — the per-hop building block of the
@@ -888,18 +647,11 @@ def flash_backward_blocks(qx, kx, vx, g, lse, delta, *, causal: bool,
     where dk/dv ride the ring with their K/V blocks. ``causal=True`` masks with
     LOCAL block indices, i.e. it assumes q and k share a global origin — ring
     callers use it only for the diagonal hop."""
-    s = qx.shape[1]
-    if heads and qx.shape[-1] % heads:
-        raise ValueError(
-            f"native-flat operands need last dim divisible by heads, got "
-            f"{qx.shape[-1]} % {heads}")
-    d = qx.shape[-1] // (heads or 1)       # per-head width sets the softmax scale
+    bh, s, d = qx.shape
     if kx.shape != qx.shape:
         raise ValueError(
             f"flash_backward_blocks needs equal q/k block sets, got {qx.shape} vs "
             f"{kx.shape}")
-    lay = _GridLayout(qx.shape, block, heads, per_head_grid=per_head_grid)
-    unroll_heads = None if per_head_grid else heads
     _check_block(s, block)
     _check_offset(q_offset, block)
     dyn = q_offset_dyn is not None
@@ -947,36 +699,34 @@ def flash_backward_blocks(qx, kx, vx, g, lse, delta, *, causal: bool,
                 i + sign * (off[0] // block) + o - base, 0, nq - 1)
         return lambda i, o: jnp.clip(i + center_off + o - base, 0, nq - 1)
 
-    row_spec = lay.row_spec(prefetch=dyn)
-    lse_row_spec = lay.lse_row_spec(prefetch=dyn)
+    row_spec = _spec((block, d), _row_idx, dyn)
+    lse_row_spec = _spec((1, 1, block), _row_idx, dyn)
+    out_like = lambda x: jax.ShapeDtypeStruct(qx.shape, x.dtype)
+    acc = pltpu.VMEM((block, d), jnp.float32)
     dyn_args = ((jnp.asarray(q_offset_dyn, jnp.int32).reshape(1),) if dyn else ())
 
     def call(kernel_fn, base, steps, in_specs, out_specs, out_shape, scratch):
         kernel = functools.partial(kernel_fn, scale=scale, causal=causal,
                                    num_steps=steps, num_blocks=nq, band_base=base,
                                    window=window, q_offset=q_offset,
-                                   dyn_offset=dyn, heads=unroll_heads,
-                                   head_dim=d)
-        return _pallas_dispatch(kernel, lay, nq, steps, in_specs, out_specs,
+                                   dyn_offset=dyn)
+        return _pallas_dispatch(kernel, (bh, nq, steps), in_specs, out_specs,
                                 out_shape, scratch, dyn)(
             *dyn_args, qx, kx, vx, g, lse, delta)
 
-    dq_walk = lay.walk_spec(_walk_idx(dq_base, off_blocks), prefetch=dyn)
+    dq_walk = _spec((block, d), _walk_idx(dq_base, off_blocks), dyn)
     dq = call(_dq_kernel, dq_base, dq_steps,
               [row_spec, dq_walk, dq_walk, row_spec, lse_row_spec, lse_row_spec],
-              [row_spec], [lay.out_shape(qx.dtype)],
-              [lay.acc(d)])[0]
+              [row_spec], [out_like(qx)], [acc])[0]
 
     # dkv grid: the query-block axis walks (accumulators persist per key block).
     kv_idx = _walk_idx(kv_base, -off_blocks, kv=True)
-    kv_walk = lay.walk_spec(kv_idx, prefetch=dyn)
-    kv_lse_walk = lay.lse_walk_spec(kv_idx, prefetch=dyn)
+    kv_walk = _spec((block, d), kv_idx, dyn)
+    kv_lse_walk = _spec((1, 1, block), kv_idx, dyn)
     dk, dv = call(_dkv_kernel, kv_base, kv_steps,
                   [kv_walk, row_spec, row_spec, kv_walk, kv_lse_walk,
                    kv_lse_walk],
-                  [row_spec, row_spec],
-                  [lay.out_shape(kx.dtype), lay.out_shape(vx.dtype)],
-                  [lay.acc(d), lay.acc(d)])
+                  [row_spec, row_spec], [out_like(kx), out_like(vx)], [acc, acc])
     return dq, dk, dv
 
 
@@ -986,14 +736,12 @@ def flash_backward_blocks(qx, kx, vx, g, lse, delta, *, causal: bool,
 
 
 @functools.lru_cache(maxsize=None)
-def _make_op(causal: bool, block: int = BLOCK, window: int = 0,
-             heads: int | None = None, per_head_grid: bool = False):
+def _make_op(causal: bool, block: int = BLOCK, window: int = 0):
     # The two halves are jitted, and this factory is cached: every layer of a
     # model calls the same two functions, so a program traces and lowers the three
     # kernels once, not once a layer (PR 25: the 24 Pallas calls of the 8-layer LM,
     # lowered in each of three programs, put 10 s on a 42 s warm start).
-    kw = dict(causal=causal, block=block, window=window, heads=heads,
-              per_head_grid=per_head_grid)
+    kw = dict(causal=causal, block=block, window=window)
 
     @jax.jit
     def flash_forward(q3, k3, v3):
@@ -1034,43 +782,9 @@ def flash_forward_with_lse(q3: jax.Array, k3: jax.Array, v3: jax.Array, *,
                           q_offset=q_offset, q_offset_dyn=q_offset_dyn)
 
 
-def native_mode(head_dim: int) -> str:
-    """Which native-layout form a given head width gets: ``"strided"`` (packed
-    grid + D-wide lane blocks over the flat operands — packed-kernel
-    efficiency, zero repacks) when D is a whole number of 128-lane registers
-    (``D % 128 == 0``), else ``"unroll"`` (all-heads blocks + static head
-    unroll, the only form Mosaic accepts at sub-register head widths).
-    ``FLASH_NATIVE_MODE=unroll`` forces the unroll form everywhere — a
-    measurement knob for pricing the two. Anything else is rejected loudly:
-    a typo'd mode silently timing the default form would poison exactly the
-    measurements the knob exists for."""
-    mode = os.environ.get("FLASH_NATIVE_MODE", "").strip().lower()
-    if mode not in ("", "unroll"):
-        raise ValueError(
-            f"FLASH_NATIVE_MODE must be '' (auto: strided at D%128==0, else "
-            f"unroll) or 'unroll', got {mode!r}")
-    if head_dim % 128 == 0 and mode != "unroll":
-        return "strided"
-    return "unroll"
-
-
-def _native_layout_default() -> bool:
-    """Whether ``flash_attention`` feeds the kernels the model's [B, S, H, D]
-    layout directly (no transpose repacks) instead of packing to [BH, S, D].
-    Opt-in via ``FLASH_NATIVE_LAYOUT=1``; the r5 chip captures settled the
-    default AGAINST it: deleting the repack copies (11% of the r4 large
-    transformer step) buys less than the native forms' direct access patterns
-    cost — 57.8% (strided) / 47.2% (unroll) vs packed's 59.5% MFU
-    (``bench_results/hw_r5/``). The knob stays for geometries where the
-    tradeoff may differ and for re-pricing on future hardware."""
-    return os.environ.get("FLASH_NATIVE_LAYOUT", "0").strip().lower() in (
-        "1", "true", "yes", "on")
-
-
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False, block: int | None = None,
-                    window: int | None = None,
-                    native_layout: bool | None = None) -> jax.Array:
+                    window: int | None = None) -> jax.Array:
     """Drop-in for ``ops.full_attention``: ``[B, S, H, D]`` → ``[B, S, H, D]``.
 
     Requires ``S % block == 0`` with ``block`` a multiple of 128 (lane-aligned), or
@@ -1080,15 +794,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     for the shape via ``auto_block``. Differentiable via the two-kernel flash backward; usable as the
     transformer family's ``attention_fn``. ``block`` is a pure performance knob
     (numerics are block-invariant — pinned in tests); tune it with
-    ``bench_attention.py --block``. ``native_layout`` (default: the
-    ``FLASH_NATIVE_LAYOUT`` env knob) skips the [B,S,H,D]↔[BH,S,D] repacks,
-    feeding the kernels the flat [B,S,H·D] view in the form ``native_mode``
-    picks for the head width: STRIDED at D%128==0 (packed grid and caps, lane-
-    block index maps) or UNROLL otherwise (static head unroll over lane
-    slices; auto-block caps block·H·D at ``NATIVE_BLOCK_ELEMS``). Measured on
-    v5e: packed 59.5% MFU vs strided 57.8% vs unroll 47.2% at the large-
-    transformer config — the repacks are cheaper than either direct access
-    pattern, so packed stays the default (``bench_results/hw_r5/``).
+    ``bench_attention.py --block``. The kernels take operands packed
+    ``[B·H, S, D]``: the two S↔H transposes around them cost less on the chip than
+    either way of reading the model's layout in place (DESIGN.md §9).
 
     ``window=W`` is sliding-window/local attention with ``full_attention``'s exact
     semantics (distance < W; causal restricts to the past side) — and a BANDED grid:
@@ -1099,8 +807,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """
     b, s, h, d = q.shape
     validate_window(window)
-    layout, padded, block = _flash_plan(s, h, d, causal=causal, window=window,
-                                        block=block, native_layout=native_layout)
+    padded, block = _flash_plan(s, causal=causal, window=window, block=block)
     if padded != s:
         # Exact under the causal mask: padded keys lie after every real query and
         # are masked; padded query rows are sliced away, so their dout and Δ are
@@ -1108,71 +815,32 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         # as themselves around the custom-VJP op.
         q, k, v = (jnp.pad(x, ((0, 0), (0, padded - s), (0, 0), (0, 0)))
                    for x in (q, k, v))
-    if layout == "packed":
-        op = _make_op(bool(causal), block, int(window or 0))
-        to3 = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, padded, d)
-        out = jnp.transpose(op(to3(q), to3(k), to3(v)).reshape(b, h, padded, d),
-                            (0, 2, 1, 3))
-    else:
-        # [B, S, H, D] → [B, S, H·D] is a free contiguous view (the repack the
-        # packed path pays is the S↔H transpose above, not this reshape).
-        op = _make_op(bool(causal), block, int(window or 0), heads=h,
-                      per_head_grid=layout == "strided")
-        flat = lambda x: x.reshape(b, padded, h * d)
-        out = op(flat(q), flat(k), flat(v)).reshape(b, padded, h, d)
+    op = _make_op(bool(causal), block, int(window or 0))
+    to3 = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, padded, d)
+    out = jnp.transpose(op(to3(q), to3(k), to3(v)).reshape(b, h, padded, d),
+                        (0, 2, 1, 3))
     return out[:, :s] if padded != s else out
 
 
-def _flash_plan(s: int, h: int, d: int, *, causal: bool, window: int | None,
-                block: int | None = None,
-                native_layout: bool | None = None) -> tuple[str, int, int]:
-    """``(layout, padded length, block)`` of a ``flash_attention`` call: the one
-    place its tiling is decided, for the op itself and for ``dispatch_plan``.
-
-    ``layout`` is ``"packed"``, ``"strided"`` or ``"unroll"`` (``native_layout=None``:
-    the ``FLASH_NATIVE_LAYOUT`` knob; ``native_mode`` picks between the two native
-    forms). The strided form keeps packed-size [block, D] refs, so it takes the
-    packed caps; only the all-heads unroll form pays the block·H·D envelope. A
-    geometry whose SMALLEST legal block (128·H·D) already busts that envelope can't
-    run native-unroll at any block — with ``block=None`` that is a layout
-    preference, not a user contract, so fall back to the packed layout (same math,
-    repacks paid) with a warning rather than dying at trace time; explicitly
-    requested blocks keep the hard error. The padded length is ``s`` itself when it
-    is lane-aligned or the call is not causal (only a causal mask makes tail
-    padding exact; ``_check_block`` refuses the rest), else the next multiple of
-    ``block`` (of 128 when ``auto_block`` is to choose)."""
-    if native_layout is None:
-        native_layout = _native_layout_default()
-    layout = native_mode(d) if native_layout else "packed"
-    unroll_elems = h * d if layout == "unroll" else None
-    if unroll_elems and block is None and 128 * unroll_elems > NATIVE_BLOCK_ELEMS:
-        warnings.warn(
-            f"native-layout flash cannot tile heads*head_dim={h * d} "
-            f"(128*{h * d} exceeds the {NATIVE_BLOCK_ELEMS}-element VMEM "
-            f"envelope); falling back to the packed layout for this shape",
-            stacklevel=3)
-        layout, unroll_elems = "packed", None
-    if unroll_elems and block and block * unroll_elems > NATIVE_BLOCK_ELEMS:
-        # Explicit blocks get the same VMEM envelope the auto path respects:
-        # native-flat blocks hold all H heads, so block·H·D is the real
-        # working-set knob and oversizing it is a Mosaic scoped-vmem compile
-        # failure on chip, not a perf tradeoff.
-        raise ValueError(
-            f"native-layout flash needs block*heads*head_dim <= "
-            f"{NATIVE_BLOCK_ELEMS} (got {block}*{h}*{d} = {block * h * d}); "
-            f"pass a smaller block or use the packed layout")
+def _flash_plan(s: int, *, causal: bool, window: int | None,
+                block: int | None = None) -> tuple[int, int]:
+    """``(padded length, block)`` of a ``flash_attention`` call: the one place its
+    tiling is decided, for the op itself and for ``dispatch_plan``. The padded length
+    is ``s`` itself when it is lane-aligned or the call is not causal (only a causal
+    mask makes tail padding exact; ``_check_block`` refuses the rest), else the next
+    multiple of ``block`` (of 128 when ``auto_block`` is to choose)."""
     pad_to = block or BLOCK
     padded = s if s % BLOCK == 0 or not causal else -(-s // pad_to) * pad_to
     if block is None:
-        block = auto_block(padded, int(window or 0), native_hd=unroll_elems)
+        block = auto_block(padded, int(window or 0))
     _check_block(padded, block)
-    return layout, padded, int(block)
+    return padded, int(block)
 
 
 def dispatch_plan(shape, *, causal: bool = False, window: int | None = None,
                   k_len: int | None = None) -> dict:
     """What ``dispatch_attention`` does with a per-device ``[B, S, H, D]`` call, from
-    its shapes alone: ``{impl, score_bytes, seq_padded, block, layout}``. The one
+    its shapes alone: ``{impl, score_bytes, seq_padded, block}``. The one
     routing predicate: the dispatcher runs what this returns, and callers that label
     a measurement or a telemetry event (``train/lm.py``'s ``compile`` event,
     ``bench_transformer.py``, ``chip_smoke.py``) read the same dict, so a label
@@ -1187,15 +855,15 @@ def dispatch_plan(shape, *, causal: bool = False, window: int | None = None,
     Under ``jit`` over a mesh the shapes a trace sees are global: callers there
     hand the dispatcher per-device calls (``shard_map``) or keep the dense core
     (``train/lm.py``)."""
-    b, s, h, d = shape
+    b, s, h, _ = shape
     s_k = s if k_len is None else k_len
     plan = {"impl": "dense", "score_bytes": 4 * b * h * s * s_k,
-            "seq_padded": None, "block": None, "layout": None}
+            "seq_padded": None, "block": None}
     if (plan["score_bytes"] >= FLASH_MIN_SCORE_BYTES
             and 4 * s * s_k >= FLASH_MIN_HEAD_SCORE_BYTES
             and s_k == s and (causal or s % BLOCK == 0)):
-        layout, padded, block = _flash_plan(s, h, d, causal=causal, window=window)
-        plan.update(impl="flash", seq_padded=padded, block=block, layout=layout)
+        padded, block = _flash_plan(s, causal=causal, window=window)
+        plan.update(impl="flash", seq_padded=padded, block=block)
     return plan
 
 

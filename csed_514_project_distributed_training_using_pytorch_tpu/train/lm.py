@@ -82,7 +82,7 @@ def _attention_plan(config: LMConfig, seq_len: int, world: int, *,
          head_dim or config.embed_dim // heads),
         causal=True, window=config.attention_window)
     if not dispatched:
-        plan.update(impl="dense", seq_padded=None, block=None, layout=None)
+        plan.update(impl="dense", seq_padded=None, block=None)
     return plan
 
 

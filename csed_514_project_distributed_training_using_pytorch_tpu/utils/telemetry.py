@@ -311,7 +311,7 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
                   attention: dict | None = None, experts: dict | None = None) -> dict:
     """The ``compile`` event for one AOT-timed program. ``attention``: which core the
     program's attention calls get and why (``ops.dispatch_plan``'s dict: ``impl``,
-    ``score_bytes``, ``seq_padded``, ``block``, ``layout``), for the trainers that
+    ``score_bytes``, ``seq_padded``, ``block``), for the trainers that
     route through the dispatcher. ``experts``: what a step asks of each sparse expert
     layer (``ops.moe.expert_plan``: ``held``, ``row_bound``, ``rows_buffer``,
     ``block``, ``rows_moved``)."""

@@ -110,11 +110,10 @@ def test_dispatch_predicate_on_recorded_shapes(shape, causal, impl):
     assert plan["impl"] == impl
     assert plan["score_bytes"] == 4 * b * h * s * s
     if impl == "dense":
-        assert plan["seq_padded"] is plan["block"] is plan["layout"] is None
+        assert plan["seq_padded"] is plan["block"] is None
     else:
         assert plan["seq_padded"] % 128 == 0 and 0 <= plan["seq_padded"] - s < 128
         assert plan["seq_padded"] % plan["block"] == 0
-        assert plan["layout"] == "packed"
 
 
 def test_dispatch_plan_is_what_the_dispatcher_runs(monkeypatch):
@@ -132,6 +131,29 @@ def test_dispatch_plan_is_what_the_dispatcher_runs(monkeypatch):
     np.testing.assert_array_equal(
         np.asarray(pa.dispatch_attention(q, k[:, :100], v[:, :100])),
         np.asarray(full_attention(q, k[:, :100], v[:, :100])))
+
+
+def test_the_environment_does_not_choose_the_program(monkeypatch):
+    """Two variables once switched the kernels to other operand layouts at trace
+    time, so a shell that had them set timed another program. Nothing reads them:
+    the plan at both cells' shapes and the traced program are what they are without
+    them."""
+    cells = [(16, 784, 8, 128), (4, 8192, 32, 64)]
+    q, k, v = _qkv(256, d=128)
+
+    def program():
+        pa._make_op.cache_clear()       # a cached op would hide a read at trace time
+        return ([pa.dispatch_plan(shape, causal=True) for shape in cells],
+                str(jax.make_jaxpr(functools.partial(pa.flash_attention, causal=True))(
+                    q, k, v)))
+
+    plain = program()
+    assert [(p["impl"], p["seq_padded"], p["block"]) for p in plain[0]] == [
+        ("flash", 896, 896), ("flash", 8192, 1024)]
+    assert "flash_fwd" in plain[1]
+    monkeypatch.setenv("FLASH_NATIVE_LAYOUT", "1")
+    monkeypatch.setenv("FLASH_NATIVE_MODE", "unroll")
+    assert program() == plain
 
 
 def _lm(attention_fn, **kw):
@@ -204,7 +226,7 @@ def test_compile_event_reports_dense_for_a_tier1_sized_run(tmp_path, mesh):
     (event,) = _compile_events(tmp_path, mesh=mesh)
     assert event["attention"] == {"impl": "dense", "score_bytes": 4 * 8 * 2 * 784 * 784
                                   // (1 if mesh else jax.device_count()),
-                                  "seq_padded": None, "block": None, "layout": None}
+                                  "seq_padded": None, "block": None}
 
 
 def test_compile_event_reports_flash_for_the_cells_shapes():

@@ -115,7 +115,7 @@ def test_the_configuration_is_published_layers_1_to_5():
 def test_the_cells_attention_is_dispatched_to_the_flash_kernels():
     """``[4, 8192, 32, 64]`` causal: 34 GB of float32 scores, so never ``dense``."""
     plan = ops.dispatch_plan((4, 8192, 32, 64), causal=True)
-    assert (plan["impl"], plan["seq_padded"], plan["layout"]) == ("flash", 8192, "packed")
+    assert (plan["impl"], plan["seq_padded"], plan["block"]) == ("flash", 8192, 1024)
     assert plan["score_bytes"] == 4 * 4 * 32 * 8192 * 8192
     one_row = ops.dispatch_plan((1, 8192, 32, 64), causal=True)    # the checked steps
     assert one_row["impl"] == "flash"

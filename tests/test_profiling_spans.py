@@ -7,6 +7,7 @@ import json
 import os
 import threading
 import time
+import types
 
 import numpy as np
 import pytest
@@ -76,18 +77,21 @@ def test_drain_reports_the_step_before_the_open_one_too():
     assert set(P.drain()[0]) == {"loop", "loop/emit"}
 
 
-def test_drain_splits_the_spans_open_at_that_instant():
+def test_drain_splits_the_spans_open_at_that_instant(monkeypatch):
     """Their time so far counts in this period and the rest in the next, so the
-    seconds of a period are pieces of it."""
+    seconds of a period are pieces of it. The test drives the module's clock: a
+    bound on a ``sleep`` is a bound on how loaded the machine is."""
+    now = [100.0]
+    monkeypatch.setattr(P, "time", types.SimpleNamespace(perf_counter=lambda: now[0]))
+    P.drain()                               # the table's last drain, on this clock
     with P.step("loop", 0):
         with P.span("loop/emit"):
-            time.sleep(0.003)
+            now[0] += 3.0
             first, period_1 = P.drain()
-            time.sleep(0.001)
+            now[0] += 1.0
         second, period_2 = P.drain()
-    assert 0.003 <= first["loop/emit"] <= first["loop"] <= period_1
-    assert 0.001 <= second["loop/emit"] <= second["loop"] <= period_2
-    assert second["loop/emit"] < 0.003      # not the whole span again
+    assert (first, period_1) == ({"loop": 3.0, "loop/emit": 3.0}, 3.0)
+    assert (second, period_2) == ({"loop": 1.0, "loop/emit": 1.0}, 1.0)   # the rest only
 
 
 def test_an_exception_closes_its_spans():

@@ -45,10 +45,18 @@ _REPO = os.path.join(os.path.dirname(__file__), os.pardir)
 SMALL = dict(vocab_size=9, seq_len=16, embed_dim=32, num_layers=2, num_heads=4)
 
 # The tier-1 accuracy budget for TINY RANDOM-INIT models (near-uniform logits —
-# the hardest case for argmax stability; measured 0.95-1.0 across configs and
-# seeds). The committed real-checkpoint artifact documents the trained-model
-# budget, which is tighter.
+# the hardest case for argmax stability): the share of steps at which the int8
+# path, GIVEN THE FLOAT32 STREAM'S PREFIX, picks the float32 token (measured
+# 0.958-1.0 over the four configs x three inits x two request sets; free-running
+# streams read 0.896-1.0 on the same runs, because one flipped near-tie puts
+# every later token on another prefix). The committed real-checkpoint artifact
+# documents the trained-model budget, which is tighter.
 TOKEN_MATCH_BOUND = 0.90
+# What a flipped step may cost: the float32 log-prob of the int8 path's pick lies
+# this close under the float32 best. int8 KV + w8 moved no log-prob of those runs
+# by more than 0.0035, a flip needs a gap under twice that, and the largest seen
+# was 0.0017; 0.01 is three times the largest move.
+NEAR_TIE_LOGP_GAP = 0.01
 NLL_DELTA_BOUND = 0.05
 
 
@@ -70,6 +78,23 @@ def _mixed_requests(model, n, seed=0, temperature=0.0):
         .astype(np.int32),
         max_new_tokens=int(rng.integers(1, model.seq_len - 1)),
         sampling=sampling, request_id=i) for i in range(n)]
+
+
+def _teacher_forced_logp(model, params, streams, kv_dtype=None):
+    """``[B, S, V - 1]`` log-probs of the serving decode path
+    (``decode_step_slots``, as ``lm.decode_nll`` scores it) with ``streams`` as
+    the forced inputs, without the BOS column: the sampler never emits it."""
+    b, s = streams.shape
+    inputs = jnp.transpose(model.shift_right(jnp.asarray(streams)))     # [S, B]
+
+    def step(cache, xs):
+        t, ids_t = xs
+        return lm.decode_step_slots(model, params, cache, ids_t,
+                                    jnp.full((b,), t, jnp.int32))
+
+    _, logp = jax.lax.scan(step, lm.init_cache(model, b, kv_dtype=kv_dtype),
+                           (jnp.arange(s, dtype=jnp.int32), inputs))
+    return np.asarray(jnp.transpose(logp, (1, 0, 2)))[..., :-1]
 
 
 def _run_engine(model, params, reqs, **kw):
@@ -278,27 +303,46 @@ def test_nll_delta_within_budget(kv, policy):
     dict(), dict(num_kv_heads=2), dict(attention_window=5), dict(rope=True),
 ], ids=["mha", "gqa", "window", "rope"])
 def test_engine_int8_greedy_token_match_budget(cfg):
-    """Acceptance: the int8-KV + int8-weight engine's greedy streams match the
-    fp32 engine's token-for-token above TOKEN_MATCH_BOUND across model configs,
-    with the decode program still compiled exactly once and every prefill size
-    compiled at most once (quantization changes plane I/O, never shape)."""
+    """Acceptance: given the fp32 engine's greedy stream as its prefix, the
+    int8-KV + int8-weight serving path picks the fp32 token at TOKEN_MATCH_BOUND
+    of the steps and a token within NEAR_TIE_LOGP_GAP of the fp32 best at all of
+    them, across model configs; the int8 engine's own stream does as much where it
+    first leaves the fp32 one (past that step the two are on different prefixes
+    and say nothing about quantization). The decode program is still compiled
+    exactly once and every prefill size at most once (quantization changes plane
+    I/O, never shape)."""
     model = _model(**cfg)
     params = _params(model)
+    qparams = quant.quantize_params(
+        params, quant.QuantPolicy(kv_dtype="int8", weights="w8"))
     reqs = _mixed_requests(model, 6, seed=7)
     _, ref = _run_engine(model, params, reqs)
     eng, got = _run_engine(model, params, reqs,
                            kv_dtype="int8", quant_policy="w8")
     assert eng.trace_count == 1
     assert all(v <= 1 for v in eng.prefill_trace_counts.values())
+    streams = np.zeros((len(reqs), model.seq_len), np.int32)
+    for row, req in zip(streams, reqs):
+        row[:len(ref[req.request_id])] = ref[req.request_id]
+    logp_32 = _teacher_forced_logp(model, params, streams)
+    logp_8 = _teacher_forced_logp(model, qparams, streams, kv_dtype="int8")
     agree = total = 0
-    for req in reqs:
+    for i, req in enumerate(reqs):
         p = len(req.prompt)
         a, b = ref[req.request_id], got[req.request_id]
         # The teacher-forced prompt prefix survives bit-exactly regardless.
         np.testing.assert_array_equal(a[:p], b[:p])
-        n = min(len(a), len(b)) - p
-        agree += int((a[p:p + n] == b[p:p + n]).sum())
-        total += n
+        steps = np.arange(p, len(a))
+        picks = logp_8[i, steps].argmax(-1)
+        best = logp_32[i, steps].max(-1)
+        # The fp32 stream is this path's own greedy stream (to float32 round-off).
+        np.testing.assert_allclose(logp_32[i, steps, a[p:]], best, atol=1e-5)
+        assert (best - logp_32[i, steps, picks]).max() <= NEAR_TIE_LOGP_GAP
+        agree += int((picks == a[p:]).sum())
+        total += len(steps)
+        n = min(len(a), len(b))
+        left = p + np.flatnonzero(a[p:n] != b[p:n])[:1]      # the engine's own stream
+        assert (logp_32[i, left].max(-1) - logp_32[i, left, b[left]] <= NEAR_TIE_LOGP_GAP).all()
     assert total > 0
     assert agree / total >= TOKEN_MATCH_BOUND, \
         f"token match {agree / total:.3f} under budget {TOKEN_MATCH_BOUND}"

@@ -216,14 +216,21 @@ def test_spec_engine_ctor_validation():
 
 def test_spec_rejection_sampling_total_variation_bound():
     """Distribution preservation: with a drafter in play on the very first
-    generated token, temperature-1.0 speculative sampling's first-token
-    distribution stays within small total-variation distance of the
-    non-speculative sampler's — the rejection rule (accept d w.p. p(d), else
-    resample from p with d masked) IS the target distribution, so only RNG
-    scheduling differs (the quant suite's bound style)."""
+    generated token, temperature-1.0 speculative sampling draws that token from
+    the model's own next-token distribution, as the non-speculative sampler
+    does — the rejection rule (accept d w.p. p(d), else resample from p with d
+    masked) IS the target distribution, so only RNG scheduling differs.
+
+    Each sampler's ``n`` draws are held against the exact distribution ``p``
+    (every request has the same prompt), not against each other: two empirical
+    distributions of 64 draws over 8 tokens lie 0.19 apart on average, which the
+    earlier bound of 0.15 between them did not allow. The bound is the 99.9th
+    percentile of the total-variation distance between ``n`` draws of ``p`` and
+    ``p`` itself, over 4,000 seeded multinomial draws (0.130 at n = 256, mean
+    0.066; an always-accept rule would read 1 - p(3) = 0.89)."""
     model = _model()
     params = _params(model)
-    n = 64
+    n = 256
     sampling = SamplingParams(temperature=1.0)
     reqs = [Request(prompt=np.asarray([1, 2], np.int32), max_new_tokens=2,
                     sampling=sampling, request_id=i) for i in range(n)]
@@ -238,11 +245,18 @@ def test_spec_rejection_sampling_total_variation_bound():
     a, _ = first_tokens()
     b, eng = first_tokens(spec="const", spec_k=2, drafter=_ConstDrafter(3))
     assert eng.spec_stats()["proposed"] > 0   # drafts were actually in play
-    v = model.vocab_size
-    pa = np.bincount(a, minlength=v) / n
-    pb = np.bincount(b, minlength=v) / n
-    tv = 0.5 * float(np.abs(pa - pb).sum())
-    assert tv <= 0.15, f"total-variation distance {tv:.3f} too large"
+    # The target: the float32 forward's distribution after the prompt, without the
+    # BOS id, which the sampler masks.
+    ids = np.zeros((1, model.seq_len), np.int32)
+    ids[0, :2] = reqs[0].prompt
+    logp = model.apply({"params": params}, model.shift_right(jnp.asarray(ids)))
+    p = np.exp(np.asarray(logp, np.float64)[0, 2, :-1])
+    p /= p.sum()
+    draws = np.random.default_rng(0).multinomial(n, p, size=4000) / n
+    bound = float(np.quantile(0.5 * np.abs(draws - p).sum(axis=1), 0.999))
+    for name, tokens in (("plain", a), ("speculative", b)):
+        tv = 0.5 * float(np.abs(np.bincount(tokens, minlength=p.size) / n - p).sum())
+        assert tv <= bound, f"{name}: total-variation distance {tv:.3f} > {bound:.3f}"
 
 
 # -----------------------------------------------------------------------------------------
