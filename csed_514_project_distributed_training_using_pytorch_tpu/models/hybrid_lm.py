@@ -25,7 +25,11 @@ The head is the embedding, tied, over the held slice of the vocabulary; the loss
 the mean next-token NLL over the ``S - 1`` targets of each sequence (position ``t``
 predicts token ``t + 1``; there is no BOS id). Parameters are a plain dict; ``init``
 and ``apply`` keep flax's calling convention so ``train/step.py`` builds the state
-as for any other model. No serving path: a short-convolution state beside keys and
+as for any other model. ``remat`` recomputes each block in the backward pass from
+its input and from what ``KEPT`` names: the flash kernel's output and statistics,
+the router's and the sort's products, and the matmul outputs that fit the chip beside
+the cell's state (everything else of a block, and the head's logits, runs again).
+No serving path: a short-convolution state beside keys and
 values in the slot engine is ROADMAP R4's.
 """
 
@@ -37,6 +41,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from csed_514_project_distributed_training_using_pytorch_tpu import ops
 from csed_514_project_distributed_training_using_pytorch_tpu.ops import moe
@@ -45,6 +50,11 @@ from csed_514_project_distributed_training_using_pytorch_tpu.ops.rotary import (
 )
 
 LAYER_KINDS = ("conv", "full_attention")
+# What ``remat`` keeps of a block between its forward and its backward pass, beside
+# the block's input: the names of ``jax.ad_checkpoint.checkpoint_name`` tags, set
+# where each value is born (here, ``ops/pallas_attention.py``, ``ops/moe.py``).
+KEPT = ("flash_out", "flash_lse", "moe_route", "moe_sort", "mixer_out",
+        "attn_proj", "conv_in_proj", "ff_gate")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,6 +112,22 @@ class HybridLM:
             return None
         return moe.expert_plan(tokens, top_k=self.num_experts_per_tok,
                                held=self.held_experts, block=self.expert_block)
+
+    def recompute_plan(self, jaxpr) -> dict | None:
+        """The ``compile`` event's ``recompute`` field: the names ``remat`` keeps and
+        the bytes held under them between a step's forward and its backward pass
+        (the blocks' inputs, kept under any policy, are not in it), summed from the
+        values tagged with those names in ``jaxpr``: that of a program which
+        differentiates the loss once (a train step, an epoch that scans it), where a
+        kept tag stands once, in the forward pass (the backward pass of a
+        ``jax.checkpoint`` takes the value as an input). None without ``remat``."""
+        if not self.remat:
+            return None
+        kept = [v.aval for eqn in _equations(getattr(jaxpr, "jaxpr", jaxpr))
+                if eqn.primitive.name == "name" and eqn.params["name"] in KEPT
+                for v in eqn.outvars]
+        return {"kept": list(KEPT),
+                "kept_bytes": sum(a.size * a.dtype.itemsize for a in kept)}
 
     def param_shapes(self) -> dict:
         d, hd = self.hidden_size, self.head_dim
@@ -173,7 +199,8 @@ class HybridLM:
         for i, kind in enumerate(self.layer_types[:layers]):
             fn = make_block(self, kind, i >= self.num_dense_layers)
             if self.remat:
-                fn = jax.checkpoint(fn)
+                fn = jax.checkpoint(
+                    fn, policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
             x, arrived = fn(params[f"layer_{i}"], x, positions)
             if arrived is not None:
                 counts.append(arrived)
@@ -239,6 +266,17 @@ class HybridLM:
         return total / (tokens.shape[0] * (tokens.shape[1] - 1)), counts
 
 
+def _equations(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs among its equations' parameters."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
 def make_block(model: HybridLM, kind: str, sparse: bool):
     """``block(p, x, positions) -> (y, counts | None)`` of one layer."""
 
@@ -256,9 +294,9 @@ def make_block(model: HybridLM, kind: str, sparse: bool):
 def mix(p, x, positions, kind: str, model: HybridLM):
     """``x + mixer(RMSNorm(x))``: the first half of a block."""
     u = ops.rms_norm(x, p["mixer_norm_scale"], eps=model.norm_eps)
-    if kind == "conv":
-        return x + conv_mixer(p["conv"], u)
-    return x + attention_mixer(p["attn"], u, positions, model)
+    mixed = (conv_mixer(p["conv"], u) if kind == "conv"
+             else attention_mixer(p["attn"], u, positions, model))
+    return checkpoint_name(x + mixed, "mixer_out")
 
 
 def _dense(x, kernel):
@@ -275,7 +313,8 @@ def causal_depthwise_conv(z: jax.Array, kernel: jax.Array) -> jax.Array:
 
 def conv_mixer(p, u):
     with jax.named_scope("conv_mixer"):
-        b, c, x = jnp.split(_dense(u, p["in_proj_kernel"]), 3, axis=-1)
+        b, c, x = jnp.split(
+            checkpoint_name(_dense(u, p["in_proj_kernel"]), "conv_in_proj"), 3, axis=-1)
         return _dense(c * causal_depthwise_conv(b * x, p["conv_kernel"]),
                       p["out_proj_kernel"])
 
@@ -285,9 +324,10 @@ def attention_mixer(p, u, positions, model: HybridLM):
         b, s, _ = u.shape
         heads, kv, hd = (model.num_attention_heads, model.num_key_value_heads,
                          model.head_dim)
-        q = _dense(u, p["q_kernel"]).reshape(b, s, heads, hd)
-        k = _dense(u, p["k_kernel"]).reshape(b, s, kv, hd)
-        v = _dense(u, p["v_kernel"]).reshape(b, s, kv, hd)
+        # Named as the matmuls wrote them, not after the norm and the rotation: the
+        # norm's backward pass reads its input, so its output kept spares no matmul.
+        q, k, v = (checkpoint_name(_dense(u, p[f"{name}_kernel"]), "attn_proj")
+                   .reshape(b, s, n, hd) for name, n in (("q", heads), ("k", kv), ("v", kv)))
         q = apply_rotary(ops.rms_norm(q, p["q_norm_scale"], eps=model.norm_eps),
                          positions, base=model.rope_theta)
         k = apply_rotary(ops.rms_norm(k, p["k_norm_scale"], eps=model.norm_eps),
@@ -299,8 +339,10 @@ def attention_mixer(p, u, positions, model: HybridLM):
 
 def dense_ff(p, u):
     with jax.named_scope("dense_ff"):
-        return _dense(ops.swiglu(_dense(u, p["w1_kernel"]), _dense(u, p["w3_kernel"])),
-                      p["w2_kernel"])
+        # ``W1 u`` is kept and ``W3 u`` recomputed: beside the cell's state the chip
+        # has room for one ``[T, intermediate]`` array more, not for two (PERF.md §6).
+        gate = checkpoint_name(_dense(u, p["w1_kernel"]), "ff_gate")
+        return _dense(ops.swiglu(gate, _dense(u, p["w3_kernel"])), p["w2_kernel"])
 
 
 def sparse_ff(p, u, model: HybridLM):

@@ -52,6 +52,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -73,14 +74,20 @@ def route(u: jax.Array, router_kernel: jax.Array, select_bias: jax.Array, *,
     selections), sigmoid and top-k in float32; ``select_bias`` moves the selection
     and not the weights, and gets no gradient."""
     with jax.named_scope("moe/route"):
-        scores = jax.nn.sigmoid(jnp.dot(
+        # Named: what a caller's ``jax.checkpoint`` may keep of the router (a policy
+        # over names; an identity otherwise). The logits and not the scores, because
+        # the sigmoid's derivative reads the variable the sigmoid wrote.
+        logits = checkpoint_name(jnp.dot(
             u.astype(jnp.float32), router_kernel.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
+            precision=jax.lax.Precision.HIGHEST), "moe_route")
+        scores = jax.nn.sigmoid(logits)
         _, experts = jax.lax.top_k(
             scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)), top_k)
-        picked = jnp.take_along_axis(scores, experts, axis=-1)
+        experts = checkpoint_name(experts.astype(jnp.int32), "moe_route")
+        picked = checkpoint_name(jnp.take_along_axis(scores, experts, axis=-1),
+                                 "moe_route")
         weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-6)
-        return weights * scaling, experts.astype(jnp.int32)
+        return weights * scaling, experts
 
 
 def expert_plan(tokens: int, *, top_k: int, held: tuple[int, int],
@@ -546,4 +553,6 @@ def held_experts_ffn(x: jax.Array, weights: jax.Array, experts: jax.Array,
     with jax.named_scope("moe/sort"):
         sort = _sort(experts, held, tm)
     counts = sort.pop("counts")
+    # Residuals of the VJP below, named before they enter it (see ``route``).
+    sort = jax.tree.map(lambda leaf: checkpoint_name(leaf, "moe_sort"), sort)
     return _grouped_ffn(tm)(x, weights, w1, w3, w2, sort), counts

@@ -57,6 +57,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -756,7 +757,11 @@ def _make_op(causal: bool, block: int = BLOCK, window: int = 0):
         return flash_forward(q3, k3, v3)[0]
 
     def fwd(q3, k3, v3):
+        # Named here, outside the jitted half, as the VJP's residuals: a caller's
+        # ``jax.checkpoint`` whose policy keeps these names does not run the forward
+        # kernel again in its backward pass (an identity under no such policy).
         out, lse = flash_forward(q3, k3, v3)
+        out, lse = checkpoint_name(out, "flash_out"), checkpoint_name(lse, "flash_lse")
         return out, (q3, k3, v3, out, lse)
 
     bwd = flash_backward

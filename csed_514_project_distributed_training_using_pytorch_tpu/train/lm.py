@@ -431,9 +431,11 @@ def main(config: LMConfig = LMConfig(), *,
             experts = model.expert_plan(
                 config.batch_size // world // config.grad_accum * seq_len
             ) if hybrid else None
+            recompute = model.recompute_plan(aot["jaxpr"]) if hybrid else None
             tele.emit(T.compile_event("epoch", aot,
                                       steps_per_call=steps_per_epoch,
-                                      attention=attention, experts=experts))
+                                      attention=attention, experts=experts,
+                                      recompute=recompute))
     history = M.MetricsHistory()
     saver = checkpoint.make_saver(config.async_checkpoint, tele=tele)
 

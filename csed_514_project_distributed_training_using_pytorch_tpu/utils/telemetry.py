@@ -280,7 +280,9 @@ def compiled_bytes_accessed(compiled) -> float | None:
 def aot_compile(jit_fn, *args) -> tuple[object | None, dict | None]:
     """Time ``jit_fn.lower(*args).compile()`` — the compile/execute split.
 
-    Returns ``(compiled, {"lower_s", "compile_s", "flops"})``; the caller should
+    Returns ``(compiled, {"lower_s", "compile_s", "flops", "bytes_accessed",
+    "jaxpr"})`` (``jaxpr``: the traced program's, for a caller that reads what the
+    trace holds, as ``HybridLM.recompute_plan`` does); the caller should
     invoke ``compiled`` directly (the AOT program does not populate ``jit_fn``'s
     cache, so calling the jit object afterwards would compile twice). ``args`` may
     mix concrete arrays and ``jax.ShapeDtypeStruct``s. ``(None, None)`` when the
@@ -293,7 +295,8 @@ def aot_compile(jit_fn, *args) -> tuple[object | None, dict | None]:
         return None, None
     try:
         t0 = time.perf_counter()
-        lowered = jit_fn.lower(*args)
+        traced = jit_fn.trace(*args)
+        lowered = traced.lower()
         lower_s = time.perf_counter() - t0
         t0 = time.perf_counter()
         compiled = lowered.compile()
@@ -304,17 +307,21 @@ def aot_compile(jit_fn, *args) -> tuple[object | None, dict | None]:
         return None, None
     return compiled, {"lower_s": lower_s, "compile_s": compile_s,
                       "flops": compiled_flops(compiled),
-                      "bytes_accessed": compiled_bytes_accessed(compiled)}
+                      "bytes_accessed": compiled_bytes_accessed(compiled),
+                      "jaxpr": traced.jaxpr}
 
 
 def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
-                  attention: dict | None = None, experts: dict | None = None) -> dict:
+                  attention: dict | None = None, experts: dict | None = None,
+                  recompute: dict | None = None) -> dict:
     """The ``compile`` event for one AOT-timed program. ``attention``: which core the
     program's attention calls get and why (``ops.dispatch_plan``'s dict: ``impl``,
     ``score_bytes``, ``seq_padded``, ``block``), for the trainers that
     route through the dispatcher. ``experts``: what a step asks of each sparse expert
     layer (``ops.moe.expert_plan``: ``held``, ``row_bound``, ``rows_buffer``,
-    ``block``, ``rows_moved``)."""
+    ``block``, ``rows_moved``). ``recompute``: what per-block recomputation keeps
+    between a step's forward and backward pass (``HybridLM.recompute_plan``: the
+    ``kept`` names and ``kept_bytes``); None when nothing is recomputed."""
     flops = aot.get("flops")
     return {
         "event": "compile",
@@ -331,6 +338,7 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
             if aot.get("bytes_accessed") and steps_per_call else None),
         "attention": attention,
         "experts": experts,
+        "recompute": recompute,
     }
 
 

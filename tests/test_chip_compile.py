@@ -8,6 +8,7 @@ All such tests live in this one file: the worker that gets it loads the TPU's
 library once, inside the fixture, after collection.
 """
 
+import contextlib
 import os
 
 import jax
@@ -25,6 +26,24 @@ def one_chip():
     except Exception as e:      # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     return SingleDeviceSharding(topo.devices[0])
+
+
+@contextlib.contextmanager
+def lowering_for_the_chip(*modules):
+    """Inside, the Pallas kernels of ``modules`` lower for Mosaic and not for the
+    interpreter (``_interpret`` sees the CPU here), and nothing is written to a
+    compile cache that could not be read back without a chip."""
+    cache = jax.config.jax_enable_compilation_cache
+    interprets = [module._interpret for module in modules]
+    jax.config.update("jax_enable_compilation_cache", False)
+    for module in modules:
+        module._interpret = lambda: False
+    try:
+        yield
+    finally:
+        for module, interpret in zip(modules, interprets):
+            module._interpret = interpret
+        jax.config.update("jax_enable_compilation_cache", cache)
 
 
 KERNELS = ("moe_ffn_fwd", "moe_ffn_bwd", "moe_ffn_dw",
@@ -50,20 +69,13 @@ def compiled_layer(one_chip):
                                                held=(0, HELD))
             return jnp.sum(out.astype(jnp.float32)), counts
 
-        cache = jax.config.jax_enable_compilation_cache
-        interpret = moe._interpret
-        jax.config.update("jax_enable_compilation_cache", False)
-        moe._interpret = lambda: False
-        try:
+        with lowering_for_the_chip(moe):
             texts[tokens] = jax.jit(jax.value_and_grad(
                 layer, argnums=(0, 1, 3, 4, 5), has_aux=True)).lower(
                 spec((tokens, D), jnp.bfloat16), spec((D, ROUTER), jnp.float32),
                 spec((ROUTER,), jnp.float32), spec((D, HELD * F), jnp.float32),
                 spec((D, HELD * F), jnp.float32), spec((F, HELD * D), jnp.float32)
             ).compile().as_text()
-        finally:
-            moe._interpret = interpret
-            jax.config.update("jax_enable_compilation_cache", cache)
         return texts[tokens]
 
     return compile_for
@@ -98,3 +110,45 @@ def test_no_crossing_is_left_to_xla_at_the_size_of_the_bound(compiled_layer, tok
                  and "tpu_custom_call" not in line
                  and not re.search(r"= \S+ (parameter|get-tuple-element|bitcast)\(", line)]
     assert not offenders, offenders
+
+
+def test_the_lfm2_step_keeps_its_flash_forward_and_fits_the_v5e(one_chip):
+    """The training step of ``lfm2-24b-a2b-ep8`` at the cell's batch, 4 x 8192 tokens
+    (value, gradient, clip, AdamW; bf16, per-block recomputation on), compiled for the
+    described chip: what recomputation keeps leaves arguments + temporaries under
+    15.0 GB of the chip's 15.75, and the differentiated step calls ``flash_fwd``
+    once for its one attention layer, not again in the backward pass."""
+    import re
+    from csed_514_project_distributed_training_using_pytorch_tpu import ops
+    from csed_514_project_distributed_training_using_pytorch_tpu.models import hybrid_lm
+    from csed_514_project_distributed_training_using_pytorch_tpu.ops import (
+        moe, optim, pallas_attention)
+    from csed_514_project_distributed_training_using_pytorch_tpu.train.step import (
+        create_train_state, make_train_step)
+    config = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                          "benchmark", "configs", "lfm2-24b-a2b-ep8.json")
+    batch, seq = 4, 8192
+    model = hybrid_lm.from_config_file(
+        config, vocab_size=8192, seq_len=seq, dtype=jnp.bfloat16, remat=True,
+        attention_fn=ops.dispatch_attention)
+    assert model.layer_types.count("full_attention") == 1
+    optimizer = optim.freeze(optim.make_optimizer(
+        "adamw", learning_rate=1e-6, momentum=0.0, weight_decay=0.01), hybrid_lm.is_frozen)
+    step = make_train_step(
+        model, learning_rate=1e-6, momentum=0.0, optimizer=optimizer, clip_grad_norm=1.0,
+        loss_fn=lambda params, xs, ys, rng: model.loss(params, xs), loss_has_aux=True)
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    state = jax.eval_shape(lambda: create_train_state(
+        model, jax.random.PRNGKey(0), sample_input_shape=(1, seq), optimizer=optimizer))
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+    labels = jax.ShapeDtypeStruct((batch,), jnp.int32)
+    rng = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+
+    with lowering_for_the_chip(moe, pallas_attention):
+        compiled = jax.jit(step, donate_argnums=(0,)).lower(
+            *on_chip((state, tokens, labels, rng))).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes <= 15.0e9
+    calls = lambda kernel: len(re.findall(rf"%{kernel}[.\d]* = ", compiled.as_text()))
+    assert (calls("flash_fwd"), calls("flash_dq"), calls("flash_dkv")) == (1, 1, 1)

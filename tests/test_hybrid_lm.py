@@ -96,6 +96,76 @@ def test_loss_and_gradients_match_the_reference(stack, remat):
         assert float(jnp.abs(g - w).max()) <= 2e-4 * scale, jax.tree_util.keystr(path)
 
 
+@pytest.mark.parametrize("stack", STACKS)
+def test_remat_changes_no_float(stack):
+    """What ``remat`` keeps is what it would have recomputed: the loss and every
+    gradient leaf are the same floats with and without it."""
+    results = []
+    for remat in (False, True):
+        model, params = build(STACKS[stack], remat=remat)
+        results.append(jax.value_and_grad(model.loss, has_aux=True)(params, tokens()))
+    ((loss, _), grads), ((want, _), want_grads) = results
+    np.testing.assert_array_equal(loss, want)
+    for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
+                            jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_array_equal(g, w, err_msg=jax.tree_util.keystr(path))
+
+
+def _forward_runs(model, params):
+    """``(flash_fwd calls, top_k's, sorts)`` in the loss's value and gradient."""
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(model.loss, has_aux=True))(params, tokens())
+    names = [eqn.params["name"] if eqn.primitive.name == "pallas_call"
+             else eqn.primitive.name for eqn in hybrid_lm._equations(jaxpr.jaxpr)]
+    return names.count("flash_fwd"), names.count("top_k"), names.count("sort")
+
+
+def test_remat_runs_the_flash_forward_the_router_and_the_sort_once(monkeypatch):
+    """Published layers 1-5 (one attention layer, four sparse; the flash kernels in
+    interpret mode): the backward pass of
+    a block takes the flash kernel's output and statistics, the router's choice and
+    the sort's products from its forward pass. Under a ``jax.checkpoint`` with no
+    policy, as before PR 29, each ran again."""
+    model, params = build(tiny_config(), remat=True, attention_fn=ops.flash_attention)
+    assert _forward_runs(model, params) == (1, 4, 4)
+    plain, _ = build(tiny_config(), attention_fn=ops.flash_attention)
+    assert _forward_runs(plain, params) == (1, 4, 4)
+    block = hybrid_lm.make_block
+    monkeypatch.setattr(hybrid_lm, "make_block", lambda *a: jax.checkpoint(block(*a)))
+    assert _forward_runs(plain, params) == (2, 8, 8)
+
+
+ITEMSIZE = {"f32": 4, "bf16": 2, "i32": 4, "u32": 4, "bool": 1}
+
+
+def _residual_bytes(capsys, model, params) -> int:
+    """The bytes ``jax.ad_checkpoint.print_saved_residuals`` lists for the loss,
+    the parameters left out (an argument is not held for the backward pass's sake)."""
+    import re
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(lambda p: model.loss(p, tokens())[0], params)
+    lines = [line for line in capsys.readouterr().out.strip().splitlines()
+             if " from the argument " not in line]
+    shapes = [re.match(r"(\w+)\[([\d,]*)\] ", line).groups() for line in lines]
+    return sum(ITEMSIZE[dtype] * int(np.prod([int(n) for n in dims.split(",") if n]))
+               for dtype, dims in shapes)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bf16"])
+def test_kept_bytes_are_the_named_residuals(capsys, monkeypatch, dtype):
+    """``recompute_plan``'s ``kept_bytes`` in the loss's gradient against jax's own
+    list of what the backward pass is handed: with the policy it holds, beside
+    everything a ``jax.checkpoint`` with no policy holds (the blocks' inputs, the
+    head's), the named values and nothing else."""
+    model, params = build(tiny_config(), remat=True, attention_fn=ops.flash_attention, dtype=dtype)
+    gradient = jax.make_jaxpr(jax.grad(lambda p: model.loss(p, tokens())[0]))(params)
+    plan = model.recompute_plan(gradient)
+    assert plan["kept"] == list(hybrid_lm.KEPT) and plan["kept_bytes"] > 0
+    with_names = _residual_bytes(capsys, model, params)
+    monkeypatch.setattr(hybrid_lm, "KEPT", ())
+    assert with_names - _residual_bytes(capsys, model, params) == plan["kept_bytes"]
+    assert build(tiny_config())[0].recompute_plan(gradient) is None
+
+
 def test_the_configuration_is_published_layers_1_to_5():
     model, params = build(tiny_config())
     assert model.layer_types == ("conv", "full_attention", "conv", "conv", "conv")
@@ -418,10 +488,10 @@ def trained(tmp_path_factory):
     with open(config_file, "w") as fh:
         json.dump(tiny_config(vocab_size=256), fh)
     runs = []
-    for name in ("a", "b"):
+    for name, remat in (("a", False), ("b", True)):
         tele = str(work / f"{name}.jsonl")
         state, history = train_lm.main(LMConfig(
-            model_config=config_file, mesh="data=1",
+            model_config=config_file, mesh="data=1", remat=remat,
             corpus=os.path.join(REPO, "tests", "fixtures", "corpus_tiny"),
             epochs=2, batch_size=8, eval_batch=19, learning_rate=3e-3, seed=5,
             telemetry=tele, results_dir="", images_dir=str(work / "images"), generate=0))
@@ -456,7 +526,25 @@ def test_the_events_carry_the_expert_layers_fields(trained):
         assert 0 < rows.sum() < 4 * 8 * 64 * rows.size     # under the static bound
 
 
+def test_the_compile_event_says_what_recomputation_keeps(trained):
+    """``recompute``: null without ``--remat``; with it the kept names and the bytes
+    a step of 8 x 64 tokens holds under them (float32, dense attention: the flash
+    names tag nothing here)."""
+    (_, _, plain), (_, _, remat) = trained
+    event = lambda events: [e for e in events if e["event"] == "compile"][0]
+    assert event(plain)["recompute"] is None
+    t, d, k, held = 8 * 64, 32, 4, 4
+    rows = (t * k // 256 + held) * 256
+    sort = 2 * rows * 4 + t * k * (4 + 1) + 4 + held * (t // 256 + 1) * 4 + (rows // 256) * 4
+    route = t * 16 * 4 + 2 * t * k * 4
+    assert event(remat)["recompute"] == {
+        "kept": list(hybrid_lm.KEPT),
+        "kept_bytes": 5 * t * d * 4 + 2 * t * d * 4 + 4 * 3 * t * d * 4 + t * 48 * 4
+        + 4 * (route + sort)}
+
+
 def test_two_runs_from_one_seed_agree_and_the_bias_stays(trained):
+    """The second run recomputes (``remat``): the same floats all the same."""
     (a, _, ea), (b, _, eb) = trained
     for x, y in zip(jax.tree_util.tree_leaves(a.params), jax.tree_util.tree_leaves(b.params)):
         np.testing.assert_array_equal(x, y)
