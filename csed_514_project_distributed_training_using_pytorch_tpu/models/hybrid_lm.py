@@ -1,27 +1,46 @@
 """Decoder LM built from a published configuration file: a stack of
-short-convolution and QK-norm GQA blocks with dense or sparse gated feed-forwards.
+short-convolution, Mamba-2, GQA and expert layers.
 
 ``TransformerLM`` (``models/lm.py``) is the repo's own pixel decoder; this module is
 how a catalog architecture trains through ``train.lm``: the keys of the model's
-public ``config.json`` (``layer_types``, ``num_dense_layers``, widths, ``norm_eps``,
-``rope_parameters``) build the stack, and a file that also states this chip's share
-of a deployment (``share``: which experts of every sparse layer and which slice of
-the vocabulary are held here, which published layer comes first) builds that share.
-``LFM2-24B-A2B`` (``model_type`` ``lfm2_moe``) is the first such file.
+public ``config.json`` build the stack (``from_config`` dispatches on ``model_type``),
+and a file that also states this chip's share of a deployment (``share``: which
+experts of every sparse layer, which heads and groups of every mixer and which slice
+of the vocabulary are held here, which published layer comes first) builds that
+share. ``LFM2-24B-A2B`` (``lfm2_moe``: ``layer_types``, ``num_dense_layers``) was the
+first such file, ``NVIDIA-Nemotron-3-Super-120B-A12B`` (``nemotron_h``:
+``hybrid_override_pattern``) is the second.
 
-Each block kind is written once, as a function of the block's parameters, the
-normalized input and the positions:
+A layer is a block of two sublayers (``LAYER_KINDS``: its mixer) or one sublayer
+alone (``SUBLAYER_KINDS``). Each kind is written once, as a function of its
+parameters, the normalized input and the positions:
 
     block          h = x + mixer(RMSNorm(x));  y = h + ff(RMSNorm(h))
+    sublayer       y = x + sublayer(RMSNorm(x)): a mamba mixer, an attention or an
+                   expert feed-forward
     conv_mixer     [B, C, X] = split3(W_in u);  c_t = Σ_j w[j] ⊙ (B ⊙ X)_{t-L+1+j}
                    (depthwise, causal, zeros before the start);  W_out (C ⊙ c)
-    attention      q, k RMS-normed per head before RoPE (half-split pairing), causal
-                   softmax(q·k/√D)·v in groups, through the pluggable ``attention_fn``
+    mamba_mixer    [z | xBC | dt] = W_in u;  xBC = silu(conv(xBC) + bias);  [X | B | C] =
+                   split(xBC);  Δ = softplus(dt + dt_bias), A = −exp(A_log) in float32;
+                   per head S_t = exp(Δ_t A) S_{t−1} + Δ_t X_t ⊗ B_t, Y_t = S_t C_t + D X_t
+                   (``ops/ssm.py``, heads of a group read its B, C; D is the leaf
+                   ``D_scale``, one at the start as published);
+                   W_out (w ⊙ RMSNorm_group(Y ⊙ silu(z)))
+    attention      q, k RMS-normed per head (or not) before RoPE (half-split pairing;
+                   or no positions at all), causal softmax(q·k/√D)·v in groups,
+                   through the pluggable ``attention_fn``
     dense_ff       W_2 (silu(W_1 u) ⊙ W_3 u)
     sparse_ff      ``ops/moe.py``: sigmoid router over all experts, top-k of s + b,
-                   the held experts' part of the result, dropless
+                   the held experts' part of the result, dropless. Experts are gated
+                   (three matrices) on the model's rows, or relu² (two) on a latent row
+                   (W_fc2 Σ_e w_e W2_e relu(W1_e W_fc1 u)²), beside a shared expert
+                   W_s2 relu(W_s1 u)² that every token passes
 
-The head is the embedding, tied, over the held slice of the vocabulary; the loss is
+A share holds a mixer's heads as it holds experts: the out-projection sums over the
+held heads (of a Mamba-2 layer: whole groups, each with its own B, C and its own
+group of the gated norm), the shared expert over its held columns, and the partial
+result is what goes on. The head is the embedding, tied, or a matrix of its own,
+over the held slice of the vocabulary; the loss is
 the mean next-token NLL over the ``S - 1`` targets of each sequence (position ``t``
 predicts token ``t + 1``; there is no BOS id). Parameters are a plain dict; ``init``
 and ``apply`` keep flax's calling convention so ``train/step.py`` builds the state
@@ -44,24 +63,28 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from csed_514_project_distributed_training_using_pytorch_tpu import ops
-from csed_514_project_distributed_training_using_pytorch_tpu.ops import moe
+from csed_514_project_distributed_training_using_pytorch_tpu.ops import moe, ssm
 from csed_514_project_distributed_training_using_pytorch_tpu.ops.rotary import (
     apply_rotary,
 )
 
-LAYER_KINDS = ("conv", "full_attention")
+LAYER_KINDS = ("conv", "full_attention")      # a block: this mixer, then a feed-forward
+SUBLAYER_KINDS = ("mamba", "attention", "moe")  # a layer that is one sublayer
 # What ``remat`` keeps of a block between its forward and its backward pass, beside
 # the block's input: the names of ``jax.ad_checkpoint.checkpoint_name`` tags, set
 # where each value is born (here, ``ops/pallas_attention.py``, ``ops/moe.py``).
 KEPT = ("flash_out", "flash_lse", "moe_route", "moe_sort", "mixer_out",
-        "attn_proj", "conv_in_proj", "ff_gate")
+        "attn_proj", "conv_in_proj", "ff_gate",
+        "ssd_out", "ssd_state", "mamba_in_proj", "moe_latent", "moe_routed",
+        "shared_hidden")
 
 
 @dataclasses.dataclass(frozen=True)
 class HybridLM:
     """The model, or one chip's share of it. Widths are the published ones; the
-    share is ``held_experts`` (first id, how many, of ``router_experts``) and
-    ``vocab_size`` (the slice's width)."""
+    share is ``held_experts`` (first id, how many, of ``router_experts``),
+    ``vocab_size`` (the slice's width) and, where a deployment divides the mixers,
+    the heads, groups and shared-expert columns held."""
 
     vocab_size: int
     seq_len: int
@@ -70,24 +93,43 @@ class HybridLM:
     moe_intermediate_size: int
     num_attention_heads: int
     num_key_value_heads: int
-    layer_types: tuple[str, ...]        # one kind a layer, of LAYER_KINDS
+    layer_types: tuple[str, ...]        # one kind a layer, of LAYER_KINDS + SUBLAYER_KINDS
     num_dense_layers: int               # leading layers with the dense feed-forward
     router_experts: int
     held_experts: tuple[int, int]
     num_experts_per_tok: int
     conv_L_cache: int = 3
     norm_eps: float = 1e-5
-    rope_theta: float = 1e6
+    rope_theta: float | None = 1e6      # None: attention without positions
+    qk_norm: bool = True
+    attention_head_dim: int | None = None   # None: hidden_size / num_attention_heads
+    tied_head: bool = True
     routed_scaling_factor: float = 1.0
+    router_eps: float = 1e-6            # beside the selected scores' sum
+    router_bias_update_rate: float = 0.0    # of the selection's bias a step; 0: held fixed
+    gated_experts: bool = True          # swiglu over W1, W3; else relu² over W1
+    moe_latent_size: int = 0            # the experts' row width, where not the model's
+    shared_expert_size: int = 0         # held columns of the shared relu² expert
+    mamba_heads: int = 0                # held heads of a Mamba-2 mixer, in whole groups
+    mamba_groups: int = 1
+    mamba_head_dim: int = 64
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = ssm.CHUNK
     dtype: jnp.dtype = jnp.float32
     remat: bool = False
     attention_fn: Callable = ops.full_attention
     expert_block: int | None = None     # rows of a kernel step (None: ops.moe.ROW_TILE)
 
     def __post_init__(self):
-        odd = sorted(set(self.layer_types) - set(LAYER_KINDS))
+        odd = sorted(set(self.layer_types) - set(LAYER_KINDS + SUBLAYER_KINDS))
         if odd:
-            raise ValueError(f"layer_types {odd} are not of {LAYER_KINDS}")
+            raise ValueError(f"layer_types {odd} are not of "
+                             f"{LAYER_KINDS + SUBLAYER_KINDS}")
+        if "mamba" in self.layer_types and (
+                self.mamba_heads < 1 or self.mamba_heads % self.mamba_groups):
+            raise ValueError(f"{self.mamba_groups} groups do not divide the "
+                             f"{self.mamba_heads} heads of a mamba layer")
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("num_key_value_heads must divide num_attention_heads")
         first, count = self.held_experts
@@ -99,19 +141,55 @@ class HybridLM:
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_attention_heads
+        return self.attention_head_dim or self.hidden_size // self.num_attention_heads
+
+    def is_sparse(self, layer: int) -> bool:
+        """Whether layer ``layer`` holds an expert feed-forward."""
+        kind = self.layer_types[layer]
+        return kind == "moe" or (kind in LAYER_KINDS and layer >= self.num_dense_layers)
 
     @property
     def sparse_layers(self) -> int:
-        return max(0, len(self.layer_types) - self.num_dense_layers)
+        return sum(self.is_sparse(i) for i in range(len(self.layer_types)))
+
+    def ssm_plan(self) -> dict | None:
+        """What a step asks of each state-space layer (``ops.ssm.scan_plan``), or None
+        for a stack with none."""
+        if "mamba" not in self.layer_types:
+            return None
+        return ssm.scan_plan(heads=self.mamba_heads, groups=self.mamba_groups,
+                             head_dim=self.mamba_head_dim, state=self.ssm_state_size,
+                             seq_len=self.seq_len, chunk=self.chunk_size,
+                             kept=KEPT if self.remat else ())
 
     def expert_plan(self, tokens: int) -> dict | None:
         """What a step of ``tokens`` tokens asks of each sparse layer
         (``ops.moe.expert_plan``), or None for a stack with none."""
         if not self.sparse_layers:
             return None
-        return moe.expert_plan(tokens, top_k=self.num_experts_per_tok,
+        plan = moe.expert_plan(tokens, top_k=self.num_experts_per_tok,
                                held=self.held_experts, block=self.expert_block)
+        if self.router_bias_update_rate:
+            plan["bias_update_rate"] = self.router_bias_update_rate
+        return plan
+
+    def rebalance(self, params, arrived):
+        """A training step's ``after_update`` where ``router_bias_update_rate`` is set:
+        ``arrived`` is what ``loss`` handed out beside the loss, ``(counts, load)`` with
+        ``load [sparse layers, router_experts]`` the tokens that chose each expert in
+        the step's forward pass. Returns the parameters with every sparse layer's
+        ``expert_bias_b`` moved by ``ops.moe.rebalanced_bias``, and ``counts``."""
+        counts, load = arrived
+        sparse = [i for i in range(len(self.layer_types)) if self.is_sparse(i)]
+        rows = {f"layer_{i}": row for i, row in zip(sparse, load)}
+
+        def leaf(path, value):
+            if not is_frozen(path):
+                return value
+            return moe.rebalanced_bias(value, rows[path[0].key],
+                                       self.router_bias_update_rate)
+
+        return jax.tree_util.tree_map_with_path(leaf, params), counts
 
     def recompute_plan(self, jaxpr) -> dict | None:
         """The ``compile`` event's ``recompute`` field: the names ``remat`` keeps and
@@ -134,36 +212,62 @@ class HybridLM:
         heads, kv = self.num_attention_heads, self.num_key_value_heads
         held, f = self.held_experts[1], self.moe_intermediate_size
         tree = {"embed_tokens": (self.vocab_size, d), "final_norm_scale": (d,)}
+        if not self.tied_head:
+            tree["lm_head_kernel"] = (d, self.vocab_size)
+        attn = {"q_kernel": (d, heads * hd), "k_kernel": (d, kv * hd),
+                "v_kernel": (d, kv * hd), "out_kernel": (heads * hd, d)}
+        if self.qk_norm:
+            attn.update(q_norm_scale=(hd,), k_norm_scale=(hd,))
+        # expert_bias_b: the selection's bias. Fixed (is_frozen): its gradient
+        # is zero and no update rule is published.
+        row = self.moe_latent_size or d
+        experts = {"router_kernel": (d, self.router_experts),
+                   "expert_bias_b": (self.router_experts,),
+                   "experts_w1_kernel": (row, held * f),
+                   "experts_w2_kernel": (f, held * row)}
+        if self.gated_experts:
+            experts["experts_w3_kernel"] = (row, held * f)
+        if self.moe_latent_size:
+            experts.update(fc1_latent_kernel=(d, row), fc2_latent_kernel=(row, d))
+        if self.shared_expert_size:
+            experts.update(shared_w1_kernel=(d, self.shared_expert_size),
+                           shared_w2_kernel=(self.shared_expert_size, d))
+        inner = self.mamba_heads * self.mamba_head_dim
+        conv_width = inner + 2 * self.mamba_groups * self.ssm_state_size
+        mamba = {"in_proj_kernel": (d, inner + conv_width + self.mamba_heads),
+                 "conv_kernel": (self.conv_kernel, conv_width),
+                 "conv_bias": (conv_width,), "dt_bias": (self.mamba_heads,),
+                 "A_log": (self.mamba_heads,), "D_scale": (self.mamba_heads,),
+                 "gate_norm_scale": (inner,), "out_proj_kernel": (inner, d)}
+        alone = {"mamba": ("mamba", mamba), "attention": ("attn", attn),
+                 "moe": ("moe", experts)}
         for i, kind in enumerate(self.layer_types):
+            if kind in SUBLAYER_KINDS:
+                group, leaves = alone[kind]
+                tree[f"layer_{i}"] = {"norm_scale": (d,), group: dict(leaves)}
+                continue
             layer = {"mixer_norm_scale": (d,), "ff_norm_scale": (d,)}
             if kind == "conv":
                 layer["conv"] = {"in_proj_kernel": (d, 3 * d),
                                  "conv_kernel": (self.conv_L_cache, d),
                                  "out_proj_kernel": (d, d)}
             else:
-                layer["attn"] = {"q_kernel": (d, heads * hd), "k_kernel": (d, kv * hd),
-                                 "v_kernel": (d, kv * hd), "out_kernel": (heads * hd, d),
-                                 "q_norm_scale": (hd,), "k_norm_scale": (hd,)}
+                layer["attn"] = dict(attn)
             if i < self.num_dense_layers:
                 layer["ff"] = {"w1_kernel": (d, self.intermediate_size),
                                "w3_kernel": (d, self.intermediate_size),
                                "w2_kernel": (self.intermediate_size, d)}
             else:
-                # expert_bias_b: the selection's bias. Fixed (is_frozen): its gradient
-                # is zero and no update rule is published.
-                layer["moe"] = {"router_kernel": (d, self.router_experts),
-                                "expert_bias_b": (self.router_experts,),
-                                "experts_w1_kernel": (d, held * f),
-                                "experts_w3_kernel": (d, held * f),
-                                "experts_w2_kernel": (f, held * d)}
+                layer["moe"] = dict(experts)
             tree[f"layer_{i}"] = layer
         return tree
 
     # -- flax's calling convention --------------------------------------------------
 
     def init(self, rngs, sample=None) -> dict:
-        """``{"params": tree}``: kernels normal(0, 1/sqrt(fan_in)), the embedding
-        normal(0, 0.02), norm scales one, the selection's bias zero."""
+        """``{"params": tree}``: kernels normal(0, 1/sqrt(fan_in)), the embedding and
+        a mamba layer's ``A_log`` normal(0, 0.02), norm scales and ``D_scale`` one, biases
+        (the selection's among them) zero."""
         del sample
         key = rngs["params"] if isinstance(rngs, dict) else rngs
         flat, treedef = jax.tree_util.tree_flatten_with_path(
@@ -173,10 +277,10 @@ class HybridLM:
             name = path[-1].key
             if name.endswith("scale"):
                 leaves.append(jnp.ones(shape, jnp.float32))
-            elif name == "expert_bias_b":
+            elif name == "expert_bias_b" or name.endswith("bias"):
                 leaves.append(jnp.zeros(shape, jnp.float32))
             else:
-                std = 0.02 if name == "embed_tokens" else shape[0] ** -0.5
+                std = 0.02 if name == "embed_tokens" or len(shape) < 2 else shape[0] ** -0.5
                 leaves.append(std * jax.random.normal(jax.random.fold_in(key, n),
                                                       shape, jnp.float32))
         return {"params": jax.tree_util.tree_unflatten(treedef, leaves)}
@@ -185,7 +289,7 @@ class HybridLM:
         """``[B, S]`` ids -> ``[B, S, vocab]`` float32 log-probabilities of the
         next token."""
         hidden, _ = self.hidden_states(variables["params"], ids)
-        return ops.log_softmax(self._logits(variables["params"]["embed_tokens"], hidden))
+        return ops.log_softmax(self._logits(self._head(variables["params"]), hidden))
 
     # -- forward --------------------------------------------------------------------
 
@@ -197,7 +301,7 @@ class HybridLM:
         x = params["embed_tokens"].astype(self.dtype)[ids]
         counts = []
         for i, kind in enumerate(self.layer_types[:layers]):
-            fn = make_block(self, kind, i >= self.num_dense_layers)
+            fn = make_block(self, kind, self.is_sparse(i))
             if self.remat:
                 fn = jax.checkpoint(
                     fn, policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
@@ -208,31 +312,43 @@ class HybridLM:
 
     def hidden_states(self, params, ids) -> tuple[jax.Array, jax.Array | None]:
         """``(final-normed hidden [B, S, d], counts [sparse layers, held] | None)``:
-        the rows that arrived at each held expert of each sparse layer."""
+        the rows that arrived at each held expert of each sparse layer; with
+        ``router_bias_update_rate`` set, ``(counts, load [sparse layers,
+        router_experts])``, the tokens that chose each of the router's experts."""
         x, _, counts = self._blocks(params, ids)
         x = ops.rms_norm(x, params["final_norm_scale"], eps=self.norm_eps)
-        return x, (jnp.stack(counts) if counts else None)
+        if not counts:
+            return x, None
+        return x, jax.tree_util.tree_map(lambda *layers: jnp.stack(layers), *counts)
 
     def router_choices(self, params, ids, layer: int) -> jax.Array:
         """The experts ``[B, S, k]`` (ids over all the router's experts) that sparse
         layer ``layer`` selects: a diagnostic, for tests and for the benchmark's
         count of selections a lower precision moves."""
         x, positions, _ = self._blocks(params, ids, layer)
-        p = params[f"layer_{layer}"]
-        h = mix(p, x, positions, self.layer_types[layer], self)
-        u = ops.rms_norm(h, p["ff_norm_scale"], eps=self.norm_eps)
+        p, kind = params[f"layer_{layer}"], self.layer_types[layer]
+        if kind in SUBLAYER_KINDS:
+            u = ops.rms_norm(x, p["norm_scale"], eps=self.norm_eps)
+        else:
+            h = mix(p, x, positions, kind, self)
+            u = ops.rms_norm(h, p["ff_norm_scale"], eps=self.norm_eps)
         _, experts = moe.route(u.reshape(-1, u.shape[-1]), p["moe"]["router_kernel"],
                                p["moe"]["expert_bias_b"],
                                top_k=self.num_experts_per_tok)
         return experts.reshape(*ids.shape, -1)
 
-    def _logits(self, table, hidden):
-        """Tied head: ``hidden · tableᵀ`` in float32, as one ``[B·S, d] x [d, vocab]``
-        product, so that the vocabulary is the logits' minor axis (as
-        ``bsd,vd->bsv`` the compiler made it the sequence, and the step's
-        temporaries 0.5 GB larger)."""
+    def _head(self, params):
+        """The head's leaf: the embedding ``[vocab, d]``, tied, or ``[d, vocab]``."""
+        return params["embed_tokens" if self.tied_head else "lm_head_kernel"]
+
+    def _logits(self, head, hidden):
+        """``hidden · head`` in float32, as one ``[B·S, d] x [d, vocab]`` product, so
+        that the vocabulary is the logits' minor axis (as ``bsd,vd->bsv`` the compiler
+        made it the sequence, and the step's temporaries 0.5 GB larger)."""
         b, s, d = hidden.shape
-        flat = jnp.matmul(hidden.reshape(b * s, d), table.astype(self.dtype).T,
+        rows = hidden.reshape(b * s, d)
+        head = head.astype(self.dtype)
+        flat = jnp.matmul(rows, head.T if self.tied_head else head,
                           preferred_element_type=jnp.float32)
         return flat.reshape(b, s, -1)
 
@@ -258,7 +374,7 @@ class HybridLM:
         if self.remat:          # the [B, S, vocab] float32 logits are not kept
             head = jax.checkpoint(head)
         with jax.named_scope("head_loss"):
-            return head(params["embed_tokens"], hidden), counts
+            return head(self._head(params), hidden), counts
 
     def loss(self, params, tokens) -> tuple[jax.Array, jax.Array | None]:
         """``(mean next-token NLL, counts)``: the training objective."""
@@ -279,6 +395,17 @@ def _equations(jaxpr):
 
 def make_block(model: HybridLM, kind: str, sparse: bool):
     """``block(p, x, positions) -> (y, counts | None)`` of one layer."""
+
+    def sublayer(p, x, positions):
+        u = ops.rms_norm(x, p["norm_scale"], eps=model.norm_eps)
+        if kind == "moe":
+            out, counts = sparse_ff(p["moe"], u, model)
+            return x + out, counts
+        return x + (mamba_mixer(p["mamba"], u, model) if kind == "mamba"
+                    else attention_mixer(p["attn"], u, positions, model)), None
+
+    if kind in SUBLAYER_KINDS:
+        return sublayer
 
     def block(p, x, positions):
         h = mix(p, x, positions, kind, model)
@@ -319,6 +446,37 @@ def conv_mixer(p, u):
                       p["out_proj_kernel"])
 
 
+def mamba_mixer(p, u, model: HybridLM):
+    with jax.named_scope("mamba_mixer"):
+        b, s, _ = u.shape
+        heads, groups = model.mamba_heads, model.mamba_groups
+        hd, n = model.mamba_head_dim, model.ssm_state_size
+        inner, bc = heads * hd, groups * n
+        z, xbc, dt = jnp.split(
+            checkpoint_name(_dense(u, p["in_proj_kernel"]), "mamba_in_proj"),
+            [inner, 2 * inner + 2 * bc], axis=-1)
+        xbc = jax.nn.silu(causal_depthwise_conv(xbc, p["conv_kernel"])
+                          + p["conv_bias"].astype(xbc.dtype))
+        x, b_in, c_out = jnp.split(xbc, [inner, inner + bc], axis=-1)
+        x = x.reshape(b, s, heads, hd)
+        step = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+        decay = -jnp.exp(p["A_log"].astype(jnp.float32))
+        y = ssm.ssd_scan(x, step, step * decay, b_in.reshape(b, s, groups, n),
+                         c_out.reshape(b, s, groups, n), chunk=model.chunk_size)
+        y = y.astype(jnp.float32) + p["D_scale"].astype(jnp.float32)[:, None] * x
+        normed = gated_group_norm(y.reshape(b, s, inner), z, p["gate_norm_scale"],
+                                  groups, model.norm_eps)
+        return _dense(normed.astype(u.dtype), p["out_proj_kernel"])
+
+
+def gated_group_norm(y, z, scale, groups: int, eps: float):
+    """``scale ⊙ RMSNorm_group(y ⊙ silu(z))``, float32: gated first, then normed, a
+    group of the norm being a group's channels."""
+    gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    grouped = gated.reshape(*gated.shape[:-1], groups, -1)
+    return ops.rms_norm(grouped, scale.reshape(groups, -1), eps=eps).reshape(gated.shape)
+
+
 def attention_mixer(p, u, positions, model: HybridLM):
     with jax.named_scope("attention"):
         b, s, _ = u.shape
@@ -328,10 +486,14 @@ def attention_mixer(p, u, positions, model: HybridLM):
         # norm's backward pass reads its input, so its output kept spares no matmul.
         q, k, v = (checkpoint_name(_dense(u, p[f"{name}_kernel"]), "attn_proj")
                    .reshape(b, s, n, hd) for name, n in (("q", heads), ("k", kv), ("v", kv)))
-        q = apply_rotary(ops.rms_norm(q, p["q_norm_scale"], eps=model.norm_eps),
-                         positions, base=model.rope_theta)
-        k = apply_rotary(ops.rms_norm(k, p["k_norm_scale"], eps=model.norm_eps),
-                         positions, base=model.rope_theta)
+        def placed(x, scale):       # per-head norm, then the rotation; either or neither
+            if model.qk_norm:
+                x = ops.rms_norm(x, p[scale], eps=model.norm_eps)
+            if model.rope_theta is not None:
+                x = apply_rotary(x, positions, base=model.rope_theta)
+            return x
+
+        q, k = placed(q, "q_norm_scale"), placed(k, "k_norm_scale")
         k, v = (jnp.repeat(x, heads // kv, axis=2) for x in (k, v))
         out = model.attention_fn(q, k, v, causal=True)
         return _dense(out.reshape(b, s, heads * hd), p["out_kernel"])
@@ -348,52 +510,131 @@ def dense_ff(p, u):
 def sparse_ff(p, u, model: HybridLM):
     b, s, d = u.shape
     flat = u.reshape(b * s, d)
-    weights, experts = moe.route(flat, p["router_kernel"], p["expert_bias_b"],
-                                 top_k=model.num_experts_per_tok,
-                                 scaling=model.routed_scaling_factor)
+    balanced = bool(model.router_bias_update_rate)
+    weights, experts, *load = moe.route(flat, p["router_kernel"], p["expert_bias_b"],
+                                        top_k=model.num_experts_per_tok,
+                                        scaling=model.routed_scaling_factor,
+                                        eps=model.router_eps, load=balanced)
+    rows = flat
+    if model.moe_latent_size:
+        with jax.named_scope("moe/latent"):
+            rows = checkpoint_name(_dense(flat, p["fc1_latent_kernel"]), "moe_latent")
     out, counts = moe.held_experts_ffn(
-        flat, weights, experts, p["experts_w1_kernel"], p["experts_w3_kernel"],
+        rows, weights, experts, p["experts_w1_kernel"],
+        p["experts_w3_kernel"] if model.gated_experts else None,
         p["experts_w2_kernel"], held=model.held_experts, block=model.expert_block)
-    return out.reshape(b, s, d), counts
+    if model.moe_latent_size:
+        with jax.named_scope("moe/latent"):
+            # the experts' sum is an operand of W_fc2's gradient: not kept, the
+            # sort's gather, ``moe_ffn_fwd`` and the combine would run again for it
+            out = _dense(checkpoint_name(out, "moe_routed"), p["fc2_latent_kernel"])
+    if model.shared_expert_size:
+        with jax.named_scope("moe/shared"):
+            hidden = checkpoint_name(_dense(flat, p["shared_w1_kernel"]), "shared_hidden")
+            out = out + _dense(jnp.square(jax.nn.relu(hidden)), p["shared_w2_kernel"])
+    return out.reshape(b, s, d), ((counts, *load) if balanced else counts)
 
 
 def is_frozen(path) -> bool:
-    """Leaves the optimizer leaves alone (``optim.freeze``): the selection's bias."""
+    """Leaves the optimizer leaves alone (``optim.freeze``): the selection's bias,
+    which only ``HybridLM.rebalance`` moves."""
     return str(getattr(path[-1], "key", path[-1])) == "expert_bias_b"
 
 
 def from_config(config: dict, *, vocab_size: int, seq_len: int, **kwargs) -> HybridLM:
     """The model a configuration file describes: the published keys at the top
-    level (``layer_types`` whole), and, for one chip's share of a deployment,
-    ``num_hidden_layers`` / ``num_dense_layers`` / ``num_experts`` / ``vocab_size`` as
-    held here with ``share`` = ``{first_layer, first_expert}`` and ``published`` =
-    ``{num_experts}`` beside them. ``vocab_size`` is the corpus's and has to be the
-    file's."""
-    share, published = config.get("share", {}), config.get("published", {})
+    level, and, for one chip's share of a deployment, the keys that count layers,
+    experts, heads, groups, shared-expert columns and ids as held here, with ``share``
+    (``first_layer``, ``first_expert``) and ``published`` (the router's width) beside
+    them. ``model_type`` names the family (``lfm2_moe`` when absent). ``vocab_size``
+    is the corpus's and has to be the file's."""
     if int(config["vocab_size"]) != int(vocab_size):
         raise ValueError(f"the corpus has {vocab_size} ids, the configuration's "
                          f"vocabulary (slice) has {config['vocab_size']}")
+    family = config.get("model_type", "lfm2_moe")
+    if family not in _FAMILIES:
+        raise ValueError(f"model_type {family!r} is not of {sorted(_FAMILIES)}")
+    first = int(config.get("share", {}).get("first_layer", 0))
+    depth = int(config["num_hidden_layers"])
+    pattern, fields = _FAMILIES[family](config)
+    if len(pattern[first:first + depth]) != depth:
+        raise ValueError("the layer pattern is shorter than first_layer + "
+                         "num_hidden_layers")
+    return HybridLM(vocab_size=int(vocab_size), seq_len=int(seq_len),
+                    hidden_size=int(config["hidden_size"]),
+                    intermediate_size=int(config["intermediate_size"]),
+                    moe_intermediate_size=int(config["moe_intermediate_size"]),
+                    num_attention_heads=int(config["num_attention_heads"]),
+                    num_key_value_heads=int(config["num_key_value_heads"]),
+                    layer_types=tuple(pattern[first:first + depth]),
+                    num_experts_per_tok=int(config["num_experts_per_tok"]),
+                    norm_eps=float(config["norm_eps"]),
+                    routed_scaling_factor=float(config.get("routed_scaling_factor", 1.0)),
+                    **fields, **kwargs)
+
+
+def _held_experts(config: dict, count_key: str) -> dict:
+    """``router_experts`` and ``held_experts`` from the key that counts the experts."""
+    share, published = config.get("share", {}), config.get("published", {})
+    return {"router_experts": int(published.get(count_key, config[count_key])),
+            "held_experts": (int(share.get("first_expert", 0)), int(config[count_key]))}
+
+
+def _lfm2_moe(config: dict) -> tuple[list, dict]:
+    """``layer_types`` whole; ``num_dense_layers`` leading blocks with the dense
+    feed-forward, the others sparse; tied head."""
     if config.get("conv_bias"):
         raise ValueError("conv_bias true is not written here (no catalog model has it)")
-    first = int(share.get("first_layer", 0))
-    kinds = tuple(config["layer_types"][first:first + int(config["num_hidden_layers"])])
-    if len(kinds) != int(config["num_hidden_layers"]):
-        raise ValueError("layer_types is shorter than first_layer + num_hidden_layers")
-    return HybridLM(
-        vocab_size=int(vocab_size), seq_len=int(seq_len),
-        hidden_size=int(config["hidden_size"]),
-        intermediate_size=int(config["intermediate_size"]),
-        moe_intermediate_size=int(config["moe_intermediate_size"]),
-        num_attention_heads=int(config["num_attention_heads"]),
-        num_key_value_heads=int(config["num_key_value_heads"]),
-        layer_types=kinds, num_dense_layers=int(config["num_dense_layers"]),
-        router_experts=int(published.get("num_experts", config["num_experts"])),
-        held_experts=(int(share.get("first_expert", 0)), int(config["num_experts"])),
-        num_experts_per_tok=int(config["num_experts_per_tok"]),
-        conv_L_cache=int(config["conv_L_cache"]), norm_eps=float(config["norm_eps"]),
-        rope_theta=float(config["rope_parameters"]["rope_theta"]),
-        routed_scaling_factor=float(config.get("routed_scaling_factor", 1.0)),
-        **kwargs)
+    return list(config["layer_types"]), dict(
+        _held_experts(config, "num_experts"),
+        num_dense_layers=int(config["num_dense_layers"]),
+        conv_L_cache=int(config["conv_L_cache"]),
+        rope_theta=float(config["rope_parameters"]["rope_theta"]))
+
+
+_NEMOTRON_LETTERS = {"M": "mamba", "*": "attention", "E": "moe"}
+
+
+def _nemotron_h(config: dict) -> tuple[list, dict]:
+    """``hybrid_override_pattern`` whole, a letter a layer, each layer one sublayer;
+    attention without positions or q/k norm; relu² experts on a latent row beside a
+    shared expert; an untied head. What the file states and this module does not
+    compute is refused, not ignored."""
+    odd = sorted(set(config["hybrid_override_pattern"]) - set(_NEMOTRON_LETTERS))
+    unwritten = {
+        f"layers {odd} of hybrid_override_pattern": bool(odd),
+        "a bias on a projection": any(config.get(k) for k in (
+            "attention_bias", "mlp_bias", "mamba_proj_bias", "use_bias")),
+        "a convolution without bias": not config.get("use_conv_bias", True),
+        "grouped expert selection":
+            (config.get("n_group", 1), config.get("topk_group", 1)) != (1, 1),
+        "an activation other than silu in the mixer and relu2 in the experts": (
+            config.get("mamba_hidden_act", "silu"), config.get("mlp_hidden_act", "relu2"))
+        != ("silu", "relu2"),
+        "multi-token prediction (num_nextn_predict_layers > 0: the trainer has no "
+        "such loss)": bool(config.get("num_nextn_predict_layers", 0)),
+        "selected scores that are not normalised": not config.get("norm_topk_prob", True),
+    }
+    for what, stated in unwritten.items():
+        if stated:
+            raise ValueError(f"{what} is not written here")
+    return [_NEMOTRON_LETTERS[c] for c in config["hybrid_override_pattern"]], dict(
+        _held_experts(config, "n_routed_experts"), num_dense_layers=0,
+        rope_theta=None, qk_norm=False, attention_head_dim=int(config["head_dim"]),
+        tied_head=bool(config.get("tie_word_embeddings", False)),
+        router_eps=1e-20, gated_experts=False,
+        router_bias_update_rate=float(config.get("moe_router_bias_update_rate", 0.0)),
+        moe_latent_size=int(config.get("moe_latent_size") or 0),
+        shared_expert_size=int(config.get("share", {}).get(
+            "shared_expert_columns", int(config["moe_shared_expert_intermediate_size"])
+            * int(config.get("n_shared_experts", 1)))),
+        mamba_heads=int(config["mamba_num_heads"]), mamba_groups=int(config["n_groups"]),
+        mamba_head_dim=int(config["mamba_head_dim"]),
+        ssm_state_size=int(config["ssm_state_size"]),
+        conv_kernel=int(config["conv_kernel"]), chunk_size=int(config["chunk_size"]))
+
+
+_FAMILIES = {"lfm2_moe": _lfm2_moe, "nemotron_h": _nemotron_h}
 
 
 def from_config_file(path: str, **kwargs) -> HybridLM:

@@ -8,8 +8,13 @@ exchange. No token is dropped and there is no capacity: every assignment to a he
 expert is computed, at any imbalance.
 
     route      s = sigmoid(u · W_r) in float32; the experts are the top-k of s + b
-               (b enters the selection only); their weights are s_e / (Σ s_e + 1e-6)
-    sort       the held assignments, grouped by expert; each expert's rows start on a
+               (b enters the selection only); their weights are s_e / (Σ s_e + eps),
+               times the model's scaling
+    sort       a token can send a held expert at most one row, so of its k assignments
+               at most min(k, n_held) land here: where k is the larger, each token's
+               held assignments are moved to the front and the rest cut off, and
+               everything below sees min(k, n_held) assignments a token. Then
+               the held assignments, grouped by expert; each expert's rows start on a
                row tile, so a tile belongs to one expert. ``moe_pack`` writes the token
                array once as row-major 32-bit words (a row of a tiled ``[T, d]`` array
                is not contiguous in HBM, a row of that copy is one DMA), and
@@ -17,10 +22,11 @@ expert is computed, at any imbalance.
                into expert order
     experts    the grouped product, three Pallas kernels whose grid is the number of
                row tiles that arrived (a scalar the sort hands them), not the static
-               bound of k·T rows: ``moe_ffn_fwd`` (W2 · (silu(W1 x) ⊙ W3 x) per tile,
-               the hidden tile never leaving VMEM), ``moe_ffn_bwd`` (the same tile's
-               input and routing-weight gradients) and ``moe_ffn_dw`` (the three
-               weight gradients, accumulated over an expert's tiles)
+               bound of min(k, n_held)·T rows: ``moe_ffn_fwd`` (per tile W2 · (silu(W1
+               x) ⊙ W3 x), or W2 · relu(W1 x)² for an expert of two matrices, the
+               hidden tile never leaving VMEM), ``moe_ffn_bwd`` (the same tile's
+               input and routing-weight gradients) and ``moe_ffn_dw`` (the weight
+               gradients, accumulated over an expert's tiles)
     combine    the product kernels write their rows row-major too; ``moe_combine``
                copies, for a tile of tokens at a time, the rows that ARRIVED for them
                (an expert's rows are in token order, so those of a tile of tokens are
@@ -36,19 +42,22 @@ going in, the tiles ``moe_combine`` writes coming back). Every per-row and per-t
 fact a crossing needs is a scalar in SMEM (the token of a row, the assignment of a
 row, a tile's weights): a ``[T, k]`` operand of a kernel is padded to 128 lanes in HBM,
 and so is everything upstream that XLA gives its layout. Buffers in expert order are
-still sized for the bound (``k·T`` rows and a tile a held expert); their tiles past
+still sized for the bound (``min(k, n_held)·T`` rows and a tile a held expert); their tiles past
 ``num_tiles`` are never written and never read, a padding row inside an arrived tile
 is token 0's row going in, and a slot of a token no row came into is left out by a
 select, never by a product with 0.
 
-Expert weights are three leaves a layer, column-blocked by held expert: ``w1``,
-``w3`` ``[d, n_held·f]`` and ``w2`` ``[f, n_held·d]``, so that a kernel's block index
-is the expert and a leaf's fan-in is its first axis.
+Expert weights are three leaves a layer (two for the relu² expert, which has no
+``w3``), column-blocked by held expert: ``w1``, ``w3`` ``[d, n_held·f]`` and ``w2``
+``[f, n_held·d]``, so that a kernel's block index is the expert and a leaf's fan-in
+is its first axis. ``d`` is the width of the rows the layer is given: the model's, or
+a latent one the caller projects to and from.
 """
 
 from __future__ import annotations
 
 import functools
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -68,11 +77,15 @@ def _interpret() -> bool:
 
 
 def route(u: jax.Array, router_kernel: jax.Array, select_bias: jax.Array, *,
-          top_k: int, scaling: float = 1.0) -> tuple[jax.Array, jax.Array]:
+          top_k: int, scaling: float = 1.0, eps: float = 1e-6, load: bool = False
+          ) -> tuple[jax.Array, ...]:
     """``u [T, d]`` -> ``(weights [T, k] float32, experts [T, k] int32)`` over all the
     router's experts. Matmul (at ``highest``: one bf16 pass would move near-tied
     selections), sigmoid and top-k in float32; ``select_bias`` moves the selection
-    and not the weights, and gets no gradient."""
+    and not the weights, and gets no gradient. ``load=True`` adds a third result,
+    ``[experts] int32``: the tokens that selected each of the router's experts, held
+    here or not (what ``rebalanced_bias`` reads), counted as the biased scores at or
+    over a token's ``top_k``-th: one pass over ``[T, experts]``."""
     with jax.named_scope("moe/route"):
         # Named: what a caller's ``jax.checkpoint`` may keep of the router (a policy
         # over names; an identity otherwise). The logits and not the scores, because
@@ -81,23 +94,37 @@ def route(u: jax.Array, router_kernel: jax.Array, select_bias: jax.Array, *,
             u.astype(jnp.float32), router_kernel.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST), "moe_route")
         scores = jax.nn.sigmoid(logits)
-        _, experts = jax.lax.top_k(
-            scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)), top_k)
+        biased = scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32))
+        top, experts = jax.lax.top_k(biased, top_k)
         experts = checkpoint_name(experts.astype(jnp.int32), "moe_route")
         picked = checkpoint_name(jnp.take_along_axis(scores, experts, axis=-1),
                                  "moe_route")
-        weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-6)
-        return weights * scaling, experts
+        weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + eps)
+        if not load:
+            return weights * scaling, experts
+        selected = biased >= top[:, -1:]
+        return weights * scaling, experts, jnp.sum(selected, axis=0, dtype=jnp.int32)
+
+
+def rebalanced_bias(select_bias: jax.Array, load: jax.Array, rate: float) -> jax.Array:
+    """The selection's bias after a step whose tokens chose the experts ``load
+    [experts]`` times: up by ``rate`` where an expert drew fewer tokens than the mean,
+    down where more (the auxiliary-loss-free balancing of Wang et al. 2024, which
+    DeepSeek-V3 and Megatron-LM's ``moe_router_enable_expert_bias`` train with). No
+    gradient is involved: the rule reads counts."""
+    load = load.astype(jnp.float32)
+    return select_bias + rate * jnp.sign(jnp.mean(load) - load).astype(select_bias.dtype)
 
 
 def expert_plan(tokens: int, *, top_k: int, held: tuple[int, int],
                 block: int | None = None) -> dict:
     """The ``compile`` event's ``experts`` field: the held range, the static bound
-    on rows (every token sending all of its ``top_k`` rows here), the rows of the
-    expert-order buffers (the bound and a tile a held expert), the row tile, and
-    what a crossing between the two orders moves: the row tiles that arrived."""
+    on rows (every token sending here all the rows it can: one a held expert, of its
+    ``top_k``), the rows of the expert-order buffers (the bound and a tile a held
+    expert), the row tile, and what a crossing between the two orders moves: the row
+    tiles that arrived."""
     tm = block or ROW_TILE
-    bound = tokens * top_k
+    bound = tokens * min(top_k, held[1])
     return {"held": [held[0], held[0] + held[1]], "row_bound": bound,
             "rows_buffer": (-(-bound // tm) + held[1]) * tm, "block": tm,
             "rows_moved": "arrived"}
@@ -230,56 +257,74 @@ def _dot(a, b, contract):
                                preferred_element_type=jnp.float32)
 
 
-def _fwd_kernel(te_ref, x_ref, w1_ref, w3_ref, w2_ref, y_ref, *, r):
-    """One row tile's result, written row-major: only the combine reads it."""
+def _hidden(x, w_in_refs):
+    """A row tile's hidden tile from its pre-activations, ``silu(W1 x) ⊙ W3 x`` of
+    two or ``relu(W1 x)²`` of one, float32, and a function from the hidden tile's
+    gradient to theirs, rounded to ``x``'s dtype."""
+    pre = [_dot(x, w_ref[...], ((1,), (0,))) for w_ref in w_in_refs]
+    if len(pre) == 2:
+        gate, up = pre
+        sig = jax.nn.sigmoid(gate)
+        act = gate * sig
+        return act * up, lambda dhidden: [
+            (dhidden * up * (sig * (1.0 + gate * (1.0 - sig)))).astype(x.dtype),
+            (dhidden * act).astype(x.dtype)]
+    positive = jnp.maximum(pre[0], 0.0)
+    return positive * positive, lambda dhidden: [
+        (dhidden * (2.0 * positive)).astype(x.dtype)]
+
+
+def _fwd_kernel(te_ref, x_ref, *refs, r):
+    """One row tile's result, written row-major: only the combine reads it.
+    ``refs``: W1 (and W3), W2, the output."""
     del te_ref
+    *w_in_refs, w2_ref, y_ref = refs
     x = x_ref[...]
-    gate = _dot(x, w1_ref[...], ((1,), (0,)))
-    up = _dot(x, w3_ref[...], ((1,), (0,)))
-    hidden = (gate * jax.nn.sigmoid(gate) * up).astype(x.dtype)
+    hidden = _hidden(x, w_in_refs)[0].astype(x.dtype)
     _store_row_major(y_ref, _dot(hidden, w2_ref[...], ((1,), (0,))).astype(x.dtype), r)
 
 
-def _bwd_kernel(te_ref, x_ref, g_ref, wr_ref, w1_ref, w3_ref, w2_ref,
-                dx_ref, dwr_ref, dgate_ref, dup_ref, hw_ref, *, r):
+def _bwd_kernel(te_ref, x_ref, g_ref, wr_ref, *refs, r, n_in):
     """One row tile's backward. ``wr``: the rows' routing weights (0 on an invalid
-    row, which therefore adds nothing to any weight gradient). Writes the input
-    gradient (row-major, for the combine), the routing weights' gradient, and what
-    ``moe_ffn_dw`` multiplies."""
+    row, which therefore adds nothing to any weight gradient). ``refs``: the
+    ``n_in`` input matrices (W1, and W3 of a gated expert), W2; then the outputs: the
+    input gradient (row-major, for the combine), the routing weights' gradient, and
+    what ``moe_ffn_dw`` multiplies (the pre-activations' gradients, the weighted
+    hidden tile)."""
     del te_ref
+    w_in_refs, w2_ref = refs[:n_in], refs[n_in]
+    dx_ref, dwr_ref, *dpre_refs, hw_ref = refs[n_in + 1:]
     x, g, wr = x_ref[...], g_ref[...], wr_ref[...]
-    gate = _dot(x, w1_ref[...], ((1,), (0,)))
-    up = _dot(x, w3_ref[...], ((1,), (0,)))
-    sig = jax.nn.sigmoid(gate)
-    act = gate * sig
-    hidden = act * up
+    hidden, pre_gradients = _hidden(x, w_in_refs)
     dhidden = _dot(g, w2_ref[...], ((1,), (1,)))                 # [tm, f]
     dwr_ref[...] = jnp.sum(hidden * dhidden, axis=1, keepdims=True)
-    dhidden = wr * dhidden
-    dgate = (dhidden * up * (sig * (1.0 + gate * (1.0 - sig)))).astype(x.dtype)
-    dup = (dhidden * act).astype(x.dtype)
-    dgate_ref[...] = dgate
-    dup_ref[...] = dup
+    dpre = pre_gradients(wr * dhidden)
+    for ref, d in zip(dpre_refs, dpre):
+        ref[...] = d
     hw_ref[...] = (wr * hidden).astype(x.dtype)
-    _store_row_major(dx_ref, (_dot(dgate, w1_ref[...], ((1,), (1,)))
-                              + _dot(dup, w3_ref[...], ((1,), (1,)))).astype(x.dtype), r)
+    dx = functools.reduce(operator.add, (_dot(d, w_ref[...], ((1,), (1,)))
+                                         for d, w_ref in zip(dpre, w_in_refs)))
+    _store_row_major(dx_ref, dx.astype(x.dtype), r)
 
 
-def _dw_kernel(te_ref, x_ref, g_ref, dgate_ref, dup_ref, hw_ref,
-               dw1_ref, dw3_ref, dw2_ref):
+def _dw_kernel(te_ref, x_ref, g_ref, *refs):
     """Weight gradients of one hidden-column block, accumulated in the output
-    block over the consecutive row tiles of one expert."""
+    blocks over the consecutive row tiles of one expert. ``refs``: the
+    pre-activations' gradients and the weighted hidden tile, then the outputs, the
+    input matrices' gradients and W2's."""
+    n_in = len(refs) // 2 - 1
+    dpre_refs, hw_ref = refs[:n_in], refs[n_in]
+    *dw_in_refs, dw2_ref = refs[n_in + 1:]
     i = pl.program_id(1)
 
     @pl.when((i == 0) | (te_ref[i] != te_ref[jnp.maximum(i - 1, 0)]))
     def _():
-        dw1_ref[...] = jnp.zeros_like(dw1_ref)
-        dw3_ref[...] = jnp.zeros_like(dw3_ref)
-        dw2_ref[...] = jnp.zeros_like(dw2_ref)
+        for ref in (*dw_in_refs, dw2_ref):
+            ref[...] = jnp.zeros_like(ref)
 
     x, g = x_ref[...], g_ref[...]
-    dw1_ref[...] += _dot(x, dgate_ref[...], ((0,), (0,)))
-    dw3_ref[...] += _dot(x, dup_ref[...], ((0,), (0,)))
+    for dw_ref, dpre_ref in zip(dw_in_refs, dpre_refs):
+        dw_ref[...] += _dot(x, dpre_ref[...], ((0,), (0,)))
     dw2_ref[...] += _dot(hw_ref[...], g, ((0,), (0,)))
 
 
@@ -303,44 +348,43 @@ def _row_major(tm: int, r: int):
     return pl.BlockSpec((tm * r, LANES), lambda i, te: (i, 0))
 
 
-def _experts_fwd(sort, x_sorted, w1, w3, w2, tm):
+def _experts_fwd(sort, x_sorted, w_in, w2, tm):
     (m, d), f = x_sorted.shape, w2.shape[0]
     r = _word_rows(d, x_sorted.dtype)
     row = lambda width: pl.BlockSpec((tm, width), lambda i, te: (i, 0))
     return _call(
         functools.partial(_fwd_kernel, r=r), "moe_ffn_fwd", (sort["num_tiles"],),
-        [row(d), _of_expert((d, f)), _of_expert((d, f)), _of_expert((f, d))],
+        [row(d)] + [_of_expert((d, f))] * len(w_in) + [_of_expert((f, d))],
         _row_major(tm, r), jax.ShapeDtypeStruct((m * r, LANES), jnp.uint32),
-    )(sort["tile_expert"], x_sorted, w1, w3, w2)
+    )(sort["tile_expert"], x_sorted, *w_in, w2)
 
 
-def _experts_bwd(sort, x_sorted, g_sorted, w_row, w1, w3, w2, tm):
-    d, f = x_sorted.shape[1], w2.shape[0]
+def _experts_bwd(sort, x_sorted, g_sorted, w_row, w_in, w2, tm):
+    d, f, n_in = x_sorted.shape[1], w2.shape[0], len(w_in)
     row = lambda width: pl.BlockSpec((tm, width), lambda i, te: (i, 0))
     m, dt = x_sorted.shape[0], x_sorted.dtype
     r = _word_rows(d, dt)
     hidden = jax.ShapeDtypeStruct((m, f), dt)
-    dx, dwr, dgate, dup, hw = _call(
-        functools.partial(_bwd_kernel, r=r), "moe_ffn_bwd", (sort["num_tiles"],),
-        [row(d), row(d), row(1), _of_expert((d, f)), _of_expert((d, f)),
-         _of_expert((f, d))],
-        [_row_major(tm, r), row(1), row(f), row(f), row(f)],
+    dx, dwr, *dpre_and_hw = _call(
+        functools.partial(_bwd_kernel, r=r, n_in=n_in), "moe_ffn_bwd",
+        (sort["num_tiles"],),
+        [row(d), row(d), row(1)] + [_of_expert((d, f))] * n_in + [_of_expert((f, d))],
+        [_row_major(tm, r), row(1)] + [row(f)] * (n_in + 1),
         [jax.ShapeDtypeStruct((m * r, LANES), jnp.uint32),
-         jax.ShapeDtypeStruct((m, 1), jnp.float32), hidden, hidden, hidden],
-    )(sort["tile_expert"], x_sorted, g_sorted, w_row, w1, w3, w2)
+         jax.ShapeDtypeStruct((m, 1), jnp.float32)] + [hidden] * (n_in + 1),
+    )(sort["tile_expert"], x_sorted, g_sorted, w_row, *w_in, w2)
     fb = HIDDEN_TILE if f % HIDDEN_TILE == 0 else f
     nf = f // fb
     rows = lambda width: pl.BlockSpec((tm, width), lambda j, i, te: (i, 0))
     cols = pl.BlockSpec((tm, fb), lambda j, i, te: (i, j))
-    dw13 = pl.BlockSpec((d, fb), lambda j, i, te: (0, te[i] * nf + j))
-    dw1, dw3, dw2 = _call(
+    dw_in_block = pl.BlockSpec((d, fb), lambda j, i, te: (0, te[i] * nf + j))
+    *dw_in, dw2 = _call(
         _dw_kernel, "moe_ffn_dw", (nf, sort["num_tiles"]),
-        [rows(d), rows(d), cols, cols, cols],
-        [dw13, dw13, pl.BlockSpec((fb, d), lambda j, i, te: (j, te[i]))],
-        [jax.ShapeDtypeStruct(w1.shape, jnp.float32)] * 2
-        + [jax.ShapeDtypeStruct(w2.shape, jnp.float32)],
-    )(sort["tile_expert"], x_sorted, g_sorted, dgate, dup, hw)
-    return dx, dwr[:, 0], dw1, dw3, dw2
+        [rows(d), rows(d)] + [cols] * (n_in + 1),
+        [dw_in_block] * n_in + [pl.BlockSpec((fb, d), lambda j, i, te: (j, te[i]))],
+        [jax.ShapeDtypeStruct(w.shape, jnp.float32) for w in (*w_in, w2)],
+    )(sort["tile_expert"], x_sorted, g_sorted, *dpre_and_hw)
+    return dx, dwr[:, 0], tuple(dw_in), dw2
 
 
 # --------------------------------------------------------------------------------------
@@ -492,30 +536,31 @@ def _from_rows(rows: jax.Array, sort: dict, weights: jax.Array | None, d: int, d
 
 @functools.lru_cache(maxsize=None)
 def _grouped_ffn(tm: int):
-    """``ffn(x, weights, w1, w3, w2, sort) -> [T, d]`` with its hand-written
-    backward: recomputes the hidden tile instead of keeping ``[rows, f]``. The two
-    halves are jitted and this factory is cached, as ``pallas_attention._make_op``'s
-    are: every sparse layer of a model calls the same two functions, so a program
-    traces and lowers the six kernels once, not once a layer and pass."""
+    """``ffn(x, weights, w_in, w2, sort) -> [T, d]`` with its hand-written backward
+    (``w_in``: ``(w1, w3)`` of a gated expert, ``(w1,)`` of a relu² one): recomputes
+    the hidden tile instead of keeping ``[rows, f]``. The two halves are jitted and
+    this factory is cached, as ``pallas_attention._make_op``'s are: every sparse layer
+    of a model calls the same two functions, so a program traces and lowers the six
+    kernels once, not once a layer and pass."""
 
     @jax.custom_vjp
-    def ffn(x, weights, w1, w3, w2, sort):
-        return forward(x, weights, w1, w3, w2, sort)[0]
+    def ffn(x, weights, w_in, w2, sort):
+        return forward(x, weights, w_in, w2, sort)[0]
 
     @jax.jit
-    def forward(x, weights, w1, w3, w2, sort):
+    def forward(x, weights, w_in, w2, sort):
         cast = lambda w: w.astype(x.dtype)
         with jax.named_scope("moe/sort"):
             x_sorted, = _to_rows((x,), sort, tm)
         with jax.named_scope("moe/experts"):
-            y_sorted = _experts_fwd(sort, x_sorted, cast(w1), cast(w3), cast(w2), tm)
+            y_sorted = _experts_fwd(sort, x_sorted, tuple(map(cast, w_in)), cast(w2), tm)
         with jax.named_scope("moe/combine"):
             out = _from_rows(y_sorted, sort, weights, x.shape[1], x.dtype, tm)
-        return out, (x, weights, w1, w3, w2, sort)
+        return out, (x, weights, w_in, w2, sort)
 
     @jax.jit
     def backward(residuals, dout):
-        x, weights, w1, w3, w2, sort = residuals
+        x, weights, w_in, w2, sort = residuals
         dtype, d = x.dtype, x.shape[1]
         cast = lambda w: w.astype(dtype)
         with jax.named_scope("moe/combine"):
@@ -523,25 +568,41 @@ def _grouped_ffn(tm: int):
             w_row = jnp.where(sort["token_of_row"] >= 0,
                               weights.reshape(-1)[sort["assignment_of_row"]], 0.0)
         with jax.named_scope("moe/experts"):
-            dx_sorted, dw_row, dw1, dw3, dw2 = _experts_bwd(
+            dx_sorted, dw_row, dw_in, dw2 = _experts_bwd(
                 sort, x_sorted, g_sorted, w_row[:, None].astype(jnp.float32),
-                cast(w1), cast(w3), cast(w2), tm)
+                tuple(map(cast, w_in)), cast(w2), tm)
         with jax.named_scope("moe/sort"):
             dx = _from_rows(dx_sorted, sort, None, d, dtype, tm)
             dweights = jnp.where(sort["is_held"], dw_row.at[sort["pos"]].get(
                 mode="promise_in_bounds"), 0.0)
-        return (dx, dweights.astype(weights.dtype), dw1.astype(w1.dtype),
-                dw3.astype(w3.dtype), dw2.astype(w2.dtype), None)
+        return (dx, dweights.astype(weights.dtype),
+                tuple(dw.astype(w.dtype) for dw, w in zip(dw_in, w_in)),
+                dw2.astype(w2.dtype), None)
 
     ffn.defvjp(forward, backward)
     return ffn
 
 
+def _held_first(weights: jax.Array, experts: jax.Array, held: tuple[int, int]):
+    """``weights``, ``experts`` ``[T, k]`` cut to ``[T, min(k, n_held)]``: a token's
+    assignments to held experts first, in the router's order. The router's experts of
+    a token are distinct, so none that is held is cut off."""
+    k, keep = experts.shape[1], min(experts.shape[1], held[1])
+    if keep == k:
+        return weights, experts
+    local = experts - held[0]
+    is_held = (local >= 0) & (local < held[1])
+    _, order = jax.lax.top_k(jnp.where(is_held, 2 * k, k) - jnp.arange(k), keep)
+    return (jnp.take_along_axis(weights, order, axis=1),
+            jnp.take_along_axis(experts, order, axis=1))
+
+
 def held_experts_ffn(x: jax.Array, weights: jax.Array, experts: jax.Array,
-                     w1: jax.Array, w3: jax.Array, w2: jax.Array, *,
+                     w1: jax.Array, w3: jax.Array | None, w2: jax.Array, *,
                      held: tuple[int, int], block: int | None = None
                      ) -> tuple[jax.Array, jax.Array]:
-    """The held experts' part of ``Σ_e w_e · W2_e (silu(W1_e x) ⊙ W3_e x)``.
+    """The held experts' part of ``Σ_e w_e · W2_e (silu(W1_e x) ⊙ W3_e x)``, or, with
+    ``w3`` None, of ``Σ_e w_e · W2_e relu(W1_e x)²``.
 
     ``x [T, d]``; ``weights``, ``experts`` ``[T, k]`` as ``route`` gives them (ids over
     all experts); ``held = (first id, how many)``; ``w1``, ``w3`` ``[d, n_held·f]``,
@@ -551,8 +612,10 @@ def held_experts_ffn(x: jax.Array, weights: jax.Array, experts: jax.Array,
     if x.dtype not in (jnp.float32, jnp.bfloat16):
         raise TypeError(f"the row-major copies pack float32 or bfloat16, not {x.dtype}")
     with jax.named_scope("moe/sort"):
+        weights, experts = _held_first(weights, experts, held)
         sort = _sort(experts, held, tm)
     counts = sort.pop("counts")
     # Residuals of the VJP below, named before they enter it (see ``route``).
     sort = jax.tree.map(lambda leaf: checkpoint_name(leaf, "moe_sort"), sort)
-    return _grouped_ffn(tm)(x, weights, w1, w3, w2, sort), counts
+    w_in = (w1,) if w3 is None else (w1, w3)
+    return _grouped_ffn(tm)(x, weights, w_in, w2, sort), counts

@@ -390,7 +390,9 @@ def main(config: LMConfig = LMConfig(), *,
                               clip_grad_norm=config.clip_grad_norm,
                               ema_decay=config.ema_decay, loss_fn=lm_loss,
                               with_metrics=health, guard=grt.spec,
-                              loss_has_aux=hybrid)
+                              loss_has_aux=hybrid,
+                              after_update=model.rebalance if hybrid
+                              and model.router_bias_update_rate else None)
     epoch_fn = compile_lm_epoch(make_epoch_from_step(step_fn, health=health,
                                                      aux=hybrid))
     eval_fn = jax.jit(make_eval_nll_fn(model, batch_size=eval_batch))
@@ -435,7 +437,8 @@ def main(config: LMConfig = LMConfig(), *,
             tele.emit(T.compile_event("epoch", aot,
                                       steps_per_call=steps_per_epoch,
                                       attention=attention, experts=experts,
-                                      recompute=recompute))
+                                      recompute=recompute,
+                                      ssm=model.ssm_plan() if hybrid else None))
     history = M.MetricsHistory()
     saver = checkpoint.make_saver(config.async_checkpoint, tele=tele)
 

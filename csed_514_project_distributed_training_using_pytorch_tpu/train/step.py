@@ -235,7 +235,8 @@ def make_train_step(model, *, learning_rate: float, momentum: float,
                     loss_fn: Callable | None = None,
                     with_metrics: bool = False,
                     guard: GuardSpec | None = None,
-                    loss_has_aux: bool = False) -> Callable:
+                    loss_has_aux: bool = False,
+                    after_update: Callable | None = None) -> Callable:
     """Build ``step(state, images, labels, rng) -> (state, loss)``.
 
     The loss is the canonical ``nll(log_probs)`` formulation (see
@@ -290,6 +291,12 @@ def make_train_step(model, *, learning_rate: float, momentum: float,
     ``models/hybrid_lm.py``); the step then returns ``(state, (out, aux))`` with ``out``
     what it would have returned without, and the scanned epoch stacks ``aux`` over
     its steps beside the losses. Microbatches' ``aux`` add up.
+
+    ``after_update(params, aux) -> (params, aux)`` (with ``loss_has_aux``) runs after
+    the optimizer's update, on the parameters it wrote and what the forward pass
+    handed out, for a rule that moves a leaf by counts and not by a gradient (the
+    expert layers' selection bias, ``HybridLM.rebalance``); the ``aux`` it returns is
+    the step's. The moving average and a guarded step's skip do not see it.
 
     ``with_metrics=True`` changes the return to ``(state, (loss, grad_norm))``,
     where ``grad_norm`` is the PRE-clip global L2 norm of the (microbatch-averaged)
@@ -458,13 +465,18 @@ def make_train_step(model, *, learning_rate: float, momentum: float,
 
     value_and_grad = jax.value_and_grad(loss_fn, has_aux=loss_has_aux)
 
+    def with_aux(new_state: TrainState, out, aux):
+        if after_update is not None:
+            params, aux = after_update(new_state.params, aux)
+            new_state = new_state._replace(params=params)
+        return new_state, (out, aux)
+
     def step(state: TrainState, images, labels, rng) -> tuple[TrainState, jax.Array]:
         step_rng = jax.random.fold_in(rng, state.step)
         loss, grads = value_and_grad(state.params, images, labels, step_rng)
         if not loss_has_aux:
             return apply_update(state, grads, loss)
-        new_state, out = apply_update(state, grads, loss[0])
-        return new_state, (out, loss[1])
+        return with_aux(*apply_update(state, grads, loss[0]), loss[1])
 
     if grad_accum == 1:
         return step
@@ -495,7 +507,7 @@ def make_train_step(model, *, learning_rate: float, momentum: float,
         new_state, out = apply_update(state, grads, loss_sum / grad_accum)
         if not loss_has_aux:
             return new_state, out
-        return new_state, (out, jax.tree_util.tree_map(lambda a: a.sum(0), aux))
+        return with_aux(new_state, out, jax.tree_util.tree_map(lambda a: a.sum(0), aux))
 
     return accum_step
 

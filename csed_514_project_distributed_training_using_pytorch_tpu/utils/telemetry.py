@@ -313,7 +313,7 @@ def aot_compile(jit_fn, *args) -> tuple[object | None, dict | None]:
 
 def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
                   attention: dict | None = None, experts: dict | None = None,
-                  recompute: dict | None = None) -> dict:
+                  recompute: dict | None = None, ssm: dict | None = None) -> dict:
     """The ``compile`` event for one AOT-timed program. ``attention``: which core the
     program's attention calls get and why (``ops.dispatch_plan``'s dict: ``impl``,
     ``score_bytes``, ``seq_padded``, ``block``), for the trainers that
@@ -321,7 +321,10 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
     layer (``ops.moe.expert_plan``: ``held``, ``row_bound``, ``rows_buffer``,
     ``block``, ``rows_moved``). ``recompute``: what per-block recomputation keeps
     between a step's forward and backward pass (``HybridLM.recompute_plan``: the
-    ``kept`` names and ``kept_bytes``); None when nothing is recomputed."""
+    ``kept`` names and ``kept_bytes``); None when nothing is recomputed. ``ssm``: what a
+    step asks of each state-space layer (``ops.ssm.scan_plan``: heads and groups held,
+    head and state widths, the chunk, chunks and state bytes a sequence, what
+    recomputation keeps of the scan); None for a model with none."""
     flops = aot.get("flops")
     return {
         "event": "compile",
@@ -339,6 +342,7 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
         "attention": attention,
         "experts": experts,
         "recompute": recompute,
+        "ssm": ssm,
     }
 
 

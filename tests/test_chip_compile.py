@@ -152,3 +152,56 @@ def test_the_lfm2_step_keeps_its_flash_forward_and_fits_the_v5e(one_chip):
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes <= 15.0e9
     calls = lambda kernel: len(re.findall(rf"%{kernel}[.\d]* = ", compiled.as_text()))
     assert (calls("flash_fwd"), calls("flash_dq"), calls("flash_dkv")) == (1, 1, 1)
+
+
+# NVIDIA-Nemotron-3-Super-120B-A12B, chip 0 of 64: 16 Mamba-2 heads of 64 in one group with
+# a state of 128; 8 of 512 relu² experts of 1024 x 2688, 22 a token; 2 x 8192 tokens
+SSM = dict(batch=2, seq=8192, heads=16, head_dim=64, groups=1, state=128)
+LATENT, EXPERT_F, ROUTER_512, K_22, TOKENS = 1024, 2688, 512, 22, 2 * 8192
+
+
+def test_scan_kernels_compile_for_the_v5e_at_published_widths(one_chip):
+    """``ssd_fwd`` and ``ssd_bwd`` at the cell's shapes (chunk 128, P 64, N 128, sixteen
+    heads a grid step): Mosaic takes the per-head column slices, the transposed-operand
+    products and the carried state."""
+    from csed_514_project_distributed_training_using_pytorch_tpu.ops import ssm
+    b, s, h, p, g, n = (SSM[k] for k in ("batch", "seq", "heads", "head_dim", "groups", "state"))
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    x, step = spec((b, s, h, p), jnp.bfloat16), spec((b, s, h), jnp.float32)
+    bc = spec((b, s, g, n), jnp.bfloat16)
+    loss = lambda *args: jnp.sum(ssm.ssd_scan(*args).astype(jnp.float32))
+    with lowering_for_the_chip(ssm):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            x, step, step, bc, bc).compile().as_text()
+    assert "%ssd_fwd" in text and "%ssd_bwd" in text
+
+
+def test_latent_expert_layer_compiles_for_the_v5e_within_the_true_bound(one_chip):
+    """The relu² two-matrix product on latent rows, 22 assignments a token over 512
+    experts with 8 held: the expert-order buffers are ``min(k, held) · T`` rows and a
+    tile an expert, and no array of ``k · T`` rows of the latent width is in the
+    program."""
+    import re
+    from csed_514_project_distributed_training_using_pytorch_tpu.ops import moe
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(l, u, router_kernel, bias, w1, w2):
+        weights, experts = moe.route(u, router_kernel, bias, top_k=K_22, scaling=5.0,
+                                     eps=1e-20)
+        out, counts = moe.held_experts_ffn(l, weights, experts, w1, None, w2,
+                                           held=(0, HELD))
+        return jnp.sum(out.astype(jnp.float32)), counts
+
+    with lowering_for_the_chip(moe):
+        text = jax.jit(jax.value_and_grad(layer, argnums=(0, 2, 4, 5), has_aux=True)).lower(
+            spec((TOKENS, LATENT), jnp.bfloat16), spec((TOKENS, 4096), jnp.bfloat16),
+            spec((4096, ROUTER_512), jnp.float32), spec((ROUTER_512,), jnp.float32),
+            spec((LATENT, HELD * EXPERT_F), jnp.float32),
+            spec((EXPERT_F, HELD * LATENT), jnp.float32)).compile().as_text()
+    for name in KERNELS:
+        assert f"%{name}" in text, name
+    plan = moe.expert_plan(TOKENS, top_k=K_22, held=(0, HELD))
+    assert plan["row_bound"] == HELD * TOKENS
+    assert plan["rows_buffer"] == HELD * TOKENS + HELD * moe.ROW_TILE
+    assert f"[{plan['rows_buffer']},{LATENT}]" in text
+    assert not re.search(rf"\[{K_22 * TOKENS}(,\d+)*,{LATENT}\]", text)
