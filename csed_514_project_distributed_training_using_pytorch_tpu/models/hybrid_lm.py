@@ -47,7 +47,10 @@ and ``apply`` keep flax's calling convention so ``train/step.py`` builds the sta
 as for any other model. ``remat`` recomputes each block in the backward pass from
 its input and from what ``KEPT`` names: the flash kernel's output and statistics,
 the router's and the sort's products, and the matmul outputs that fit the chip beside
-the cell's state (everything else of a block, and the head's logits, runs again).
+the cell's state (everything else of a block runs again). The head runs once whatever
+``remat`` says: its loss has a differentiation rule of its own (``head_nll``) whose
+forward pass, asked for a gradient, takes the loss's gradient from the ``[T, vocab]``
+logits it has just computed, so they are neither kept nor computed again.
 No serving path: a short-convolution state beside keys and
 values in the slot engine is ROADMAP R4's.
 """
@@ -55,6 +58,7 @@ values in the slot engine is ROADMAP R4's.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 from typing import Callable
 
@@ -207,6 +211,17 @@ class HybridLM:
         return {"kept": list(KEPT),
                 "kept_bytes": sum(a.size * a.dtype.itemsize for a in kept)}
 
+    def head_products(self, jaxpr, tokens: int) -> int:
+        """The ``compile`` event's ``head_products``: the matrix products in ``jaxpr``
+        (a program that differentiates the loss once, as ``recompute_plan``'s) with an
+        operand or a result of the logits' shape ``[tokens, vocab]``, ``tokens`` the
+        rows of a step. Three are the mathematics (the logits and the two gradients
+        made of theirs); a fourth is the logits computed again."""
+        logits = (tokens, self.vocab_size)
+        return sum(eqn.primitive.name == "dot_general"
+                   and any(v.aval.shape == logits for v in (*eqn.invars, *eqn.outvars))
+                   for eqn in _equations(getattr(jaxpr, "jaxpr", jaxpr)))
+
     def param_shapes(self) -> dict:
         d, hd = self.hidden_size, self.head_dim
         heads, kv = self.num_attention_heads, self.num_key_value_heads
@@ -358,23 +373,8 @@ class HybridLM:
         # Row t's target is token t + 1. The last row has none: its log-probabilities
         # are computed and dropped, which keeps the head's matmul at S rows.
         targets = jnp.roll(tokens.astype(jnp.int32), -1, axis=1)[..., None]
-
-        def head(table, h):
-            logits = self._logits(table, h)
-            # The row maximum behind a barrier: fused with the subtraction, the
-            # compiler took it with a reduce-window as wide as the vocabulary
-            # (61 ms a pass on the v5e where the logits' product needs 6).
-            top = jax.lax.optimization_barrier(
-                jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True)))
-            shifted = logits - top
-            picked = jnp.take_along_axis(shifted, targets, axis=-1)[..., 0] \
-                - jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
-            return -jnp.sum(picked[:, :-1])
-
-        if self.remat:          # the [B, S, vocab] float32 logits are not kept
-            head = jax.checkpoint(head)
         with jax.named_scope("head_loss"):
-            return head(self._head(params), hidden), counts
+            return head_nll(self, self._head(params), hidden, targets), counts
 
     def loss(self, params, tokens) -> tuple[jax.Array, jax.Array | None]:
         """``(mean next-token NLL, counts)``: the training objective."""
@@ -391,6 +391,55 @@ def _equations(jaxpr):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
                     yield from _equations(sub)
+
+
+def _summed_nll(model: HybridLM, table, hidden, targets):
+    """``-Σ log softmax(hidden · table)[target]`` over every row but each sequence's
+    last, by plain ``jax.numpy``."""
+    logits = model._logits(table, hidden)
+    # The row maximum behind a barrier: fused with the subtraction, the compiler took
+    # it with a reduce-window as wide as the vocabulary (61 ms a pass on the v5e where
+    # the logits' product needs 6).
+    top = jax.lax.optimization_barrier(
+        jax.lax.stop_gradient(jnp.max(logits, axis=-1, keepdims=True)))
+    shifted = logits - top
+    picked = jnp.take_along_axis(shifted, targets, axis=-1)[..., 0] \
+        - jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
+    return -jnp.sum(picked[:, :-1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def head_nll(model: HybridLM, table, hidden, targets):
+    """The summed next-token NLL of ``hidden [B, S, d]`` under the head's ``table``
+    (``model._head``'s leaf), ``targets [B, S, 1]`` a row's next token. Alone it is
+    ``_summed_nll``. Differentiated, its forward pass also takes that function's
+    gradient with respect to table and hidden states, at a cotangent of one, while it
+    holds the ``[T, vocab]`` logits (the products autodiff makes of them: nothing of
+    their size outlives the pass, and nothing of the head runs again), and its
+    backward pass scales the two by the cotangent that arrives. One program on one
+    device: ``train/lm.py`` refuses ``--model-config`` on a mesh of several, so no
+    sharded logits reach the rule yet."""
+    return _summed_nll(model, table, hidden, targets)
+
+
+def _head_nll_fwd(model, table, hidden, targets):
+    total, pull = jax.vjp(lambda t, h: _summed_nll(model, t, h, targets), table, hidden)
+    d_table, d_hidden = pull(jnp.ones_like(total))
+    # The table's gradient is held in the model's dtype, which ``_logits``' cast of the
+    # table has rounded it to already, until the optimizer wants it. Both behind one
+    # barrier: the hidden states' is wanted at once, the table's after every block, and
+    # left to the scheduler that product waited there with its [T, vocab] operand.
+    return total, jax.lax.optimization_barrier((d_table.astype(model.dtype), d_hidden))
+
+
+def _head_nll_bwd(model, held, cotangent):
+    del model
+    d_table, d_hidden = held
+    # scaled in the cotangent's float32, which is a parameter's dtype (``init``)
+    return cotangent * d_table, (cotangent * d_hidden).astype(d_hidden.dtype), None
+
+
+head_nll.defvjp(_head_nll_fwd, _head_nll_bwd)
 
 
 def make_block(model: HybridLM, kind: str, sparse: bool):

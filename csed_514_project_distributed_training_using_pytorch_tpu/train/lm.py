@@ -430,15 +430,14 @@ def main(config: LMConfig = LMConfig(), *,
                 config, seq_len, world, dispatched=mesh.size == 1,
                 heads=model.num_attention_heads if hybrid else None,
                 head_dim=model.head_dim if hybrid else None)
-            experts = model.expert_plan(
-                config.batch_size // world // config.grad_accum * seq_len
-            ) if hybrid else None
-            recompute = model.recompute_plan(aot["jaxpr"]) if hybrid else None
+            step_tokens = config.batch_size // world // config.grad_accum * seq_len
+            plans = dict(experts=model.expert_plan(step_tokens),
+                         recompute=model.recompute_plan(aot["jaxpr"]),
+                         head_products=model.head_products(aot["jaxpr"], step_tokens),
+                         ssm=model.ssm_plan()) if hybrid else {}
             tele.emit(T.compile_event("epoch", aot,
                                       steps_per_call=steps_per_epoch,
-                                      attention=attention, experts=experts,
-                                      recompute=recompute,
-                                      ssm=model.ssm_plan() if hybrid else None))
+                                      attention=attention, **plans))
     history = M.MetricsHistory()
     saver = checkpoint.make_saver(config.async_checkpoint, tele=tele)
 

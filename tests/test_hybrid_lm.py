@@ -17,6 +17,7 @@ if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
 from reference import lfm2_moe as ref  # noqa: E402
+import head_rule  # noqa: E402
 import weights as bench_weights  # noqa: E402
 
 from csed_514_project_distributed_training_using_pytorch_tpu import ops  # noqa: E402
@@ -111,6 +112,34 @@ def test_remat_changes_no_float(stack):
         np.testing.assert_array_equal(g, w, err_msg=jax.tree_util.keystr(path))
 
 
+# (a') the head's loss and its rule: a tied table [vocab, d] ------------------------------
+
+
+@pytest.mark.parametrize("dtype", head_rule.DTYPES)
+def test_the_heads_rule_gives_the_plain_formulas_value_and_gradients(dtype):
+    model, params = build(tiny_config(), dtype=head_rule.DTYPES[dtype])
+    head_rule.check_value_and_gradients(model, params, tokens(), dtype)
+
+
+@pytest.mark.parametrize("scale", [1 / (3 * (SEQ - 1)), 3.0], ids=["the mean", "times 3"])
+@pytest.mark.parametrize("dtype", head_rule.DTYPES)
+def test_a_cotangent_scales_the_heads_two_gradients(dtype, scale):
+    model, params = build(tiny_config(), dtype=head_rule.DTYPES[dtype])
+    head_rule.check_a_cotangent_scales_both_gradients(model, params, tokens(), dtype, scale)
+
+
+@pytest.mark.parametrize("dtype", head_rule.DTYPES)
+def test_a_sequences_last_row_gets_no_gradient_from_the_head(dtype):
+    model, params = build(tiny_config(), dtype=head_rule.DTYPES[dtype])
+    head_rule.check_the_last_row_gets_no_gradient(model, params, tokens())
+
+
+@pytest.mark.parametrize("case", head_rule.PRODUCT_CASES)
+def test_the_logits_are_multiplied_once_a_pass(case, monkeypatch):
+    head_rule.check_head_products(lambda **kw: build(tiny_config(), **kw), tokens(),
+                                  case, monkeypatch)
+
+
 def _forward_runs(model, params):
     """``(flash_fwd calls, top_k's, sorts)`` in the loss's value and gradient."""
     jaxpr = jax.make_jaxpr(jax.value_and_grad(model.loss, has_aux=True))(params, tokens())
@@ -154,8 +183,8 @@ def _residual_bytes(capsys, model, params) -> int:
 def test_kept_bytes_are_the_named_residuals(capsys, monkeypatch, dtype):
     """``recompute_plan``'s ``kept_bytes`` in the loss's gradient against jax's own
     list of what the backward pass is handed: with the policy it holds, beside
-    everything a ``jax.checkpoint`` with no policy holds (the blocks' inputs, the
-    head's), the named values and nothing else."""
+    everything a ``jax.checkpoint`` with no policy holds (the blocks' inputs) and the
+    head's two gradients, the named values and nothing else."""
     model, params = build(tiny_config(), remat=True, attention_fn=ops.flash_attention, dtype=dtype)
     gradient = jax.make_jaxpr(jax.grad(lambda p: model.loss(p, tokens())[0]))(params)
     plan = model.recompute_plan(gradient)
@@ -529,10 +558,12 @@ def test_the_events_carry_the_expert_layers_fields(trained):
 def test_the_compile_event_says_what_recomputation_keeps(trained):
     """``recompute``: null without ``--remat``; with it the kept names and the bytes
     a step of 8 x 64 tokens holds under them (float32, dense attention: the flash
-    names tag nothing here)."""
+    names tag nothing here). ``head_products`` beside it, either way: the epoch
+    program multiplies the ``[T, vocab]`` logits three times a step."""
     (_, _, plain), (_, _, remat) = trained
     event = lambda events: [e for e in events if e["event"] == "compile"][0]
     assert event(plain)["recompute"] is None
+    assert event(plain)["head_products"] == event(remat)["head_products"] == 3
     t, d, k, held = 8 * 64, 32, 4, 4
     rows = (t * k // 256 + held) * 256
     sort = 2 * rows * 4 + t * k * (4 + 1) + 4 + held * (t // 256 + 1) * 4 + (rows // 256) * 4

@@ -21,6 +21,7 @@ if BENCH not in sys.path:
 
 from reference import nemotron_h as ref  # noqa: E402
 from reference import precision as prec  # noqa: E402
+import head_rule  # noqa: E402
 import weights as bench_weights  # noqa: E402
 
 from csed_514_project_distributed_training_using_pytorch_tpu.models import (  # noqa: E402
@@ -401,6 +402,32 @@ def test_loss_and_every_leafs_gradient_match_the_reference(remat):
     assert bias and all(float(jnp.abs(g).max()) == 0.0 for g in bias)
 
 
+@pytest.mark.parametrize("dtype", head_rule.DTYPES)
+def test_the_untied_heads_rule_gives_the_plain_formulas_value_and_gradients(dtype):
+    model, params = build(tiny_config(), dtype=head_rule.DTYPES[dtype])
+    assert not model.tied_head and model._head(params).shape == (32, VOCAB)
+    head_rule.check_value_and_gradients(model, params, tokens(), dtype)
+
+
+@pytest.mark.parametrize("scale", [1 / (2 * (SEQ - 1)), 3.0], ids=["the mean", "times 3"])
+@pytest.mark.parametrize("dtype", head_rule.DTYPES)
+def test_a_cotangent_scales_the_untied_heads_two_gradients(dtype, scale):
+    model, params = build(tiny_config(), dtype=head_rule.DTYPES[dtype])
+    head_rule.check_a_cotangent_scales_both_gradients(model, params, tokens(), dtype, scale)
+
+
+@pytest.mark.parametrize("dtype", head_rule.DTYPES)
+def test_a_sequences_last_row_gets_no_gradient_from_the_untied_head(dtype):
+    model, params = build(tiny_config(), dtype=head_rule.DTYPES[dtype])
+    head_rule.check_the_last_row_gets_no_gradient(model, params, tokens())
+
+
+@pytest.mark.parametrize("case", head_rule.PRODUCT_CASES)
+def test_the_untied_heads_logits_are_multiplied_once_a_pass(case, monkeypatch):
+    head_rule.check_head_products(lambda **kw: build(tiny_config(), **kw), tokens(),
+                                  case, monkeypatch)
+
+
 def test_router_choices_are_the_references():
     config = tiny_config()
     model, params = build(config)
@@ -615,6 +642,7 @@ def test_the_compile_event_says_what_the_new_layers_ask(trained):
                             "kept": ["ssd_out", "ssd_state"]}
     assert event["experts"]["row_bound"] == 4 * 8 * 64 and event["experts"]["held"] == [0, 4]
     assert event["recompute"]["kept_bytes"] > 0
+    assert event["head_products"] == 3      # the [T, vocab] logits: once a pass
     assert event["experts"]["bias_update_rate"] == 0.003
     # the selection's bias: out of AdamW, moved by the balancing rule alone, a rate a step
     steps = sum(e["steps"] for e in events if e["event"] == "epoch")
