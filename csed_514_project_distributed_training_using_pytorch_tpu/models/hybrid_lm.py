@@ -1,5 +1,6 @@
 """Decoder LM built from a published configuration file: a stack of
-short-convolution, Mamba-2, GQA and expert layers.
+short-convolution, Mamba-2, delta-rule (KDA), GQA, latent-attention (MLA) and expert
+layers.
 
 ``TransformerLM`` (``models/lm.py``) is the repo's own pixel decoder; this module is
 how a catalog architecture trains through ``train.lm``: the keys of the model's
@@ -9,7 +10,8 @@ experts of every sparse layer, which heads and groups of every mixer and which s
 of the vocabulary are held here, which published layer comes first) builds that
 share. ``LFM2-24B-A2B`` (``lfm2_moe``: ``layer_types``, ``num_dense_layers``) was the
 first such file, ``NVIDIA-Nemotron-3-Super-120B-A12B`` (``nemotron_h``:
-``hybrid_override_pattern``) is the second.
+``hybrid_override_pattern``) the second, ``Kimi-Linear-48B-A3B`` (``kimi_linear``:
+``linear_attn_config``'s 1-based lists of layers) the third.
 
 A layer is a block of two sublayers (``LAYER_KINDS``: its mixer) or one sublayer
 alone (``SUBLAYER_KINDS``). Each kind is written once, as a function of its
@@ -29,12 +31,22 @@ parameters, the normalized input and the positions:
     attention      q, k RMS-normed per head (or not) before RoPE (half-split pairing;
                    or no positions at all), causal softmax(q·k/√D)·v in groups,
                    through the pluggable ``attention_fn``
+    kda_mixer      q̃, k̃, ṽ = silu(conv(W u)) each;  q = q̃/‖q̃‖·K^-½, k = k̃/‖k̃‖ per head;
+                   g = −exp(A_log) · softplus(W_f↑ W_f↓ u + dt_bias) a channel, β =
+                   sigmoid(W_β u) a head, float32;  S_t = (I − β_t k_t k_tᵀ) Diag(e^{g_t})
+                   S_{t−1} + β_t k_t v_tᵀ, o_t = S_tᵀ q_t (``ops/kda.py``);
+                   W_o (RMSNorm_head(o) ⊙ sigmoid(W_g↑ W_g↓ u))
+    mla_mixer      q = W_q u, a head [nope | pe];  [c | k_pe] = W_kva u;  [k_nope | v] =
+                   W_kvb RMSNorm(c) a head;  a head's key is [k_nope | k_pe], k_pe the
+                   same for every head; no positions; causal softmax(q·k/√(nope + pe))·v
+                   through ``attention_fn`` at a key width that is not the value width
     dense_ff       W_2 (silu(W_1 u) ⊙ W_3 u)
     sparse_ff      ``ops/moe.py``: sigmoid router over all experts, top-k of s + b,
                    the held experts' part of the result, dropless. Experts are gated
                    (three matrices) on the model's rows, or relu² (two) on a latent row
                    (W_fc2 Σ_e w_e W2_e relu(W1_e W_fc1 u)²), beside a shared expert
-                   W_s2 relu(W_s1 u)² that every token passes
+                   that every token passes, W_s2 relu(W_s1 u)² or the gated W_s2
+                   (silu(W_s1 u) ⊙ W_s3 u)
 
 A share holds a mixer's heads as it holds experts: the out-projection sums over the
 held heads (of a Mamba-2 layer: whole groups, each with its own B, C and its own
@@ -67,12 +79,13 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from csed_514_project_distributed_training_using_pytorch_tpu import ops
-from csed_514_project_distributed_training_using_pytorch_tpu.ops import moe, ssm
+from csed_514_project_distributed_training_using_pytorch_tpu.ops import kda, moe, ssm
 from csed_514_project_distributed_training_using_pytorch_tpu.ops.rotary import (
     apply_rotary,
 )
 
-LAYER_KINDS = ("conv", "full_attention")      # a block: this mixer, then a feed-forward
+# a block: this mixer, then a feed-forward
+LAYER_KINDS = ("conv", "full_attention", "kda", "mla")
 SUBLAYER_KINDS = ("mamba", "attention", "moe")  # a layer that is one sublayer
 # What ``remat`` keeps of a block between its forward and its backward pass, beside
 # the block's input: the names of ``jax.ad_checkpoint.checkpoint_name`` tags, set
@@ -80,7 +93,7 @@ SUBLAYER_KINDS = ("mamba", "attention", "moe")  # a layer that is one sublayer
 KEPT = ("flash_out", "flash_lse", "moe_route", "moe_sort", "mixer_out",
         "attn_proj", "conv_in_proj", "ff_gate",
         "ssd_out", "ssd_state", "mamba_in_proj", "moe_latent", "moe_routed",
-        "shared_hidden")
+        "shared_hidden", "kda_out", "kda_state", "mla_latent")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,7 +126,15 @@ class HybridLM:
     router_bias_update_rate: float = 0.0    # of the selection's bias a step; 0: held fixed
     gated_experts: bool = True          # swiglu over W1, W3; else relu² over W1
     moe_latent_size: int = 0            # the experts' row width, where not the model's
-    shared_expert_size: int = 0         # held columns of the shared relu² expert
+    shared_expert_size: int = 0         # held columns of the shared expert
+    gated_shared_expert: bool = False   # swiglu over W_s1, W_s3; else relu² over W_s1
+    kda_heads: int = 0                  # heads of a KDA mixer
+    kda_head_dim: int = 128             # their key width, and their value width
+    kda_tiling: tuple[int, int, int] = (kda.CHUNK, kda.SUB, kda.GROUP)  # ops.kda's
+    kv_lora_rank: int = 0               # the key/value latent of an MLA mixer
+    qk_nope_head_dim: int = 128         # a head's key channels from the latent
+    qk_rope_head_dim: int = 64          # and those every head shares (carried, not rotated)
+    v_head_dim: int = 128
     mamba_heads: int = 0                # held heads of a Mamba-2 mixer, in whole groups
     mamba_groups: int = 1
     mamba_head_dim: int = 64
@@ -134,6 +155,10 @@ class HybridLM:
                 self.mamba_heads < 1 or self.mamba_heads % self.mamba_groups):
             raise ValueError(f"{self.mamba_groups} groups do not divide the "
                              f"{self.mamba_heads} heads of a mamba layer")
+        if "kda" in self.layer_types and self.kda_heads < 1:
+            raise ValueError("a kda layer needs kda_heads")
+        if "mla" in self.layer_types and self.kv_lora_rank < 1:
+            raise ValueError("an mla layer needs kv_lora_rank")
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("num_key_value_heads must divide num_attention_heads")
         first, count = self.held_experts
@@ -145,7 +170,14 @@ class HybridLM:
 
     @property
     def head_dim(self) -> int:
+        """An attention head's query and key width."""
+        if "mla" in self.layer_types:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.attention_head_dim or self.hidden_size // self.num_attention_heads
+
+    @property
+    def value_head_dim(self) -> int:
+        return self.v_head_dim if "mla" in self.layer_types else self.head_dim
 
     def is_sparse(self, layer: int) -> bool:
         """Whether layer ``layer`` holds an expert feed-forward."""
@@ -165,6 +197,19 @@ class HybridLM:
                              head_dim=self.mamba_head_dim, state=self.ssm_state_size,
                              seq_len=self.seq_len, chunk=self.chunk_size,
                              kept=KEPT if self.remat else ())
+
+    def kda_plan(self) -> dict | None:
+        """What a step asks of each KDA layer (``ops.kda.scan_plan``), or None for a
+        stack with none."""
+        if "kda" not in self.layer_types:
+            return None
+        return kda.scan_plan(heads=self.kda_heads, key_dim=self.kda_head_dim,
+                             value_dim=self.kda_head_dim, seq_len=self.seq_len,
+                             **self._kda_tiles, kept=KEPT if self.remat else ())
+
+    @property
+    def _kda_tiles(self) -> dict:
+        return dict(zip(("chunk", "sub", "group"), self.kda_tiling))
 
     def expert_plan(self, tokens: int) -> dict | None:
         """What a step of ``tokens`` tokens asks of each sparse layer
@@ -247,6 +292,23 @@ class HybridLM:
         if self.shared_expert_size:
             experts.update(shared_w1_kernel=(d, self.shared_expert_size),
                            shared_w2_kernel=(self.shared_expert_size, d))
+        if self.gated_shared_expert:
+            experts["shared_w3_kernel"] = (d, self.shared_expert_size)
+        wide, low = self.kda_heads * self.kda_head_dim, self.kda_head_dim
+        delta = {f"{name}_{leaf}": shape for name in "qkv" for leaf, shape in (
+            ("kernel", (d, wide)), ("conv_kernel", (self.conv_kernel, wide)))}
+        delta.update(f_a_kernel=(d, low), f_b_kernel=(low, wide), dt_bias=(wide,),
+                     A_log=(self.kda_heads,), b_kernel=(d, self.kda_heads),
+                     g_a_kernel=(d, low), g_b_kernel=(low, wide), o_norm_scale=(low,),
+                     out_kernel=(wide, d))
+        latent = {"q_kernel": (d, heads * self.head_dim),
+                  "kv_a_kernel": (d, self.kv_lora_rank + self.qk_rope_head_dim),
+                  "kv_a_norm_scale": (self.kv_lora_rank,),
+                  "kv_b_kernel": (self.kv_lora_rank,
+                                  heads * (self.qk_nope_head_dim + self.v_head_dim)),
+                  "out_kernel": (heads * self.v_head_dim, d)}
+        mixers = {"full_attention": ("attn", attn), "kda": ("kda", delta),
+                  "mla": ("mla", latent)}
         inner = self.mamba_heads * self.mamba_head_dim
         conv_width = inner + 2 * self.mamba_groups * self.ssm_state_size
         mamba = {"in_proj_kernel": (d, inner + conv_width + self.mamba_heads),
@@ -267,7 +329,8 @@ class HybridLM:
                                  "conv_kernel": (self.conv_L_cache, d),
                                  "out_proj_kernel": (d, d)}
             else:
-                layer["attn"] = dict(attn)
+                group, leaves = mixers[kind]
+                layer[group] = dict(leaves)
             if i < self.num_dense_layers:
                 layer["ff"] = {"w1_kernel": (d, self.intermediate_size),
                                "w3_kernel": (d, self.intermediate_size),
@@ -281,8 +344,8 @@ class HybridLM:
 
     def init(self, rngs, sample=None) -> dict:
         """``{"params": tree}``: kernels normal(0, 1/sqrt(fan_in)), the embedding and
-        a mamba layer's ``A_log`` normal(0, 0.02), norm scales and ``D_scale`` one, biases
-        (the selection's among them) zero."""
+        a mamba or KDA layer's ``A_log`` normal(0, 0.02), norm scales and ``D_scale`` one,
+        biases (the selection's among them) zero."""
         del sample
         key = rngs["params"] if isinstance(rngs, dict) else rngs
         flat, treedef = jax.tree_util.tree_flatten_with_path(
@@ -426,10 +489,13 @@ def _head_nll_fwd(model, table, hidden, targets):
     total, pull = jax.vjp(lambda t, h: _summed_nll(model, t, h, targets), table, hidden)
     d_table, d_hidden = pull(jnp.ones_like(total))
     # The table's gradient is held in the model's dtype, which ``_logits``' cast of the
-    # table has rounded it to already, until the optimizer wants it. Both behind one
-    # barrier: the hidden states' is wanted at once, the table's after every block, and
-    # left to the scheduler that product waited there with its [T, vocab] operand.
-    return total, jax.lax.optimization_barrier((d_table.astype(model.dtype), d_hidden))
+    # table has rounded it to already, until the optimizer wants it. All three behind one
+    # barrier: the hidden states' is wanted at once, the table's after every block and the
+    # loss's value at the step's end, and left to the scheduler the table's product and
+    # the gather of the targets' logits waited there with their [T, vocab] operand.
+    total, d_table, d_hidden = jax.lax.optimization_barrier(
+        (total, d_table.astype(model.dtype), d_hidden))
+    return total, (d_table, d_hidden)
 
 
 def _head_nll_bwd(model, held, cotangent):
@@ -470,8 +536,14 @@ def make_block(model: HybridLM, kind: str, sparse: bool):
 def mix(p, x, positions, kind: str, model: HybridLM):
     """``x + mixer(RMSNorm(x))``: the first half of a block."""
     u = ops.rms_norm(x, p["mixer_norm_scale"], eps=model.norm_eps)
-    mixed = (conv_mixer(p["conv"], u) if kind == "conv"
-             else attention_mixer(p["attn"], u, positions, model))
+    if kind == "conv":
+        mixed = conv_mixer(p["conv"], u)
+    elif kind == "kda":
+        mixed = kda_mixer(p["kda"], u, model)
+    elif kind == "mla":
+        mixed = mla_mixer(p["mla"], u, model)
+    else:
+        mixed = attention_mixer(p["attn"], u, positions, model)
     return checkpoint_name(x + mixed, "mixer_out")
 
 
@@ -548,6 +620,53 @@ def attention_mixer(p, u, positions, model: HybridLM):
         return _dense(out.reshape(b, s, heads * hd), p["out_kernel"])
 
 
+def kda_mixer(p, u, model: HybridLM):
+    with jax.named_scope("kda_mixer"):
+        b, s, _ = u.shape
+        heads, hd = model.kda_heads, model.kda_head_dim
+        f32 = jnp.float32
+
+        def branch(name):       # projection, short convolution, silu; by head
+            x = causal_depthwise_conv(_dense(u, p[f"{name}_kernel"]),
+                                      p[f"{name}_conv_kernel"])
+            return jax.nn.silu(x).reshape(b, s, heads, hd)
+
+        def unit(x, scale=1.0):     # a head's channels to length ``scale``
+            x = x.astype(f32)
+            norm = jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+            return (x * (norm * scale)).astype(u.dtype)
+
+        low_rank = lambda name: _dense(_dense(u, p[f"{name}_a_kernel"]),
+                                       p[f"{name}_b_kernel"]).astype(f32)
+        rate = jax.nn.softplus(low_rank("f") + p["dt_bias"].astype(f32))
+        decay = -jnp.exp(p["A_log"].astype(f32))[:, None] * rate.reshape(b, s, heads, hd)
+        beta = jax.nn.sigmoid(_dense(u, p["b_kernel"]).astype(f32))
+        o = kda.kda_scan(unit(branch("q"), hd ** -0.5), unit(branch("k")), branch("v"),
+                         decay, beta, **model._kda_tiles)
+        gate = jax.nn.sigmoid(low_rank("g")).reshape(b, s, heads, hd)
+        normed = ops.rms_norm(o.astype(f32), p["o_norm_scale"], eps=model.norm_eps)
+        return _dense((normed * gate).reshape(b, s, heads * hd).astype(u.dtype),
+                      p["out_kernel"])
+
+
+def mla_mixer(p, u, model: HybridLM):
+    with jax.named_scope("mla_attention"):
+        b, s, _ = u.shape
+        heads, nope, rank = model.num_attention_heads, model.qk_nope_head_dim, model.kv_lora_rank
+        q = checkpoint_name(_dense(u, p["q_kernel"]), "attn_proj").reshape(b, s, heads, -1)
+        latent, shared_key = jnp.split(
+            checkpoint_name(_dense(u, p["kv_a_kernel"]), "mla_latent"), [rank], axis=-1)
+        latent = ops.rms_norm(latent, p["kv_a_norm_scale"], eps=model.norm_eps)
+        own_key, v = jnp.split(
+            checkpoint_name(_dense(latent, p["kv_b_kernel"]), "attn_proj")
+            .reshape(b, s, heads, -1), [nope], axis=-1)
+        # the shared channels are carried as they are: no rotation (mla_use_nope)
+        k = jnp.concatenate([own_key, jnp.broadcast_to(
+            shared_key[:, :, None], (b, s, heads, shared_key.shape[-1]))], axis=-1)
+        out = model.attention_fn(q, k, v, causal=True)
+        return _dense(out.reshape(b, s, -1), p["out_kernel"])
+
+
 def dense_ff(p, u):
     with jax.named_scope("dense_ff"):
         # ``W1 u`` is kept and ``W3 u`` recomputed: beside the cell's state the chip
@@ -580,7 +699,9 @@ def sparse_ff(p, u, model: HybridLM):
     if model.shared_expert_size:
         with jax.named_scope("moe/shared"):
             hidden = checkpoint_name(_dense(flat, p["shared_w1_kernel"]), "shared_hidden")
-            out = out + _dense(jnp.square(jax.nn.relu(hidden)), p["shared_w2_kernel"])
+            hidden = (ops.swiglu(hidden, _dense(flat, p["shared_w3_kernel"]))
+                      if model.gated_shared_expert else jnp.square(jax.nn.relu(hidden)))
+            out = out + _dense(hidden, p["shared_w2_kernel"])
     return out.reshape(b, s, d), ((counts, *load) if balanced else counts)
 
 
@@ -606,9 +727,13 @@ def from_config(config: dict, *, vocab_size: int, seq_len: int, **kwargs) -> Hyb
     first = int(config.get("share", {}).get("first_layer", 0))
     depth = int(config["num_hidden_layers"])
     pattern, fields = _FAMILIES[family](config)
-    if len(pattern[first:first + depth]) != depth:
+    first = fields.pop("first_layer", first)    # a family that numbers its layers from 1
+    if first < 0 or len(pattern[first:first + depth]) != depth:
         raise ValueError("the layer pattern is shorter than first_layer + "
                          "num_hidden_layers")
+    for key, as_ in (("num_experts_per_tok", int), ("norm_eps", float)):
+        if key not in fields:       # a family whose file names it otherwise hands it over
+            fields[key] = as_(config[key])
     return HybridLM(vocab_size=int(vocab_size), seq_len=int(seq_len),
                     hidden_size=int(config["hidden_size"]),
                     intermediate_size=int(config["intermediate_size"]),
@@ -616,8 +741,6 @@ def from_config(config: dict, *, vocab_size: int, seq_len: int, **kwargs) -> Hyb
                     num_attention_heads=int(config["num_attention_heads"]),
                     num_key_value_heads=int(config["num_key_value_heads"]),
                     layer_types=tuple(pattern[first:first + depth]),
-                    num_experts_per_tok=int(config["num_experts_per_tok"]),
-                    norm_eps=float(config["norm_eps"]),
                     routed_scaling_factor=float(config.get("routed_scaling_factor", 1.0)),
                     **fields, **kwargs)
 
@@ -683,7 +806,62 @@ def _nemotron_h(config: dict) -> tuple[list, dict]:
         conv_kernel=int(config["conv_kernel"]), chunk_size=int(config["chunk_size"]))
 
 
-_FAMILIES = {"lfm2_moe": _lfm2_moe, "nemotron_h": _nemotron_h}
+def _kimi_linear(config: dict) -> tuple[list, dict]:
+    """``linear_attn_config``'s lists whole, layers numbered from 1 (so is
+    ``share.first_layer``, 1 when absent: the pattern has a layer 0 that no share can
+    start at): a KDA or a latent-attention mixer a layer, the first
+    ``first_k_dense_replace`` layers with the dense feed-forward and the others with gated
+    experts beside a gated shared expert; no positions; an untied head. What the file
+    states and this module does not compute is refused, not ignored."""
+    linear = config["linear_attn_config"]
+    kinds = {**{int(i): "kda" for i in linear["kda_layers"]},
+             **{int(i): "mla" for i in linear["full_attn_layers"]}}
+    published = int(config.get("published", {}).get("num_hidden_layers",
+                                                    config["num_hidden_layers"]))
+    first = int(config.get("share", {}).get("first_layer", 1))
+    unwritten = {
+        "a query latent (q_lora_rank not null)": config.get("q_lora_rank") is not None,
+        "rope_scaling not null": config.get("rope_scaling") is not None,
+        "rotated shared key channels (mla_use_nope false)":
+            not config.get("mla_use_nope", False),
+        "grouped expert selection (num_expert_group, topk_group other than 1)":
+            (config.get("num_expert_group", 1), config.get("topk_group", 1)) != (1, 1),
+        "multi-token prediction (num_nextn_predict_layers > 0: the trainer has no "
+        "such loss)": bool(config.get("num_nextn_predict_layers", 0)),
+        "moe_layer_freq other than 1": config.get("moe_layer_freq", 1) != 1,
+        "hidden_act other than silu": config.get("hidden_act", "silu") != "silu",
+        "moe_router_activation_func other than sigmoid":
+            config.get("moe_router_activation_func", "sigmoid") != "sigmoid",
+        "selected scores that are not normalised (moe_renormalize false)":
+            not config.get("moe_renormalize", True),
+        "layers of linear_attn_config's lists that are not 1 to the published depth, "
+        "each once": sorted(kinds) != list(range(1, published + 1))
+        or len(kinds) != len(linear["kda_layers"]) + len(linear["full_attn_layers"]),
+        "share.first_layer 0 (the file's lists count layers from 1)": first < 1,
+    }
+    for what, stated in unwritten.items():
+        if stated:
+            raise ValueError(f"{what} is not written here")
+    return [None] + [kinds[i] for i in range(1, published + 1)], dict(
+        _held_experts(config, "num_experts"), first_layer=first,
+        num_dense_layers=max(0, int(config["first_k_dense_replace"]) - (first - 1)),
+        num_experts_per_tok=int(config["num_experts_per_token"]),
+        norm_eps=float(config["rms_norm_eps"]), rope_theta=None, qk_norm=False,
+        tied_head=bool(config.get("tie_word_embeddings", False)), router_eps=1e-20,
+        router_bias_update_rate=float(config.get("moe_router_bias_update_rate", 0.0)),
+        shared_expert_size=int(config["moe_intermediate_size"])
+        * int(config.get("num_shared_experts", 0)),
+        gated_shared_expert=bool(config.get("num_shared_experts", 0)),
+        kda_heads=int(linear["num_heads"]), kda_head_dim=int(linear["head_dim"]),
+        conv_kernel=int(linear["short_conv_kernel_size"]),
+        kv_lora_rank=int(config["kv_lora_rank"]),
+        qk_nope_head_dim=int(config["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(config["qk_rope_head_dim"]),
+        v_head_dim=int(config["v_head_dim"]))
+
+
+_FAMILIES = {"lfm2_moe": _lfm2_moe, "nemotron_h": _nemotron_h,
+             "kimi_linear": _kimi_linear}
 
 
 def from_config_file(path: str, **kwargs) -> HybridLM:

@@ -1,0 +1,325 @@
+"""The gated delta rule with a per-channel decay (Kimi Delta Attention) as a chunked
+scan, forward and backward, in Pallas.
+
+Per head (keys of ``K`` channels, values of ``V``, a state of ``K x V``) and token ``t``::
+
+    S_t = (I − β_t k_t k_tᵀ) Diag(exp(g_t)) S_{t−1} + β_t k_t v_tᵀ        S_0 = 0
+    o_t = S_tᵀ q_t
+
+``g_t <= 0`` is a vector of ``K`` log-decays, ``β_t`` a scalar. The convolutions, the
+norms of ``q`` and ``k``, softplus, the sigmoid and the output gate are the caller's
+(``models/hybrid_lm.py``). ``β`` enters the kernels folded into two operands, ``β k`` and
+``β v``, so that its gradient is autodiff's, outside.
+
+The scan walks a sequence in chunks of ``C`` tokens (``CHUNK``). With ``G`` the running
+sum of ``g`` from the chunk's start (inclusive) and ``P(a, b)_ij = Σ_c a_ic b_jc
+exp(G_ic − G_jc)``::
+
+    A = strict_lower(P(βk, k))        Ũ = (I + A)⁻¹ (βv − (βk ⊙ e^G) S)
+    o = (q ⊙ e^G) S + lower(P(q, k)) Ũ
+    S' = Diag(e^{G_C}) S + (k ⊙ e^{G_C − G})ᵀ Ũ
+
+``exp(−G)`` is never formed: a trained decay spans a few units a chunk, a seeded one
+hundreds, and ``exp(G_i − G_j)`` is wanted only where ``i >= j``, where it is at most
+one. ``P`` is built in sub-blocks of ``SUB`` rows. A pair less than ``SUB`` apart inside
+one sub-block is computed exactly, ``Σ_c a_ic b_jc exp(G_ic − G_jc)`` a diagonal of the
+sub-block at a time (the rows shifted by their distance, on the VPU); a sub-block of
+rows against the columns before it is one product of two operands rescaled against
+the sub-block's first row ``n``, ``a ⊙ exp(G − G_n)`` and ``b ⊙ exp(G_n − G)``, both
+factors at most one, so a decay too small for float32 reads zero and never infinity.
+``(I + A)⁻¹`` is exact elimination in products: inside the diagonal sub-blocks
+``(I − D)(I + D²)(I + D⁴)…`` (``D`` is nilpotent), then ``(I − N)(I + N²)…`` over the
+sub-blocks with ``N = (I + D)⁻¹(A − D)``, so no power of the whole ``A`` is taken.
+
+``kda_fwd`` takes ``GROUP`` chunks a grid step, the groups of one (batch, head) along a
+sequential grid axis with the state carried in VMEM (held transposed, ``[V, K]``, so
+that a channel's decay is a lane's), and writes the state that entered every GROUP
+(``[B, S/(GROUP·C), H, V, K]`` float32: a state is 64 KiB at the published 128 x 128, so
+every chunk's would be 0.27 GB a layer and sequence of 8192, and a group's is a
+quarter of that). ``kda_bwd`` walks the groups in reverse carrying the state's
+gradient: a step runs its group's chunks again from the kept state and then their
+transpose, which is ``jax.vjp`` of the very function the forward kernel runs, traced
+into the kernel (so the two cannot drift apart). Decays, masks, the running sums
+(one float32 product with a triangle of ones, at ``highest``) and the state are
+float32; every other product runs on the MXU in the model's dtype.
+
+A sequence whose length is not a multiple of ``GROUP·C`` is padded at its end with
+tokens that decay nothing and write nothing (``g = 0``, ``β = 0``), and the result sliced.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+CHUNK = 64      # tokens of a chunk: the published kernels', and what keeps (I + A)⁻¹ small
+SUB = 16        # rows of a sub-block: pairs closer than this are computed exactly
+GROUP = 4       # chunks a grid step, and between two kept states
+
+NN, NT, TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
+
+
+def _interpret() -> bool:
+    """Compiled on TPU; interpret mode on CPU (the test platform)."""
+    return jax.default_backend() != "tpu"
+
+
+def _dot(a, b, contract, dtype):
+    return jax.lax.dot_general(a.astype(dtype), b.astype(dtype), (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _rolled(x, by: int):
+    return pltpu.roll(x, by, 0)
+
+
+_rolled.defvjp(lambda x, by: (pltpu.roll(x, by, 0), None),
+               lambda by, _, d: (pltpu.roll(d, d.shape[0] - by, 0),))
+
+
+def _shifted(x, by: int):
+    """Row ``i`` of the result is row ``i − by`` of ``x`` (cyclic; callers mask the wrap)."""
+    return jnp.roll(x, by, axis=0) if _interpret() else _rolled(x, by)
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _pair_scores(q, k, kb, cum, sub: int, dtype):
+    """``(strict_lower P(kb, k), lower P(q, k))`` of one chunk, ``[C, C]`` float32; the
+    operands float32 ``[C, K]``."""
+    c, d = k.shape
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+    within = _iota((c, d), 0) % sub
+    rowsum = lambda x: jnp.sum(x, axis=1, keepdims=True)
+    # inside a sub-block, a diagonal at a time: exp(G_i − G_{i−by}) itself
+    kk = jnp.zeros((c, c), jnp.float32)
+    qk = jnp.where(row == col, rowsum(q * k), 0.0)
+    for by in range(1, sub):
+        near = within >= by
+        decay = jnp.exp(jnp.where(near, cum - _shifted(cum, by), 0.0))
+        kd = jnp.where(near, _shifted(k, by) * decay, 0.0)
+        at = row - col == by
+        kk = jnp.where(at, rowsum(kb * kd), kk)
+        qk = jnp.where(at, rowsum(q * kd), qk)
+    # a sub-block's rows against every column before it, rescaled against its first row
+    far_kk, far_qk = [jnp.zeros((sub, c), jnp.float32)], [jnp.zeros((sub, c), jnp.float32)]
+    for n in range(sub, c, sub):
+        first = cum[n:n + 1]
+        down = jnp.exp(cum[n:n + sub] - first)
+        before = k * jnp.exp(jnp.minimum(first - cum, 0.0))
+        both = _dot(jnp.concatenate([kb[n:n + sub] * down, q[n:n + sub] * down]),
+                    before, NT, dtype)
+        far_kk.append(both[:sub])
+        far_qk.append(both[sub:])
+    far = col // sub < row // sub
+    return (jnp.where(far, jnp.concatenate(far_kk), kk),
+            jnp.where(far, jnp.concatenate(far_qk), qk))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _unit_lower_inverse(a, sub: int, dtype):
+    """``(I + a)⁻¹`` of a strictly lower triangular ``a [C, C]``."""
+    c = a.shape[0]
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+    eye = (row == col).astype(jnp.float32)
+    mm = lambda x, y: _dot(x, y, NN, dtype)
+
+    def nilpotent_inverse(n, index: int):
+        """``(I + n)⁻¹ = (I − n)(I + n²)(I + n⁴)…`` where ``n`` to the ``index`` is zero."""
+        inverse, power, reach = eye - n, n, 2
+        while reach < index:
+            power = mm(power, power)
+            inverse = inverse + mm(inverse, power)
+            reach *= 2
+        return inverse
+
+    inside = jnp.where(row // sub == col // sub, a, 0.0)
+    blocks = nilpotent_inverse(inside, sub)
+    if c == sub:
+        return blocks
+    return mm(nilpotent_inverse(mm(blocks, a - inside), c // sub), blocks)
+
+
+def _inverse_fwd(a, sub, dtype):
+    inverse = _unit_lower_inverse(a, sub, dtype)
+    return inverse, inverse
+
+
+def _inverse_bwd(sub, dtype, inverse, d):
+    # d(M⁻¹) = −M⁻¹ dM M⁻¹
+    return (-_dot(_dot(inverse, d, TN, dtype), inverse, NT, dtype),)
+
+
+_unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
+
+
+def _chunk(q, k, kb, vb, g, state, sub: int):
+    """One chunk of one head: ``(o [C, V] float32, the state after it)``. ``state`` is
+    ``Sᵀ [V, K]`` float32; ``q``, ``k``, ``kb`` ``[C, K]`` and ``vb [C, V]`` in the model's
+    dtype; ``g [C, K]`` float32."""
+    dtype, c = q.dtype, q.shape[0]
+    q, k, kb = (x.astype(jnp.float32) for x in (q, k, kb))
+    ones = (_iota((c, c), 0) >= _iota((c, c), 1)).astype(jnp.float32)
+    cum = jax.lax.dot_general(ones, g, (NN, ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
+                              preferred_element_type=jnp.float32)
+    grown, total = jnp.exp(cum), cum[c - 1:c]
+    a_kk, a_qk = _pair_scores(q, k, kb, cum, sub, dtype)
+    inverse = _unit_lower_inverse(a_kk, sub, dtype)
+    w = _dot(inverse, kb * grown, NN, dtype)
+    u = _dot(inverse, vb, NN, dtype)
+    fresh = u - _dot(w, state, NT, dtype)                           # Ũ [C, V]
+    o = _dot(q * grown, state, NT, dtype) + _dot(a_qk, fresh, NN, dtype)
+    state = state * jnp.exp(total) + _dot(fresh, k * jnp.exp(total - cum), TN, dtype)
+    return o, state
+
+
+def _group(q, k, kb, vb, g, state, chunk: int, sub: int):
+    """The chunks of one grid step, one after the other: ``(o, the state after)``."""
+    out = []
+    for at in range(0, q.shape[0], chunk):
+        o, state = _chunk(*(x[at:at + chunk] for x in (q, k, kb, vb, g)), state, sub)
+        out.append(o)
+    return jnp.concatenate(out).astype(vb.dtype), state
+
+
+def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, entered_ref, state, *,
+                chunk, sub):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    entered_ref[...] = state[...]
+    o_ref[...], state[...] = _group(q_ref[...], k_ref[...], kb_ref[...], vb_ref[...],
+                                    g_ref[...], state[...], chunk, sub)
+
+
+def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, entered_ref, do_ref,
+                dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref, dstate, *, chunk, sub):
+    """The same group's transpose, the groups taken last to first; ``dstate``: the
+    gradient of the state this group hands on."""
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    _, pull = jax.vjp(functools.partial(_group, chunk=chunk, sub=sub),
+                      q_ref[...], k_ref[...], kb_ref[...], vb_ref[...], g_ref[...],
+                      entered_ref[...])
+    (dq_ref[...], dk_ref[...], dkb_ref[...], dvb_ref[...], dg_ref[...],
+     dstate[...]) = pull((do_ref[...], dstate[...]))
+
+
+def _specs(rows: int, k: int, v: int, at):
+    """Block specs by operand; ``at(s)`` is the group that step ``s`` of the sequential
+    grid axis works on (the backward pass walks them in reverse). A head's channels
+    are a block of lanes of the ``[B, S, H·width]`` arrays: no operand is transposed."""
+    tokens = lambda width: pl.BlockSpec((None, rows, width),
+                                        lambda b, h, s: (b, at(s), h))
+    return {"k": tokens(k), "v": tokens(v),
+            "state": pl.BlockSpec((None, None, None, v, k),
+                                  lambda b, h, s: (b, at(s), h, 0, 0))}
+
+
+def _params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _scan_fwd(q, k, kb, vb, g, heads: int, chunk: int, sub: int, group: int):
+    bsz, s, _ = q.shape
+    dk, dv, rows = q.shape[2] // heads, vb.shape[2] // heads, group * chunk
+    sp = _specs(rows, dk, dv, lambda step: step)
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, chunk=chunk, sub=sub), name="kda_fwd",
+        interpret=_interpret(), grid=(bsz, heads, s // rows),
+        in_specs=[sp["k"], sp["k"], sp["k"], sp["v"], sp["k"]],
+        out_specs=[sp["v"], sp["state"]],
+        out_shape=[jax.ShapeDtypeStruct(vb.shape, vb.dtype),
+                   jax.ShapeDtypeStruct((bsz, s // rows, heads, dv, dk), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        compiler_params=_params(),
+    )(q, k, kb, vb, g)
+
+
+def _scan_bwd(q, k, kb, vb, g, entered, do, heads: int, chunk: int, sub: int, group: int):
+    bsz, s, _ = q.shape
+    dk, dv, rows = q.shape[2] // heads, vb.shape[2] // heads, group * chunk
+    groups = s // rows
+    sp = _specs(rows, dk, dv, lambda step: groups - 1 - step)
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, chunk=chunk, sub=sub), name="kda_bwd",
+        interpret=_interpret(), grid=(bsz, heads, groups),
+        in_specs=[sp["k"], sp["k"], sp["k"], sp["v"], sp["k"], sp["state"], sp["v"]],
+        out_specs=[sp["k"], sp["k"], sp["k"], sp["v"], sp["k"]],
+        out_shape=[like(q), like(k), like(kb), like(vb), like(g)],
+        scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
+        compiler_params=_params(),
+    )(q, k, kb, vb, g, entered, do.astype(vb.dtype))
+
+
+@functools.lru_cache(maxsize=None)
+def _make_op(heads: int, chunk: int, sub: int, group: int):
+    # Jitted halves behind a cached factory, as ``ssm._make_op``: every KDA layer of a
+    # model calls the same two functions, lowered once a program.
+    kw = dict(heads=heads, chunk=chunk, sub=sub, group=group)
+    forward = jax.jit(functools.partial(_scan_fwd, **kw))
+    backward = jax.jit(functools.partial(_scan_bwd, **kw))
+
+    @jax.custom_vjp
+    def op(q, k, kb, vb, g):
+        return forward(q, k, kb, vb, g)[0]
+
+    def fwd(q, k, kb, vb, g):
+        # Named as the VJP's residuals: a caller's ``jax.checkpoint`` whose policy
+        # keeps these names does not run ``kda_fwd`` again in its backward pass.
+        o, entered = forward(q, k, kb, vb, g)
+        o, entered = checkpoint_name(o, "kda_out"), checkpoint_name(entered, "kda_state")
+        return o, (q, k, kb, vb, g, entered)
+
+    def bwd(residuals, do):
+        return backward(*residuals, do)
+
+    op.defvjp(fwd, bwd)
+    return op
+
+
+def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array, *,
+             chunk: int = CHUNK, sub: int = SUB, group: int = GROUP) -> jax.Array:
+    """``o [B, S, H, V]`` of the recurrence above. ``q``, ``k`` ``[B, S, H, K]`` and ``v
+    [B, S, H, V]`` in the model's dtype; ``g [B, S, H, K]`` (log-decays, ``<= 0``) and
+    ``beta [B, S, H]`` float32. Differentiable in all five. Any ``S``: the tail of a
+    sequence is padded to a whole group of chunks."""
+    bsz, s, heads, dk = q.shape
+    if chunk % sub or (chunk // sub) & (chunk // sub - 1) or sub & (sub - 1):
+        raise ValueError(f"sub-blocks of {sub} rows do not halve a chunk of {chunk}")
+    scaled = lambda x: (beta[..., None] * x.astype(jnp.float32)).astype(x.dtype)
+    operands = [q, k, scaled(k), scaled(v), g.astype(jnp.float32)]
+    short = -s % (group * chunk)
+    flat = [jnp.pad(x, ((0, 0), (0, short), (0, 0), (0, 0))).reshape(bsz, s + short, -1)
+            for x in operands]
+    with jax.named_scope("kda"):
+        o = _make_op(heads, chunk, sub, group)(*flat)
+    return o[:, :s].reshape(bsz, s, heads, -1)
+
+
+def scan_plan(*, heads: int, key_dim: int, value_dim: int, seq_len: int,
+              chunk: int = CHUNK, sub: int = SUB, group: int = GROUP,
+              kept: tuple[str, ...] = ()) -> dict:
+    """The ``compile`` event's ``kda`` field: what a KDA layer asks of a step. A state is
+    kept a group of chunks, not a chunk."""
+    rows = group * chunk
+    groups = -(-seq_len // rows)
+    return {"heads": heads, "key_dim": key_dim, "value_dim": value_dim, "chunk": chunk,
+            "sub_block": sub, "chunks_per_sequence": groups * group,
+            "states_per_sequence": groups,
+            "state_bytes_per_sequence": groups * heads * key_dim * value_dim * 4,
+            "kept": [name for name in ("kda_out", "kda_state") if name in kept]}

@@ -425,7 +425,8 @@ def _fwd_kernel(*refs, scale, causal, num_steps, num_blocks,
 
 def _flash_forward(qx, kx, vx, *, causal: bool, block: int = BLOCK,
                    window: int = 0, q_offset: int = 0, q_offset_dyn=None):
-    """Packed [BH, S, D]³ → (out [BH, S, D], lse [BH, S/block, 1, block]).
+    """Packed q, k ``[BH, S, D]`` and v ``[BH, S, Dv]`` → (out [BH, S, Dv], lse [BH,
+    S/block, 1, block]); the scores' scale is ``D^-½``, the key width's.
     ``q_offset`` (static, a multiple of ``block``) shifts query positions globally
     relative to the keys — the ring hop offset (see ``_visibility_mask``).
     ``q_offset_dyn`` (a traced int32 scalar, mutually exclusive with a nonzero
@@ -438,6 +439,7 @@ def _flash_forward(qx, kx, vx, *, causal: bool, block: int = BLOCK,
     (``_dyn_band_reach``) to absorb the sub-block remainder its floor-division
     steering discards."""
     bh, s, d = qx.shape
+    dv = vx.shape[-1]
     _check_block(s, block)
     _check_offset(q_offset, block)
     dyn = q_offset_dyn is not None
@@ -471,21 +473,22 @@ def _flash_forward(qx, kx, vx, *, causal: bool, block: int = BLOCK,
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                num_steps=num_steps, num_blocks=nq, band_base=base,
                                window=window, q_offset=q_offset, dyn_offset=dyn)
-    row_spec = _spec((block, d), _row_idx, dyn)
-    walk_spec = _spec((block, d), key_idx, dyn)
+    out_spec = _spec((block, dv), _row_idx, dyn)
     out_shape = [
-        jax.ShapeDtypeStruct(qx.shape, qx.dtype),
+        jax.ShapeDtypeStruct((bh, s, dv), qx.dtype),
         jax.ShapeDtypeStruct((bh, nq, 1, block), jnp.float32),
     ]
     scratch_shapes = [
-        pltpu.VMEM((block, d), jnp.float32),    # acc
+        pltpu.VMEM((block, dv), jnp.float32),   # acc
         pltpu.VMEM((block, 1), jnp.float32),    # running max m
         pltpu.VMEM((block, 1), jnp.float32),    # running normalizer l
     ]
     dyn_args = ((jnp.asarray(q_offset_dyn, jnp.int32).reshape(1),) if dyn else ())
     out, lse = _pallas_dispatch(
-        kernel, (bh, nq, num_steps), [row_spec, walk_spec, walk_spec],
-        [row_spec, _spec((1, 1, block), _row_idx, dyn)], out_shape,
+        kernel, (bh, nq, num_steps),
+        [_spec((block, d), _row_idx, dyn), _spec((block, d), key_idx, dyn),
+         _spec((block, dv), key_idx, dyn)],
+        [out_spec, _spec((1, 1, block), _row_idx, dyn)], out_shape,
         scratch_shapes, dyn)(*dyn_args, qx, kx, vx)
     return out, lse
 
@@ -638,9 +641,9 @@ def flash_backward_blocks(qx, kx, vx, g, lse, delta, *, causal: bool,
     """One flash-backward pass of a query-block set against a key/value-block set,
     given the GLOBAL softmax statistics: ``(dq, dk, dv)`` contributions.
 
-    Packed layout (the ring schedules' shard form): ``qx/g: [BH, Sq, D]``,
-    ``kx/vx: [BH, Sk, D]`` with ``Sq == Sk``, ``lse/delta: [BH, Sq/BLOCK, 1,
-    BLOCK]``. The statistics are of the FULL attention row (all
+    Packed layout (the ring schedules' shard form): ``qx: [BH, Sq, D]``, ``kx: [BH,
+    Sk, D]``, ``vx: [BH, Sk, Dv]`` and ``g: [BH, Sq, Dv]`` with ``Sq == Sk``,
+    ``lse/delta: [BH, Sq/BLOCK, 1, BLOCK]``. The statistics are of the FULL attention row (all
     keys, not just this block set): ``p = exp(q·kᵀ·scale − lse)`` then yields the
     true softmax coefficients restricted to these keys, so the returned
     contributions sum exactly over block sets — the per-hop building block of the
@@ -649,10 +652,11 @@ def flash_backward_blocks(qx, kx, vx, g, lse, delta, *, causal: bool,
     LOCAL block indices, i.e. it assumes q and k share a global origin — ring
     callers use it only for the diagonal hop."""
     bh, s, d = qx.shape
-    if kx.shape != qx.shape:
+    dv = vx.shape[-1]
+    if kx.shape != qx.shape or vx.shape[:2] != qx.shape[:2]:
         raise ValueError(
             f"flash_backward_blocks needs equal q/k block sets, got {qx.shape} vs "
-            f"{kx.shape}")
+            f"{kx.shape} and values {vx.shape}")
     _check_block(s, block)
     _check_offset(q_offset, block)
     dyn = q_offset_dyn is not None
@@ -700,10 +704,11 @@ def flash_backward_blocks(qx, kx, vx, g, lse, delta, *, causal: bool,
                 i + sign * (off[0] // block) + o - base, 0, nq - 1)
         return lambda i, o: jnp.clip(i + center_off + o - base, 0, nq - 1)
 
-    row_spec = _spec((block, d), _row_idx, dyn)
+    # key-width operands (q, k and their gradients) and value-width ones (v, dout)
+    row_spec, row_spec_v = (_spec((block, w), _row_idx, dyn) for w in (d, dv))
     lse_row_spec = _spec((1, 1, block), _row_idx, dyn)
-    out_like = lambda x: jax.ShapeDtypeStruct(qx.shape, x.dtype)
-    acc = pltpu.VMEM((block, d), jnp.float32)
+    out_like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+    acc, acc_v = (pltpu.VMEM((block, w), jnp.float32) for w in (d, dv))
     dyn_args = ((jnp.asarray(q_offset_dyn, jnp.int32).reshape(1),) if dyn else ())
 
     def call(kernel_fn, base, steps, in_specs, out_specs, out_shape, scratch):
@@ -715,20 +720,20 @@ def flash_backward_blocks(qx, kx, vx, g, lse, delta, *, causal: bool,
                                 out_shape, scratch, dyn)(
             *dyn_args, qx, kx, vx, g, lse, delta)
 
-    dq_walk = _spec((block, d), _walk_idx(dq_base, off_blocks), dyn)
+    dq_idx = _walk_idx(dq_base, off_blocks)
     dq = call(_dq_kernel, dq_base, dq_steps,
-              [row_spec, dq_walk, dq_walk, row_spec, lse_row_spec, lse_row_spec],
+              [row_spec, _spec((block, d), dq_idx, dyn), _spec((block, dv), dq_idx, dyn),
+               row_spec_v, lse_row_spec, lse_row_spec],
               [row_spec], [out_like(qx)], [acc])[0]
 
     # dkv grid: the query-block axis walks (accumulators persist per key block).
     kv_idx = _walk_idx(kv_base, -off_blocks, kv=True)
-    kv_walk = _spec((block, d), kv_idx, dyn)
     kv_lse_walk = _spec((1, 1, block), kv_idx, dyn)
-    dk, dv = call(_dkv_kernel, kv_base, kv_steps,
-                  [kv_walk, row_spec, row_spec, kv_walk, kv_lse_walk,
-                   kv_lse_walk],
-                  [row_spec, row_spec], [out_like(kx), out_like(vx)], [acc, acc])
-    return dq, dk, dv
+    dk, dvx = call(_dkv_kernel, kv_base, kv_steps,
+                   [_spec((block, d), kv_idx, dyn), row_spec, row_spec_v,
+                    _spec((block, dv), kv_idx, dyn), kv_lse_walk, kv_lse_walk],
+                   [row_spec, row_spec_v], [out_like(kx), out_like(vx)], [acc, acc_v])
+    return dq, dk, dvx
 
 
 # =========================================================================================
@@ -790,7 +795,9 @@ def flash_forward_with_lse(q3: jax.Array, k3: jax.Array, v3: jax.Array, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False, block: int | None = None,
                     window: int | None = None) -> jax.Array:
-    """Drop-in for ``ops.full_attention``: ``[B, S, H, D]`` → ``[B, S, H, D]``.
+    """Drop-in for ``ops.full_attention``: ``[B, S, H, D]`` → ``[B, S, H, D]``; ``v`` may
+    be of another width than ``q`` and ``k`` (``[B, S, H, Dv]`` → ``[B, S, H, Dv]``: latent
+    attention's 192 / 128), the scores' scale being the key width's ``D^-½``.
 
     Requires ``S % block == 0`` with ``block`` a multiple of 128 (lane-aligned), or
     ``causal=True``: a causal call of any other length is zero-padded at the tail to
@@ -821,8 +828,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         q, k, v = (jnp.pad(x, ((0, 0), (0, padded - s), (0, 0), (0, 0)))
                    for x in (q, k, v))
     op = _make_op(bool(causal), block, int(window or 0))
-    to3 = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, padded, d)
-    out = jnp.transpose(op(to3(q), to3(k), to3(v)).reshape(b, h, padded, d),
+    to3 = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, padded, x.shape[-1])
+    out = jnp.transpose(op(to3(q), to3(k), to3(v)).reshape(b, h, padded, v.shape[-1]),
                         (0, 2, 1, 3))
     return out[:, :s] if padded != s else out
 
@@ -843,9 +850,10 @@ def _flash_plan(s: int, *, causal: bool, window: int | None,
 
 
 def dispatch_plan(shape, *, causal: bool = False, window: int | None = None,
-                  k_len: int | None = None) -> dict:
-    """What ``dispatch_attention`` does with a per-device ``[B, S, H, D]`` call, from
-    its shapes alone: ``{impl, score_bytes, seq_padded, block}``. The one
+                  k_len: int | None = None, value_dim: int | None = None) -> dict:
+    """What ``dispatch_attention`` does with a per-device ``[B, S, H, D]`` call (values
+    of ``value_dim`` channels where that is not ``D``), from its shapes alone: ``{impl,
+    score_bytes, seq_padded, block, key_dim, value_dim}``. The one
     routing predicate: the dispatcher runs what this returns, and callers that label
     a measurement or a telemetry event (``train/lm.py``'s ``compile`` event,
     ``bench_transformer.py``, ``chip_smoke.py``) read the same dict, so a label
@@ -860,10 +868,11 @@ def dispatch_plan(shape, *, causal: bool = False, window: int | None = None,
     Under ``jit`` over a mesh the shapes a trace sees are global: callers there
     hand the dispatcher per-device calls (``shard_map``) or keep the dense core
     (``train/lm.py``)."""
-    b, s, h, _ = shape
+    b, s, h, d = shape
     s_k = s if k_len is None else k_len
     plan = {"impl": "dense", "score_bytes": 4 * b * h * s * s_k,
-            "seq_padded": None, "block": None}
+            "seq_padded": None, "block": None, "key_dim": d,
+            "value_dim": d if value_dim is None else value_dim}
     if (plan["score_bytes"] >= FLASH_MIN_SCORE_BYTES
             and 4 * s * s_k >= FLASH_MIN_HEAD_SCORE_BYTES
             and s_k == s and (causal or s % BLOCK == 0)):
@@ -884,7 +893,8 @@ def dispatch_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``bench_results/hw_r3/bench_transformer_flash_tpu.json``). Calls the kernels
     cannot run (cross-attention, a non-causal S that is not a multiple of 128)
     take the dense path."""
-    plan = dispatch_plan(q.shape, causal=causal, window=window, k_len=k.shape[1])
+    plan = dispatch_plan(q.shape, causal=causal, window=window, k_len=k.shape[1],
+                         value_dim=v.shape[-1])
     if plan["impl"] == "dense":
         return full_attention(q, k, v, causal=causal, window=window)
     return flash_attention(q, k, v, causal=causal, window=window)

@@ -71,16 +71,17 @@ EPOCH_SPANS = ("data", "execute", "eval", "log", "emit", "guard", "checkpoint", 
 
 def _attention_plan(config: LMConfig, seq_len: int, world: int, *,
                     dispatched: bool, heads: int | None = None,
-                    head_dim: int | None = None) -> dict:
+                    head_dim: int | None = None, value_dim: int | None = None) -> dict:
     """The ``compile`` event's ``attention`` field: what the step's attention call
     gets, by the dispatcher's own predicate on the per-device microbatch; where the
     model keeps the dense core (``dispatched`` false) the same keys say so. ``heads``
-    and ``head_dim`` are the flags' unless the model came from a file."""
+    and ``head_dim`` are the flags' unless the model came from a file, which may also
+    give values another width (``value_dim``)."""
     heads = heads or config.num_heads
     plan = ops.dispatch_plan(
         (config.batch_size // world // config.grad_accum, seq_len, heads,
          head_dim or config.embed_dim // heads),
-        causal=True, window=config.attention_window)
+        causal=True, window=config.attention_window, value_dim=value_dim)
     if not dispatched:
         plan.update(impl="dense", seq_padded=None, block=None)
     return plan
@@ -429,12 +430,13 @@ def main(config: LMConfig = LMConfig(), *,
             attention = None if seq_size > 1 else _attention_plan(
                 config, seq_len, world, dispatched=mesh.size == 1,
                 heads=model.num_attention_heads if hybrid else None,
-                head_dim=model.head_dim if hybrid else None)
+                head_dim=model.head_dim if hybrid else None,
+                value_dim=model.value_head_dim if hybrid else None)
             step_tokens = config.batch_size // world // config.grad_accum * seq_len
             plans = dict(experts=model.expert_plan(step_tokens),
                          recompute=model.recompute_plan(aot["jaxpr"]),
                          head_products=model.head_products(aot["jaxpr"], step_tokens),
-                         ssm=model.ssm_plan()) if hybrid else {}
+                         ssm=model.ssm_plan(), kda=model.kda_plan()) if hybrid else {}
             tele.emit(T.compile_event("epoch", aot,
                                       steps_per_call=steps_per_epoch,
                                       attention=attention, **plans))

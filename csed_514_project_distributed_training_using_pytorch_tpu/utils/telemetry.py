@@ -314,7 +314,7 @@ def aot_compile(jit_fn, *args) -> tuple[object | None, dict | None]:
 def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
                   attention: dict | None = None, experts: dict | None = None,
                   recompute: dict | None = None, ssm: dict | None = None,
-                  head_products: int | None = None) -> dict:
+                  head_products: int | None = None, kda: dict | None = None) -> dict:
     """The ``compile`` event for one AOT-timed program. ``attention``: which core the
     program's attention calls get and why (``ops.dispatch_plan``'s dict: ``impl``,
     ``score_bytes``, ``seq_padded``, ``block``), for the trainers that
@@ -325,7 +325,10 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
     ``kept`` names and ``kept_bytes``); None when nothing is recomputed. ``ssm``: what a
     step asks of each state-space layer (``ops.ssm.scan_plan``: heads and groups held,
     head and state widths, the chunk, chunks and state bytes a sequence, what
-    recomputation keeps of the scan); None for a model with none. ``head_products``:
+    recomputation keeps of the scan); None for a model with none. ``kda``: the same of
+    each delta-rule layer (``ops.kda.scan_plan``: heads, key and value widths, chunk and
+    sub-block, chunks, kept states and their bytes a sequence, what recomputation
+    keeps); None for a model with none. ``head_products``:
     the matrix products of a step that touch the head's ``[T, vocab]`` logits
     (``HybridLM.head_products``: 3 when they are computed once); None for a model
     whose head is not counted."""
@@ -347,6 +350,7 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
         "experts": experts,
         "recompute": recompute,
         "ssm": ssm,
+        "kda": kda,
         "head_products": head_products,
     }
 

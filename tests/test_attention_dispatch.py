@@ -23,8 +23,11 @@ from csed_514_project_distributed_training_using_pytorch_tpu.ops.attention impor
 
 
 def _qkv(s, *, b=2, h=2, d=32, dtype=jnp.float32, seed=0):
+    """``d`` is the head width, or ``(key width, value width)`` where the two differ."""
     rng = np.random.default_rng(seed)
-    return tuple(jnp.asarray(rng.normal(size=(b, s, h, d)), dtype) for _ in range(3))
+    dk, dv = d if isinstance(d, tuple) else (d, d)
+    return tuple(jnp.asarray(rng.normal(size=(b, s, h, width)), dtype)
+                 for width in (dk, dk, dv))
 
 
 def _grads(attn, q, k, v, **kw):
@@ -42,24 +45,26 @@ _TOL = {jnp.float32: (dict(rtol=1e-5, atol=1e-5), dict(rtol=1e-4, atol=2e-5)),
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("s,kw", [(200, {}), (300, {}), (200, {"window": 48}),
-                                  (200, {"block": 256})],
-                         ids=["s200", "s300", "s200-window48", "s200-block256"])
+                                  (200, {"block": 256}), (200, {"d": (192, 128)})],
+                         ids=["s200", "s300", "s200-window48", "s200-block256",
+                              "s200-keys192-values128"])
 def test_padded_causal_flash_matches_dense(s, kw, dtype):
     """A causal call at an S the kernels cannot tile is padded at the tail, run
     through them and sliced: output and q/k/v gradients are the dense core's."""
-    q, k, v = _qkv(s, dtype=dtype, seed=s)
+    kw = dict(kw)
+    q, k, v = _qkv(s, dtype=dtype, seed=s, d=kw.pop("d", 32))
     dense_kw = {"window": kw["window"]} if "window" in kw else {}
     q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
     out_tol, grad_tol = _TOL[dtype]
     out = pa.flash_attention(q, k, v, causal=True, **kw)
-    assert out.shape == q.shape and out.dtype == q.dtype
+    assert out.shape == v.shape and out.dtype == q.dtype
     np.testing.assert_allclose(
         np.asarray(out.astype(jnp.float32)),
         np.asarray(full_attention(q32, k32, v32, causal=True, **dense_kw)), **out_tol)
     want = _grads(full_attention, q32, k32, v32, **dense_kw)
     got = _grads(pa.flash_attention, q, k, v, **kw)
     for name, g, r in zip("qkv", got, want):
-        assert g.shape == q.shape
+        assert g.shape == r.shape
         np.testing.assert_allclose(np.asarray(g.astype(jnp.float32)), np.asarray(r),
                                    err_msg=f"d{name}", **grad_tol)
 
@@ -99,16 +104,20 @@ _RECORDED = [
     ("B8-H8-S512-flash-67MB", (8, 512, 8, 128), True, "flash"),
     ("classifier-B16-S2048-flash", (16, 2048, 8, 128), False, "flash"),
     ("tier1-tiny-dense", (8, 784, 2, 16), True, "dense"),
+    ("kimi-cell-B2-H32-S8192-keys192-values128-flash", (2, 8192, 32, (192, 128)), True, "flash"),
 ]
 
 
 @pytest.mark.parametrize("shape,causal,impl", [c[1:] for c in _RECORDED],
                          ids=[c[0] for c in _RECORDED])
 def test_dispatch_predicate_on_recorded_shapes(shape, causal, impl):
-    plan = pa.dispatch_plan(shape, causal=causal)
-    b, s, h, _ = shape
+    b, s, h, d = shape
+    dk, dv = d if isinstance(d, tuple) else (d, d)      # (key, value) widths, or one
+    plan = pa.dispatch_plan((b, s, h, dk), causal=causal,
+                            **({"value_dim": dv} if dv != dk else {}))
     assert plan["impl"] == impl
     assert plan["score_bytes"] == 4 * b * h * s * s
+    assert (plan["key_dim"], plan["value_dim"]) == (dk, dv)
     if impl == "dense":
         assert plan["seq_padded"] is plan["block"] is None
     else:
@@ -116,14 +125,16 @@ def test_dispatch_predicate_on_recorded_shapes(shape, causal, impl):
         assert plan["seq_padded"] % plan["block"] == 0
 
 
-def test_dispatch_plan_is_what_the_dispatcher_runs(monkeypatch):
+@pytest.mark.parametrize("widths", [32, (192, 128)], ids=["d32", "keys192-values128"])
+def test_dispatch_plan_is_what_the_dispatcher_runs(monkeypatch, widths):
     """The plan's block and padded length are the ones ``flash_attention`` is
     called into, and cross-attention (S_q != S_k) stays dense."""
     monkeypatch.setattr(pa, "FLASH_MIN_SCORE_BYTES", 1)
     monkeypatch.setattr(pa, "FLASH_MIN_HEAD_SCORE_BYTES", 1)
-    q, k, v = _qkv(200, seed=5)
-    plan = pa.dispatch_plan(q.shape, causal=True)
+    q, k, v = _qkv(200, seed=5, d=widths)
+    plan = pa.dispatch_plan(q.shape, causal=True, value_dim=v.shape[-1])
     assert (plan["impl"], plan["seq_padded"]) == ("flash", 256)
+    assert (plan["key_dim"], plan["value_dim"]) == (q.shape[-1], v.shape[-1])
     np.testing.assert_array_equal(
         np.asarray(pa.dispatch_attention(q, k, v, causal=True)),
         np.asarray(pa.flash_attention(q, k, v, causal=True, block=plan["block"])))
@@ -226,7 +237,8 @@ def test_compile_event_reports_dense_for_a_tier1_sized_run(tmp_path, mesh):
     (event,) = _compile_events(tmp_path, mesh=mesh)
     assert event["attention"] == {"impl": "dense", "score_bytes": 4 * 8 * 2 * 784 * 784
                                   // (1 if mesh else jax.device_count()),
-                                  "seq_padded": None, "block": None}
+                                  "seq_padded": None, "block": None,
+                                  "key_dim": 8, "value_dim": 8}
 
 
 def test_compile_event_reports_flash_for_the_cells_shapes():
@@ -244,6 +256,10 @@ def test_compile_event_reports_flash_for_the_cells_shapes():
     config = LMConfig(batch_size=16, embed_dim=1024, num_heads=8, kv_heads=2)
     plan = _attention_plan(config, 784, 1, dispatched=True)
     assert plan == pa.dispatch_plan((16, 784, 8, 128), causal=True)
+    latent = _attention_plan(LMConfig(batch_size=2), 8192, 1, dispatched=True, heads=32,
+                             head_dim=192, value_dim=128)
+    assert (latent["impl"], latent["key_dim"], latent["value_dim"], latent["seq_padded"]) == (
+        "flash", 192, 128, 8192)
     assert (plan["impl"], plan["score_bytes"], plan["seq_padded"]) == (
         "flash", 314703872, 896)
     event = T.compile_event("epoch", {"lower_s": 1.0, "compile_s": 2.0},

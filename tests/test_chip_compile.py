@@ -205,3 +205,48 @@ def test_latent_expert_layer_compiles_for_the_v5e_within_the_true_bound(one_chip
     assert plan["rows_buffer"] == HELD * TOKENS + HELD * moe.ROW_TILE
     assert f"[{plan['rows_buffer']},{LATENT}]" in text
     assert not re.search(rf"\[{K_22 * TOKENS}(,\d+)*,{LATENT}\]", text)
+
+
+# Kimi-Linear-48B-A3B, chip 0 of 32: 32 KDA heads of 128 x 128; 32 attention heads whose keys
+# are 192 wide (128 from the latent, 64 shared) and whose values are 128; 2 x 8192 tokens
+KDA = dict(batch=2, seq=8192, heads=32, head_dim=128)
+
+
+def test_delta_rule_kernels_compile_for_the_v5e_at_published_widths(one_chip):
+    """``kda_fwd`` and ``kda_bwd`` at the cell's shapes (chunks of 64 in sub-blocks of 16,
+    four chunks a grid step, a 128 x 128 state): Mosaic takes the sublane rolls of the
+    exact diagonals, the triangular inverse's products and the transpose of the whole
+    group that ``jax.vjp`` traces into the backward kernel."""
+    from csed_514_project_distributed_training_using_pytorch_tpu.ops import kda
+    b, s, h, d = (KDA[k] for k in ("batch", "seq", "heads", "head_dim"))
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    x, g, beta = (spec((b, s, h, d), jnp.bfloat16), spec((b, s, h, d), jnp.float32),
+                  spec((b, s, h), jnp.float32))
+    loss = lambda *args: jnp.sum(kda.kda_scan(*args).astype(jnp.float32))
+    with lowering_for_the_chip(kda):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            x, x, x, g, beta).compile().as_text()
+    assert "%kda_fwd" in text and "%kda_bwd" in text
+    kept = f"f32[{b},{s // (kda.GROUP * kda.CHUNK)},{h},{d},{d}]"      # a state a group
+    assert kept in text and f"f32[{b},{s // kda.CHUNK},{h},{d},{d}]" not in text
+
+
+def test_flash_kernels_compile_for_the_v5e_at_latent_attentions_widths(one_chip):
+    """``flash_fwd``, ``flash_dq`` and ``flash_dkv`` with keys of 192 channels and values
+    of 128 (a lane register and a half against one): the output and ``dv`` are 128 wide,
+    ``dq`` and ``dk`` 192, and nothing is padded to a common width."""
+    from csed_514_project_distributed_training_using_pytorch_tpu.ops import pallas_attention
+    b, s, h = KDA["batch"], KDA["seq"], KDA["heads"]
+    spec = lambda width: jax.ShapeDtypeStruct((b, s, h, width), jnp.bfloat16,
+                                              sharding=one_chip)
+    loss = lambda q, k, v: jnp.sum(pallas_attention.flash_attention(
+        q, k, v, causal=True).astype(jnp.float32))
+    with lowering_for_the_chip(pallas_attention):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            spec(192), spec(192), spec(128)).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert f"%{name}" in text, name
+    widths = [x.shape[-1] for x in jax.tree.leaves(compiled.out_info)]
+    assert widths == [192, 192, 128]       # dq, dk, dv
+    assert f"bf16[{b * h},{s},256]" not in text

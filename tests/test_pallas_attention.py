@@ -21,9 +21,15 @@ from csed_514_project_distributed_training_using_pytorch_tpu.ops.pallas_attentio
 
 
 def _qkv(b=2, s=256, h=2, d=64, seed=0):
+    """``d`` is the head width, or ``(key width, value width)`` where the two differ."""
     rng = np.random.default_rng(seed)
-    return tuple(jnp.asarray(rng.normal(size=(b, s, h, d)).astype(np.float32))
-                 for _ in range(3))
+    dk, dv = d if isinstance(d, tuple) else (d, d)
+    return tuple(jnp.asarray(rng.normal(size=(b, s, h, width)).astype(np.float32))
+                 for width in (dk, dk, dv))
+
+
+# one width for queries, keys and values, and latent attention's (key, value) widths
+WIDTHS = [64, (192, 128)]
 
 
 def _tol(tight_rtol, tight_atol):
@@ -35,8 +41,9 @@ def _tol(tight_rtol, tight_atol):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_forward_matches_dense(causal):
-    q, k, v = _qkv()
+@pytest.mark.parametrize("head_dim", WIDTHS)
+def test_forward_matches_dense(causal, head_dim):
+    q, k, v = _qkv(d=head_dim)
     np.testing.assert_allclose(
         np.asarray(flash_attention(q, k, v, causal=causal)),
         np.asarray(full_attention(q, k, v, causal=causal)),
@@ -45,12 +52,12 @@ def test_forward_matches_dense(causal):
 
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("window", [None, 160])
-@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("head_dim", [64, 128, (192, 128)])
 def test_gradients_match_dense(causal, window, head_dim):
-    """Forward and all three gradients against the dense oracle at both cells' head
+    """Forward and all three gradients against the dense oracle at the cells' head
     widths (64 is ``lfm2_moe_train_8k``'s, 128 ``lm_train_b16``'s: a whole lane
-    register), with and without a band that straddles the two 128-row blocks, over
-    an odd number of heads."""
+    register, keys of 192 and values of 128 ``kimi_linear_train_8k``'s), with and without
+    a band that straddles the two 128-row blocks, over an odd number of heads."""
     q, k, v = _qkv(b=2, s=256, h=3, d=head_dim, seed=13)
     kw = dict(causal=causal, window=window)
     np.testing.assert_allclose(
@@ -176,12 +183,13 @@ def test_indivisible_sequence_rejected():
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_bf16_forward_and_gradients_match_f32_dense(causal):
+@pytest.mark.parametrize("head_dim", WIDTHS)
+def test_bf16_forward_and_gradients_match_f32_dense(causal, head_dim):
     """The r4 kernels keep matmul operands in the INPUT dtype (bf16 on the MXU's
     native path) with f32 accumulation — so the bf16 path must be pinned against
     the f32 dense oracle at bf16-resolution tolerance, not just exercised as the
     identity-astype f32 case the other tests cover."""
-    q, k, v = _qkv(seed=11)
+    q, k, v = _qkv(d=head_dim, seed=11)
     qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
     ref = full_attention(qb.astype(jnp.float32), kb.astype(jnp.float32),
                          vb.astype(jnp.float32), causal=causal)
