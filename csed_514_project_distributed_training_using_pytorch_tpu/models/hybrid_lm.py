@@ -31,11 +31,12 @@ parameters, the normalized input and the positions:
     attention      q, k RMS-normed per head (or not) before RoPE (half-split pairing;
                    or no positions at all), causal softmax(q·k/√D)·v in groups,
                    through the pluggable ``attention_fn``
-    kda_mixer      q̃, k̃, ṽ = silu(conv(W u)) each;  q = q̃/‖q̃‖·K^-½, k = k̃/‖k̃‖ per head;
-                   g = −exp(A_log) · softplus(W_f↑ W_f↓ u + dt_bias) a channel, β =
-                   sigmoid(W_β u) a head, float32;  S_t = (I − β_t k_t k_tᵀ) Diag(e^{g_t})
-                   S_{t−1} + β_t k_t v_tᵀ, o_t = S_tᵀ q_t (``ops/kda.py``);
-                   W_o (RMSNorm_head(o) ⊙ sigmoid(W_g↑ W_g↓ u))
+    kda_mixer      q̃, k̃, ṽ = silu(conv(W u)) each;  g = −exp(A_log) · softplus(W_f↑ W_f↓ u +
+                   dt_bias) a channel, β = sigmoid(W_β u) a head, float32; all flat,
+                   ``[B, S, H·128]``. In ``ops/kda.py``'s kernels, on a head's block:
+                   q = q̃/‖q̃‖·K^-½, k = k̃/‖k̃‖;  S_t = (I − β_t k_t k_tᵀ) Diag(e^{g_t})
+                   S_{t−1} + β_t k_t v_tᵀ, o_t = S_tᵀ q_t;  ô = o / rms_head(o). Then
+                   W_o (w ⊙ ô ⊙ sigmoid(W_g↑ W_g↓ u)), w the head norm's scale tiled
     mla_mixer      q = W_q u, a head [nope | pe];  [c | k_pe] = W_kva u;  [k_nope | v] =
                    W_kvb RMSNorm(c) a head;  a head's key is [k_nope | k_pe], k_pe the
                    same for every head; no positions; causal softmax(q·k/√(nope + pe))·v
@@ -621,31 +622,26 @@ def attention_mixer(p, u, positions, model: HybridLM):
 
 
 def kda_mixer(p, u, model: HybridLM):
+    """Flat from the projections to the output projection: a head's channels are 128
+    lanes of ``[B, S, H·128]``, and what is one number a token and head (the unit norms
+    of q and k, β, the output norm's statistic) is ``ops/kda.py``'s, inside its kernels."""
     with jax.named_scope("kda_mixer"):
-        b, s, _ = u.shape
         heads, hd = model.kda_heads, model.kda_head_dim
         f32 = jnp.float32
 
-        def branch(name):       # projection, short convolution, silu; by head
-            x = causal_depthwise_conv(_dense(u, p[f"{name}_kernel"]),
-                                      p[f"{name}_conv_kernel"])
-            return jax.nn.silu(x).reshape(b, s, heads, hd)
-
-        def unit(x, scale=1.0):     # a head's channels to length ``scale``
-            x = x.astype(f32)
-            norm = jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
-            return (x * (norm * scale)).astype(u.dtype)
+        def branch(name):       # projection, short convolution, silu
+            return jax.nn.silu(causal_depthwise_conv(_dense(u, p[f"{name}_kernel"]),
+                                                     p[f"{name}_conv_kernel"]))
 
         low_rank = lambda name: _dense(_dense(u, p[f"{name}_a_kernel"]),
                                        p[f"{name}_b_kernel"]).astype(f32)
         rate = jax.nn.softplus(low_rank("f") + p["dt_bias"].astype(f32))
-        decay = -jnp.exp(p["A_log"].astype(f32))[:, None] * rate.reshape(b, s, heads, hd)
+        decay = rate * jnp.repeat(-jnp.exp(p["A_log"].astype(f32)), hd)
         beta = jax.nn.sigmoid(_dense(u, p["b_kernel"]).astype(f32))
-        o = kda.kda_scan(unit(branch("q"), hd ** -0.5), unit(branch("k")), branch("v"),
-                         decay, beta, **model._kda_tiles)
-        gate = jax.nn.sigmoid(low_rank("g")).reshape(b, s, heads, hd)
-        normed = ops.rms_norm(o.astype(f32), p["o_norm_scale"], eps=model.norm_eps)
-        return _dense((normed * gate).reshape(b, s, heads * hd).astype(u.dtype),
+        normed = kda.kda_scan(branch("q"), branch("k"), branch("v"), decay, beta,
+                              eps=model.norm_eps, **model._kda_tiles)
+        scaled = normed.astype(f32) * jnp.tile(p["o_norm_scale"].astype(f32), heads)
+        return _dense((scaled * jax.nn.sigmoid(low_rank("g"))).astype(u.dtype),
                       p["out_kernel"])
 
 

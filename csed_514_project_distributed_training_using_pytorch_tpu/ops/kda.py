@@ -6,10 +6,19 @@ Per head (keys of ``K`` channels, values of ``V``, a state of ``K x V``) and tok
     S_t = (I − β_t k_t k_tᵀ) Diag(exp(g_t)) S_{t−1} + β_t k_t v_tᵀ        S_0 = 0
     o_t = S_tᵀ q_t
 
-``g_t <= 0`` is a vector of ``K`` log-decays, ``β_t`` a scalar. The convolutions, the
-norms of ``q`` and ``k``, softplus, the sigmoid and the output gate are the caller's
-(``models/hybrid_lm.py``). ``β`` enters the kernels folded into two operands, ``β k`` and
-``β v``, so that its gradient is autodiff's, outside.
+``g_t <= 0`` is a vector of ``K`` log-decays, ``β_t`` a scalar. The convolutions,
+softplus, the sigmoid, the output norm's learned scale and the output gate are the
+caller's (``models/hybrid_lm.py``), all on the flat ``[B, S, H·width]`` layout the
+projections write. What is one number a token and head is computed here, inside the
+kernels, where a head's channels are the 128 lanes of the block a program holds and
+such a number is a column that broadcasts along them: the unit norms (``q = q̃ ·
+rsqrt(Σ_c q̃² + 1e-6) · K^-½``, ``k = k̃ · rsqrt(Σ_c k̃² + 1e-6)``, float32, from the operands
+as silu wrote them), ``β`` (a program reads its head's column of the ``[rows, H]`` block
+and forms ``βk``, ``βv``) and the output norm's statistic (``o · rsqrt(mean_c o² + eps)``
+is what the kernel writes). Outside, each would be a ``[B, S, H, 1]`` factor broadcast
+to 128 lanes and rewritten flat, with the heads moved between sublanes and lanes
+(PERF.md §6, PR 34). Their gradients are autodiff's, inside ``kda_bwd``; ``dβ`` leaves as
+a row a program.
 
 The scan walks a sequence in chunks of ``C`` tokens (``CHUNK``). With ``G`` the running
 sum of ``g`` from the chunk's start (inclusive) and ``P(a, b)_ij = Σ_c a_ic b_jc
@@ -44,7 +53,8 @@ into the kernel (so the two cannot drift apart). Decays, masks, the running sums
 float32; every other product runs on the MXU in the model's dtype.
 
 A sequence whose length is not a multiple of ``GROUP·C`` is padded at its end with
-tokens that decay nothing and write nothing (``g = 0``, ``β = 0``), and the result sliced.
+tokens that decay nothing and write nothing (``g = 0``, ``β = 0``, zero ``q̃``, ``k̃`` and ``v``,
+which the ``1e-6`` under the norms' roots leaves zeros), and the result sliced.
 """
 
 from __future__ import annotations
@@ -161,12 +171,11 @@ def _inverse_bwd(sub, dtype, inverse, d):
 _unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
 
 
-def _chunk(q, k, kb, vb, g, state, sub: int):
+def _chunk(q, k, kb, vb, g, state, sub: int, dtype):
     """One chunk of one head: ``(o [C, V] float32, the state after it)``. ``state`` is
-    ``Sᵀ [V, K]`` float32; ``q``, ``k``, ``kb`` ``[C, K]`` and ``vb [C, V]`` in the model's
-    dtype; ``g [C, K]`` float32."""
-    dtype, c = q.dtype, q.shape[0]
-    q, k, kb = (x.astype(jnp.float32) for x in (q, k, kb))
+    ``Sᵀ [V, K]``; ``q``, ``k``, ``kb = βk`` ``[C, K]``, ``vb = βv [C, V]`` and ``g [C, K]``
+    float32; the products run in ``dtype``."""
+    c = q.shape[0]
     ones = (_iota((c, c), 0) >= _iota((c, c), 1)).astype(jnp.float32)
     cum = jax.lax.dot_general(ones, g, (NN, ((), ())),
                               precision=jax.lax.Precision.HIGHEST,
@@ -182,48 +191,85 @@ def _chunk(q, k, kb, vb, g, state, sub: int):
     return o, state
 
 
-def _group(q, k, kb, vb, g, state, chunk: int, sub: int):
-    """The chunks of one grid step, one after the other: ``(o, the state after)``."""
+def _unit(x, scale: float = 1.0):
+    """A row's channels to length ``scale`` (``1e-6`` under the root: a row of zeros
+    stays zeros)."""
+    return x * (jax.lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + 1e-6) * scale)
+
+
+def _group(q, k, v, g, beta, state, chunk: int, sub: int, eps: float):
+    """The chunks of one grid step, one after the other: ``(o, the state after)``, with
+    everything that is one number a token of this head: ``q``, ``k`` ``[R, K]`` and ``v
+    [R, V]`` as the projections wrote them (the model's dtype) are brought to unit
+    length and to ``βv`` here, ``beta [R, 1]`` float32 a column that broadcasts along
+    the lanes, and a row of ``o`` leaves divided by its root mean square."""
+    dtype = q.dtype
+    q = _unit(q.astype(jnp.float32), q.shape[1] ** -0.5)
+    k = _unit(k.astype(jnp.float32))
+    kb, vb = beta * k, beta * v.astype(jnp.float32)
     out = []
     for at in range(0, q.shape[0], chunk):
-        o, state = _chunk(*(x[at:at + chunk] for x in (q, k, kb, vb, g)), state, sub)
+        o, state = _chunk(*(x[at:at + chunk] for x in (q, k, kb, vb, g)), state, sub, dtype)
         out.append(o)
-    return jnp.concatenate(out).astype(vb.dtype), state
+    o = jnp.concatenate(out)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=1, keepdims=True) + eps)
+    return o.astype(v.dtype), state
 
 
-def _fwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, o_ref, entered_ref, state, *,
-                chunk, sub):
+def _head_column(block):
+    """This program's head of a ``[R, H]`` block of per-head scalars, ``[R, 1]``."""
+    head = _iota(block.shape, 1) == pl.program_id(1)
+    return jnp.sum(jnp.where(head, block, 0.0), axis=1, keepdims=True)
+
+
+def _as_row(column, width: int):
+    """``[R, 1]`` to ``[1, R]`` without a sublane-to-lane transpose: an ``NT`` product with
+    rows (a sublane tile of them) that read the first lane, exact at ``highest``."""
+    first = (_iota((8, width), 1) == 0).astype(jnp.float32)
+    return jax.lax.dot_general(
+        first, jnp.broadcast_to(column, (column.shape[0], width)), (NT, ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)[:1]
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, entered_ref, state, **static):
     @pl.when(pl.program_id(2) == 0)
     def _():
         state[...] = jnp.zeros_like(state)
 
     entered_ref[...] = state[...]
-    o_ref[...], state[...] = _group(q_ref[...], k_ref[...], kb_ref[...], vb_ref[...],
-                                    g_ref[...], state[...], chunk, sub)
+    o_ref[...], state[...] = _group(q_ref[...], k_ref[...], v_ref[...], g_ref[...],
+                                    _head_column(beta_ref[...]), state[...], **static)
 
 
-def _bwd_kernel(q_ref, k_ref, kb_ref, vb_ref, g_ref, entered_ref, do_ref,
-                dq_ref, dk_ref, dkb_ref, dvb_ref, dg_ref, dstate, *, chunk, sub):
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, entered_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate, **static):
     """The same group's transpose, the groups taken last to first; ``dstate``: the
     gradient of the state this group hands on."""
     @pl.when(pl.program_id(2) == 0)
     def _():
         dstate[...] = jnp.zeros_like(dstate)
 
-    _, pull = jax.vjp(functools.partial(_group, chunk=chunk, sub=sub),
-                      q_ref[...], k_ref[...], kb_ref[...], vb_ref[...], g_ref[...],
-                      entered_ref[...])
-    (dq_ref[...], dk_ref[...], dkb_ref[...], dvb_ref[...], dg_ref[...],
+    _, pull = jax.vjp(functools.partial(_group, **static),
+                      q_ref[...], k_ref[...], v_ref[...], g_ref[...],
+                      _head_column(beta_ref[...]), entered_ref[...])
+    (dq_ref[...], dk_ref[...], dv_ref[...], dg_ref[...], dbeta,
      dstate[...]) = pull((do_ref[...], dstate[...]))
+    dbeta_ref[...] = _as_row(dbeta, q_ref.shape[1])
 
 
-def _specs(rows: int, k: int, v: int, at):
+def _specs(rows: int, heads: int, k: int, v: int, at):
     """Block specs by operand; ``at(s)`` is the group that step ``s`` of the sequential
     grid axis works on (the backward pass walks them in reverse). A head's channels
-    are a block of lanes of the ``[B, S, H·width]`` arrays: no operand is transposed."""
+    are a block of lanes of the ``[B, S, H·width]`` arrays: no operand is transposed.
+    ``beta`` comes as the ``[R, H]`` block of every head, of which a program reads its
+    column; its gradient leaves as a row of ``[B, H, S/R, 1, R]``, so that the programs
+    of one block of tokens write blocks of their own."""
     tokens = lambda width: pl.BlockSpec((None, rows, width),
                                         lambda b, h, s: (b, at(s), h))
     return {"k": tokens(k), "v": tokens(v),
+            "beta": pl.BlockSpec((None, rows, heads), lambda b, h, s: (b, at(s), 0)),
+            "dbeta": pl.BlockSpec((None, None, None, 1, rows),
+                                  lambda b, h, s: (b, h, at(s), 0, 0)),
             "state": pl.BlockSpec((None, None, None, v, k),
                                   lambda b, h, s: (b, at(s), h, 0, 0))}
 
@@ -233,57 +279,59 @@ def _params():
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _scan_fwd(q, k, kb, vb, g, heads: int, chunk: int, sub: int, group: int):
-    bsz, s, _ = q.shape
-    dk, dv, rows = q.shape[2] // heads, vb.shape[2] // heads, group * chunk
-    sp = _specs(rows, dk, dv, lambda step: step)
+def _scan_fwd(q, k, v, g, beta, chunk: int, sub: int, group: int, eps: float):
+    bsz, s, heads = beta.shape
+    dk, dv, rows = q.shape[2] // heads, v.shape[2] // heads, group * chunk
+    sp = _specs(rows, heads, dk, dv, lambda step: step)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, chunk=chunk, sub=sub), name="kda_fwd",
+        functools.partial(_fwd_kernel, chunk=chunk, sub=sub, eps=eps), name="kda_fwd",
         interpret=_interpret(), grid=(bsz, heads, s // rows),
-        in_specs=[sp["k"], sp["k"], sp["k"], sp["v"], sp["k"]],
+        in_specs=[sp["k"], sp["k"], sp["v"], sp["k"], sp["beta"]],
         out_specs=[sp["v"], sp["state"]],
-        out_shape=[jax.ShapeDtypeStruct(vb.shape, vb.dtype),
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct((bsz, s // rows, heads, dv, dk), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
         compiler_params=_params(),
-    )(q, k, kb, vb, g)
+    )(q, k, v, g, beta)
 
 
-def _scan_bwd(q, k, kb, vb, g, entered, do, heads: int, chunk: int, sub: int, group: int):
-    bsz, s, _ = q.shape
-    dk, dv, rows = q.shape[2] // heads, vb.shape[2] // heads, group * chunk
+def _scan_bwd(q, k, v, g, beta, entered, do, chunk: int, sub: int, group: int, eps: float):
+    bsz, s, heads = beta.shape
+    dk, dv, rows = q.shape[2] // heads, v.shape[2] // heads, group * chunk
     groups = s // rows
-    sp = _specs(rows, dk, dv, lambda step: groups - 1 - step)
+    sp = _specs(rows, heads, dk, dv, lambda step: groups - 1 - step)
     like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
-    return pl.pallas_call(
-        functools.partial(_bwd_kernel, chunk=chunk, sub=sub), name="kda_bwd",
+    *wide, dbeta = pl.pallas_call(
+        functools.partial(_bwd_kernel, chunk=chunk, sub=sub, eps=eps), name="kda_bwd",
         interpret=_interpret(), grid=(bsz, heads, groups),
-        in_specs=[sp["k"], sp["k"], sp["k"], sp["v"], sp["k"], sp["state"], sp["v"]],
-        out_specs=[sp["k"], sp["k"], sp["k"], sp["v"], sp["k"]],
-        out_shape=[like(q), like(k), like(kb), like(vb), like(g)],
+        in_specs=[sp["k"], sp["k"], sp["v"], sp["k"], sp["beta"], sp["state"], sp["v"]],
+        out_specs=[sp["k"], sp["k"], sp["v"], sp["k"], sp["dbeta"]],
+        out_shape=[like(q), like(k), like(v), like(g),
+                   jax.ShapeDtypeStruct((bsz, heads, groups, 1, rows), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
         compiler_params=_params(),
-    )(q, k, kb, vb, g, entered, do.astype(vb.dtype))
+    )(q, k, v, g, beta, entered, do.astype(v.dtype))
+    return *wide, jnp.swapaxes(dbeta.reshape(bsz, heads, s), 1, 2)
 
 
 @functools.lru_cache(maxsize=None)
-def _make_op(heads: int, chunk: int, sub: int, group: int):
+def _make_op(chunk: int, sub: int, group: int, eps: float):
     # Jitted halves behind a cached factory, as ``ssm._make_op``: every KDA layer of a
     # model calls the same two functions, lowered once a program.
-    kw = dict(heads=heads, chunk=chunk, sub=sub, group=group)
+    kw = dict(chunk=chunk, sub=sub, group=group, eps=eps)
     forward = jax.jit(functools.partial(_scan_fwd, **kw))
     backward = jax.jit(functools.partial(_scan_bwd, **kw))
 
     @jax.custom_vjp
-    def op(q, k, kb, vb, g):
-        return forward(q, k, kb, vb, g)[0]
+    def op(q, k, v, g, beta):
+        return forward(q, k, v, g, beta)[0]
 
-    def fwd(q, k, kb, vb, g):
+    def fwd(q, k, v, g, beta):
         # Named as the VJP's residuals: a caller's ``jax.checkpoint`` whose policy
         # keeps these names does not run ``kda_fwd`` again in its backward pass.
-        o, entered = forward(q, k, kb, vb, g)
+        o, entered = forward(q, k, v, g, beta)
         o, entered = checkpoint_name(o, "kda_out"), checkpoint_name(entered, "kda_state")
-        return o, (q, k, kb, vb, g, entered)
+        return o, (q, k, v, g, beta, entered)
 
     def bwd(residuals, do):
         return backward(*residuals, do)
@@ -293,22 +341,22 @@ def _make_op(heads: int, chunk: int, sub: int, group: int):
 
 
 def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array, *,
-             chunk: int = CHUNK, sub: int = SUB, group: int = GROUP) -> jax.Array:
-    """``o [B, S, H, V]`` of the recurrence above. ``q``, ``k`` ``[B, S, H, K]`` and ``v
-    [B, S, H, V]`` in the model's dtype; ``g [B, S, H, K]`` (log-decays, ``<= 0``) and
-    ``beta [B, S, H]`` float32. Differentiable in all five. Any ``S``: the tail of a
-    sequence is padded to a whole group of chunks."""
-    bsz, s, heads, dk = q.shape
+             eps: float, chunk: int = CHUNK, sub: int = SUB, group: int = GROUP) -> jax.Array:
+    """``RMSNorm_head(o) [B, S, H·V]`` (no learned scale) of the recurrence above, on the
+    flat layout the projections write: ``q``, ``k`` ``[B, S, H·K]`` and ``v [B, S, H·V]`` in
+    the model's dtype, ``q`` and ``k`` before their unit norms; ``g [B, S, H·K]`` (log-decays,
+    ``<= 0``) and ``beta [B, S, H]`` float32, whose last axis says how many heads there
+    are. Differentiable in all five. Any ``S``: the tail of a sequence is padded to a
+    whole group of chunks."""
+    s = q.shape[1]
     if chunk % sub or (chunk // sub) & (chunk // sub - 1) or sub & (sub - 1):
         raise ValueError(f"sub-blocks of {sub} rows do not halve a chunk of {chunk}")
-    scaled = lambda x: (beta[..., None] * x.astype(jnp.float32)).astype(x.dtype)
-    operands = [q, k, scaled(k), scaled(v), g.astype(jnp.float32)]
     short = -s % (group * chunk)
-    flat = [jnp.pad(x, ((0, 0), (0, short), (0, 0), (0, 0))).reshape(bsz, s + short, -1)
-            for x in operands]
+    padded = [jnp.pad(x, ((0, 0), (0, short), (0, 0)))
+              for x in (q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32))]
     with jax.named_scope("kda"):
-        o = _make_op(heads, chunk, sub, group)(*flat)
-    return o[:, :s].reshape(bsz, s, heads, -1)
+        o = _make_op(chunk, sub, group, eps)(*padded)
+    return o[:, :s]
 
 
 def scan_plan(*, heads: int, key_dim: int, value_dim: int, seq_len: int,
@@ -322,4 +370,5 @@ def scan_plan(*, heads: int, key_dim: int, value_dim: int, seq_len: int,
             "sub_block": sub, "chunks_per_sequence": groups * group,
             "states_per_sequence": groups,
             "state_bytes_per_sequence": groups * heads * key_dim * value_dim * 4,
-            "kept": [name for name in ("kda_out", "kda_state") if name in kept]}
+            "kept": [name for name in ("kda_out", "kda_state") if name in kept],
+            "in_kernel": ["q_norm", "k_norm", "beta", "out_norm"]}

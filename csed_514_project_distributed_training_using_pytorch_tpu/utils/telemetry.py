@@ -328,7 +328,8 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
     recomputation keeps of the scan); None for a model with none. ``kda``: the same of
     each delta-rule layer (``ops.kda.scan_plan``: heads, key and value widths, chunk and
     sub-block, chunks, kept states and their bytes a sequence, what recomputation
-    keeps); None for a model with none. ``head_products``:
+    keeps, and ``in_kernel``: the per-token, per-head scalars computed inside the
+    kernels); None for a model with none. ``head_products``:
     the matrix products of a step that touch the head's ``[T, vocab]`` logits
     (``HybridLM.head_products``: 3 when they are computed once); None for a model
     whose head is not counted."""

@@ -213,22 +213,62 @@ KDA = dict(batch=2, seq=8192, heads=32, head_dim=128)
 
 
 def test_delta_rule_kernels_compile_for_the_v5e_at_published_widths(one_chip):
-    """``kda_fwd`` and ``kda_bwd`` at the cell's shapes (chunks of 64 in sub-blocks of 16,
-    four chunks a grid step, a 128 x 128 state): Mosaic takes the sublane rolls of the
-    exact diagonals, the triangular inverse's products and the transpose of the whole
-    group that ``jax.vjp`` traces into the backward kernel."""
+    """``kda_fwd`` and ``kda_bwd`` at the cell's shapes, on the flat layout (chunks of 64 in
+    sub-blocks of 16, four chunks a grid step, a 128 x 128 state): Mosaic takes the
+    sublane rolls of the exact diagonals, the triangular inverse's products, the lane
+    select of a head's β out of the ``[256, 32]`` block, the norms' lane reductions, the
+    row that ``dβ`` leaves as, and the transpose of the whole group that ``jax.vjp`` traces
+    into the backward kernel."""
     from csed_514_project_distributed_training_using_pytorch_tpu.ops import kda
     b, s, h, d = (KDA[k] for k in ("batch", "seq", "heads", "head_dim"))
     spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-    x, g, beta = (spec((b, s, h, d), jnp.bfloat16), spec((b, s, h, d), jnp.float32),
+    x, g, beta = (spec((b, s, h * d), jnp.bfloat16), spec((b, s, h * d), jnp.float32),
                   spec((b, s, h), jnp.float32))
-    loss = lambda *args: jnp.sum(kda.kda_scan(*args).astype(jnp.float32))
+    loss = lambda *args: jnp.sum(kda.kda_scan(*args, eps=1e-5).astype(jnp.float32))
     with lowering_for_the_chip(kda):
-        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-            x, x, x, g, beta).compile().as_text()
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            x, x, x, g, beta).compile()
+    text = compiled.as_text()
     assert "%kda_fwd" in text and "%kda_bwd" in text
-    kept = f"f32[{b},{s // (kda.GROUP * kda.CHUNK)},{h},{d},{d}]"      # a state a group
+    rows = kda.GROUP * kda.CHUNK
+    kept = f"f32[{b},{s // rows},{h},{d},{d}]"      # a state a group
     assert kept in text and f"f32[{b},{s // kda.CHUNK},{h},{d},{d}]" not in text
+    assert f"f32[{b},{h},{s // rows},1,{rows}]" in text         # dβ, a row a program
+    assert [x.shape for x in jax.tree.leaves(compiled.out_info)] == \
+        [(b, s, h * d)] * 4 + [(b, s, h)]
+
+
+def test_a_delta_rule_layer_never_leaves_the_flat_layout(one_chip):
+    """``kda_mixer``, value and every gradient, at the cell's shapes: a head's channels
+    stay 128 lanes of ``[2, 8192, 4096]`` from the projections to the output projection.
+    No array of the program, fused or not and in any dtype, has 32 heads on the sublanes
+    (trailing dimensions ``32, 128`` of B·S·4096 elements: ``[2, 8192, 32, 128]``, ``[2048, 8,
+    32, 128]``), and no per-head factor is broadcast and rewritten flat (a ``reshape`` of a
+    ``broadcast`` to ``[2, 8192, 4096]``). With the norms, β and the output's statistic
+    outside the kernels (PR 32) this count found 65 and 7."""
+    import math
+    import re
+    from csed_514_project_distributed_training_using_pytorch_tpu.models import hybrid_lm
+    from csed_514_project_distributed_training_using_pytorch_tpu.ops import kda
+    b, s, h, d = (KDA[k] for k in ("batch", "seq", "heads", "head_dim"))
+    config = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                          "benchmark", "configs", "kimi-linear-48b-a3b-ep32.json")
+    model = hybrid_lm.from_config_file(config, vocab_size=20480, seq_len=s,
+                                       dtype=jnp.bfloat16, remat=True)
+    assert (model.kda_heads, model.kda_head_dim) == (h, d)
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    p = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))["params"]["layer_0"]["kda"]
+    u = jax.ShapeDtypeStruct((b, s, model.hidden_size), jnp.bfloat16)
+    loss = lambda p, u: jnp.sum(hybrid_lm.kda_mixer(p, u, model).astype(jnp.float32))
+    with lowering_for_the_chip(kda):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*on_chip((p, u))).compile().as_text()
+    assert "%kda_fwd" in text and "%kda_bwd" in text
+    by_head = [m.group(0) for m in re.finditer(rf"= \w+\[([\d,]+),{h},{d}\]", text)
+               if math.prod(map(int, m.group(1).split(","))) == b * s]
+    assert not by_head, by_head
+    rewritten = re.findall(rf"= \w+\[{b},{s},{h * d}\]\S* reshape\(\S*broadcast\S*", text)
+    assert not rewritten, rewritten
 
 
 def test_flash_kernels_compile_for_the_v5e_at_latent_attentions_widths(one_chip):

@@ -5,6 +5,7 @@ against hand-written loops: small sizes, float32, seeded weights; Pallas in inte
 mode."""
 
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -70,12 +71,14 @@ def tokens(batch=2, seed=0):
 # (a) the scan ----------------------------------------------------------------------------
 
 
+EPS = 1e-5      # under the output norm's root
+
+
 def scan_inputs(b, s, h, k, v, decay, seed=0):
-    """Unit keys, queries of length ``K^-½``, ``g = −decay · softplus(N(0, 1))`` a channel."""
+    """By head: ``q̃``, ``k̃`` of no particular length, as a projection's silu leaves them;
+    ``g = −decay · softplus(N(0, 1))`` a channel; ``β`` a sigmoid."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
-    return (unit(jax.random.normal(ks[0], (b, s, h, k))) * k ** -0.5,
-            unit(jax.random.normal(ks[1], (b, s, h, k))),
+    return (jax.random.normal(ks[0], (b, s, h, k)), jax.random.normal(ks[1], (b, s, h, k)),
             jax.random.normal(ks[2], (b, s, h, v)),
             -decay * jax.nn.softplus(jax.random.normal(ks[3], (b, s, h, k))),
             jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h))))
@@ -94,6 +97,47 @@ def token_by_token(q, k, v, g, beta):
     zero = jnp.zeros(q.shape[:1] + q.shape[2:] + v.shape[-1:])
     _, o = jax.lax.scan(token, zero, tuple(jnp.moveaxis(x, 1, 0) for x in (q, k, v, g, beta)))
     return jnp.moveaxis(o, 0, 1)
+
+
+def normed_recurrence(q, k, v, g, beta):
+    """What ``kda_scan`` computes, in plain ``jnp`` by head: ``q̃`` to length ``K^-½`` and
+    ``k̃`` to length one (``1e-6`` under the root), the recurrence, and each head's output
+    over its root mean square."""
+    unit = lambda x: x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+    o = token_by_token(unit(q) * q.shape[-1] ** -0.5, unit(k), v, g, beta)
+    return o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + EPS)
+
+
+def flat_scan(q, k, v, g, beta, **tiles):
+    """``kda_scan`` on the flat layout it takes, from and to the tests' layout by head."""
+    flat = lambda x: x.reshape(x.shape[:2] + (-1,))
+    return kda.kda_scan(flat(q), flat(k), flat(v), flat(g), beta, eps=EPS,
+                        **tiles).reshape(v.shape)
+
+
+OPERANDS = ("q", "k", "v", "g", "beta")
+
+
+def scan_and_gradients(fn, args, w):
+    return fn(*args), jax.grad(lambda *a: jnp.sum(w * fn(*a).astype(jnp.float32)),
+                               argnums=(0, 1, 2, 3, 4))(*args)
+
+
+def assert_the_scan_is_the_recurrence(args, tiles, out=2e-5, grad=3e-5):
+    """Output and the gradient of every operand, each within its share of the largest
+    entry of the float32 recurrence's on the same values."""
+    w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    got, grads = scan_and_gradients(functools.partial(flat_scan, **tiles), args, w)
+    with jax.default_matmul_precision("highest"):
+        want, wants = scan_and_gradients(
+            normed_recurrence, tuple(x.astype(jnp.float32) for x in args), w)
+    np.testing.assert_allclose(got.astype(jnp.float32), want,
+                               atol=out * float(jnp.abs(want).max()))
+    for name, g, r in zip(OPERANDS, grads, wants):
+        assert np.isfinite(np.asarray(g, np.float32)).all(), name
+        np.testing.assert_allclose(g.astype(jnp.float32), r,
+                                   atol=grad * float(jnp.abs(r).max()), err_msg=name)
+    return grads
 
 
 def test_the_references_recurrence_is_the_definition():
@@ -118,30 +162,75 @@ SCAN_SIZES = {
 
 @pytest.mark.parametrize("size", SCAN_SIZES)
 def test_the_scan_kernels_match_the_recurrence(size):
-    """``kda_fwd`` and ``kda_bwd`` (chunks, sub-blocks, the triangular inverse, a carried
-    state, states kept a group) against the token-by-token recurrence: the output and
-    the gradient of every operand, at decays that leave float32 if ``exp(−G)`` were ever
-    formed and at decays near none."""
+    """``kda_fwd`` and ``kda_bwd`` (the two unit norms, β and the output's statistic on the
+    head's block; chunks, sub-blocks, the triangular inverse, a carried state, states kept
+    a group) against the token-by-token recurrence with the norms, β and the statistic
+    in plain ``jnp``: the output and the gradient of every operand, ``dβ`` among them, at
+    decays that leave float32 if ``exp(−G)`` were ever formed and at decays near none."""
     *shape, chunk, sub, group, decay = SCAN_SIZES[size]
     args = scan_inputs(*shape, decay)
     if decay == 8.0:
         assert float(jnp.min(jnp.sum(args[3][:, :chunk], axis=1))) < -100
-    scan = lambda *a: kda.kda_scan(*a, chunk=chunk, sub=sub, group=group)
     with jax.default_matmul_precision("highest"):
-        want = token_by_token(*args)
-        np.testing.assert_allclose(scan(*args), want, atol=2e-5 * float(jnp.abs(want).max()))
-        w = jax.random.normal(jax.random.PRNGKey(9), want.shape)
-        grads = jax.grad(lambda *a: jnp.sum(w * scan(*a)), argnums=(0, 1, 2, 3, 4))(*args)
-        wants = jax.grad(lambda *a: jnp.sum(w * token_by_token(*a)),
-                         argnums=(0, 1, 2, 3, 4))(*args)
-    for name, g, r in zip(("q", "k", "v", "g", "beta"), grads, wants):
-        assert np.isfinite(np.asarray(g)).all(), name
-        np.testing.assert_allclose(g, r, atol=3e-5 * float(jnp.abs(r).max()), err_msg=name)
+        assert_the_scan_is_the_recurrence(args, dict(chunk=chunk, sub=sub, group=group))
+
+
+@pytest.mark.parametrize("zeroed", ["q", "k", "q and k"])
+def test_a_head_of_zeros_keeps_the_norms_finite(zeroed):
+    """Some tokens of one head have ``q̃`` or ``k̃`` all zeros (silu of very negative
+    channels): the ``1e-6`` under the root keeps the unit norm and its gradient finite
+    inside the kernels, and both are the recurrence's."""
+    q, k, v, g, beta = scan_inputs(2, 32, 2, 8, 8, 1.0, seed=3)
+    gone = jnp.zeros((2, 32, 2, 1)).at[:, 3:9, 1].set(1.0).at[1, 20:, 0].set(1.0) == 1.0
+    args = (jnp.where(gone, 0.0, q) if "q" in zeroed else q,
+            jnp.where(gone, 0.0, k) if "k" in zeroed else k, v, g, beta)
+    with jax.default_matmul_precision("highest"):
+        assert_the_scan_is_the_recurrence(args, dict(chunk=8, sub=4, group=2))
+
+
+@pytest.mark.parametrize("tail", [3, 11, 16])
+def test_padding_tokens_write_nothing_and_hand_back_no_gradient(tail):
+    """The tokens ``kda_scan`` pads a sequence with (``g = 0``, ``β = 0``, zero ``q̃``, ``k̃``,
+    ``v``), given as a tail of the operands: their rows of the output are zero, the rows
+    before them and their gradients are the unpadded sequence's, and the tail's own
+    gradients are zero and not a NaN of ``0 · rsqrt(0)``."""
+    args = scan_inputs(1, 21, 2, 8, 8, 1.0, seed=4)
+    padded = tuple(jnp.pad(x, ((0, 0), (0, tail)) + ((0, 0),) * (x.ndim - 2)) for x in args)
+    scan = functools.partial(flat_scan, chunk=8, sub=4, group=2)
+    w = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
+    with jax.default_matmul_precision("highest"):
+        want, wants = scan_and_gradients(scan, args, w)
+        got, grads = scan_and_gradients(scan, padded, jnp.pad(w, ((0, 0), (0, tail), (0, 0),
+                                                                  (0, 0))))
+    assert float(jnp.abs(got[:, 21:]).max()) == 0.0
+    np.testing.assert_allclose(got[:, :21], want, atol=1e-6)
+    for name, g, r in zip(OPERANDS, grads, wants):
+        assert float(jnp.abs(g[:, 21:]).max()) == 0.0, name
+        np.testing.assert_allclose(g[:, :21], r, atol=1e-6 * float(jnp.abs(r).max()),
+                                   err_msg=name)
+
+
+BF16 = 0.03     # of the largest entry: what the bf16 path met with the norms and β outside
+
+
+@pytest.mark.parametrize("size", [s for s in SCAN_SIZES if "published" not in s])
+def test_bf16_operands_stay_near_the_float32_recurrence(size):
+    """``q̃``, ``k̃``, ``v`` in bfloat16 as the model hands them (``g``, ``β`` float32) against
+    the float32 recurrence on the same rounded values, output and gradients. With the
+    norms and β applied outside the kernels and rounded to bfloat16 on the way in (PR 32)
+    these inputs read up to 0.027 of the largest entry (output) and 0.030 (gradients);
+    inside, where nothing is rounded before the products' own casts, 0.012 and 0.014."""
+    *shape, chunk, sub, group, decay = SCAN_SIZES[size]
+    q, k, v, g, beta = scan_inputs(*shape, decay)
+    low = tuple(x.astype(jnp.bfloat16) for x in (q, k, v)) + (g, beta)
+    grads = assert_the_scan_is_the_recurrence(low, dict(chunk=chunk, sub=sub, group=group),
+                                              out=BF16, grad=BF16)
+    assert grads[0].dtype == grads[2].dtype == jnp.bfloat16 and grads[4].dtype == jnp.float32
 
 
 def test_the_scan_refuses_sub_blocks_that_do_not_halve_a_chunk():
     with pytest.raises(ValueError, match="sub-blocks"):
-        kda.kda_scan(*scan_inputs(1, 24, 1, 8, 8, 1.0), chunk=24, sub=8)
+        flat_scan(*scan_inputs(1, 24, 1, 8, 8, 1.0), chunk=24, sub=8)
 
 
 def test_the_scan_plan_counts_the_states_a_sequence_keeps():
@@ -150,7 +239,8 @@ def test_the_scan_plan_counts_the_states_a_sequence_keeps():
     assert plan == {"heads": 32, "key_dim": 128, "value_dim": 128, "chunk": 64,
                     "sub_block": 16, "chunks_per_sequence": 128, "states_per_sequence": 32,
                     "state_bytes_per_sequence": 32 * 32 * 128 * 128 * 4,
-                    "kept": ["kda_out", "kda_state"]}
+                    "kept": ["kda_out", "kda_state"],
+                    "in_kernel": ["q_norm", "k_norm", "beta", "out_norm"]}
 
 
 # (b) latent attention through the flash kernels ---------------------------------------------
@@ -271,12 +361,13 @@ def test_a_planted_fault_fails_the_comparison(fault, monkeypatch):
     elif fault == "beta dropped from the correction":
         # S_t = (I − k kᵀ) Diag(α) S + β k vᵀ: β = 1 with the values scaled in its place
         monkeypatch.setattr(hybrid_lm.kda, "kda_scan", lambda q, k, v, g, beta, **kw: whole(
-            q, k, beta[..., None] * v, g, jnp.ones_like(beta), **kw))
+            q, k, jnp.repeat(beta, v.shape[-1] // beta.shape[-1], axis=-1) * v, g,
+            jnp.ones_like(beta), **kw))
     elif fault == "decay applied after the correction":
         # S_t = Diag(α)(I − β k kᵀ) S + β k vᵀ: the key the correction reads is k / α's
         # side of the state, which a decay one token late gives
         monkeypatch.setattr(hybrid_lm.kda, "kda_scan", lambda q, k, v, g, beta, **kw: whole(
-            q, k, v, jnp.pad(g, ((0, 0), (1, 0), (0, 0), (0, 0)))[:, :-1], beta, **kw))
+            q, k, v, jnp.pad(g, ((0, 0), (1, 0), (0, 0)))[:, :-1], beta, **kw))
     elif fault == "shared expert dropped":
         model = dataclasses.replace(model, shared_expert_size=0)
     elif fault == "2 of a token's 3 experts":
@@ -450,7 +541,9 @@ def test_the_compile_event_says_what_the_new_layers_ask(trained):
                             "sub_block": 4, "chunks_per_sequence": 8,
                             "states_per_sequence": 4,
                             "state_bytes_per_sequence": 4 * 4 * 8 * 8 * 4,
-                            "kept": ["kda_out", "kda_state"]}
+                            "kept": ["kda_out", "kda_state"],
+                            # the per-head scalars the kernels compute on a head's block
+                            "in_kernel": ["q_norm", "k_norm", "beta", "out_norm"]}
     assert event["ssm"] is None
     assert (event["attention"]["key_dim"], event["attention"]["value_dim"]) == (12, 8)
     assert event["experts"]["row_bound"] == 3 * 8 * 64 and event["experts"]["held"] == [0, 4]
