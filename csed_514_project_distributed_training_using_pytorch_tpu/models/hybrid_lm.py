@@ -377,7 +377,8 @@ class HybridLM:
         ``(x, positions, [counts of each sparse layer run])``."""
         ids = ids.astype(jnp.int32)
         positions = jnp.arange(ids.shape[1])
-        x = params["embed_tokens"].astype(self.dtype)[ids]
+        with jax.named_scope("embed"):
+            x = params["embed_tokens"].astype(self.dtype)[ids]
         counts = []
         for i, kind in enumerate(self.layer_types[:layers]):
             fn = make_block(self, kind, self.is_sparse(i))
@@ -395,7 +396,8 @@ class HybridLM:
         ``router_bias_update_rate`` set, ``(counts, load [sparse layers,
         router_experts])``, the tokens that chose each of the router's experts."""
         x, _, counts = self._blocks(params, ids)
-        x = ops.rms_norm(x, params["final_norm_scale"], eps=self.norm_eps)
+        with jax.named_scope("final_norm"):
+            x = ops.rms_norm(x, params["final_norm_scale"], eps=self.norm_eps)
         if not counts:
             return x, None
         return x, jax.tree_util.tree_map(lambda *layers: jnp.stack(layers), *counts)
@@ -436,8 +438,8 @@ class HybridLM:
         hidden, counts = self.hidden_states(params, tokens)
         # Row t's target is token t + 1. The last row has none: its log-probabilities
         # are computed and dropped, which keeps the head's matmul at S rows.
-        targets = jnp.roll(tokens.astype(jnp.int32), -1, axis=1)[..., None]
         with jax.named_scope("head_loss"):
+            targets = jnp.roll(tokens.astype(jnp.int32), -1, axis=1)[..., None]
             return head_nll(self, self._head(params), hidden, targets), counts
 
     def loss(self, params, tokens) -> tuple[jax.Array, jax.Array | None]:
@@ -509,43 +511,59 @@ def _head_nll_bwd(model, held, cotangent):
 head_nll.defvjp(_head_nll_fwd, _head_nll_bwd)
 
 
+# The ``jax.named_scope`` of each kind of mixer. A sublayer's pre-norm and residual are
+# inside its scope (``moe/norm`` and ``moe/residual`` for the experts, whose parts have
+# scopes of their own), so that a trace joined to the scopes (``utils.profiling.scope_of``)
+# leaves unnamed only what escaped: device time is read by kind, never by layer index.
+MIXER_SCOPES = {"conv": "conv_mixer", "full_attention": "attention", "attention": "attention",
+                "kda": "kda_mixer", "mla": "mla_attention", "mamba": "mamba_mixer"}
+
+
 def make_block(model: HybridLM, kind: str, sparse: bool):
     """``block(p, x, positions) -> (y, counts | None)`` of one layer."""
 
-    def sublayer(p, x, positions):
-        u = ops.rms_norm(x, p["norm_scale"], eps=model.norm_eps)
-        if kind == "moe":
-            out, counts = sparse_ff(p["moe"], u, model)
+    def experts(p, x, scale):
+        with jax.named_scope("moe/norm"):
+            u = ops.rms_norm(x, p[scale], eps=model.norm_eps)
+        out, counts = sparse_ff(p["moe"], u, model)
+        with jax.named_scope("moe/residual"):
             return x + out, counts
-        return x + (mamba_mixer(p["mamba"], u, model) if kind == "mamba"
-                    else attention_mixer(p["attn"], u, positions, model)), None
+
+    def sublayer(p, x, positions):
+        if kind == "moe":
+            return experts(p, x, "norm_scale")
+        with jax.named_scope(MIXER_SCOPES[kind]):
+            u = ops.rms_norm(x, p["norm_scale"], eps=model.norm_eps)
+            return x + (mamba_mixer(p["mamba"], u, model) if kind == "mamba"
+                        else attention_mixer(p["attn"], u, positions, model)), None
 
     if kind in SUBLAYER_KINDS:
         return sublayer
 
     def block(p, x, positions):
         h = mix(p, x, positions, kind, model)
-        u = ops.rms_norm(h, p["ff_norm_scale"], eps=model.norm_eps)
-        if not sparse:
+        if sparse:
+            return experts(p, h, "ff_norm_scale")
+        with jax.named_scope("dense_ff"):
+            u = ops.rms_norm(h, p["ff_norm_scale"], eps=model.norm_eps)
             return h + dense_ff(p["ff"], u), None
-        out, counts = sparse_ff(p["moe"], u, model)
-        return h + out, counts
 
     return block
 
 
 def mix(p, x, positions, kind: str, model: HybridLM):
     """``x + mixer(RMSNorm(x))``: the first half of a block."""
-    u = ops.rms_norm(x, p["mixer_norm_scale"], eps=model.norm_eps)
-    if kind == "conv":
-        mixed = conv_mixer(p["conv"], u)
-    elif kind == "kda":
-        mixed = kda_mixer(p["kda"], u, model)
-    elif kind == "mla":
-        mixed = mla_mixer(p["mla"], u, model)
-    else:
-        mixed = attention_mixer(p["attn"], u, positions, model)
-    return checkpoint_name(x + mixed, "mixer_out")
+    with jax.named_scope(MIXER_SCOPES[kind]):
+        u = ops.rms_norm(x, p["mixer_norm_scale"], eps=model.norm_eps)
+        if kind == "conv":
+            mixed = conv_mixer(p["conv"], u)
+        elif kind == "kda":
+            mixed = kda_mixer(p["kda"], u, model)
+        elif kind == "mla":
+            mixed = mla_mixer(p["mla"], u, model)
+        else:
+            mixed = attention_mixer(p["attn"], u, positions, model)
+        return checkpoint_name(x + mixed, "mixer_out")
 
 
 def _dense(x, kernel):
@@ -561,34 +579,32 @@ def causal_depthwise_conv(z: jax.Array, kernel: jax.Array) -> jax.Array:
 
 
 def conv_mixer(p, u):
-    with jax.named_scope("conv_mixer"):
-        b, c, x = jnp.split(
-            checkpoint_name(_dense(u, p["in_proj_kernel"]), "conv_in_proj"), 3, axis=-1)
-        return _dense(c * causal_depthwise_conv(b * x, p["conv_kernel"]),
-                      p["out_proj_kernel"])
+    b, c, x = jnp.split(
+        checkpoint_name(_dense(u, p["in_proj_kernel"]), "conv_in_proj"), 3, axis=-1)
+    return _dense(c * causal_depthwise_conv(b * x, p["conv_kernel"]),
+                  p["out_proj_kernel"])
 
 
 def mamba_mixer(p, u, model: HybridLM):
-    with jax.named_scope("mamba_mixer"):
-        b, s, _ = u.shape
-        heads, groups = model.mamba_heads, model.mamba_groups
-        hd, n = model.mamba_head_dim, model.ssm_state_size
-        inner, bc = heads * hd, groups * n
-        z, xbc, dt = jnp.split(
-            checkpoint_name(_dense(u, p["in_proj_kernel"]), "mamba_in_proj"),
-            [inner, 2 * inner + 2 * bc], axis=-1)
-        xbc = jax.nn.silu(causal_depthwise_conv(xbc, p["conv_kernel"])
-                          + p["conv_bias"].astype(xbc.dtype))
-        x, b_in, c_out = jnp.split(xbc, [inner, inner + bc], axis=-1)
-        x = x.reshape(b, s, heads, hd)
-        step = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
-        decay = -jnp.exp(p["A_log"].astype(jnp.float32))
-        y = ssm.ssd_scan(x, step, step * decay, b_in.reshape(b, s, groups, n),
-                         c_out.reshape(b, s, groups, n), chunk=model.chunk_size)
-        y = y.astype(jnp.float32) + p["D_scale"].astype(jnp.float32)[:, None] * x
-        normed = gated_group_norm(y.reshape(b, s, inner), z, p["gate_norm_scale"],
-                                  groups, model.norm_eps)
-        return _dense(normed.astype(u.dtype), p["out_proj_kernel"])
+    b, s, _ = u.shape
+    heads, groups = model.mamba_heads, model.mamba_groups
+    hd, n = model.mamba_head_dim, model.ssm_state_size
+    inner, bc = heads * hd, groups * n
+    z, xbc, dt = jnp.split(
+        checkpoint_name(_dense(u, p["in_proj_kernel"]), "mamba_in_proj"),
+        [inner, 2 * inner + 2 * bc], axis=-1)
+    xbc = jax.nn.silu(causal_depthwise_conv(xbc, p["conv_kernel"])
+                      + p["conv_bias"].astype(xbc.dtype))
+    x, b_in, c_out = jnp.split(xbc, [inner, inner + bc], axis=-1)
+    x = x.reshape(b, s, heads, hd)
+    step = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+    decay = -jnp.exp(p["A_log"].astype(jnp.float32))
+    y = ssm.ssd_scan(x, step, step * decay, b_in.reshape(b, s, groups, n),
+                     c_out.reshape(b, s, groups, n), chunk=model.chunk_size)
+    y = y.astype(jnp.float32) + p["D_scale"].astype(jnp.float32)[:, None] * x
+    normed = gated_group_norm(y.reshape(b, s, inner), z, p["gate_norm_scale"],
+                              groups, model.norm_eps)
+    return _dense(normed.astype(u.dtype), p["out_proj_kernel"])
 
 
 def gated_group_norm(y, z, scale, groups: int, eps: float):
@@ -600,75 +616,71 @@ def gated_group_norm(y, z, scale, groups: int, eps: float):
 
 
 def attention_mixer(p, u, positions, model: HybridLM):
-    with jax.named_scope("attention"):
-        b, s, _ = u.shape
-        heads, kv, hd = (model.num_attention_heads, model.num_key_value_heads,
-                         model.head_dim)
-        # Named as the matmuls wrote them, not after the norm and the rotation: the
-        # norm's backward pass reads its input, so its output kept spares no matmul.
-        q, k, v = (checkpoint_name(_dense(u, p[f"{name}_kernel"]), "attn_proj")
-                   .reshape(b, s, n, hd) for name, n in (("q", heads), ("k", kv), ("v", kv)))
-        def placed(x, scale):       # per-head norm, then the rotation; either or neither
-            if model.qk_norm:
-                x = ops.rms_norm(x, p[scale], eps=model.norm_eps)
-            if model.rope_theta is not None:
-                x = apply_rotary(x, positions, base=model.rope_theta)
-            return x
+    b, s, _ = u.shape
+    heads, kv, hd = (model.num_attention_heads, model.num_key_value_heads,
+                     model.head_dim)
+    # Named as the matmuls wrote them, not after the norm and the rotation: the
+    # norm's backward pass reads its input, so its output kept spares no matmul.
+    q, k, v = (checkpoint_name(_dense(u, p[f"{name}_kernel"]), "attn_proj")
+               .reshape(b, s, n, hd) for name, n in (("q", heads), ("k", kv), ("v", kv)))
+    def placed(x, scale):       # per-head norm, then the rotation; either or neither
+        if model.qk_norm:
+            x = ops.rms_norm(x, p[scale], eps=model.norm_eps)
+        if model.rope_theta is not None:
+            x = apply_rotary(x, positions, base=model.rope_theta)
+        return x
 
-        q, k = placed(q, "q_norm_scale"), placed(k, "k_norm_scale")
-        k, v = (jnp.repeat(x, heads // kv, axis=2) for x in (k, v))
-        out = model.attention_fn(q, k, v, causal=True)
-        return _dense(out.reshape(b, s, heads * hd), p["out_kernel"])
+    q, k = placed(q, "q_norm_scale"), placed(k, "k_norm_scale")
+    k, v = (jnp.repeat(x, heads // kv, axis=2) for x in (k, v))
+    out = model.attention_fn(q, k, v, causal=True)
+    return _dense(out.reshape(b, s, heads * hd), p["out_kernel"])
 
 
 def kda_mixer(p, u, model: HybridLM):
     """Flat from the projections to the output projection: a head's channels are 128
     lanes of ``[B, S, H·128]``, and what is one number a token and head (the unit norms
     of q and k, β, the output norm's statistic) is ``ops/kda.py``'s, inside its kernels."""
-    with jax.named_scope("kda_mixer"):
-        heads, hd = model.kda_heads, model.kda_head_dim
-        f32 = jnp.float32
+    heads, hd = model.kda_heads, model.kda_head_dim
+    f32 = jnp.float32
 
-        def branch(name):       # projection, short convolution, silu
-            return jax.nn.silu(causal_depthwise_conv(_dense(u, p[f"{name}_kernel"]),
-                                                     p[f"{name}_conv_kernel"]))
+    def branch(name):       # projection, short convolution, silu
+        return jax.nn.silu(causal_depthwise_conv(_dense(u, p[f"{name}_kernel"]),
+                                                 p[f"{name}_conv_kernel"]))
 
-        low_rank = lambda name: _dense(_dense(u, p[f"{name}_a_kernel"]),
-                                       p[f"{name}_b_kernel"]).astype(f32)
-        rate = jax.nn.softplus(low_rank("f") + p["dt_bias"].astype(f32))
-        decay = rate * jnp.repeat(-jnp.exp(p["A_log"].astype(f32)), hd)
-        beta = jax.nn.sigmoid(_dense(u, p["b_kernel"]).astype(f32))
-        normed = kda.kda_scan(branch("q"), branch("k"), branch("v"), decay, beta,
-                              eps=model.norm_eps, **model._kda_tiles)
-        scaled = normed.astype(f32) * jnp.tile(p["o_norm_scale"].astype(f32), heads)
-        return _dense((scaled * jax.nn.sigmoid(low_rank("g"))).astype(u.dtype),
-                      p["out_kernel"])
+    low_rank = lambda name: _dense(_dense(u, p[f"{name}_a_kernel"]),
+                                   p[f"{name}_b_kernel"]).astype(f32)
+    rate = jax.nn.softplus(low_rank("f") + p["dt_bias"].astype(f32))
+    decay = rate * jnp.repeat(-jnp.exp(p["A_log"].astype(f32)), hd)
+    beta = jax.nn.sigmoid(_dense(u, p["b_kernel"]).astype(f32))
+    normed = kda.kda_scan(branch("q"), branch("k"), branch("v"), decay, beta,
+                          eps=model.norm_eps, **model._kda_tiles)
+    scaled = normed.astype(f32) * jnp.tile(p["o_norm_scale"].astype(f32), heads)
+    return _dense((scaled * jax.nn.sigmoid(low_rank("g"))).astype(u.dtype),
+                  p["out_kernel"])
 
 
 def mla_mixer(p, u, model: HybridLM):
-    with jax.named_scope("mla_attention"):
-        b, s, _ = u.shape
-        heads, nope, rank = model.num_attention_heads, model.qk_nope_head_dim, model.kv_lora_rank
-        q = checkpoint_name(_dense(u, p["q_kernel"]), "attn_proj").reshape(b, s, heads, -1)
-        latent, shared_key = jnp.split(
-            checkpoint_name(_dense(u, p["kv_a_kernel"]), "mla_latent"), [rank], axis=-1)
-        latent = ops.rms_norm(latent, p["kv_a_norm_scale"], eps=model.norm_eps)
-        own_key, v = jnp.split(
-            checkpoint_name(_dense(latent, p["kv_b_kernel"]), "attn_proj")
-            .reshape(b, s, heads, -1), [nope], axis=-1)
-        # the shared channels are carried as they are: no rotation (mla_use_nope)
-        k = jnp.concatenate([own_key, jnp.broadcast_to(
-            shared_key[:, :, None], (b, s, heads, shared_key.shape[-1]))], axis=-1)
-        out = model.attention_fn(q, k, v, causal=True)
-        return _dense(out.reshape(b, s, -1), p["out_kernel"])
+    b, s, _ = u.shape
+    heads, nope, rank = model.num_attention_heads, model.qk_nope_head_dim, model.kv_lora_rank
+    q = checkpoint_name(_dense(u, p["q_kernel"]), "attn_proj").reshape(b, s, heads, -1)
+    latent, shared_key = jnp.split(
+        checkpoint_name(_dense(u, p["kv_a_kernel"]), "mla_latent"), [rank], axis=-1)
+    latent = ops.rms_norm(latent, p["kv_a_norm_scale"], eps=model.norm_eps)
+    own_key, v = jnp.split(
+        checkpoint_name(_dense(latent, p["kv_b_kernel"]), "attn_proj")
+        .reshape(b, s, heads, -1), [nope], axis=-1)
+    # the shared channels are carried as they are: no rotation (mla_use_nope)
+    k = jnp.concatenate([own_key, jnp.broadcast_to(
+        shared_key[:, :, None], (b, s, heads, shared_key.shape[-1]))], axis=-1)
+    out = model.attention_fn(q, k, v, causal=True)
+    return _dense(out.reshape(b, s, -1), p["out_kernel"])
 
 
 def dense_ff(p, u):
-    with jax.named_scope("dense_ff"):
-        # ``W1 u`` is kept and ``W3 u`` recomputed: beside the cell's state the chip
-        # has room for one ``[T, intermediate]`` array more, not for two (PERF.md §6).
-        gate = checkpoint_name(_dense(u, p["w1_kernel"]), "ff_gate")
-        return _dense(ops.swiglu(gate, _dense(u, p["w3_kernel"])), p["w2_kernel"])
+    # ``W1 u`` is kept and ``W3 u`` recomputed: beside the cell's state the chip
+    # has room for one ``[T, intermediate]`` array more, not for two (PERF.md §6).
+    gate = checkpoint_name(_dense(u, p["w1_kernel"]), "ff_gate")
+    return _dense(ops.swiglu(gate, _dense(u, p["w3_kernel"])), p["w2_kernel"])
 
 
 def sparse_ff(p, u, model: HybridLM):

@@ -439,7 +439,11 @@ def main(config: LMConfig = LMConfig(), *,
                          ssm=model.ssm_plan(), kda=model.kda_plan()) if hybrid else {}
             tele.emit(T.compile_event("epoch", aot,
                                       steps_per_call=steps_per_epoch,
-                                      attention=attention, **plans))
+                                      attention=attention,
+                                      scopes=T.write_scope_table(
+                                          config.telemetry, aot["scopes"],
+                                          steps_per_call=steps_per_epoch),
+                                      **plans))
     history = M.MetricsHistory()
     saver = checkpoint.make_saver(config.async_checkpoint, tele=tele)
 

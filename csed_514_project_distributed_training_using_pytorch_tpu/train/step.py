@@ -474,9 +474,12 @@ def make_train_step(model, *, learning_rate: float, momentum: float,
     def step(state: TrainState, images, labels, rng) -> tuple[TrainState, jax.Array]:
         step_rng = jax.random.fold_in(rng, state.step)
         loss, grads = value_and_grad(state.params, images, labels, step_rng)
-        if not loss_has_aux:
-            return apply_update(state, grads, loss)
-        return with_aux(*apply_update(state, grads, loss[0]), loss[1])
+        # the clip, the update and ``after_update`` under one name on a trace
+        # (``utils.profiling.scope_of``): device time outside every model scope
+        with jax.named_scope("optimizer"):
+            if not loss_has_aux:
+                return apply_update(state, grads, loss)
+            return with_aux(*apply_update(state, grads, loss[0]), loss[1])
 
     if grad_accum == 1:
         return step
@@ -503,11 +506,13 @@ def make_train_step(model, *, learning_rate: float, momentum: float,
         (grads_sum, loss_sum), aux = lax.scan(
             body, (zeros, jnp.zeros((), jnp.float32)),
             (xs, ys, jnp.arange(grad_accum)))
-        grads = jax.tree_util.tree_map(lambda g: g / grad_accum, grads_sum)
-        new_state, out = apply_update(state, grads, loss_sum / grad_accum)
-        if not loss_has_aux:
-            return new_state, out
-        return with_aux(new_state, out, jax.tree_util.tree_map(lambda a: a.sum(0), aux))
+        with jax.named_scope("optimizer"):
+            grads = jax.tree_util.tree_map(lambda g: g / grad_accum, grads_sum)
+            new_state, out = apply_update(state, grads, loss_sum / grad_accum)
+            if not loss_has_aux:
+                return new_state, out
+            return with_aux(new_state, out,
+                            jax.tree_util.tree_map(lambda a: a.sum(0), aux))
 
     return accum_step
 

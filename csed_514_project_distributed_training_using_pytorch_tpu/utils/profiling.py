@@ -16,6 +16,13 @@ because it *is* the baseline metric. This module adds what the reference lacks:
   written per span; with no trace running a span costs two clock reads and one
   inactive ``TraceMe``.
 
+- ``scope_of`` / ``scope_table``: which ``jax.named_scope`` and which pass (forward,
+  recompute, backward) made each instruction of a compiled program, read from the
+  ``op_name`` the compiler kept in the program's own text. A trace names device ops by
+  instruction (``fusion.12``); joined to this table, device time is by the model's scopes
+  (``benchmark/reducers/scope_time.py`` does the join, and prints it for any
+  ``--profile --telemetry`` run).
+
 The structured (always-parseable, per-run) counterpart is ``utils/telemetry.py`` — the
 trace is for timeline forensics, telemetry for the numbers.
 """
@@ -24,6 +31,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import re
 import threading
 import time
 
@@ -116,3 +124,134 @@ def drain() -> tuple[dict[str, float], float]:
     out = local.seconds, now - local.t_drain
     local.seconds, local.t_drain = {}, now
     return out
+
+
+# -- from a compiled program's text to the scope and the pass of each instruction --------
+
+# Segments of an ``op_name`` that say how the program is built, not who wrote the op.
+_STRUCTURE = frozenset({"while", "body", "cond", "closed_call", "checkpoint",
+                        "rematted_computation", "remat", "remat2", "pjit", "core_call",
+                        "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
+                        "custom_lin", "named_call", "shard_map"})
+_TRANSFORMS = frozenset({"transpose", "jvp", "vmap"})   # jvp(scope): keep what it wraps
+_CALL = re.compile(r"([\w.\-]*)\(([^()]*)\)")          # jit(f): a function, no scope
+_SCOPE = re.compile(r"[A-Za-z_][\w.\-]*\Z")
+_INSTANCE = re.compile(r"_\d+\Z")                                   # flax: TransformerBlock_3
+_BRANCH = re.compile(r"branch_\d+_fun\Z")                            # a ``lax.cond``'s arms
+PASSES = ("forward", "recompute", "backward")
+
+
+def scope_of(op_name: str | None) -> tuple[str | None, str | None]:
+    """``(scope, pass)`` of one instruction, from the ``op_name`` of its metadata.
+
+    An ``op_name`` is the name stack at the op's trace, then the primitive:
+    ``jit(epoch)/while/body/closed_call/transpose(jvp(jvp()))/checkpoint/
+    rematted_computation/moe/route/dot_general``. ``pass`` is ``recompute`` if
+    ``rematted_computation`` is a segment (``jax.checkpoint`` running a block's forward
+    again), else ``backward`` if a ``transpose(`` wraps anything, else ``forward``.
+    ``scope`` is the path of what ``jax.named_scope`` (a flax module, a Pallas kernel's
+    ``name``) pushed: the structural segments and the primitive dropped, ``jvp(...)`` /
+    ``transpose(...)`` unwrapped around a scope opened inside them, ``jit(f)`` dropped
+    whole, a flax instance's number dropped (``TransformerBlock_3`` adds to
+    ``TransformerBlock``); ``None`` when nothing is left. No scope's name is known
+    here: one added later is found as it is. ``(None, None)`` without an ``op_name``."""
+    if not op_name:
+        return None, None
+    which = ("recompute" if "rematted_computation" in op_name
+             else "backward" if "transpose(" in op_name else "forward")
+    path, before = op_name, None
+    while path != before:       # innermost parentheses first
+        before, path = path, _CALL.sub(
+            lambda m: m.group(2) if m.group(1) in _TRANSFORMS else "", path)
+    kept = [_INSTANCE.sub("", seg) for seg in path.split("/")[:-1]
+            if seg not in _STRUCTURE and _SCOPE.match(seg) and not _BRANCH.match(seg)]
+    return "/".join(kept) or None, which
+
+
+_COMPUTATION = re.compile(r"(?:ENTRY )?%(\S+) \(.*\) -> .* \{\s*\Z")
+_INSTRUCTION = re.compile(r"\s+(?:ROOT )?%(\S+) = ")
+_OPCODE = re.compile(r"(?:^|[\]})] )([a-z][a-z0-9\-]*)\(")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_CALLS = re.compile(r"\b(calls|to_apply)=%([^\s,)}]+)")
+# written in the text, never run as an op of their own
+_FREE = frozenset({"parameter", "constant", "tuple", "get-tuple-element", "bitcast"})
+
+
+def scope_table(text: str, *, detail: bool = False) -> dict:
+    """A compiled program's text (``compiled.as_text()``) as ``{"module": its name,
+    "ops": {instruction: [scope, pass]}, "mixed": [instructions]}``.
+
+    ``ops`` has one entry an instruction that can run on the device, from every
+    computation but the ones inside an instruction (a fusion's fused computation, a
+    reduction's or a sort's scalar function): the ``while`` bodies hold the step. A
+    profiler's trace names a device op by exactly that instruction name. ``mixed``:
+    the fusions whose fused computation (and the fusions nested in it) holds
+    instructions of more than one scope; such a fusion still counts whole under its
+    own ``op_name``, which is its root's. A fusion the compiler left without an
+    ``op_name`` (a scatter it built around a gradient's ``add_any``) takes the scope
+    and pass its contents agree on, and stays unnamed where they do not.
+    ``detail=True`` adds ``"detail": {instruction: {"shape", "op_name"}}``."""
+    module = ""
+    computations: dict[str, list] = {}     # name -> [(instruction, opcode, op_name, shape)]
+    inside: set[str] = set()               # computations that are part of one instruction
+    fusions: dict[str, str] = {}           # fusion instruction -> its fused computation
+    current = None
+    for line in text.splitlines():
+        if current is None:
+            if line.startswith("HloModule "):
+                module = line[len("HloModule "):].split(",", 1)[0].strip()
+            else:
+                m = _COMPUTATION.match(line)
+                if m:
+                    current = computations.setdefault(m.group(1), [])
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, rest = m.group(1), line[m.end():]
+        op = _OPCODE.search(rest)       # the first `<shape> opcode(`: a tuple's shape has none
+        opcode = op.group(1) if op else ""
+        shape = rest[:op.start(1)].strip() if op else ""
+        named = _OP_NAME.search(line)
+        current.append((name, opcode, named.group(1) if named else None, shape))
+        for how, callee in _CALLS.findall(line):
+            if how == "calls" and opcode == "fusion":
+                fusions[name] = callee
+                inside.add(callee)
+            elif how == "to_apply" and opcode != "call":
+                inside.add(callee)
+    def held(callee: str) -> set:
+        """``(scope, pass)`` of every instruction with an ``op_name`` in a fused
+        computation and in the fusions nested in it."""
+        found = set()
+        for name, _, op_name, _ in computations.get(callee, ()):
+            if op_name:
+                found.add(scope_of(op_name))
+            if name in fusions:
+                found |= held(fusions[name])
+        return found
+
+    ops, details, mixed = {}, {}, []
+    for computation, instructions in computations.items():
+        if computation in inside:
+            continue
+        for name, opcode, op_name, shape in instructions:
+            if opcode in _FREE:
+                continue
+            ops[name] = list(scope_of(op_name))
+            if name in fusions:
+                contents = held(fusions[name])
+                if len({scope for scope, _ in contents}) > 1:
+                    mixed.append(name)
+                if not op_name and len(contents) == 1:
+                    (agreed,) = contents
+                    ops[name] = list(agreed)
+            if detail:
+                details[name] = {"shape": shape[:60], "op_name": op_name}
+    table = {"module": module, "ops": ops, "mixed": mixed}
+    if detail:
+        table["detail"] = details
+    return table

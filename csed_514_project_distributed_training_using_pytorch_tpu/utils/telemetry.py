@@ -75,6 +75,7 @@ renderer: ``tools/telemetry_report.py``.
 from __future__ import annotations
 
 import dataclasses
+import json
 import math
 import platform
 import threading
@@ -84,6 +85,7 @@ import jax
 import numpy as np
 
 from csed_514_project_distributed_training_using_pytorch_tpu.utils import metrics as M
+from csed_514_project_distributed_training_using_pytorch_tpu.utils import profiling
 
 SCHEMA_VERSION = 1
 
@@ -281,8 +283,14 @@ def aot_compile(jit_fn, *args) -> tuple[object | None, dict | None]:
     """Time ``jit_fn.lower(*args).compile()`` — the compile/execute split.
 
     Returns ``(compiled, {"lower_s", "compile_s", "flops", "bytes_accessed",
-    "jaxpr"})`` (``jaxpr``: the traced program's, for a caller that reads what the
-    trace holds, as ``HybridLM.recompute_plan`` does); the caller should
+    "jaxpr", "scopes", "scopes_s"})`` (``jaxpr``: the traced program's, for a caller
+    that reads what the trace holds, as ``HybridLM.recompute_plan`` does; ``scopes``:
+    ``profiling.scope_table`` of the executable's own text, which ``jax.named_scope``
+    and which pass made each instruction, and ``scopes_s`` the seconds printing and
+    parsing it took. From ``compiled`` and not from the lowering: the persistent
+    cache's key leaves op metadata out, so an executable another tree wrote can be
+    the one that runs, with that tree's names and numbering, and a trace names
+    device ops by the numbering of what ran); the caller should
     invoke ``compiled`` directly (the AOT program does not populate ``jit_fn``'s
     cache, so calling the jit object afterwards would compile twice). ``args`` may
     mix concrete arrays and ``jax.ShapeDtypeStruct``s. ``(None, None)`` when the
@@ -305,16 +313,49 @@ def aot_compile(jit_fn, *args) -> tuple[object | None, dict | None]:
         reason = (str(e).strip().splitlines() or [""])[0]
         M.log(f"aot_compile: falling back to jit ({type(e).__name__}: {reason})")
         return None, None
+    t0 = time.perf_counter()
+    scopes = profiling.scope_table(compiled.as_text())
+    scopes_s = time.perf_counter() - t0
     return compiled, {"lower_s": lower_s, "compile_s": compile_s,
                       "flops": compiled_flops(compiled),
                       "bytes_accessed": compiled_bytes_accessed(compiled),
-                      "jaxpr": traced.jaxpr}
+                      "jaxpr": traced.jaxpr, "scopes": scopes, "scopes_s": scopes_s}
+
+
+def write_scope_table(telemetry_path: str, table: dict, *,
+                      steps_per_call: int | None = None) -> dict:
+    """``aot["scopes"]`` as ``<telemetry path>.scopes.json``, one line an instruction
+    (a file of its own: a ``compile`` event is one line), with the program's
+    ``steps_per_call`` so that a reader can speak of a step. Returns the ``compile``
+    event's ``scopes`` field: the file's ``path``, the program's ``module`` name, its
+    count of ``instructions``, the share of them whose ``op_name`` holds a scope
+    (``named_share``), how many fusions hold more than one (``mixed``) and the first
+    segment of every scope found (``top_scopes``: an executable that the compile cache
+    handed over from an older tree carries that tree's names, and this list says so).
+    What reads the file: ``benchmark/reducers/scope_time.py``, beside a trace of the
+    same run."""
+    path = telemetry_path + ".scopes.json"
+    ops = table["ops"]
+    if M.is_logging_process():
+        with open(path, "w") as fh:
+            fh.write('{"module": %s,\n "steps_per_call": %s,\n "mixed": %s,\n "ops": {\n'
+                     % (json.dumps(table["module"]), json.dumps(steps_per_call),
+                        json.dumps(table["mixed"])))
+            fh.write(",\n".join(f"{json.dumps(name)}: {json.dumps(where)}"
+                                for name, where in ops.items()))
+            fh.write("\n}}\n")
+    return {"path": path, "module": table["module"], "instructions": len(ops),
+            "named_share": _finite(sum(1 for scope, _ in ops.values() if scope)
+                                   / len(ops) if ops else None),
+            "mixed": len(table["mixed"]),
+            "top_scopes": sorted({scope.split("/")[0] for scope, _ in ops.values() if scope})}
 
 
 def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
                   attention: dict | None = None, experts: dict | None = None,
                   recompute: dict | None = None, ssm: dict | None = None,
-                  head_products: int | None = None, kda: dict | None = None) -> dict:
+                  head_products: int | None = None, kda: dict | None = None,
+                  scopes: dict | None = None) -> dict:
     """The ``compile`` event for one AOT-timed program. ``attention``: which core the
     program's attention calls get and why (``ops.dispatch_plan``'s dict: ``impl``,
     ``score_bytes``, ``seq_padded``, ``block``), for the trainers that
@@ -332,7 +373,9 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
     kernels); None for a model with none. ``head_products``:
     the matrix products of a step that touch the head's ``[T, vocab]`` logits
     (``HybridLM.head_products``: 3 when they are computed once); None for a model
-    whose head is not counted."""
+    whose head is not counted. ``scopes``: where the table of each instruction's scope
+    and pass was written and what it holds (``write_scope_table``); ``scopes_s``, what
+    building it added to the run's set-up, is the ``aot`` dict's."""
     flops = aot.get("flops")
     return {
         "event": "compile",
@@ -353,6 +396,8 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
         "ssm": ssm,
         "kda": kda,
         "head_products": head_products,
+        "scopes": scopes,
+        "scopes_s": _finite(aot.get("scopes_s")),
     }
 
 

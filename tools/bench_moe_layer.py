@@ -33,6 +33,7 @@ import jax.numpy as jnp  # noqa: E402
 
 import xplane  # noqa: E402  (benchmark/: the trace reduction)
 from csed_514_project_distributed_training_using_pytorch_tpu.ops import moe  # noqa: E402
+from csed_514_project_distributed_training_using_pytorch_tpu.utils import profiling  # noqa: E402
 
 KERNEL = re.compile(r"^moe_")
 
@@ -41,16 +42,12 @@ def instruction(text: str) -> str:
     return text.split(" = ", 1)[0].strip().lstrip("%")
 
 
-def program_index(text: str) -> dict[str, dict]:
-    """``{instruction name: {shape, op_name}}`` from a compiled program's text."""
-    index = {}
-    for line in text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\(?[a-z0-9]+\[[^ ]*)", line)
-        if m:
-            op = re.search(r'op_name="([^"]*)"', line)
-            index[m.group(1)] = {"shape": m.group(2)[:60],
-                                 "op_name": op.group(1)[-90:] if op else None}
-    return index
+def _brief(entry: dict | None) -> dict:
+    """An instruction's shape, and the end of its ``op_name`` (the scope and the
+    primitive: the front is the same for every op of the layer)."""
+    if not entry:
+        return {}
+    return {"shape": entry["shape"], "op_name": (entry["op_name"] or "")[-90:] or None}
 
 
 def measure(args, held: int, key) -> dict:
@@ -72,7 +69,8 @@ def measure(args, held: int, key) -> dict:
 
     step = jax.jit(jax.value_and_grad(layer, argnums=(0, 1, 2, 3, 4), has_aux=True))
     compiled = step.lower(u, router_kernel, w1, w3, w2).compile()
-    index = program_index(compiled.as_text())
+    # {instruction: {shape, op_name}}, by the repo's one parser of a program's text
+    index = profiling.scope_table(compiled.as_text(), detail=True)["detail"]
     (loss, counts), grads = compiled(u, router_kernel, w1, w3, w2)      # warm-up
     norms = [float(jnp.sqrt(jnp.sum(jnp.square(g.astype(jnp.float32))))) for g in grads]
     with tempfile.TemporaryDirectory() as trace_dir:
@@ -107,7 +105,7 @@ def measure(args, held: int, key) -> dict:
         "product_kernels_ms": products,
         "outside_product_kernels_ms": busy_ns / 1e6 / args.calls - products,
         "kernels_ms": dict(sorted(by_kernel.items())),
-        "other_ops_ms": [{"name": name, "ms": round(ms, 4), **index.get(name, {})}
+        "other_ops_ms": [{"name": name, "ms": round(ms, 4), **_brief(index.get(name))}
                          for ms, name in others[:args.top]],
         "other_ops_rest_ms": sum(ms for ms, _ in others[args.top:]),
     }
