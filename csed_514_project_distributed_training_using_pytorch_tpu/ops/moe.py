@@ -12,10 +12,16 @@ expert is computed, at any imbalance.
                times the model's scaling
     sort       a token can send a held expert at most one row, so of its k assignments
                at most min(k, n_held) land here: where k is the larger, each token's
-               held assignments are moved to the front and the rest cut off, and
-               everything below sees min(k, n_held) assignments a token. Then
-               the held assignments, grouped by expert; each expert's rows start on a
-               row tile, so a tile belongs to one expert. ``moe_pack`` writes the token
+               held assignments are moved to the front and the rest cut off (each takes
+               the slot its place among the held ones gives it: a select and a sum over
+               the k candidates of a slot), and everything below sees min(k, n_held)
+               assignments a token. Then the held assignments, grouped by expert; each
+               expert's rows start on a row tile, so a tile belongs to one expert. What
+               an assignment needs of its expert (its rank there, the expert's first
+               row) is a sum over its one-hot over the held experts; what a row needs
+               (its expert, the expert's first row and count) is its tile's, repeated;
+               the assignment of a row is a stable ``argsort`` with each expert's run
+               shifted to where the expert's rows start. ``moe_pack`` writes the token
                array once as row-major 32-bit words (a row of a tiled ``[T, d]`` array
                is not contiguous in HBM, a row of that copy is one DMA), and
                ``moe_gather`` copies, tile by tile, the rows of the tiles that ARRIVED
@@ -33,9 +39,16 @@ expert is computed, at any imbalance.
                a range, which the sort hands over), sums each token's in float32,
                weighted, and rounds once to the model's dtype
 
-Only gathers cross between token order and expert order, forward and backward (a
+Only row copies cross between token order and expert order, forward and backward (a
 scatter-add would read and write every float32 sum once a row): the backward of the
-combine is the gather of the sort, and the other way round. What a crossing copies
+combine is the gather of the sort, and the other way round. The scalars that drive
+them cross with no gather or scatter: over the ``T·k`` assignments nothing is fetched or
+stored by index (one scalar at a time, that costs 10 ns an element on the chip where
+the bytes need microseconds) in ``_held_first``, in ``_sort``, or in the router's
+picked scores and their gradient; each is a select and a sum over the few candidates
+(the k of a token, the held experts, the router's experts) or a shift of a contiguous
+run. (Two fetches by index are left, both in the layer's backward pass: the routing
+weight of a row, and a row's weight gradient back at its assignment.) What a crossing copies
 follows the rows that arrived (``num_tiles``, ``rows_of_tokens``), as the products do;
 what does not is one pass over the ``[T, d]`` token array on either side (``moe_pack``
 going in, the tiles ``moe_combine`` writes coming back). Every per-row and per-token
@@ -82,10 +95,13 @@ def route(u: jax.Array, router_kernel: jax.Array, select_bias: jax.Array, *,
     """``u [T, d]`` -> ``(weights [T, k] float32, experts [T, k] int32)`` over all the
     router's experts. Matmul (at ``highest``: one bf16 pass would move near-tied
     selections), sigmoid and top-k in float32; ``select_bias`` moves the selection
-    and not the weights, and gets no gradient. ``load=True`` adds a third result,
-    ``[experts] int32``: the tokens that selected each of the router's experts, held
-    here or not (what ``rebalanced_bias`` reads), counted as the biased scores at or
-    over a token's ``top_k``-th: one pass over ``[T, experts]``."""
+    and not the weights, and gets no gradient. The selected experts' scores are picked
+    by k masked sums over ``[T, experts]`` (``_pick``), whose transpose is k selects:
+    no gather of ``T·k`` scalars going forward, no scatter-add of them coming back.
+    ``load=True`` adds a third result, ``[experts] int32``: the tokens that selected
+    each of the router's experts, held here or not (what ``rebalanced_bias`` reads),
+    counted as the biased scores at or over a token's ``top_k``-th: one pass over
+    ``[T, experts]``."""
     with jax.named_scope("moe/route"):
         # Named: what a caller's ``jax.checkpoint`` may keep of the router (a policy
         # over names; an identity otherwise). The logits and not the scores, because
@@ -97,13 +113,40 @@ def route(u: jax.Array, router_kernel: jax.Array, select_bias: jax.Array, *,
         biased = scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32))
         top, experts = jax.lax.top_k(biased, top_k)
         experts = checkpoint_name(experts.astype(jnp.int32), "moe_route")
-        picked = checkpoint_name(jnp.take_along_axis(scores, experts, axis=-1),
-                                 "moe_route")
+        picked = checkpoint_name(_pick(scores, experts), "moe_route")
         weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + eps)
         if not load:
             return weights * scaling, experts
         selected = biased >= top[:, -1:]
         return weights * scaling, experts, jnp.sum(selected, axis=0, dtype=jnp.int32)
+
+
+@jax.custom_vjp
+def _cotangent_written(x: jax.Array) -> jax.Array:
+    """``x``, whose cotangent is written out before anything reads it."""
+    return x
+
+
+_cotangent_written.defvjp(lambda x: (x, None),
+                          lambda _, g: (jax.lax.optimization_barrier(g),))
+
+
+def _pick(scores: jax.Array, experts: jax.Array) -> jax.Array:
+    """``scores [T, E]`` at ``experts [T, k]``, what ``take_along_axis`` gives, as k
+    masked sums over ``[T, E]`` (one term of a sum is not zero, so they are exact, and
+    no ``[T, k, E]`` array is formed: a pass a candidate). Autodiff transposes them into
+    k selects; ``take_along_axis`` fetches ``T·k`` scalars and its transpose scatters
+    them, 3.8 and 3.5 ms at 16,384 × 22 of 512 where this takes under 0.3 either way
+    (``PERF.md`` §6, PR 36). At 10 ns a fetched scalar against the 1.5 ps a compared one
+    cost there, the fetch would win from about 7,000 experts. The gradient is written
+    once as ``[T, E]``: left to the compiler, the k selects fuse into both of the
+    router's backward products as an operand computed again a tile (1.3 ms a layer at
+    22 of 512, nothing at 8 of 256 or 4 of 64)."""
+    of_expert = jnp.arange(scores.shape[-1], dtype=experts.dtype)[None]
+    scores = _cotangent_written(scores)
+    return jnp.stack(
+        [jnp.sum(jnp.where(experts[:, j:j + 1] == of_expert, scores, 0), axis=1)
+         for j in range(experts.shape[1])], axis=1)
 
 
 def rebalanced_bias(select_bias: jax.Array, load: jax.Array, rate: float) -> jax.Array:
@@ -136,39 +179,53 @@ def _sort(experts: jax.Array, held: tuple[int, int], tm: int) -> dict:
     segment a whole number of tiles (an empty expert keeps one, all invalid, so
     that its weight gradient is written). ``rows_of_tokens [n_held, tiles + 1]``:
     expert ``e``'s rows of the ``i``-th tile of ``tm`` tokens are ``[e, i]`` to
-    ``[e, i + 1]``."""
+    ``[e, i + 1]``.
+
+    Nothing here is fetched by index over the ``T·k`` assignments. What an assignment
+    needs of its expert is a sum over ``lands``, its one-hot over the held experts; what
+    a row needs is its tile's, repeated ``tm`` times; and the assignment of a row is
+    ``order`` with each expert's run of it moved to where the expert's rows start."""
     first, n = held
     t, k = experts.shape
     a = t * k
     local = experts.reshape(a) - first
     is_held = (local >= 0) & (local < n)
     key = jnp.where(is_held, local, n)
-    lands = (key[:, None] == jnp.arange(n)[None]).astype(jnp.int32)
-    running = jnp.cumsum(lands, axis=0)
-    counts = running[-1]
-    slot = jnp.minimum(key, n - 1)
-    rank = jnp.take_along_axis(running, slot[:, None], axis=1)[:, 0] - 1
+    lands = (jnp.arange(n)[:, None] == key[None]).astype(jnp.int32)        # [n, a]
+    running = jnp.cumsum(lands, axis=1)
+    counts = running[:, -1]
     tiles = jnp.maximum(1, -(-counts // tm))
     tile_end = jnp.cumsum(tiles)
     seg_start = (tile_end - tiles) * tm
-    pos = jnp.where(is_held, seg_start[slot] + rank, 0)
+    # its expert's first row and its rank there; of an assignment that is not held, 0
+    pos = jnp.sum(lands * (seg_start[:, None] + running - 1), axis=0)
     order = jnp.argsort(key, stable=True)               # held first, by expert
     unaligned = jnp.cumsum(counts) - counts
     n_tiles = -(-a // tm) + n
-    tile_expert = jnp.minimum(
-        jnp.searchsorted(tile_end, jnp.arange(n_tiles), side="right"), n - 1)
-    rows = jnp.arange(n_tiles * tm)
-    of_row = tile_expert[rows // tm]
-    offset = rows - seg_start[of_row]
-    valid = (offset < counts[of_row]) & (rows // tm < tile_end[-1])
-    source = order[jnp.clip(unaligned[of_row] + offset, 0, a - 1)]
+    tile = jnp.arange(n_tiles)
+    tile_expert = jnp.minimum(jnp.sum(tile[:, None] >= tile_end[None], axis=1), n - 1)
+    of_expert = tile_expert[:, None] == jnp.arange(n)[None]                # [n_tiles, n]
+    start, count = (jnp.sum(jnp.where(of_expert, per_expert[None], 0), axis=1)
+                    for per_expert in (seg_start, counts))
+    offset = (tile * tm - start)[:, None] + jnp.arange(tm)[None]          # [n_tiles, tm]
+    valid = (offset < count[:, None]) & (tile < tile_end[-1])[:, None]
+    # expert e's rows are order[unaligned[e]:][:counts[e]] at seg_start[e]: a shift
+    room = n * tm                       # an expert's rows start at most this far along
+    padded = jnp.pad(order, (room, n_tiles * tm - a))
+    source = jnp.zeros((n_tiles, tm), order.dtype)
+    for e in range(n):
+        moved = jax.lax.dynamic_slice(padded, (room - (seg_start[e] - unaligned[e]),),
+                                      (n_tiles * tm,))
+        source = jnp.where(of_expert[:, e:e + 1], moved.reshape(n_tiles, tm), source)
+    source, valid = source.reshape(-1), valid.reshape(-1)
     # an expert's rows are in token order: those of one tile of ``tm`` tokens are a range
     token_tiles = -(-t // tm)
-    of_tile = jnp.pad(lands, ((0, token_tiles * tm * k - a), (0, 0))).reshape(
-        token_tiles, tm * k, n).sum(axis=1)
-    before = jnp.concatenate([jnp.zeros((1, n), jnp.int32), jnp.cumsum(of_tile, axis=0)])
+    of_tile = jnp.pad(lands, ((0, 0), (0, token_tiles * tm * k - a))).reshape(
+        n, token_tiles, tm * k).sum(axis=2)
+    before = jnp.concatenate([jnp.zeros((n, 1), jnp.int32), jnp.cumsum(of_tile, axis=1)],
+                             axis=1)
     return {"counts": counts, "num_tiles": tile_end[-1].astype(jnp.int32),
-            "rows_of_tokens": (seg_start[None] + before).T.astype(jnp.int32),
+            "rows_of_tokens": (seg_start[:, None] + before).astype(jnp.int32),
             "tile_expert": tile_expert.astype(jnp.int32),
             "assignment_of_row": jnp.where(valid, source, 0).astype(jnp.int32),
             "token_of_row": jnp.where(valid, source // k, -1).astype(jnp.int32),
@@ -586,15 +643,20 @@ def _grouped_ffn(tm: int):
 def _held_first(weights: jax.Array, experts: jax.Array, held: tuple[int, int]):
     """``weights``, ``experts`` ``[T, k]`` cut to ``[T, min(k, n_held)]``: a token's
     assignments to held experts first, in the router's order. The router's experts of
-    a token are distinct, so none that is held is cut off."""
+    a token are distinct, so none that is held is cut off. An assignment's slot is a
+    count of those before it, and a slot's assignment a select and a sum over the k
+    candidates: nothing is sorted or fetched by index, and the transpose selects too."""
     k, keep = experts.shape[1], min(experts.shape[1], held[1])
     if keep == k:
         return weights, experts
     local = experts - held[0]
     is_held = (local >= 0) & (local < held[1])
-    _, order = jax.lax.top_k(jnp.where(is_held, 2 * k, k) - jnp.arange(k), keep)
-    return (jnp.take_along_axis(weights, order, axis=1),
-            jnp.take_along_axis(experts, order, axis=1))
+    ahead = jnp.cumsum(is_held, axis=1, dtype=jnp.int32)     # held ones, up to and with j
+    others = jnp.arange(1, k + 1)[None] - ahead              # the rest, the same
+    slot = jnp.where(is_held, ahead, ahead[:, -1:] + others) - 1
+    front = lambda x: jnp.stack(
+        [jnp.sum(jnp.where(slot == s, x, 0), axis=1) for s in range(keep)], axis=1)
+    return front(weights), front(experts)
 
 
 def held_experts_ffn(x: jax.Array, weights: jax.Array, experts: jax.Array,

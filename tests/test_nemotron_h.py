@@ -246,6 +246,218 @@ def test_held_first_keeps_every_held_assignment_in_the_routers_order():
     assert same[0] is not w and same[1].shape == (2, 3)     # k <= held: nothing is cut
 
 
+# (b1) the routing's crossings against their index forms -----------------------------------
+#
+# What ``ops/moe.py`` computed until PR 36, a fact an assignment fetched by index: kept here
+# as the references of the select-and-reduce forms, which give the same values bit for bit.
+
+
+def index_held_first(weights, experts, held):
+    k, keep = experts.shape[1], min(experts.shape[1], held[1])
+    if keep == k:
+        return weights, experts
+    local = experts - held[0]
+    is_held = (local >= 0) & (local < held[1])
+    _, order = jax.lax.top_k(jnp.where(is_held, 2 * k, k) - jnp.arange(k), keep)
+    return (jnp.take_along_axis(weights, order, axis=1),
+            jnp.take_along_axis(experts, order, axis=1))
+
+
+def index_sort(experts, held, tm):
+    first, n = held
+    t, k = experts.shape
+    a = t * k
+    local = experts.reshape(a) - first
+    is_held = (local >= 0) & (local < n)
+    key = jnp.where(is_held, local, n)
+    lands = (key[:, None] == jnp.arange(n)[None]).astype(jnp.int32)
+    running = jnp.cumsum(lands, axis=0)
+    counts = running[-1]
+    slot = jnp.minimum(key, n - 1)
+    rank = jnp.take_along_axis(running, slot[:, None], axis=1)[:, 0] - 1
+    tiles = jnp.maximum(1, -(-counts // tm))
+    tile_end = jnp.cumsum(tiles)
+    seg_start = (tile_end - tiles) * tm
+    pos = jnp.where(is_held, seg_start[slot] + rank, 0)
+    order = jnp.argsort(key, stable=True)
+    unaligned = jnp.cumsum(counts) - counts
+    n_tiles = -(-a // tm) + n
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(n_tiles), side="right"), n - 1)
+    rows = jnp.arange(n_tiles * tm)
+    of_row = tile_expert[rows // tm]
+    offset = rows - seg_start[of_row]
+    valid = (offset < counts[of_row]) & (rows // tm < tile_end[-1])
+    source = order[jnp.clip(unaligned[of_row] + offset, 0, a - 1)]
+    token_tiles = -(-t // tm)
+    of_tile = jnp.pad(lands, ((0, token_tiles * tm * k - a), (0, 0))).reshape(
+        token_tiles, tm * k, n).sum(axis=1)
+    before = jnp.concatenate([jnp.zeros((1, n), jnp.int32), jnp.cumsum(of_tile, axis=0)])
+    return {"counts": counts, "num_tiles": tile_end[-1].astype(jnp.int32),
+            "rows_of_tokens": (seg_start[None] + before).T.astype(jnp.int32),
+            "tile_expert": tile_expert.astype(jnp.int32),
+            "assignment_of_row": jnp.where(valid, source, 0).astype(jnp.int32),
+            "token_of_row": jnp.where(valid, source // k, -1).astype(jnp.int32),
+            "pos": pos.reshape(t, k).astype(jnp.int32),
+            "is_held": is_held.reshape(t, k)}
+
+
+def drawn_experts(t, k, router, seed=0, without=(), tokens_without=None):
+    """``[t, k]`` distinct experts a token of ``router``, none of ``without``; the tokens
+    ``tokens_without = (tokens, ids)`` choose among the experts outside ``ids``."""
+    rng = np.random.default_rng(seed)
+    allowed = [e for e in range(router) if e not in without]
+    experts = np.stack([rng.choice(allowed, k, replace=False) for _ in range(t)])
+    if tokens_without:
+        rows, ids = tokens_without
+        outside = [e for e in allowed if e not in ids]
+        experts[rows] = np.stack([rng.choice(outside, k, replace=False) for _ in rows])
+    return jnp.asarray(experts, jnp.int32)
+
+
+# tokens, assignments a token, the router's experts, the held range, the row tile
+ROUTINGS = {
+    "k_under_held": dict(t=40, k=2, router=16, held=(4, 4), tm=8),
+    "k_equals_held": dict(t=40, k=4, router=16, held=(4, 4), tm=8),
+    "k_over_held": dict(t=40, k=6, router=16, held=(4, 4), tm=8),
+    "k_far_over_held": dict(t=64, k=11, router=16, held=(8, 4), tm=16),
+    "an_empty_held_expert": dict(t=40, k=6, router=16, held=(4, 4), tm=8, without=(5,)),
+    "every_held_expert_empty": dict(t=24, k=3, router=16, held=(4, 4), tm=8,
+                                    without=(4, 5, 6, 7)),
+    "tokens_with_no_held_assignment": dict(t=40, k=6, router=16, held=(4, 4), tm=8,
+                                           tokens_without=([0, 7, 8, 39], (4, 5, 6, 7))),
+    "rows_not_a_multiple_of_the_tile": dict(t=37, k=3, router=16, held=(4, 4), tm=8),
+    "tokens_not_a_multiple_of_the_tile": dict(t=37, k=6, router=16, held=(4, 4), tm=16),
+    "held_from_0": dict(t=40, k=6, router=16, held=(0, 4), tm=8),
+    "held_to_the_last": dict(t=40, k=6, router=16, held=(12, 4), tm=8),
+    "every_token_sends_all_it_can": dict(t=24, k=4, router=4, held=(0, 4), tm=8),
+    "one_expert_draws_every_token": dict(t=40, k=1, router=6, held=(2, 3), tm=8,
+                                         without=(0, 1, 2, 4, 5)),
+}
+
+
+def routing_case(name):
+    case = dict(ROUTINGS[name])
+    held, tm = case.pop("held"), case.pop("tm")
+    experts = drawn_experts(**case)
+    weights = jax.random.uniform(jax.random.PRNGKey(2), experts.shape, minval=0.1)
+    return weights, experts, held, tm
+
+
+def assert_same(got, want, what=""):
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.dtype == want.dtype and got.shape == want.shape, what
+    assert (got == want).all(), what
+
+
+@pytest.mark.parametrize("name", ROUTINGS)
+def test_held_first_is_the_index_form_and_so_is_its_transpose(name):
+    weights, experts, held, _ = routing_case(name)
+    got, pull = jax.vjp(lambda w: moe._held_first(w, experts, held), weights)
+    want, index_pull = jax.vjp(lambda w: index_held_first(w, experts, held), weights)
+    assert_same(got[0], want[0], "weights")
+    assert_same(got[1], want[1], "experts")
+    cotangent = (jax.random.normal(jax.random.PRNGKey(3), want[0].shape),
+                 np.zeros(want[1].shape, jax.dtypes.float0))
+    assert_same(pull(cotangent)[0], index_pull(cotangent)[0], "the weights' gradient")
+
+
+@pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("name", ROUTINGS)
+def test_the_sort_is_the_index_form(name, jit):
+    _, experts, held, tm = routing_case(name)
+    experts = index_held_first(experts, experts, held)[1]
+    new, old = (jax.jit(f, static_argnums=(1, 2)) if jit else f
+                for f in (moe._sort, index_sort))
+    got, want = new(experts, held, tm), old(experts, held, tm)
+    assert sorted(got) == sorted(want)
+    for key in want:
+        assert_same(got[key], want[key], key)
+
+
+PICKS = dict(ROUTINGS, one_expert_twice_a_token=None)
+
+
+@pytest.mark.parametrize("name", PICKS)
+def test_the_pick_is_the_gather_and_its_transpose_the_scatter_add(name):
+    """``_pick`` and ``jax.grad`` through it against ``take_along_axis`` and its own
+    gradient, which scatters; an expert named twice by a token (``route`` never does)
+    gets both gradients."""
+    if PICKS[name] is None:
+        experts, router = jnp.asarray([[3, 1, 3], [0, 0, 2], [1, 2, 3]], jnp.int32), 4
+    else:
+        experts, router = routing_case(name)[1], ROUTINGS[name]["router"]
+    scores = jax.random.uniform(jax.random.PRNGKey(5), (experts.shape[0], router))
+    cotangent = jax.random.normal(jax.random.PRNGKey(6), experts.shape)
+    got, pull = jax.vjp(lambda s: moe._pick(s, experts), scores)
+    want, index_pull = jax.vjp(lambda s: jnp.take_along_axis(s, experts, axis=-1), scores)
+    assert_same(got, want)
+    assert_same(pull(cotangent)[0], index_pull(cotangent)[0])
+
+
+@pytest.mark.parametrize("k", [2, 6])
+def test_routes_weights_and_gradients_are_those_of_the_index_pick(k, monkeypatch):
+    x = latent_layer_inputs(t=50)
+    w = jax.random.normal(jax.random.PRNGKey(7), (50, k))
+
+    def value_and_gradients():
+        value, gradients = jax.value_and_grad(lambda l, kernel: jnp.sum(w * moe.route(
+            l, kernel, x["expert_bias_b"], top_k=k, scaling=2.5)[0]), argnums=(0, 1))(
+                x["l"], x["router_kernel"])
+        return (value, *gradients)
+
+    got = value_and_gradients()
+    monkeypatch.setattr(moe, "_pick", lambda s, e: jnp.take_along_axis(s, e, axis=-1))
+    for g, r in zip(got, value_and_gradients()):
+        assert float(jnp.abs(r).max()) > 0
+        assert_same(g, r)
+
+
+def _crossings_by_index(jaxpr, least):
+    """The ``gather`` and ``scatter`` equations of ``jaxpr`` before its first Pallas call
+    whose operand or indices hold ``least`` elements or more (a ``sort`` is none)."""
+    found = []
+    for eqn in hybrid_lm._equations(jaxpr.jaxpr):
+        if eqn.primitive.name == "pallas_call":
+            break
+        if eqn.primitive.name == "gather" or eqn.primitive.name.startswith("scatter"):
+            sizes = [int(np.prod(v.aval.shape)) for v in eqn.invars[:2]]
+            if max(sizes) >= least:
+                found.append((eqn.primitive.name, sizes))
+    return found
+
+
+@pytest.mark.parametrize("k", [3, 6], ids=["k_under_held", "k_over_held"])
+def test_no_fact_of_the_routing_is_fetched_or_stored_by_index(k, monkeypatch):
+    """The counter that says the mechanism engaged: up to the first kernel the expert
+    layer's forward, and the gradient through ``route``, hold no ``gather`` and no
+    ``scatter`` over ``T·k`` elements or more (``top_k`` and ``argsort`` are sorts). The
+    index forms hold them, which is what says the search finds one."""
+    x, t, held, f, d = latent_layer_inputs(t=48), 48, (4, 4), 24, 16
+    cut = lambda w, width: w[:, held[0] * width:(held[0] + held[1]) * width]
+    route = lambda l, kernel: moe.route(l, kernel, x["expert_bias_b"], top_k=k)
+    weights, experts = route(x["l"], x["router_kernel"])
+
+    def searches():
+        forward = jax.make_jaxpr(lambda l, w: moe.held_experts_ffn(
+            l, w, experts, cut(x["w1"], f), None, cut(x["w2"], d), held=held, block=8))(
+                x["l"], weights)
+        backward = jax.make_jaxpr(jax.grad(
+            lambda l, kernel: jnp.sum(route(l, kernel)[0] ** 2), argnums=(0, 1)))(
+                x["l"], x["router_kernel"])
+        names = [eqn.primitive.name for eqn in hybrid_lm._equations(forward.jaxpr)]
+        assert "pallas_call" in names and "sort" in names
+        return (_crossings_by_index(forward, t * min(k, held[1])),
+                _crossings_by_index(backward, t * k))
+
+    assert searches() == ([], [])
+    monkeypatch.setattr(moe, "_held_first", index_held_first)
+    monkeypatch.setattr(moe, "_sort", index_sort)
+    monkeypatch.setattr(moe, "_pick", lambda s, e: jnp.take_along_axis(s, e, axis=-1))
+    in_the_sort, in_the_gradient = searches()
+    assert in_the_sort and [name for name, _ in in_the_gradient] == ["gather", "scatter-add"]
+
+
 # (b2) the selection bias's rule ------------------------------------------------------------
 
 
