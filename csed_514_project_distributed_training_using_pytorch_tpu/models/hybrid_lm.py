@@ -1,5 +1,5 @@
 """Decoder LM built from a published configuration file: a stack of
-short-convolution, Mamba-2, delta-rule (KDA), GQA, latent-attention (MLA) and expert
+short-convolution, Mamba-2, delta-rule (KDA), GQA, latent-attention (MLA), EVA and expert
 layers.
 
 ``TransformerLM`` (``models/lm.py``) is the repo's own pixel decoder; this module is
@@ -11,7 +11,9 @@ of the vocabulary are held here, which published layer comes first) builds that
 share. ``LFM2-24B-A2B`` (``lfm2_moe``: ``layer_types``, ``num_dense_layers``) was the
 first such file, ``NVIDIA-Nemotron-3-Super-120B-A12B`` (``nemotron_h``:
 ``hybrid_override_pattern``) the second, ``Kimi-Linear-48B-A3B`` (``kimi_linear``:
-``linear_attn_config``'s 1-based lists of layers) the third.
+``linear_attn_config``'s 1-based lists of layers) the third, ``EvaByte`` (``evabyte``:
+every layer an EVA mixer and a dense feed-forward, no expert anywhere, eight prediction
+heads over 320 byte ids) the fourth.
 
 A layer is a block of two sublayers (``LAYER_KINDS``: its mixer) or one sublayer
 alone (``SUBLAYER_KINDS``). Each kind is written once, as a function of its
@@ -41,7 +43,11 @@ parameters, the normalized input and the positions:
                    W_kvb RMSNorm(c) a head;  a head's key is [k_nope | k_pe], k_pe the
                    same for every head; no positions; causal softmax(q·k/√(nope + pe))·v
                    through ``attention_fn`` at a key width that is not the value width
-    dense_ff       W_2 (silu(W_1 u) ⊙ W_3 u)
+    eva_mixer      q, k = R(W_q u), R(W_k u) (RoPE over all of a head's channels), v = W_v u;
+                   ``ops/eva.py``: a summary (k̃, ṽ) a chunk by the head's learned φ, μ;
+                   one float32 softmax over the keys of the query's own window up to the
+                   query and the summaries of every chunk before that window; W_o
+    dense_ff       W_2 (silu(W_1 u) ⊙ W_3 u), over the held columns of a share
     sparse_ff      ``ops/moe.py``: sigmoid router over all experts, top-k of s + b,
                    the held experts' part of the result, dropless. Experts are gated
                    (three matrices) on the model's rows, or relu² (two) on a latent row
@@ -55,10 +61,16 @@ group of the gated norm), the shared expert over its held columns, and the parti
 result is what goes on. The head is the embedding, tied, or a matrix of its own,
 over the held slice of the vocabulary; the loss is
 the mean next-token NLL over the ``S - 1`` targets of each sequence (position ``t``
-predicts token ``t + 1``; there is no BOS id). Parameters are a plain dict; ``init``
+predicts token ``t + 1``; there is no BOS id). With ``num_pred_heads`` P > 1 the head is
+one ``[d, P·vocab]`` matrix, head ``i`` of place ``t`` predicts token ``t + 1 + i``, and the
+loss is the mean over the heads and places that have a target. ``norm_unit_offset``
+reads a norm's leaf (``…_offset``, zero at the start) as ``1 + g``; ``fp32_residual`` keeps
+the residual stream in float32 between blocks whose matmuls run in ``dtype``.
+Parameters are a plain dict; ``init``
 and ``apply`` keep flax's calling convention so ``train/step.py`` builds the state
 as for any other model. ``remat`` recomputes each block in the backward pass from
-its input and from what ``KEPT`` names: the flash kernel's output and statistics,
+its input and from what ``kept`` names (``KEPT`` unless the family says otherwise):
+the flash kernel's output and statistics,
 the router's and the sort's products, and the matmul outputs that fit the chip beside
 the cell's state (everything else of a block runs again). The head runs once whatever
 ``remat`` says: its loss has a differentiation rule of its own (``head_nll``) whose
@@ -80,13 +92,13 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from csed_514_project_distributed_training_using_pytorch_tpu import ops
-from csed_514_project_distributed_training_using_pytorch_tpu.ops import kda, moe, ssm
+from csed_514_project_distributed_training_using_pytorch_tpu.ops import eva, kda, moe, ssm
 from csed_514_project_distributed_training_using_pytorch_tpu.ops.rotary import (
     apply_rotary,
 )
 
 # a block: this mixer, then a feed-forward
-LAYER_KINDS = ("conv", "full_attention", "kda", "mla")
+LAYER_KINDS = ("conv", "full_attention", "kda", "mla", "eva")
 SUBLAYER_KINDS = ("mamba", "attention", "moe")  # a layer that is one sublayer
 # What ``remat`` keeps of a block between its forward and its backward pass, beside
 # the block's input: the names of ``jax.ad_checkpoint.checkpoint_name`` tags, set
@@ -95,6 +107,10 @@ KEPT = ("flash_out", "flash_lse", "moe_route", "moe_sort", "mixer_out",
         "attn_proj", "conv_in_proj", "ff_gate",
         "ssd_out", "ssd_state", "mamba_in_proj", "moe_latent", "moe_routed",
         "shared_hidden", "kda_out", "kda_state", "mla_latent")
+# What an ``evabyte`` stack keeps: the attention's output and statistics (its forward
+# kernels are not run again). Beside six layers' 9.9 GB of state and float32 block
+# inputs of 0.5 GB each at 32k tokens the chip has no room for a projection's output.
+EVA_KEPT = ("eva_out", "eva_lse")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,6 +162,12 @@ class HybridLM:
     remat: bool = False
     attention_fn: Callable = ops.full_attention
     expert_block: int | None = None     # rows of a kernel step (None: ops.moe.ROW_TILE)
+    eva_window: int = 0                 # keys an EVA query sees exactly: its own window's
+    eva_chunk: int = 0                  # tokens a summary stands for
+    num_pred_heads: int = 1             # head i of place t predicts token t + 1 + i
+    norm_unit_offset: bool = False      # a norm's weight is 1 + its leaf
+    fp32_residual: bool = False         # the residual stream in float32 whatever ``dtype``
+    kept: tuple[str, ...] = KEPT        # what ``remat`` keeps of a block
 
     def __post_init__(self):
         odd = sorted(set(self.layer_types) - set(LAYER_KINDS + SUBLAYER_KINDS))
@@ -162,8 +184,14 @@ class HybridLM:
             raise ValueError("an mla layer needs kv_lora_rank")
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("num_key_value_heads must divide num_attention_heads")
+        if "eva" in self.layer_types and (
+                self.eva_chunk < 1 or self.eva_window % self.eva_chunk
+                or self.seq_len % self.eva_window):
+            raise ValueError(
+                f"an eva layer needs a window ({self.eva_window}) of whole chunks "
+                f"({self.eva_chunk}) and a sequence ({self.seq_len}) of whole windows")
         first, count = self.held_experts
-        if not 0 <= first < first + count <= self.router_experts:
+        if self.sparse_layers and not 0 <= first < first + count <= self.router_experts:
             raise ValueError(f"held experts {self.held_experts} are not a range of "
                              f"the router's {self.router_experts}")
 
@@ -180,10 +208,33 @@ class HybridLM:
     def value_head_dim(self) -> int:
         return self.v_head_dim if "mla" in self.layer_types else self.head_dim
 
+    @property
+    def dispatches_attention(self) -> bool:
+        """Whether any mixer calls ``attention_fn`` (an EVA mixer has a core of its own)."""
+        return any(kind in ("full_attention", "attention", "mla") for kind in self.layer_types)
+
     def is_sparse(self, layer: int) -> bool:
         """Whether layer ``layer`` holds an expert feed-forward."""
         kind = self.layer_types[layer]
         return kind == "moe" or (kind in LAYER_KINDS and layer >= self.num_dense_layers)
+
+    @property
+    def _norm_leaf(self) -> str:
+        """A norm's leaf: its weight, or with ``norm_unit_offset`` what is added to one."""
+        return "norm_offset" if self.norm_unit_offset else "norm_scale"
+
+    def normed(self, x, p, which: str = ""):
+        """``RMSNorm(x)`` by ``p``'s leaf ``<which>norm_scale`` (``<which>norm_offset``: weight
+        one plus the leaf), in ``dtype`` where the residual stream is float32."""
+        u = ops.rms_norm(x, p[which + self._norm_leaf], eps=self.norm_eps,
+                         offset=1.0 if self.norm_unit_offset else 0.0)
+        return u.astype(self.dtype) if self.fp32_residual else u
+
+    def targets_per_seq(self, seq_len: int | None = None) -> int:
+        """The (place, head) pairs of a sequence (of ``seq_len`` tokens) that have a
+        target: place ``t``'s head ``i`` predicts token ``t + 1 + i``."""
+        heads, s = self.num_pred_heads, seq_len or self.seq_len
+        return heads * (s - 1) - heads * (heads - 1) // 2
 
     @property
     def sparse_layers(self) -> int:
@@ -197,7 +248,7 @@ class HybridLM:
         return ssm.scan_plan(heads=self.mamba_heads, groups=self.mamba_groups,
                              head_dim=self.mamba_head_dim, state=self.ssm_state_size,
                              seq_len=self.seq_len, chunk=self.chunk_size,
-                             kept=KEPT if self.remat else ())
+                             kept=self.kept if self.remat else ())
 
     def kda_plan(self) -> dict | None:
         """What a step asks of each KDA layer (``ops.kda.scan_plan``), or None for a
@@ -206,7 +257,17 @@ class HybridLM:
             return None
         return kda.scan_plan(heads=self.kda_heads, key_dim=self.kda_head_dim,
                              value_dim=self.kda_head_dim, seq_len=self.seq_len,
-                             **self._kda_tiles, kept=KEPT if self.remat else ())
+                             **self._kda_tiles, kept=self.kept if self.remat else ())
+
+    def eva_plan(self) -> dict | None:
+        """What a step asks of each EVA layer (``ops.eva.attention_plan``), or None for
+        a stack with none."""
+        if "eva" not in self.layer_types:
+            return None
+        return eva.attention_plan(heads=self.num_attention_heads, head_dim=self.head_dim,
+                                  seq_len=self.seq_len, window=self.eva_window,
+                                  chunk=self.eva_chunk,
+                                  kept=self.kept if self.remat else ())
 
     @property
     def _kda_tiles(self) -> dict:
@@ -252,18 +313,19 @@ class HybridLM:
         if not self.remat:
             return None
         kept = [v.aval for eqn in _equations(getattr(jaxpr, "jaxpr", jaxpr))
-                if eqn.primitive.name == "name" and eqn.params["name"] in KEPT
+                if eqn.primitive.name == "name" and eqn.params["name"] in self.kept
                 for v in eqn.outvars]
-        return {"kept": list(KEPT),
+        return {"kept": list(self.kept),
                 "kept_bytes": sum(a.size * a.dtype.itemsize for a in kept)}
 
     def head_products(self, jaxpr, tokens: int) -> int:
         """The ``compile`` event's ``head_products``: the matrix products in ``jaxpr``
         (a program that differentiates the loss once, as ``recompute_plan``'s) with an
         operand or a result of the logits' shape ``[tokens, vocab]``, ``tokens`` the
-        rows of a step. Three are the mathematics (the logits and the two gradients
-        made of theirs); a fourth is the logits computed again."""
-        logits = (tokens, self.vocab_size)
+        rows of a step (``vocab`` every prediction head's ids side by side). Three are the
+        mathematics (the logits and the two gradients made of theirs); a fourth is the
+        logits computed again."""
+        logits = (tokens, self.vocab_size * self.num_pred_heads)
         return sum(eqn.primitive.name == "dot_general"
                    and any(v.aval.shape == logits for v in (*eqn.invars, *eqn.outvars))
                    for eqn in _equations(getattr(jaxpr, "jaxpr", jaxpr)))
@@ -272,9 +334,10 @@ class HybridLM:
         d, hd = self.hidden_size, self.head_dim
         heads, kv = self.num_attention_heads, self.num_key_value_heads
         held, f = self.held_experts[1], self.moe_intermediate_size
-        tree = {"embed_tokens": (self.vocab_size, d), "final_norm_scale": (d,)}
+        norm = self._norm_leaf
+        tree = {"embed_tokens": (self.vocab_size, d), f"final_{norm}": (d,)}
         if not self.tied_head:
-            tree["lm_head_kernel"] = (d, self.vocab_size)
+            tree["lm_head_kernel"] = (d, self.vocab_size * self.num_pred_heads)
         attn = {"q_kernel": (d, heads * hd), "k_kernel": (d, kv * hd),
                 "v_kernel": (d, kv * hd), "out_kernel": (heads * hd, d)}
         if self.qk_norm:
@@ -308,8 +371,10 @@ class HybridLM:
                   "kv_b_kernel": (self.kv_lora_rank,
                                   heads * (self.qk_nope_head_dim + self.v_head_dim)),
                   "out_kernel": (heads * self.v_head_dim, d)}
+        summarised = dict({name: shape for name, shape in attn.items() if "norm" not in name},
+                          adaptive_phi=(heads, hd), adaptive_mu_k=(heads, hd))
         mixers = {"full_attention": ("attn", attn), "kda": ("kda", delta),
-                  "mla": ("mla", latent)}
+                  "mla": ("mla", latent), "eva": ("eva", summarised)}
         inner = self.mamba_heads * self.mamba_head_dim
         conv_width = inner + 2 * self.mamba_groups * self.ssm_state_size
         mamba = {"in_proj_kernel": (d, inner + conv_width + self.mamba_heads),
@@ -322,9 +387,9 @@ class HybridLM:
         for i, kind in enumerate(self.layer_types):
             if kind in SUBLAYER_KINDS:
                 group, leaves = alone[kind]
-                tree[f"layer_{i}"] = {"norm_scale": (d,), group: dict(leaves)}
+                tree[f"layer_{i}"] = {norm: (d,), group: dict(leaves)}
                 continue
-            layer = {"mixer_norm_scale": (d,), "ff_norm_scale": (d,)}
+            layer = {f"mixer_{norm}": (d,), f"ff_{norm}": (d,)}
             if kind == "conv":
                 layer["conv"] = {"in_proj_kernel": (d, 3 * d),
                                  "conv_kernel": (self.conv_L_cache, d),
@@ -346,7 +411,7 @@ class HybridLM:
     def init(self, rngs, sample=None) -> dict:
         """``{"params": tree}``: kernels normal(0, 1/sqrt(fan_in)), the embedding and
         a mamba or KDA layer's ``A_log`` normal(0, 0.02), norm scales and ``D_scale`` one,
-        biases (the selection's among them) zero."""
+        biases (the selection's among them) and a norm's ``…_offset`` zero."""
         del sample
         key = rngs["params"] if isinstance(rngs, dict) else rngs
         flat, treedef = jax.tree_util.tree_flatten_with_path(
@@ -356,7 +421,7 @@ class HybridLM:
             name = path[-1].key
             if name.endswith("scale"):
                 leaves.append(jnp.ones(shape, jnp.float32))
-            elif name == "expert_bias_b" or name.endswith("bias"):
+            elif name == "expert_bias_b" or name.endswith(("bias", "norm_offset")):
                 leaves.append(jnp.zeros(shape, jnp.float32))
             else:
                 std = 0.02 if name == "embed_tokens" or len(shape) < 2 else shape[0] ** -0.5
@@ -366,9 +431,12 @@ class HybridLM:
 
     def apply(self, variables, ids, **_):
         """``[B, S]`` ids -> ``[B, S, vocab]`` float32 log-probabilities of the
-        next token."""
+        next token (``[B, S, P, vocab]`` with P prediction heads: of the next P)."""
         hidden, _ = self.hidden_states(variables["params"], ids)
-        return ops.log_softmax(self._logits(self._head(variables["params"]), hidden))
+        logits = self._logits(self._head(variables["params"]), hidden)
+        if self.num_pred_heads > 1:
+            logits = logits.reshape(*ids.shape, self.num_pred_heads, self.vocab_size)
+        return ops.log_softmax(logits)
 
     # -- forward --------------------------------------------------------------------
 
@@ -378,13 +446,14 @@ class HybridLM:
         ids = ids.astype(jnp.int32)
         positions = jnp.arange(ids.shape[1])
         with jax.named_scope("embed"):
-            x = params["embed_tokens"].astype(self.dtype)[ids]
+            x = params["embed_tokens"].astype(
+                jnp.float32 if self.fp32_residual else self.dtype)[ids]
         counts = []
         for i, kind in enumerate(self.layer_types[:layers]):
             fn = make_block(self, kind, self.is_sparse(i))
             if self.remat:
                 fn = jax.checkpoint(
-                    fn, policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
+                    fn, policy=jax.checkpoint_policies.save_only_these_names(*self.kept))
             x, arrived = fn(params[f"layer_{i}"], x, positions)
             if arrived is not None:
                 counts.append(arrived)
@@ -397,7 +466,7 @@ class HybridLM:
         router_experts])``, the tokens that chose each of the router's experts."""
         x, _, counts = self._blocks(params, ids)
         with jax.named_scope("final_norm"):
-            x = ops.rms_norm(x, params["final_norm_scale"], eps=self.norm_eps)
+            x = self.normed(x, params, "final_")
         if not counts:
             return x, None
         return x, jax.tree_util.tree_map(lambda *layers: jnp.stack(layers), *counts)
@@ -406,13 +475,14 @@ class HybridLM:
         """The experts ``[B, S, k]`` (ids over all the router's experts) that sparse
         layer ``layer`` selects: a diagnostic, for tests and for the benchmark's
         count of selections a lower precision moves."""
+        if not self.is_sparse(layer):
+            raise ValueError(f"layer {layer} holds no expert layer")
         x, positions, _ = self._blocks(params, ids, layer)
         p, kind = params[f"layer_{layer}"], self.layer_types[layer]
         if kind in SUBLAYER_KINDS:
-            u = ops.rms_norm(x, p["norm_scale"], eps=self.norm_eps)
+            u = self.normed(x, p)
         else:
-            h = mix(p, x, positions, kind, self)
-            u = ops.rms_norm(h, p["ff_norm_scale"], eps=self.norm_eps)
+            u = self.normed(mix(p, x, positions, kind, self), p, "ff_")
         _, experts = moe.route(u.reshape(-1, u.shape[-1]), p["moe"]["router_kernel"],
                                p["moe"]["expert_bias_b"],
                                top_k=self.num_experts_per_tok)
@@ -434,18 +504,24 @@ class HybridLM:
         return flat.reshape(b, s, -1)
 
     def nll(self, params, tokens) -> tuple[jax.Array, jax.Array | None]:
-        """``(summed next-token NLL over the B·(S-1) targets, counts)``."""
+        """``(summed NLL over the B·targets_per_seq targets, counts)``."""
         hidden, counts = self.hidden_states(params, tokens)
-        # Row t's target is token t + 1. The last row has none: its log-probabilities
-        # are computed and dropped, which keeps the head's matmul at S rows.
+        # Row t's target is token t + 1 (head i's: token t + 1 + i). The last rows have
+        # none: their log-probabilities are computed and dropped, which keeps the head's
+        # matmul at S rows.
         with jax.named_scope("head_loss"):
-            targets = jnp.roll(tokens.astype(jnp.int32), -1, axis=1)[..., None]
+            ids = tokens.astype(jnp.int32)
+            if self.num_pred_heads == 1:
+                targets = jnp.roll(ids, -1, axis=1)[..., None]
+            else:
+                targets = jnp.stack([jnp.roll(ids, -(1 + i), axis=1)
+                                     for i in range(self.num_pred_heads)], axis=-1)
             return head_nll(self, self._head(params), hidden, targets), counts
 
     def loss(self, params, tokens) -> tuple[jax.Array, jax.Array | None]:
-        """``(mean next-token NLL, counts)``: the training objective."""
+        """``(mean NLL over the targets, counts)``: the training objective."""
         total, counts = self.nll(params, tokens)
-        return total / (tokens.shape[0] * (tokens.shape[1] - 1)), counts
+        return total / (tokens.shape[0] * self.targets_per_seq(tokens.shape[1])), counts
 
 
 def _equations(jaxpr):
@@ -461,8 +537,13 @@ def _equations(jaxpr):
 
 def _summed_nll(model: HybridLM, table, hidden, targets):
     """``-Σ log softmax(hidden · table)[target]`` over every row but each sequence's
-    last, by plain ``jax.numpy``."""
+    last, by plain ``jax.numpy``; with P prediction heads over every (row t, head i) with
+    ``t + 1 + i < S``, each head's softmax over its own ``vocab`` logits."""
     logits = model._logits(table, hidden)
+    heads = model.num_pred_heads
+    if heads > 1:       # [B, S, P, vocab] against targets [B, S, P, 1]
+        logits = logits.reshape(*logits.shape[:-1], heads, model.vocab_size)
+        targets = targets[..., None]
     # The row maximum behind a barrier: fused with the subtraction, the compiler took
     # it with a reduce-window as wide as the vocabulary (61 ms a pass on the v5e where
     # the logits' product needs 6).
@@ -471,13 +552,18 @@ def _summed_nll(model: HybridLM, table, hidden, targets):
     shifted = logits - top
     picked = jnp.take_along_axis(shifted, targets, axis=-1)[..., 0] \
         - jnp.log(jnp.sum(jnp.exp(shifted), axis=-1))
-    return -jnp.sum(picked[:, :-1])
+    if heads == 1:
+        return -jnp.sum(picked[:, :-1])
+    s = picked.shape[1]
+    has_target = jnp.arange(s)[:, None] + 1 + jnp.arange(heads)[None, :] < s
+    return -jnp.sum(jnp.where(has_target, picked, 0.0))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def head_nll(model: HybridLM, table, hidden, targets):
     """The summed next-token NLL of ``hidden [B, S, d]`` under the head's ``table``
-    (``model._head``'s leaf), ``targets [B, S, 1]`` a row's next token. Alone it is
+    (``model._head``'s leaf), ``targets [B, S, P]`` a row's next P tokens (P the prediction
+    heads, 1 for most). Alone it is
     ``_summed_nll``. Differentiated, its forward pass also takes that function's
     gradient with respect to table and hidden states, at a cotangent of one, while it
     holds the ``[T, vocab]`` logits (the products autodiff makes of them: nothing of
@@ -516,24 +602,25 @@ head_nll.defvjp(_head_nll_fwd, _head_nll_bwd)
 # scopes of their own), so that a trace joined to the scopes (``utils.profiling.scope_of``)
 # leaves unnamed only what escaped: device time is read by kind, never by layer index.
 MIXER_SCOPES = {"conv": "conv_mixer", "full_attention": "attention", "attention": "attention",
-                "kda": "kda_mixer", "mla": "mla_attention", "mamba": "mamba_mixer"}
+                "kda": "kda_mixer", "mla": "mla_attention", "mamba": "mamba_mixer",
+                "eva": "eva_mixer"}
 
 
 def make_block(model: HybridLM, kind: str, sparse: bool):
     """``block(p, x, positions) -> (y, counts | None)`` of one layer."""
 
-    def experts(p, x, scale):
+    def experts(p, x, which):
         with jax.named_scope("moe/norm"):
-            u = ops.rms_norm(x, p[scale], eps=model.norm_eps)
+            u = model.normed(x, p, which)
         out, counts = sparse_ff(p["moe"], u, model)
         with jax.named_scope("moe/residual"):
             return x + out, counts
 
     def sublayer(p, x, positions):
         if kind == "moe":
-            return experts(p, x, "norm_scale")
+            return experts(p, x, "")
         with jax.named_scope(MIXER_SCOPES[kind]):
-            u = ops.rms_norm(x, p["norm_scale"], eps=model.norm_eps)
+            u = model.normed(x, p)
             return x + (mamba_mixer(p["mamba"], u, model) if kind == "mamba"
                         else attention_mixer(p["attn"], u, positions, model)), None
 
@@ -543,9 +630,9 @@ def make_block(model: HybridLM, kind: str, sparse: bool):
     def block(p, x, positions):
         h = mix(p, x, positions, kind, model)
         if sparse:
-            return experts(p, h, "ff_norm_scale")
+            return experts(p, h, "ff_")
         with jax.named_scope("dense_ff"):
-            u = ops.rms_norm(h, p["ff_norm_scale"], eps=model.norm_eps)
+            u = model.normed(h, p, "ff_")
             return h + dense_ff(p["ff"], u), None
 
     return block
@@ -554,13 +641,15 @@ def make_block(model: HybridLM, kind: str, sparse: bool):
 def mix(p, x, positions, kind: str, model: HybridLM):
     """``x + mixer(RMSNorm(x))``: the first half of a block."""
     with jax.named_scope(MIXER_SCOPES[kind]):
-        u = ops.rms_norm(x, p["mixer_norm_scale"], eps=model.norm_eps)
+        u = model.normed(x, p, "mixer_")
         if kind == "conv":
             mixed = conv_mixer(p["conv"], u)
         elif kind == "kda":
             mixed = kda_mixer(p["kda"], u, model)
         elif kind == "mla":
             mixed = mla_mixer(p["mla"], u, model)
+        elif kind == "eva":
+            mixed = eva_mixer(p["eva"], u, positions, model)
         else:
             mixed = attention_mixer(p["attn"], u, positions, model)
         return checkpoint_name(x + mixed, "mixer_out")
@@ -615,7 +704,9 @@ def gated_group_norm(y, z, scale, groups: int, eps: float):
     return ops.rms_norm(grouped, scale.reshape(groups, -1), eps=eps).reshape(gated.shape)
 
 
-def attention_mixer(p, u, positions, model: HybridLM):
+def attention_mixer(p, u, positions, model: HybridLM, core=None):
+    """``core(q, k, v)`` in the model's ``attention_fn``'s place, where the mixer's
+    attention is not plain causal softmax (``eva_mixer``)."""
     b, s, _ = u.shape
     heads, kv, hd = (model.num_attention_heads, model.num_key_value_heads,
                      model.head_dim)
@@ -632,7 +723,7 @@ def attention_mixer(p, u, positions, model: HybridLM):
 
     q, k = placed(q, "q_norm_scale"), placed(k, "k_norm_scale")
     k, v = (jnp.repeat(x, heads // kv, axis=2) for x in (k, v))
-    out = model.attention_fn(q, k, v, causal=True)
+    out = core(q, k, v) if core else model.attention_fn(q, k, v, causal=True)
     return _dense(out.reshape(b, s, heads * hd), p["out_kernel"])
 
 
@@ -674,6 +765,14 @@ def mla_mixer(p, u, model: HybridLM):
         shared_key[:, :, None], (b, s, heads, shared_key.shape[-1]))], axis=-1)
     out = model.attention_fn(q, k, v, causal=True)
     return _dense(out.reshape(b, s, -1), p["out_kernel"])
+
+
+def eva_mixer(p, u, positions, model: HybridLM):
+    """``attention_mixer``'s projections and rotation around ``ops/eva.py``'s attention
+    with the held heads' φ and μ; the out-projection sums over the held heads."""
+    return attention_mixer(p, u, positions, model, core=lambda q, k, v: eva.eva_attention(
+        q, k, v, p["adaptive_phi"], p["adaptive_mu_k"],
+        window=model.eva_window, chunk=model.eva_chunk))
 
 
 def dense_ff(p, u):
@@ -722,8 +821,9 @@ def is_frozen(path) -> bool:
 def from_config(config: dict, *, vocab_size: int, seq_len: int, **kwargs) -> HybridLM:
     """The model a configuration file describes: the published keys at the top
     level, and, for one chip's share of a deployment, the keys that count layers,
-    experts, heads, groups, shared-expert columns and ids as held here, with ``share``
-    (``first_layer``, ``first_expert``) and ``published`` (the router's width) beside
+    experts, heads, groups, shared-expert or feed-forward columns and ids as held here,
+    with ``share`` (``first_layer``, ``first_expert``, ``mlp_columns``) and ``published``
+    (the router's width, the heads of the whole layer) beside
     them. ``model_type`` names the family (``lfm2_moe`` when absent). ``vocab_size``
     is the corpus's and has to be the file's."""
     if int(config["vocab_size"]) != int(vocab_size):
@@ -739,13 +839,12 @@ def from_config(config: dict, *, vocab_size: int, seq_len: int, **kwargs) -> Hyb
     if first < 0 or len(pattern[first:first + depth]) != depth:
         raise ValueError("the layer pattern is shorter than first_layer + "
                          "num_hidden_layers")
-    for key, as_ in (("num_experts_per_tok", int), ("norm_eps", float)):
+    for key, as_ in (("num_experts_per_tok", int), ("norm_eps", float),
+                     ("intermediate_size", int), ("moe_intermediate_size", int)):
         if key not in fields:       # a family whose file names it otherwise hands it over
             fields[key] = as_(config[key])
     return HybridLM(vocab_size=int(vocab_size), seq_len=int(seq_len),
                     hidden_size=int(config["hidden_size"]),
-                    intermediate_size=int(config["intermediate_size"]),
-                    moe_intermediate_size=int(config["moe_intermediate_size"]),
                     num_attention_heads=int(config["num_attention_heads"]),
                     num_key_value_heads=int(config["num_key_value_heads"]),
                     layer_types=tuple(pattern[first:first + depth]),
@@ -868,8 +967,50 @@ def _kimi_linear(config: dict) -> tuple[list, dict]:
         v_head_dim=int(config["v_head_dim"]))
 
 
+def _evabyte(config: dict) -> tuple[list, dict]:
+    """Every layer an EVA mixer and a dense gated feed-forward; no expert layer, no
+    leading layer of another kind; RoPE over a head's whole width; norms with the unit
+    offset, the residual stream in float32, ``num_pred_heads`` heads in one untied matrix.
+    A share holds ``num_attention_heads`` of ``published.num_attention_heads`` heads (a
+    head's width is the published one's) and ``share.mlp_columns`` of the feed-forward's
+    ``intermediate_size`` columns. What the file states and this module does not compute
+    is refused, not ignored."""
+    share, published = config.get("share", {}), config.get("published", {})
+    heads = int(config["num_attention_heads"])
+    window, chunk = int(config["window_size"]), int(config["chunk_size"])
+    unwritten = {
+        "attention_class other than eva": config.get("attention_class", "eva") != "eva",
+        "num_chunks not null": config.get("num_chunks") is not None,
+        "rope_scaling not null": config.get("rope_scaling") is not None,
+        "window_size that is not a multiple of chunk_size": bool(window % chunk),
+        "hidden_act other than silu": config.get("hidden_act", "silu") != "silu",
+        "attention_bias true": bool(config.get("attention_bias", False)),
+        "tie_word_embeddings true": bool(config.get("tie_word_embeddings", False)),
+        "num_key_value_heads other than num_attention_heads":
+            int(config["num_key_value_heads"]) != heads,
+        "share.heads other than num_attention_heads":
+            int(share.get("heads", heads)) != heads,
+    }
+    for what, stated in unwritten.items():
+        if stated:
+            raise ValueError(f"{what} is not written here")
+    depth = int(published.get("num_hidden_layers", config["num_hidden_layers"]))
+    return ["eva"] * depth, dict(
+        num_dense_layers=depth, router_experts=0, held_experts=(0, 0),
+        num_experts_per_tok=0, moe_intermediate_size=0,
+        intermediate_size=int(share.get("mlp_columns", config["intermediate_size"])),
+        norm_eps=float(config["rms_norm_eps"]), rope_theta=float(config["rope_theta"]),
+        qk_norm=False, tied_head=False,
+        attention_head_dim=int(config["hidden_size"])
+        // int(published.get("num_attention_heads", heads)),
+        eva_window=window, eva_chunk=chunk,
+        num_pred_heads=int(config.get("num_pred_heads", 1)),
+        norm_unit_offset=bool(config.get("norm_add_unit_offset", False)),
+        fp32_residual=bool(config.get("fp32_skip_add", False)), kept=EVA_KEPT)
+
+
 _FAMILIES = {"lfm2_moe": _lfm2_moe, "nemotron_h": _nemotron_h,
-             "kimi_linear": _kimi_linear}
+             "kimi_linear": _kimi_linear, "evabyte": _evabyte}
 
 
 def from_config_file(path: str, **kwargs) -> HybridLM:

@@ -134,14 +134,19 @@ def layer_norm(x: jax.Array, gamma: jax.Array, beta: jax.Array,
             + beta.astype(jnp.float32)).astype(x.dtype)
 
 
-def rms_norm(x: jax.Array, gamma: jax.Array, *, eps: float = 1e-5) -> jax.Array:
+def rms_norm(x: jax.Array, gamma: jax.Array, *, eps: float = 1e-5,
+             offset: float = 0.0) -> jax.Array:
     """Root-mean-square normalization over the last axis with a learned scale and no
-    shift (``x / sqrt(mean(x²) + eps) · gamma``): the norm of the catalog decoders
-    (``models/hybrid_lm.py``), also applied per attention head to q and k. Statistics in
+    shift (``x / sqrt(mean(x²) + eps) · (offset + gamma)``): the norm of the catalog
+    decoders (``models/hybrid_lm.py``), also applied per attention head to q and k.
+    ``offset`` 1 is the unit offset of a norm whose leaf starts at zero. Statistics in
     float32, like ``layer_norm``."""
     xf = x.astype(jnp.float32)
     normed = xf * lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
-    return (normed * gamma.astype(jnp.float32)).astype(x.dtype)
+    weight = gamma.astype(jnp.float32)
+    if offset:
+        weight = weight + offset
+    return (normed * weight).astype(x.dtype)
 
 
 def swiglu(gate: jax.Array, up: jax.Array) -> jax.Array:

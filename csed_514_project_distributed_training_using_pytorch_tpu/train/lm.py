@@ -89,7 +89,8 @@ def _attention_plan(config: LMConfig, seq_len: int, world: int, *,
 
 def make_eval_nll_fn(model, *, batch_size: int):
     """``evaluate(params, tokens) -> sum_nll`` — summed next-token NLL over the split
-    (divide by ``N·S`` for the mean, ``N·(S-1)`` for a ``HybridLM``, which has no BOS;
+    (divide by ``N·S`` for the mean, ``N·targets_per_seq()`` for a ``HybridLM``, which has
+    no BOS;
     ``exp`` of that is perplexity), one scanned program like the classifier's eval."""
 
     hybrid = isinstance(model, hybrid_lm.HybridLM)
@@ -278,7 +279,7 @@ def main(config: LMConfig = LMConfig(), *,
             **lm_kwargs)
     hybrid = isinstance(model, hybrid_lm.HybridLM)
     # Targets a sequence: a HybridLM has no BOS, so its first token is context only.
-    targets_per_seq = seq_len - 1 if hybrid else seq_len
+    targets_per_seq = model.targets_per_seq() if hybrid else seq_len
     # Decoding is single-chip (host params): restore the default core, and the
     # window as a model field so the KV-cache decode mask applies the same band the
     # (possibly ring-windowed) training attention did — decode parity holds across
@@ -427,7 +428,9 @@ def main(config: LMConfig = LMConfig(), *,
                 flops_per_step = aot["flops"] / steps_per_epoch
             if aot.get("bytes_accessed"):
                 bytes_per_step = aot["bytes_accessed"] / steps_per_epoch
-            attention = None if seq_size > 1 else _attention_plan(
+            # no plan for a stack none of whose mixers goes through ``attention_fn``
+            attention = None if seq_size > 1 or (
+                hybrid and not model.dispatches_attention) else _attention_plan(
                 config, seq_len, world, dispatched=mesh.size == 1,
                 heads=model.num_attention_heads if hybrid else None,
                 head_dim=model.head_dim if hybrid else None,
@@ -436,7 +439,8 @@ def main(config: LMConfig = LMConfig(), *,
             plans = dict(experts=model.expert_plan(step_tokens),
                          recompute=model.recompute_plan(aot["jaxpr"]),
                          head_products=model.head_products(aot["jaxpr"], step_tokens),
-                         ssm=model.ssm_plan(), kda=model.kda_plan()) if hybrid else {}
+                         ssm=model.ssm_plan(), kda=model.kda_plan(),
+                         eva=model.eva_plan()) if hybrid else {}
             tele.emit(T.compile_event("epoch", aot,
                                       steps_per_call=steps_per_epoch,
                                       attention=attention,
@@ -459,7 +463,8 @@ def main(config: LMConfig = LMConfig(), *,
                                 targets_per_seq, steps_per_epoch, start_epoch, history,
                                 watch, saver, ckpt_path, gather, tele, compile_s,
                                 flops_per_step, rt, bytes_per_step, grt, loader,
-                                model.expert_plan(1)["block"] if hybrid else None)
+                                (model.expert_plan(1) or {}).get("block") if hybrid
+                                else None)
     finally:
         # Drain the write-behind queue even on an exception/signal/preemption
         # mid-run — the queued per-epoch checkpoint is the resume artifact a killed
@@ -566,8 +571,8 @@ def _run_epochs(config, state, mesh, epoch_fn, eval_fn, tokens_d, zeros_d, test_
             with profiling.span("epoch/execute"):
                 with profiling.span("execute/dispatch"):
                     state, out = epoch_fn(state, tokens_d, zeros_d, plan, dropout_rng)
-                # (losses[, health][, the expert layers' arrived rows]): see
-                # train.step.make_epoch_from_step
+                # (losses[, health][, the expert layers' arrived rows, None for a
+                # stack with no expert layer]): see train.step.make_epoch_from_step
                 out = out if isinstance(out, tuple) else (out,)
                 losses = out[0]
                 epoch_health = out[1] if config.health_stats else None

@@ -355,7 +355,7 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
                   attention: dict | None = None, experts: dict | None = None,
                   recompute: dict | None = None, ssm: dict | None = None,
                   head_products: int | None = None, kda: dict | None = None,
-                  scopes: dict | None = None) -> dict:
+                  eva: dict | None = None, scopes: dict | None = None) -> dict:
     """The ``compile`` event for one AOT-timed program. ``attention``: which core the
     program's attention calls get and why (``ops.dispatch_plan``'s dict: ``impl``,
     ``score_bytes``, ``seq_padded``, ``block``), for the trainers that
@@ -370,7 +370,10 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
     each delta-rule layer (``ops.kda.scan_plan``: heads, key and value widths, chunk and
     sub-block, chunks, kept states and their bytes a sequence, what recomputation
     keeps, and ``in_kernel``: the per-token, per-head scalars computed inside the
-    kernels); None for a model with none. ``head_products``:
+    kernels); None for a model with none. ``eva``: the same of each EVA layer
+    (``ops.eva.attention_plan``: ``impl``, heads held, window and chunk, windows and
+    summaries a sequence, the kernels' query and summary blocks, what recomputation
+    keeps); None for a model with none. ``head_products``:
     the matrix products of a step that touch the head's ``[T, vocab]`` logits
     (``HybridLM.head_products``: 3 when they are computed once); None for a model
     whose head is not counted. ``scopes``: where the table of each instruction's scope
@@ -395,6 +398,7 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
         "recompute": recompute,
         "ssm": ssm,
         "kda": kda,
+        "eva": eva,
         "head_products": head_products,
         "scopes": scopes,
         "scopes_s": _finite(aot.get("scopes_s")),

@@ -290,3 +290,25 @@ def test_flash_kernels_compile_for_the_v5e_at_latent_attentions_widths(one_chip)
     widths = [x.shape[-1] for x in jax.tree.leaves(compiled.out_info)]
     assert widths == [192, 192, 128]       # dq, dk, dv
     assert f"bf16[{b * h},{s},256]" not in text
+
+
+def test_eva_attention_kernels_compile_for_the_v5e_at_published_widths(one_chip):
+    """One sequence of 32768 bytes, the 16 held heads of 128, windows of 2048 and chunks of
+    16: the local half's ``flash_*`` over ``[256, 2048, 128]`` and the remote half's
+    ``eva_fwd``, ``eva_dq``, ``eva_dkv`` over 2048 summaries, value and every gradient."""
+    from csed_514_project_distributed_training_using_pytorch_tpu.ops import (
+        eva, pallas_attention,
+    )
+    heads, s, d, window, chunk = 16, 32768, 128, 2048, 16
+    spec = lambda rows: jax.ShapeDtypeStruct((heads, rows, d), jnp.bfloat16,
+                                             sharding=one_chip)
+    loss = lambda *operands: jnp.sum(eva.kernel_attention(
+        *operands, window=window, chunk=chunk).astype(jnp.float32))
+    with lowering_for_the_chip(eva, pallas_attention):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            spec(s), spec(s), spec(s), spec(s // chunk), spec(s // chunk)).compile()
+    text = compiled.as_text()
+    for name in ("flash_fwd", "flash_dq", "flash_dkv", "eva_fwd", "eva_dq", "eva_dkv"):
+        assert f"%{name}" in text, name
+    assert [x.shape for x in jax.tree.leaves(compiled.out_info)] == \
+        [(heads, s, d)] * 3 + [(heads, s // chunk, d)] * 2
