@@ -30,12 +30,14 @@ exp(G_ic − G_jc)``::
 
 ``exp(−G)`` is never formed: a trained decay spans a few units a chunk, a seeded one
 hundreds, and ``exp(G_i − G_j)`` is wanted only where ``i >= j``, where it is at most
-one. ``P`` is built in sub-blocks of ``SUB`` rows. A pair less than ``SUB`` apart inside
-one sub-block is computed exactly, ``Σ_c a_ic b_jc exp(G_ic − G_jc)`` a diagonal of the
-sub-block at a time (the rows shifted by their distance, on the VPU); a sub-block of
-rows against the columns before it is one product of two operands rescaled against
-the sub-block's first row ``n``, ``a ⊙ exp(G − G_n)`` and ``b ⊙ exp(G_n − G)``, both
-factors at most one, so a decay too small for float32 reads zero and never infinity.
+one. ``P`` is built in sub-blocks of ``SUB`` rows (4: chosen on the chip, PERF.md §6, PR
+38). A pair inside one sub-block is computed exactly, ``Σ_c a_ic b_jc exp(G_ic − G_jc)``
+a diagonal of the sub-block at a time (the rows shifted by their distance, on the VPU:
+three diagonals a chunk). Every other pair is in the later half's rows against the
+earlier half's columns of one block of ``2·SUB``, ``4·SUB``, … ``C`` rows, and each doubling
+is one product of two operands rescaled against the later half's first row ``m``,
+``a ⊙ exp(G − G_m)`` below it and ``b ⊙ exp(G_m − G)`` above, both factors at most one,
+so a decay too small for float32 reads zero and never infinity.
 ``(I + A)⁻¹`` is exact elimination in products: inside the diagonal sub-blocks
 ``(I − D)(I + D²)(I + D⁴)…`` (``D`` is nilpotent), then ``(I − N)(I + N²)…`` over the
 sub-blocks with ``N = (I + D)⁻¹(A − D)``, so no power of the whole ``A`` is taken.
@@ -48,7 +50,11 @@ every chunk's would be 0.27 GB a layer and sequence of 8192, and a group's is a
 quarter of that). ``kda_bwd`` walks the groups in reverse carrying the state's
 gradient: a step runs its group's chunks again from the kept state and then their
 transpose, which is ``jax.vjp`` of the very function the forward kernel runs, traced
-into the kernel (so the two cannot drift apart). Decays, masks, the running sums
+into the kernel (so the two cannot drift apart). Within a grid step, what no state
+enters (running sums, ``P``, the inverses, ``(I + A)⁻¹βk e^G`` and ``(I + A)⁻¹βv``) is
+computed for all ``GROUP`` chunks first, the inverses' products a step at a time across
+the chunks, and the states follow: a chunk's small products wait for one another,
+and chunk by chunk the MXU stood idle in the waits. Decays, masks, the running sums
 (one float32 product with a triangle of ones, at ``highest``) and the state are
 float32; every other product runs on the MXU in the model's dtype.
 
@@ -68,7 +74,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 CHUNK = 64      # tokens of a chunk: the published kernels', and what keeps (I + A)⁻¹ small
-SUB = 16        # rows of a sub-block: pairs closer than this are computed exactly
+SUB = 4         # rows of a sub-block: pairs inside one are computed exactly
 GROUP = 4       # chunks a grid step, and between two kept states
 
 NN, NT, TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
@@ -106,89 +112,102 @@ def _pair_scores(q, k, kb, cum, sub: int, dtype):
     """``(strict_lower P(kb, k), lower P(q, k))`` of one chunk, ``[C, C]`` float32; the
     operands float32 ``[C, K]``."""
     c, d = k.shape
-    row, col = _iota((c, c), 0), _iota((c, c), 1)
-    within = _iota((c, d), 0) % sub
+    row, col, token = _iota((c, c), 0), _iota((c, c), 1), _iota((c, d), 0)
     rowsum = lambda x: jnp.sum(x, axis=1, keepdims=True)
     # inside a sub-block, a diagonal at a time: exp(G_i − G_{i−by}) itself
     kk = jnp.zeros((c, c), jnp.float32)
     qk = jnp.where(row == col, rowsum(q * k), 0.0)
     for by in range(1, sub):
-        near = within >= by
+        near = token % sub >= by
         decay = jnp.exp(jnp.where(near, cum - _shifted(cum, by), 0.0))
         kd = jnp.where(near, _shifted(k, by) * decay, 0.0)
         at = row - col == by
         kk = jnp.where(at, rowsum(kb * kd), kk)
         qk = jnp.where(at, rowsum(q * kd), qk)
-    # a sub-block's rows against every column before it, rescaled against its first row
-    far_kk, far_qk = [jnp.zeros((sub, c), jnp.float32)], [jnp.zeros((sub, c), jnp.float32)]
-    for n in range(sub, c, sub):
-        first = cum[n:n + 1]
-        down = jnp.exp(cum[n:n + sub] - first)
-        before = k * jnp.exp(jnp.minimum(first - cum, 0.0))
-        both = _dot(jnp.concatenate([kb[n:n + sub] * down, q[n:n + sub] * down]),
-                    before, NT, dtype)
-        far_kk.append(both[:sub])
-        far_qk.append(both[sub:])
-    far = col // sub < row // sub
-    return (jnp.where(far, jnp.concatenate(far_kk), kk),
-            jnp.where(far, jnp.concatenate(far_qk), qk))
+    # a doubling of the block at a time: the later half's rows against the earlier half's
+    # columns, every row rescaled against the later half's first (a row is read in one
+    # half only, so one exponential a doubling serves both operands)
+    half = sub
+    while half < c:
+        middle = jnp.concatenate([jnp.broadcast_to(cum[n:n + 1], (2 * half, d))
+                                  for n in range(half, c, 2 * half)])
+        later = token % (2 * half) >= half
+        scaled = jnp.exp(jnp.where(later, cum - middle, middle - cum))
+        both = _dot(jnp.concatenate([kb * scaled, q * scaled]), k * scaled, NT, dtype)
+        at = (row // (2 * half) == col // (2 * half)) & (col // half < row // half)
+        kk, qk = jnp.where(at, both[:c], kk), jnp.where(at, both[c:], qk)
+        half *= 2
+    return kk, qk
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
-def _unit_lower_inverse(a, sub: int, dtype):
-    """``(I + a)⁻¹`` of a strictly lower triangular ``a [C, C]``."""
-    c = a.shape[0]
+def _unit_lower_inverses(mats, sub: int, dtype):
+    """``(I + a)⁻¹`` of every strictly lower triangular ``a [C, C]`` of the tuple ``mats``,
+    each product taken for all of them before the next: one matrix's products wait
+    for one another, those of different matrices do not."""
+    c = mats[0].shape[0]
     row, col = _iota((c, c), 0), _iota((c, c), 1)
     eye = (row == col).astype(jnp.float32)
-    mm = lambda x, y: _dot(x, y, NN, dtype)
+    mm = lambda xs, ys: [_dot(x, y, NN, dtype) for x, y in zip(xs, ys)]
 
-    def nilpotent_inverse(n, index: int):
+    def nilpotent_inverses(ns, index: int):
         """``(I + n)⁻¹ = (I − n)(I + n²)(I + n⁴)…`` where ``n`` to the ``index`` is zero."""
-        inverse, power, reach = eye - n, n, 2
+        inverses, powers, reach = [eye - n for n in ns], ns, 2
         while reach < index:
-            power = mm(power, power)
-            inverse = inverse + mm(inverse, power)
+            powers = mm(powers, powers)
+            inverses = [x + y for x, y in zip(inverses, mm(inverses, powers))]
             reach *= 2
-        return inverse
+        return inverses
 
-    inside = jnp.where(row // sub == col // sub, a, 0.0)
-    blocks = nilpotent_inverse(inside, sub)
+    inside = [jnp.where(row // sub == col // sub, a, 0.0) for a in mats]
+    blocks = nilpotent_inverses(inside, sub)
     if c == sub:
-        return blocks
-    return mm(nilpotent_inverse(mm(blocks, a - inside), c // sub), blocks)
+        return tuple(blocks)
+    across = mm(blocks, [a - d for a, d in zip(mats, inside)])
+    return tuple(mm(nilpotent_inverses(across, c // sub), blocks))
 
 
-def _inverse_fwd(a, sub, dtype):
-    inverse = _unit_lower_inverse(a, sub, dtype)
-    return inverse, inverse
+def _inverses_fwd(mats, sub, dtype):
+    inverses = _unit_lower_inverses(mats, sub, dtype)
+    return inverses, inverses
 
 
-def _inverse_bwd(sub, dtype, inverse, d):
+def _inverses_bwd(sub, dtype, inverses, ds):
     # d(M⁻¹) = −M⁻¹ dM M⁻¹
-    return (-_dot(_dot(inverse, d, TN, dtype), inverse, NT, dtype),)
+    return (tuple(-_dot(_dot(inverse, d, TN, dtype), inverse, NT, dtype)
+                  for inverse, d in zip(inverses, ds)),)
 
 
-_unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
+_unit_lower_inverses.defvjp(_inverses_fwd, _inverses_bwd)
 
 
-def _chunk(q, k, kb, vb, g, state, sub: int, dtype):
-    """One chunk of one head: ``(o [C, V] float32, the state after it)``. ``state`` is
-    ``Sᵀ [V, K]``; ``q``, ``k``, ``kb = βk`` ``[C, K]``, ``vb = βv [C, V]`` and ``g [C, K]``
-    float32; the products run in ``dtype``."""
-    c = q.shape[0]
+def _chunks(q, k, kb, vb, g, state, chunk: int, sub: int, dtype):
+    """The chunks of one grid step of one head: ``(o [R, V] float32, the state after
+    them)``. ``state`` is ``Sᵀ [V, K]``; ``q``, ``k``, ``kb = βk`` ``[R, K]``, ``vb = βv [R, V]`` and
+    ``g [R, K]`` float32; the products run in ``dtype``."""
+    c = chunk
     ones = (_iota((c, c), 0) >= _iota((c, c), 1)).astype(jnp.float32)
-    cum = jax.lax.dot_general(ones, g, (NN, ((), ())),
-                              precision=jax.lax.Precision.HIGHEST,
-                              preferred_element_type=jnp.float32)
-    grown, total = jnp.exp(cum), cum[c - 1:c]
-    a_kk, a_qk = _pair_scores(q, k, kb, cum, sub, dtype)
-    inverse = _unit_lower_inverse(a_kk, sub, dtype)
-    w = _dot(inverse, kb * grown, NN, dtype)
-    u = _dot(inverse, vb, NN, dtype)
-    fresh = u - _dot(w, state, NT, dtype)                           # Ũ [C, V]
-    o = _dot(q * grown, state, NT, dtype) + _dot(a_qk, fresh, NN, dtype)
-    state = state * jnp.exp(total) + _dot(fresh, k * jnp.exp(total - cum), TN, dtype)
-    return o, state
+    by_chunk = lambda x: [x[at:at + c] for at in range(0, x.shape[0], c)]
+    # What no state enters, for every chunk before the first state: a chunk's products
+    # are small and wait for one another (the inverse's above all), and written chunk
+    # after chunk they ran so, the MXU idle in the waits (PERF.md §6, PR 38: −38 %).
+    qs, ks, kbs, vbs = map(by_chunk, (q, k, kb, vb))
+    cums = [jax.lax.dot_general(ones, x, (NN, ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
+                                preferred_element_type=jnp.float32) for x in by_chunk(g)]
+    growns = [jnp.exp(cum) for cum in cums]
+    scores = [_pair_scores(*x, sub, dtype) for x in zip(qs, ks, kbs, cums)]
+    inverses = _unit_lower_inverses(tuple(a_kk for a_kk, _ in scores), sub, dtype)
+    ws = [_dot(inverse, kb * grown, NN, dtype)
+          for inverse, kb, grown in zip(inverses, kbs, growns)]
+    us = [_dot(inverse, vb, NN, dtype) for inverse, vb in zip(inverses, vbs)]
+    out = []
+    for q, k, cum, grown, (_, a_qk), w, u in zip(qs, ks, cums, growns, scores, ws, us):
+        total = cum[c - 1:c]
+        fresh = u - _dot(w, state, NT, dtype)                       # Ũ [C, V]
+        out.append(_dot(q * grown, state, NT, dtype) + _dot(a_qk, fresh, NN, dtype))
+        state = state * jnp.exp(total) + _dot(fresh, k * jnp.exp(total - cum), TN, dtype)
+    return jnp.concatenate(out), state
 
 
 def _unit(x, scale: float = 1.0):
@@ -207,11 +226,7 @@ def _group(q, k, v, g, beta, state, chunk: int, sub: int, eps: float):
     q = _unit(q.astype(jnp.float32), q.shape[1] ** -0.5)
     k = _unit(k.astype(jnp.float32))
     kb, vb = beta * k, beta * v.astype(jnp.float32)
-    out = []
-    for at in range(0, q.shape[0], chunk):
-        o, state = _chunk(*(x[at:at + chunk] for x in (q, k, kb, vb, g)), state, sub, dtype)
-        out.append(o)
-    o = jnp.concatenate(out)
+    o, state = _chunks(q, k, kb, vb, g, state, chunk, sub, dtype)
     o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=1, keepdims=True) + eps)
     return o.astype(v.dtype), state
 

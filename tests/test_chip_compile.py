@@ -214,8 +214,9 @@ KDA = dict(batch=2, seq=8192, heads=32, head_dim=128)
 
 def test_delta_rule_kernels_compile_for_the_v5e_at_published_widths(one_chip):
     """``kda_fwd`` and ``kda_bwd`` at the cell's shapes, on the flat layout (chunks of 64 in
-    sub-blocks of 16, four chunks a grid step, a 128 x 128 state): Mosaic takes the
-    sublane rolls of the exact diagonals, the triangular inverse's products, the lane
+    sub-blocks of ``kda.SUB``, four chunks a grid step side by side before their states, a
+    128 x 128 state): Mosaic takes the sublane rolls of the exact diagonals, the far
+    pairs' product a doubling of the block, the four triangular inverses' products, the lane
     select of a head's β out of the ``[256, 32]`` block, the norms' lane reductions, the
     row that ``dβ`` leaves as, and the transpose of the whole group that ``jax.vjp`` traces
     into the backward kernel."""
@@ -230,6 +231,7 @@ def test_delta_rule_kernels_compile_for_the_v5e_at_published_widths(one_chip):
             x, x, x, g, beta).compile()
     text = compiled.as_text()
     assert "%kda_fwd" in text and "%kda_bwd" in text
+    assert kda.scan_plan(heads=h, key_dim=d, value_dim=d, seq_len=s)["sub_block"] == kda.SUB
     rows = kda.GROUP * kda.CHUNK
     kept = f"f32[{b},{s // rows},{h},{d},{d}]"      # a state a group
     assert kept in text and f"f32[{b},{s // kda.CHUNK},{h},{d},{d}]" not in text
@@ -256,6 +258,8 @@ def test_a_delta_rule_layer_never_leaves_the_flat_layout(one_chip):
     model = hybrid_lm.from_config_file(config, vocab_size=20480, seq_len=s,
                                        dtype=jnp.bfloat16, remat=True)
     assert (model.kda_heads, model.kda_head_dim) == (h, d)
+    # the record says what the kernels ran: the ``compile`` event's ``kda`` field is this plan
+    assert model.kda_plan()["sub_block"] == kda.SUB == model.kda_tiling[1]
     on_chip = lambda tree: jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
     p = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))["params"]["layer_0"]["kda"]

@@ -157,6 +157,12 @@ SCAN_SIZES = {
     "shorter than a chunk": (1, 5, 1, 8, 16, 8, 4, 1, 1.0),
     "one sub-block a chunk": (1, 32, 2, 8, 8, 8, 8, 2, 1.0),
     "published tile: chunk 64, sub-block 16, 128 x 128": (1, 128, 1, 128, 128, 64, 16, 2, 1.0),
+    "published tile: chunk 64, sub-block 8, 128 x 128": (1, 128, 1, 128, 128, 64, 8, 2, 1.0),
+    "published tile: chunk 64, sub-block 4 (ops.kda.SUB), 128 x 128":
+        (1, 128, 1, 128, 128, kda.CHUNK, kda.SUB, 2, 1.0),
+    # every pair that is not inside a sub-block of 4 is a product of two rescaled operands
+    "published tile, steep: G passes -100 inside a chunk":
+        (1, 128, 1, 128, 128, kda.CHUNK, kda.SUB, 2, 8.0),
 }
 
 
@@ -213,7 +219,7 @@ def test_padding_tokens_write_nothing_and_hand_back_no_gradient(tail):
 BF16 = 0.03     # of the largest entry: what the bf16 path met with the norms and β outside
 
 
-@pytest.mark.parametrize("size", [s for s in SCAN_SIZES if "published" not in s])
+@pytest.mark.parametrize("size", SCAN_SIZES)
 def test_bf16_operands_stay_near_the_float32_recurrence(size):
     """``q̃``, ``k̃``, ``v`` in bfloat16 as the model hands them (``g``, ``β`` float32) against
     the float32 recurrence on the same rounded values, output and gradients. With the
@@ -237,7 +243,7 @@ def test_the_scan_plan_counts_the_states_a_sequence_keeps():
     plan = kda.scan_plan(heads=32, key_dim=128, value_dim=128, seq_len=8192,
                          kept=hybrid_lm.KEPT)
     assert plan == {"heads": 32, "key_dim": 128, "value_dim": 128, "chunk": 64,
-                    "sub_block": 16, "chunks_per_sequence": 128, "states_per_sequence": 32,
+                    "sub_block": 4, "chunks_per_sequence": 128, "states_per_sequence": 32,
                     "state_bytes_per_sequence": 32 * 32 * 128 * 128 * 4,
                     "kept": ["kda_out", "kda_state"],
                     "in_kernel": ["q_norm", "k_norm", "beta", "out_norm"]}
