@@ -13,7 +13,9 @@ first such file, ``NVIDIA-Nemotron-3-Super-120B-A12B`` (``nemotron_h``:
 ``hybrid_override_pattern``) the second, ``Kimi-Linear-48B-A3B`` (``kimi_linear``:
 ``linear_attn_config``'s 1-based lists of layers) the third, ``EvaByte`` (``evabyte``:
 every layer an EVA mixer and a dense feed-forward, no expert anywhere, eight prediction
-heads over 320 byte ids) the fourth.
+heads over 320 byte ids) the fourth, ``Kanana-2-30B-A3B`` (``deepseek_v3``: latent
+attention in every layer with a rotated shared key, ``first_k_dense_replace`` dense layers
+and then fine-grained experts beside shared ones) the fifth.
 
 A layer is a block of two sublayers (``LAYER_KINDS``: its mixer) or one sublayer
 alone (``SUBLAYER_KINDS``). Each kind is written once, as a function of its
@@ -41,7 +43,10 @@ parameters, the normalized input and the positions:
                    W_o (w ⊙ ô ⊙ sigmoid(W_g↑ W_g↓ u)), w the head norm's scale tiled
     mla_mixer      q = W_q u, a head [nope | pe];  [c | k_pe] = W_kva u;  [k_nope | v] =
                    W_kvb RMSNorm(c) a head;  a head's key is [k_nope | k_pe], k_pe the
-                   same for every head; no positions; causal softmax(q·k/√(nope + pe))·v
+                   same for every head; no positions (``rope_theta`` None), or every
+                   head's q_pe and the one k_pe ``[B, S, pe]`` rotated (RoPE over the pe
+                   channels alone, in the file's pairing) before k_pe is handed to the
+                   heads; causal softmax(q·k/√(nope + pe))·v
                    through ``attention_fn`` at a key width that is not the value width
     eva_mixer      q, k = R(W_q u), R(W_k u) (RoPE over all of a head's channels), v = W_v u;
                    ``ops/eva.py``: a summary (k̃, ṽ) a chunk by the head's learned φ, μ;
@@ -111,6 +116,11 @@ KEPT = ("flash_out", "flash_lse", "moe_route", "moe_sort", "mixer_out",
 # kernels are not run again). Beside six layers' 9.9 GB of state and float32 block
 # inputs of 0.5 GB each at 32k tokens the chip has no room for a projection's output.
 EVA_KEPT = ("eva_out", "eva_lse")
+# What a ``deepseek_v3`` stack keeps: all of ``KEPT`` that it tags but ``attn_proj``. Six
+# latent-attention layers' q (201 MB at 16,384 tokens) and kv_b output (268 MB) under that
+# name do not fit beside 11.0 GB of state: the two products run again (0.55 TFLOP a layer).
+MLA_KEPT = ("flash_out", "flash_lse", "mla_latent", "moe_route", "moe_sort", "mixer_out",
+            "shared_hidden", "ff_gate")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -135,6 +145,7 @@ class HybridLM:
     conv_L_cache: int = 3
     norm_eps: float = 1e-5
     rope_theta: float | None = 1e6      # None: attention without positions
+    rope_interleave: bool = False       # an MLA mixer's rotated channels 2i, 2i + 1 turn together
     qk_norm: bool = True
     attention_head_dim: int | None = None   # None: hidden_size / num_attention_heads
     tied_head: bool = True
@@ -150,7 +161,7 @@ class HybridLM:
     kda_tiling: tuple[int, int, int] = (kda.CHUNK, kda.SUB, kda.GROUP)  # ops.kda's
     kv_lora_rank: int = 0               # the key/value latent of an MLA mixer
     qk_nope_head_dim: int = 128         # a head's key channels from the latent
-    qk_rope_head_dim: int = 64          # and those every head shares (carried, not rotated)
+    qk_rope_head_dim: int = 64          # and those every head shares (rotated with rope_theta)
     v_head_dim: int = 128
     mamba_heads: int = 0                # held heads of a Mamba-2 mixer, in whole groups
     mamba_groups: int = 1
@@ -268,6 +279,18 @@ class HybridLM:
                                   seq_len=self.seq_len, window=self.eva_window,
                                   chunk=self.eva_chunk,
                                   kept=self.kept if self.remat else ())
+
+    def rotary_plan(self) -> dict:
+        """The ``compile`` event's ``rope_dim``, ``rope_pairing`` and ``rope_theta`` of its
+        ``attention`` field: how many of a head's query and key channels turn by their
+        position (a latent-attention head's shared ones; else all), in which pairing
+        and at which base. None each for attention without positions."""
+        if self.rope_theta is None:
+            return dict.fromkeys(("rope_dim", "rope_pairing", "rope_theta"))
+        return {"rope_dim": self.qk_rope_head_dim if "mla" in self.layer_types
+                else self.head_dim,
+                "rope_pairing": "interleaved" if self.rope_interleave else "half_split",
+                "rope_theta": self.rope_theta}
 
     @property
     def _kda_tiles(self) -> dict:
@@ -647,7 +670,7 @@ def mix(p, x, positions, kind: str, model: HybridLM):
         elif kind == "kda":
             mixed = kda_mixer(p["kda"], u, model)
         elif kind == "mla":
-            mixed = mla_mixer(p["mla"], u, model)
+            mixed = mla_mixer(p["mla"], u, positions, model)
         elif kind == "eva":
             mixed = eva_mixer(p["eva"], u, positions, model)
         else:
@@ -750,7 +773,10 @@ def kda_mixer(p, u, model: HybridLM):
                   p["out_kernel"])
 
 
-def mla_mixer(p, u, model: HybridLM):
+def mla_mixer(p, u, positions, model: HybridLM):
+    """With ``rope_theta`` the last ``qk_rope_head_dim`` channels of every head's query and
+    the shared key turn by their position, the key once, ``[B, S, 1, pe]``, before the heads
+    are handed it; without, both are carried as they are (``mla_use_nope``)."""
     b, s, _ = u.shape
     heads, nope, rank = model.num_attention_heads, model.qk_nope_head_dim, model.kv_lora_rank
     q = checkpoint_name(_dense(u, p["q_kernel"]), "attn_proj").reshape(b, s, heads, -1)
@@ -760,9 +786,16 @@ def mla_mixer(p, u, model: HybridLM):
     own_key, v = jnp.split(
         checkpoint_name(_dense(latent, p["kv_b_kernel"]), "attn_proj")
         .reshape(b, s, heads, -1), [nope], axis=-1)
-    # the shared channels are carried as they are: no rotation (mla_use_nope)
+    shared_key = shared_key[:, :, None]         # one key, a head axis of one
+    if model.rope_theta is not None:
+        with jax.named_scope("rotary"):
+            turn = functools.partial(apply_rotary, positions=positions,
+                                     base=model.rope_theta,
+                                     interleaved=model.rope_interleave)
+            q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], axis=-1)
+            shared_key = turn(shared_key)
     k = jnp.concatenate([own_key, jnp.broadcast_to(
-        shared_key[:, :, None], (b, s, heads, shared_key.shape[-1]))], axis=-1)
+        shared_key, (b, s, heads, shared_key.shape[-1]))], axis=-1)
     out = model.attention_fn(q, k, v, causal=True)
     return _dense(out.reshape(b, s, -1), p["out_kernel"])
 
@@ -859,6 +892,14 @@ def _held_experts(config: dict, count_key: str) -> dict:
             "held_experts": (int(share.get("first_expert", 0)), int(config[count_key]))}
 
 
+def _refuse(unwritten: dict) -> None:
+    """``unwritten``: what a file may state that this module does not compute, each by
+    name with whether the file states it. The first that is stated raises."""
+    for what, stated in unwritten.items():
+        if stated:
+            raise ValueError(f"{what} is not written here")
+
+
 def _lfm2_moe(config: dict) -> tuple[list, dict]:
     """``layer_types`` whole; ``num_dense_layers`` leading blocks with the dense
     feed-forward, the others sparse; tied head."""
@@ -894,9 +935,7 @@ def _nemotron_h(config: dict) -> tuple[list, dict]:
         "such loss)": bool(config.get("num_nextn_predict_layers", 0)),
         "selected scores that are not normalised": not config.get("norm_topk_prob", True),
     }
-    for what, stated in unwritten.items():
-        if stated:
-            raise ValueError(f"{what} is not written here")
+    _refuse(unwritten)
     return [_NEMOTRON_LETTERS[c] for c in config["hybrid_override_pattern"]], dict(
         _held_experts(config, "n_routed_experts"), num_dense_layers=0,
         rope_theta=None, qk_norm=False, attention_head_dim=int(config["head_dim"]),
@@ -946,9 +985,7 @@ def _kimi_linear(config: dict) -> tuple[list, dict]:
         or len(kinds) != len(linear["kda_layers"]) + len(linear["full_attn_layers"]),
         "share.first_layer 0 (the file's lists count layers from 1)": first < 1,
     }
-    for what, stated in unwritten.items():
-        if stated:
-            raise ValueError(f"{what} is not written here")
+    _refuse(unwritten)
     return [None] + [kinds[i] for i in range(1, published + 1)], dict(
         _held_experts(config, "num_experts"), first_layer=first,
         num_dense_layers=max(0, int(config["first_k_dense_replace"]) - (first - 1)),
@@ -991,9 +1028,7 @@ def _evabyte(config: dict) -> tuple[list, dict]:
         "share.heads other than num_attention_heads":
             int(share.get("heads", heads)) != heads,
     }
-    for what, stated in unwritten.items():
-        if stated:
-            raise ValueError(f"{what} is not written here")
+    _refuse(unwritten)
     depth = int(published.get("num_hidden_layers", config["num_hidden_layers"]))
     return ["eva"] * depth, dict(
         num_dense_layers=depth, router_experts=0, held_experts=(0, 0),
@@ -1009,8 +1044,56 @@ def _evabyte(config: dict) -> tuple[list, dict]:
         fp32_residual=bool(config.get("fp32_skip_add", False)), kept=EVA_KEPT)
 
 
+def _deepseek_v3(config: dict) -> tuple[list, dict]:
+    """Every layer a latent-attention mixer whose shared key channels (and every head's
+    last ``qk_rope_head_dim`` query channels) turn by RoPE at ``rope_theta``, in the
+    interleaved pairing where ``rope_interleave`` says so; layers numbered from 0, the first
+    ``first_k_dense_replace`` with the dense feed-forward and the others with gated experts
+    (sigmoid scores, ``num_experts_per_tok`` of ``n_routed_experts`` by score + bias, no
+    groups) beside ``n_shared_experts`` shared ones built as one gated expert of
+    ``n_shared_experts × moe_intermediate_size`` columns; an untied head. ``head_dim`` is the
+    rotary width again and read nowhere. What the file states and this module does not
+    compute is refused, not ignored."""
+    _refuse({
+        "a query latent (q_lora_rank not null)": config.get("q_lora_rank") is not None,
+        "rope_scaling not null": config.get("rope_scaling") is not None,
+        "grouped expert selection (n_group, topk_group other than 1)":
+            (config.get("n_group", 1), config.get("topk_group", 1)) != (1, 1),
+        "scoring_func other than sigmoid": config.get("scoring_func", "sigmoid") != "sigmoid",
+        "topk_method other than noaux_tc":
+            config.get("topk_method", "noaux_tc") != "noaux_tc",
+        "selected scores that are not normalised (norm_topk_prob false)":
+            not config.get("norm_topk_prob", True),
+        "moe_layer_freq other than 1": config.get("moe_layer_freq", 1) != 1,
+        "hidden_act other than silu": config.get("hidden_act", "silu") != "silu",
+        "attention_bias true": bool(config.get("attention_bias", False)),
+        "num_key_value_heads other than num_attention_heads":
+            int(config["num_key_value_heads"]) != int(config["num_attention_heads"]),
+        "multi-token prediction (num_nextn_predict_layers > 0: the trainer has no "
+        "such loss)": bool(config.get("num_nextn_predict_layers", 0)),
+    })
+    first = int(config.get("share", {}).get("first_layer", 0))
+    depth = int(config.get("published", {}).get("num_hidden_layers",
+                                                config["num_hidden_layers"]))
+    shared = int(config.get("n_shared_experts") or 0)
+    return ["mla"] * depth, dict(
+        _held_experts(config, "n_routed_experts"),
+        num_dense_layers=max(0, int(config["first_k_dense_replace"]) - first),
+        norm_eps=float(config["rms_norm_eps"]), rope_theta=float(config["rope_theta"]),
+        rope_interleave=bool(config.get("rope_interleave", False)), qk_norm=False,
+        tied_head=bool(config.get("tie_word_embeddings", False)), router_eps=1e-20,
+        router_bias_update_rate=float(config.get("moe_router_bias_update_rate", 0.0)),
+        shared_expert_size=shared * int(config["moe_intermediate_size"]),
+        gated_shared_expert=bool(shared),
+        kv_lora_rank=int(config["kv_lora_rank"]),
+        qk_nope_head_dim=int(config["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(config["qk_rope_head_dim"]),
+        v_head_dim=int(config["v_head_dim"]), kept=MLA_KEPT)
+
+
 _FAMILIES = {"lfm2_moe": _lfm2_moe, "nemotron_h": _nemotron_h,
-             "kimi_linear": _kimi_linear, "evabyte": _evabyte}
+             "kimi_linear": _kimi_linear, "evabyte": _evabyte,
+             "deepseek_v3": _deepseek_v3}
 
 
 def from_config_file(path: str, **kwargs) -> HybridLM:

@@ -435,6 +435,8 @@ def main(config: LMConfig = LMConfig(), *,
                 heads=model.num_attention_heads if hybrid else None,
                 head_dim=model.head_dim if hybrid else None,
                 value_dim=model.value_head_dim if hybrid else None)
+            if hybrid and attention is not None:
+                attention.update(model.rotary_plan())
             step_tokens = config.batch_size // world // config.grad_accum * seq_len
             plans = dict(experts=model.expert_plan(step_tokens),
                          recompute=model.recompute_plan(aot["jaxpr"]),

@@ -316,3 +316,42 @@ def test_eva_attention_kernels_compile_for_the_v5e_at_published_widths(one_chip)
         assert f"%{name}" in text, name
     assert [x.shape for x in jax.tree.leaves(compiled.out_info)] == \
         [(heads, s, d)] * 3 + [(heads, s // chunk, d)] * 2
+
+
+def test_a_latent_attention_layer_rotates_the_shared_key_once(one_chip):
+    """``mix`` of an ``mla`` layer of the ``deepseek_v3`` file, value and every gradient, at
+    the cell's shapes (2 x 8192 tokens, 32 heads of 128 + 64 / 128): the flash kernels take
+    keys of 192 channels, and in the forward pass the rotation's product (``mla_attention/
+    rotary``, a signed permutation of the 64 shared channels) runs twice: over the 32 heads'
+    query channels ``[2, 8192, 32, 64]`` and over the one shared key ``[2, 8192, 64]``, before
+    it is handed to the heads, not over 32 copies of it."""
+    import math
+    import re
+    from csed_514_project_distributed_training_using_pytorch_tpu import ops
+    from csed_514_project_distributed_training_using_pytorch_tpu.models import hybrid_lm
+    from csed_514_project_distributed_training_using_pytorch_tpu.ops import pallas_attention
+    b, s, h = KDA["batch"], KDA["seq"], KDA["heads"]
+    config = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                          "benchmark", "configs", "kanana-2-30b-a3b-ep8.json")
+    model = hybrid_lm.from_config_file(config, vocab_size=16032, seq_len=s, dtype=jnp.bfloat16,
+                                       remat=True, attention_fn=ops.dispatch_attention)
+    assert (model.num_attention_heads, model.head_dim, model.value_head_dim,
+            model.qk_rope_head_dim) == (h, 192, 128, 64)
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    p = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))["params"]["layer_0"]
+    x = jax.ShapeDtypeStruct((b, s, model.hidden_size), jnp.bfloat16)
+    loss = lambda p, x: jnp.sum(
+        hybrid_lm.mix(p, x, jnp.arange(s), "mla", model).astype(jnp.float32))
+    with lowering_for_the_chip(pallas_attention):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*on_chip((p, x))).compile().as_text()
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert f"%{name}" in text, name
+    forward = re.findall(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* .*"
+                         r'op_name="[^"]*/jvp\(mla_attention\)/rotary/dot_general"',
+                         text, flags=re.M)
+    # a fusion and the product inside it both carry the name: as many at the key's own
+    # shape as at the queries', and none at any other
+    elements = [math.prod(map(int, shape.split(","))) for shape in forward]
+    assert sorted(set(elements)) == [b * s * 64, b * s * h * 64], forward
+    assert elements.count(b * s * 64) == elements.count(b * s * h * 64), forward

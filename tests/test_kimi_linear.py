@@ -293,7 +293,7 @@ def test_the_mixer_runs_latent_attention_through_the_dispatcher(monkeypatch):
     u = jax.random.normal(jax.random.PRNGKey(1), (2, SEQ, 32))
     p = params["layer_1"]["mla"]
     with jax.default_matmul_precision("highest"):
-        got = hybrid_lm.mla_mixer(p, u, model)
+        got = hybrid_lm.mla_mixer(p, u, jnp.arange(SEQ), model)
         want = jax.vmap(lambda row: ref.mla_mixer(p, row, config, MM, ES))(u)
     np.testing.assert_allclose(got, want, atol=3e-5 * float(jnp.abs(want).max()))
 

@@ -84,3 +84,100 @@ def test_lm_rope_decode_matches_full_forward():
                                           jnp.asarray(t, jnp.int32))
         np.testing.assert_allclose(np.asarray(log_probs), np.asarray(ref[:, t]),
                                    rtol=1e-5, atol=1e-5, err_msg=f"position {t}")
+
+
+# -- the interleaved pairing (a deepseek_v3 checkpoint's decoupled rotary channels) ----------
+
+
+def _published_interleaved(x, positions, base):
+    """The published ``deepseek_v3`` code's rotation of interleaved channels, written out:
+    each pair's halves moved apart (``x[0::2] | x[1::2]``), then ``x·cos + rotate_half(x)·sin``
+    with the angles repeated over both halves."""
+    d = x.shape[-1]
+    x = x.reshape(*x.shape[:-1], d // 2, 2).swapaxes(-1, -2).reshape(x.shape)
+    inv_freq = base ** (-np.arange(0, d, 2, dtype=np.float32) / d)
+    angles = np.asarray(positions, np.float32)[:, None] * inv_freq
+    angles = jnp.asarray(np.concatenate([angles, angles], axis=-1))[:, None, :]
+    rotate_half = jnp.concatenate([-x[..., d // 2:], x[..., :d // 2]], axis=-1)
+    return x * jnp.cos(angles) + rotate_half * jnp.sin(angles)
+
+
+def _qk(seed, heads=(3, 1), width=16):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.normal(size=(2, 8, n, width)).astype(np.float32)) for n in heads)
+
+
+@pytest.mark.parametrize("base", [10000.0, 1e6])
+def test_interleaved_pairing_gives_the_published_forms_scores(base):
+    """Pairs turned where they lie against pairs moved apart and turned half-split: the
+    rotated channels differ by one permutation, the same on both sides, so every q·k is the
+    same (three query heads against one shared key)."""
+    q, k = _qk(5)
+    pos = jnp.arange(8) + 3
+    ours = jnp.einsum("bqhd,bkgd->bhqk", apply_rotary(q, pos, base=base, interleaved=True),
+                      apply_rotary(k, pos, base=base, interleaved=True))
+    theirs = jnp.einsum("bqhd,bkgd->bhqk", _published_interleaved(q, pos, base),
+                        _published_interleaved(k, pos, base))
+    np.testing.assert_allclose(np.asarray(ours), np.asarray(theirs), rtol=1e-5, atol=1e-5)
+    # and the channels themselves are the published ones, moved back together
+    moved = _published_interleaved(q, pos, base)
+    back = moved.reshape(*q.shape[:-1], 2, q.shape[-1] // 2).swapaxes(-1, -2).reshape(q.shape)
+    np.testing.assert_allclose(np.asarray(apply_rotary(q, pos, base=base, interleaved=True)),
+                               np.asarray(back), rtol=1e-5, atol=1e-5)
+
+
+def test_interleaved_pairing_is_not_the_half_split_one():
+    q, k = _qk(6)
+    pos = jnp.arange(8)
+    scores = lambda **kw: jnp.einsum("bqhd,bkgd->bhqk", apply_rotary(q, pos, **kw),
+                                     apply_rotary(k, pos, **kw))
+    assert float(jnp.abs(scores(interleaved=True) - scores()).max()) > 0.1
+
+
+@pytest.mark.parametrize("interleaved", [False, True], ids=["half-split", "interleaved"])
+def test_shift_invariance_over_a_part_of_a_heads_channels(interleaved):
+    """A latent-attention head: 16 channels carried as they are, the last 8 rotated. The
+    score's rotated part depends on p − p' alone, so the whole score does."""
+    rng = np.random.default_rng(7)
+    q = jnp.asarray(rng.normal(size=(1, 8, 2, 24)).astype(np.float32))
+    k = jnp.asarray(rng.normal(size=(1, 8, 2, 24)).astype(np.float32))
+
+    def scores(shift):
+        pos = jnp.arange(8) + shift
+        turn = lambda x: jnp.concatenate(
+            [x[..., :16], apply_rotary(x[..., 16:], pos, base=1e6, interleaved=interleaved)],
+            axis=-1)
+        return jnp.einsum("bqhd,bkhd->bhqk", turn(q), turn(k))
+
+    np.testing.assert_allclose(np.asarray(scores(0)), np.asarray(scores(1000)),
+                               rtol=2e-4, atol=2e-4)
+    # the angles are over the 8 channels handed, not over the head's 24
+    alone = apply_rotary(q[..., 16:], jnp.arange(8), base=1e6, interleaved=interleaved)
+    whole = apply_rotary(q, jnp.arange(8), base=1e6, interleaved=interleaved)[..., 16:]
+    assert float(jnp.abs(alone - whole).max()) > 1e-3
+
+
+def test_the_half_split_path_is_unchanged_to_the_bit():
+    """``interleaved=False`` is the function as it stood: the formula written out again
+    here, bit for bit, in float32 and in bfloat16."""
+    rng = np.random.default_rng(8)
+    for dtype in (jnp.float32, jnp.bfloat16):
+        x = jnp.asarray(rng.normal(size=(2, 8, 4, 16)).astype(np.float32)).astype(dtype)
+        pos = jnp.arange(8)
+        inv_freq = 1e4 ** (-jnp.arange(0, 16, 2, dtype=jnp.float32) / 16)
+        ang = (pos.astype(jnp.float32)[..., None] * inv_freq)[:, None, :]
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        xf = x.astype(jnp.float32)
+        x1, x2 = xf[..., :8], xf[..., 8:]
+        want = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(dtype)
+        for got in (apply_rotary(x, pos), apply_rotary(x, pos, interleaved=False)):
+            assert got.dtype == dtype
+            np.testing.assert_array_equal(np.asarray(got.astype(jnp.float32)),
+                                          np.asarray(want.astype(jnp.float32)))
+
+
+def test_interleaved_scalar_position_matches_indexed_row():
+    x = jnp.asarray(np.random.default_rng(9).normal(size=(2, 8, 4, 16)).astype(np.float32))
+    full = apply_rotary(x, jnp.arange(8), base=1e6, interleaved=True)
+    row = apply_rotary(x[:, 5], jnp.asarray(5, jnp.int32), base=1e6, interleaved=True)
+    np.testing.assert_allclose(np.asarray(row), np.asarray(full[:, 5]), rtol=1e-6, atol=1e-6)
