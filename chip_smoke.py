@@ -26,7 +26,7 @@ phase                     what it runs and checks
                           token-identical, shards on four distinct devices)
 ``kernels``               each Pallas kernel compiled (Mosaic custom call found
                           in the compiled program) against its in-repo
-                          reference: flash fwd + both backwards at S=2048,
+                          reference: flash fwd + the fused backward at S=2048,
                           D=128, bf16, causal; ``paged_attend`` at G=2, R=4,
                           D=128, page 64, fp32 and int8+scales; fused NLL/SGD
                           through ``train.single --use-pallas-kernels``
@@ -289,7 +289,7 @@ def phase_server(work: str) -> dict:
 
 
 def _check_flash() -> dict:
-    """Flash forward and both backwards through ``dispatch_attention`` at the
+    """Flash forward and the fused backward through ``dispatch_attention`` at the
     geometry hw_r5 measured (S=2048, D=128, bf16, causal) vs the dense oracle,
     at the tolerances of tests/test_pallas_attention.py's TPU-gated test."""
     import jax
@@ -322,9 +322,9 @@ def _check_flash() -> dict:
     bwd = jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2))).lower(q, k, v).compile()
     compile_s = time.perf_counter() - t0
     calls = {"fwd": _mosaic_calls(fwd), "fwd+bwd": _mosaic_calls(bwd)}
-    _require(calls["fwd"] >= 1 and calls["fwd+bwd"] >= 3,
+    _require(calls["fwd"] >= 1 and calls["fwd+bwd"] >= 2,
              f"flash: compiled programs hold {calls} Mosaic custom calls; "
-             f"expected the forward kernel, and forward + dq + dkv")
+             f"expected the forward kernel, and forward + the fused backward")
     t0 = time.perf_counter()
     out = np.asarray(fwd(q, k, v).astype(jnp.float32))
     grads = [np.asarray(g.astype(jnp.float32)) for g in bwd(q, k, v)]

@@ -23,8 +23,9 @@ by window, so a block of it is whole, cut at one column or dead, never triangula
 outputs are joined by ``lse = logaddexp(lse_local, lse_remote)``. The backward pass takes
 no cotangent on a statistic: the op has one rule (``_make_op``) whose backward hands the
 joint ``lse`` and ``Δ = rowsum(do ∘ o)`` to ``flash_backward_blocks`` (written for the ring
-schedules: the statistics are the whole row's, the keys a part of it) and to ``eva_dq`` /
-``eva_dkv``, the same two-kernel recompute formulation over the summaries. The summaries'
+schedules: the statistics are the whole row's, the keys a part of it; one fused kernel
+at a window's size, ``flash_dkv`` on a trace) and to ``eva_dq`` / ``eva_dkv``, the
+two-kernel recompute formulation over the summaries. The summaries'
 own gradient (φ, μ, and k, v through the pooling) is autodiff's, outside the kernels.
 
 A window that is no multiple of 128 (the CPU tests' sizes) takes ``dense_attention``,

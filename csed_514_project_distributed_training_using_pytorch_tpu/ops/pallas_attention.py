@@ -22,11 +22,20 @@ Kernel layout (FlashAttention-2 style, in the canonical Pallas-TPU grid formulat
   (``run_scoped`` + ``make_async_copy`` double buffering) wedged this environment's AOT
   Mosaic compile helper the same way the (since-retired) whole-model fused CNN kernel
   did — the grid formulation compiles in seconds.
-- **Backward**: the standard two-kernel recompute formulation — no O(S²) residuals, only
-  ``(out, lse = m + log l)``. A ``dq`` kernel re-walks K/V blocks per query block; a
-  ``dk/dv`` kernel walks query/dout blocks per key block; both recompute
-  ``p = exp(q·kᵀ·scale − lse)`` blockwise and apply ``ds = p ∘ (dout·vᵀ − Δ)`` with
-  ``Δ = rowsum(dout ∘ out)`` computed once outside the kernels (XLA fuses it).
+- **Backward**: the recompute formulation — no O(S²) residuals, only
+  ``(out, lse = m + log l)`` — as ONE kernel (``flash_dkv`` on a trace): the grid walks
+  query/dout blocks per key block, recomputes ``p = exp(q·kᵀ·scale − lse)`` and
+  ``ds = p ∘ (dout·vᵀ − Δ)`` once a block pair, and feeds all three gradients from them
+  (five products a pair). dk and dv accumulate in a key block's float32 scratch; dq of
+  the program's whole (batch, head) stays resident in VMEM, ``[S, D]`` float32 (6.3 MB
+  at S 8192 × D 192) beside its ``[S, D]`` output block in the operands' dtype, each
+  live pair adding ``ds · k`` at its query block's rows; it is zeroed at the program's
+  first step and leaves scaled and narrowed at its last, so no float32 dq passes
+  through HBM. ``Δ = rowsum(dout ∘ out)`` is computed once outside (XLA fuses it).
+  Past ``FUSED_DQ_MAX_BYTES`` of resident dq (S·D·4 > 16 MiB: S 32768 at D 192, S 65536
+  at D 128) the two split kernels run instead, whatever the walk: ``flash_dq`` re-walks
+  K/V blocks per query block and ``flash_dkv`` makes dk and dv alone, each computing
+  the scores again (seven products a pair); ``backward_fused`` is the one predicate.
 - **Causal/banded dead blocks** cost no FLOPs (``@pl.when`` skip) and — r5 — no fetch
   either: the full walks clamp their index maps onto the nearest live block
   (``_elided_key_idx``), and Pallas skips the copy when consecutive steps request the
@@ -121,6 +130,16 @@ FLASH_MIN_HEAD_SCORE_BYTES = 1 << 20    # S_q·S_k·4 of ONE (batch, head), = S 
                        # shape; S = 384: 0.86× at 75 MB; S = 512: level at 67 MB,
                        # 2.6-2.7× at 134). S = 384 above 128 MiB is untested and takes
                        # the dense side
+
+
+FUSED_DQ_MAX_BYTES = 16 << 20   # S·D·4 of one (batch, head): the float32 dq the fused
+                       # backward keeps in VMEM across its whole walk. 16 MiB is S 32768
+                       # at D 128; the cells hold 6.3 MB (S 8192, D 192), 2.1 (S 8192,
+                       # D 64), 1 (EVA's windows of 2048 at 128) and 0.46 (S 896, D 128)
+
+FUSED_VMEM_LIMIT = 100 << 20    # scoped VMEM of the fused backward, of the chip's 128 MiB
+                       # (as ops/moe.py's): at the budget the resident dq, its output
+                       # block twice and a block pair's float32 tiles are 56 MB
 
 
 def auto_block(s: int, window: int = 0) -> int:
@@ -289,30 +308,33 @@ def _dyn_banded(window: int, nq: int, block: int) -> bool:
 
 
 def _pallas_dispatch(kernel, grid: tuple, in_specs, out_specs, out_shape,
-                     scratch_shapes, dyn: bool):
+                     scratch_shapes, dyn: bool, vmem_limit: int | None = None):
     """One owner for the dyn/static ``pallas_call`` shape (fwd, dq, and dkv all
     dispatch through here): traced offsets ride scalar prefetch
     (``PrefetchScalarGridSpec`` — the scalar is the first operand and reaches the
-    index maps as their trailing arg), static paths use the plain grid."""
+    index maps as their trailing arg), static paths use the plain grid.
+    ``vmem_limit`` raises the kernel's scoped VMEM above the compiler's default (the
+    fused backward's resident dq)."""
     # The kernel's name on a device trace: flash_fwd, flash_dq, flash_dkv.
     name = "flash" + kernel.func.__name__.removesuffix("_kernel")
+    kw = dict(out_shape=out_shape, interpret=_interpret(), name=name)
+    if vmem_limit is not None:
+        kw["compiler_params"] = pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)
     if dyn:
         return pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=grid,
                 in_specs=in_specs, out_specs=out_specs,
-                scratch_shapes=scratch_shapes),
-            out_shape=out_shape, interpret=_interpret(), name=name)
+                scratch_shapes=scratch_shapes), **kw)
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs,
-        out_specs=out_specs, out_shape=out_shape, scratch_shapes=scratch_shapes,
-        interpret=_interpret(), name=name)
+        out_specs=out_specs, scratch_shapes=scratch_shapes, **kw)
 
 
 def _dispatch_block(body, qi, ki, bq, bk, in_range, *, causal: bool,
                     window: int, q_offset):
-    """Shared liveness/interior gating for all three kernels (fwd/dq/dkv):
+    """Shared liveness/interior gating for all the kernels (fwd/dq/dkv, fused or not):
     ``body(masked)`` runs only for live blocks, and fully-visible interior blocks
     take the mask-free specialization. One owner — an edit to the gating cannot
     desynchronize forward and backward masking."""
@@ -494,7 +516,10 @@ def _flash_forward(qx, kx, vx, *, causal: bool, block: int = BLOCK,
 
 
 # =========================================================================================
-# Backward (recompute formulation: residuals are out + lse only)
+# Backward (recompute formulation: residuals are out + lse only). One kernel,
+# ``_dkv_kernel(fused=True)``, makes dq, dk and dv from one set of scores a block pair;
+# dq's [S, D] float32 of a (batch, head) is resident in VMEM (``FUSED_DQ_MAX_BYTES``).
+# Past that budget ``_dq_kernel`` and the plain ``_dkv_kernel`` run as two calls.
 # =========================================================================================
 
 
@@ -554,15 +579,26 @@ def _dq_kernel(*refs, scale, causal, num_steps, num_blocks,
 
 
 def _dkv_kernel(*refs, scale, causal, num_steps, num_blocks,
-                band_base=None, window=0, q_offset=0, dyn_offset=False):
+                band_base=None, window=0, q_offset=0, dyn_offset=False, fused=False):
+    # ``fused``: the one-kernel backward. dq of the program's whole (batch, head)
+    # stays in VMEM beside dk and dv (``dq_acc_ref`` [S, D] float32, ``dq_ref`` its
+    # [S, D] output block, whose index the two inner grid axes do not move), and
+    # each live block pair adds ``ds · k`` at its query block's rows; without it
+    # ``_dq_kernel`` makes dq in a walk of its own.
     if dyn_offset:                      # traced hop offset (see _fwd_kernel)
         off_ref, refs = refs[0], refs[1:]
         q_offset = off_ref[0]
-    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-     dk_acc_ref, dv_acc_ref) = refs
+    if fused:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+         dq_acc_ref, dk_acc_ref, dv_acc_ref) = refs
+        rows = lambda r: pl.ds(pl.multiple_of(r * bq, bq), bq)   # query block r of [S, D]
+    else:
+        (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
+         dk_acc_ref, dv_acc_ref) = refs
     ik = pl.program_id(1)
     step = pl.program_id(2)
     bk = k_ref.shape[0]
+    bq = q_ref.shape[0]
     # Banded: the step axis walks QUERY-block offsets around this key block
     # (causal keys are only visible to queries at or after them, so offsets start
     # at the diagonal: band_base == 0). A hop offset shifts the visible query
@@ -579,10 +615,18 @@ def _dkv_kernel(*refs, scale, causal, num_steps, num_blocks,
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
+    if fused:
+        @pl.when((ik == 0) & (step == 0))
+        def _():
+            @pl.loop(0, num_blocks)
+            def _(r):
+                dq_acc_ref[rows(r), :] = jnp.zeros((bq, dq_acc_ref.shape[1]),
+                                                   jnp.float32)
+
     def body(masked: bool):
         # Same precision split as the dq kernel: operands in the input dtype,
         # f32 accumulation, p/ds narrowed only at the matmul boundary.
-        visible = (_visibility_mask(i, ik, q_ref.shape[0], bk, causal=causal,
+        visible = (_visibility_mask(i, ik, bq, bk, causal=causal,
                                     window=window, q_offset=q_offset)
                    if masked else None)
         k = k_ref[:]                                              # [bk, D]
@@ -610,16 +654,37 @@ def _dkv_kernel(*refs, scale, causal, num_steps, num_blocks,
             preferred_element_type=jnp.float32)
         dv_acc_ref[:] = dv_acc_ref[:] + dv_upd
         dk_acc_ref[:] = dk_acc_ref[:] + dk_upd
+        if fused:
+            # dq[i] += ds · k: key blocks arrive in ascending order, as the dq
+            # kernel's walk sums them.
+            dq_acc_ref[rows(i), :] = dq_acc_ref[rows(i), :] + jnp.dot(
+                ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
 
     # Causal/banded: query blocks with no visible pair against this key block skip;
     # fully-visible interior blocks skip the mask chain (see _fwd_kernel).
-    _dispatch_block(body, i, ik, q_ref.shape[0], bk, in_range, causal=causal,
+    _dispatch_block(body, i, ik, bq, bk, in_range, causal=causal,
                     window=window, q_offset=q_offset)
 
     @pl.when(step == num_steps - 1)
     def _():
         dk_ref[:] = (dk_acc_ref[:] * scale).astype(dk_ref.dtype)
         dv_ref[:] = dv_acc_ref[:].astype(dv_ref.dtype)
+
+    if fused:
+        @pl.when((ik == num_blocks - 1) & (step == num_steps - 1))
+        def _():
+            @pl.loop(0, num_blocks)
+            def _(r):
+                dq_ref[rows(r), :] = (dq_acc_ref[rows(r), :] * scale).astype(
+                    dq_ref.dtype)
+
+
+def backward_fused(s: int, d: int) -> bool:
+    """Whether the backward of ``[S, D]`` queries a (batch, head) is the one fused
+    kernel: its float32 dq fits the resident budget (``FUSED_DQ_MAX_BYTES``). The one
+    predicate: ``flash_backward_blocks`` runs what this says and ``dispatch_plan``
+    reports it."""
+    return 4 * s * d <= FUSED_DQ_MAX_BYTES
 
 
 def _flash_backward(res, g, *, causal: bool, block: int = BLOCK,
@@ -711,27 +776,34 @@ def flash_backward_blocks(qx, kx, vx, g, lse, delta, *, causal: bool,
     acc, acc_v = (pltpu.VMEM((block, w), jnp.float32) for w in (d, dv))
     dyn_args = ((jnp.asarray(q_offset_dyn, jnp.int32).reshape(1),) if dyn else ())
 
-    def call(kernel_fn, base, steps, in_specs, out_specs, out_shape, scratch):
+    def call(kernel_fn, base, steps, in_specs, out_specs, out_shape, scratch,
+             vmem_limit=None):
         kernel = functools.partial(kernel_fn, scale=scale, causal=causal,
                                    num_steps=steps, num_blocks=nq, band_base=base,
                                    window=window, q_offset=q_offset,
                                    dyn_offset=dyn)
         return _pallas_dispatch(kernel, (bh, nq, steps), in_specs, out_specs,
-                                out_shape, scratch, dyn)(
+                                out_shape, scratch, dyn, vmem_limit)(
             *dyn_args, qx, kx, vx, g, lse, delta)
+
+    # dkv grid: the query-block axis walks (accumulators persist per key block).
+    kv_idx = _walk_idx(kv_base, -off_blocks, kv=True)
+    kv_lse_walk = _spec((1, 1, block), kv_idx, dyn)
+    kv_in = [_spec((block, d), kv_idx, dyn), row_spec, row_spec_v,
+             _spec((block, dv), kv_idx, dyn), kv_lse_walk, kv_lse_walk]
+    if backward_fused(s, d):
+        # One kernel: dq's whole [S, D] of a (batch, head) rides the dkv walk.
+        return call(functools.partial(_dkv_kernel, fused=True), kv_base, kv_steps,
+                    kv_in, [_spec((s, d), lambda *_: 0, dyn), row_spec, row_spec_v],
+                    [out_like(qx), out_like(kx), out_like(vx)],
+                    [pltpu.VMEM((s, d), jnp.float32), acc, acc_v], FUSED_VMEM_LIMIT)
 
     dq_idx = _walk_idx(dq_base, off_blocks)
     dq = call(_dq_kernel, dq_base, dq_steps,
               [row_spec, _spec((block, d), dq_idx, dyn), _spec((block, dv), dq_idx, dyn),
                row_spec_v, lse_row_spec, lse_row_spec],
               [row_spec], [out_like(qx)], [acc])[0]
-
-    # dkv grid: the query-block axis walks (accumulators persist per key block).
-    kv_idx = _walk_idx(kv_base, -off_blocks, kv=True)
-    kv_lse_walk = _spec((1, 1, block), kv_idx, dyn)
-    dk, dvx = call(_dkv_kernel, kv_base, kv_steps,
-                   [_spec((block, d), kv_idx, dyn), row_spec, row_spec_v,
-                    _spec((block, dv), kv_idx, dyn), kv_lse_walk, kv_lse_walk],
+    dk, dvx = call(_dkv_kernel, kv_base, kv_steps, kv_in,
                    [row_spec, row_spec_v], [out_like(kx), out_like(vx)], [acc, acc_v])
     return dq, dk, dvx
 
@@ -803,7 +875,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``causal=True``: a causal call of any other length is zero-padded at the tail to
     the next multiple of ``block``, run through the kernels and sliced, which the
     mask makes exact. ``block=None`` (the default) picks the measured-fastest size
-    for the shape via ``auto_block``. Differentiable via the two-kernel flash backward; usable as the
+    for the shape via ``auto_block``. Differentiable via the flash backward kernel; usable as the
     transformer family's ``attention_fn``. ``block`` is a pure performance knob
     (numerics are block-invariant — pinned in tests); tune it with
     ``bench_attention.py --block``. The kernels take operands packed
@@ -853,7 +925,7 @@ def dispatch_plan(shape, *, causal: bool = False, window: int | None = None,
                   k_len: int | None = None, value_dim: int | None = None) -> dict:
     """What ``dispatch_attention`` does with a per-device ``[B, S, H, D]`` call (values
     of ``value_dim`` channels where that is not ``D``), from its shapes alone: ``{impl,
-    score_bytes, seq_padded, block, key_dim, value_dim}``. The one
+    score_bytes, seq_padded, block, key_dim, value_dim, backward}``. The one
     routing predicate: the dispatcher runs what this returns, and callers that label
     a measurement or a telemetry event (``train/lm.py``'s ``compile`` event,
     ``bench_transformer.py``, ``chip_smoke.py``) read the same dict, so a label
@@ -867,17 +939,20 @@ def dispatch_plan(shape, *, causal: bool = False, window: int | None = None,
     mask (padded at the tail, ``seq_padded``). Everything else is ``"dense"``.
     Under ``jit`` over a mesh the shapes a trace sees are global: callers there
     hand the dispatcher per-device calls (``shard_map``) or keep the dense core
-    (``train/lm.py``)."""
+    (``train/lm.py``). ``backward`` is ``"fused"`` where the flash backward is its one
+    kernel, ``"split"`` where the padded ``[S, D]`` is past the resident dq's budget
+    (``backward_fused``) and ``None`` for a dense plan."""
     b, s, h, d = shape
     s_k = s if k_len is None else k_len
     plan = {"impl": "dense", "score_bytes": 4 * b * h * s * s_k,
             "seq_padded": None, "block": None, "key_dim": d,
-            "value_dim": d if value_dim is None else value_dim}
+            "value_dim": d if value_dim is None else value_dim, "backward": None}
     if (plan["score_bytes"] >= FLASH_MIN_SCORE_BYTES
             and 4 * s * s_k >= FLASH_MIN_HEAD_SCORE_BYTES
             and s_k == s and (causal or s % BLOCK == 0)):
         padded, block = _flash_plan(s, causal=causal, window=window)
-        plan.update(impl="flash", seq_padded=padded, block=block)
+        plan.update(impl="flash", seq_padded=padded, block=block,
+                    backward="fused" if backward_fused(padded, d) else "split")
     return plan
 
 

@@ -83,7 +83,7 @@ def _attention_plan(config: LMConfig, seq_len: int, world: int, *,
          head_dim or config.embed_dim // heads),
         causal=True, window=config.attention_window, value_dim=value_dim)
     if not dispatched:
-        plan.update(impl="dense", seq_padded=None, block=None)
+        plan.update(impl="dense", seq_padded=None, block=None, backward=None)
     return plan
 
 

@@ -358,7 +358,7 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
                   eva: dict | None = None, scopes: dict | None = None) -> dict:
     """The ``compile`` event for one AOT-timed program. ``attention``: which core the
     program's attention calls get and why (``ops.dispatch_plan``'s dict: ``impl``,
-    ``score_bytes``, ``seq_padded``, ``block``), for the trainers that
+    ``score_bytes``, ``seq_padded``, ``block``, ``backward``), for the trainers that
     route through the dispatcher; for a model from a configuration file also
     ``HybridLM.rotary_plan``'s ``rope_dim``, ``rope_pairing`` (``half_split`` or
     ``interleaved``) and ``rope_theta``, None each where the attention has no positions. ``experts``: what a step asks of each sparse expert

@@ -125,6 +125,33 @@ def test_dispatch_predicate_on_recorded_shapes(shape, causal, impl):
         assert plan["seq_padded"] % plan["block"] == 0
 
 
+# The plan's ``backward``: "fused" where one (batch, head)'s float32 dq, padded S x key
+# width x 4 bytes, fits the budget the fused kernel keeps resident (every cell), "split"
+# past it, None for a dense plan.
+_BACKWARD = [
+    ("kanana2-and-kimi-S8192-keys192", (2, 8192, 32, 192), 128, "fused"),
+    ("lfm2-S8192-d64", (4, 8192, 32, 64), None, "fused"),
+    ("nemotron-S8192-4-heads", (2, 8192, 4, 128), None, "fused"),
+    ("lm-b16-S784-padded-896", (16, 784, 8, 128), None, "fused"),
+    ("evabyte-windows-of-2048", (16, 2048, 16, 128), None, "fused"),
+    ("budget-edge-S32768-d128", (1, 32768, 8, 128), None, "fused"),
+    ("past-the-budget-S32768-keys192", (1, 32768, 8, 192), 128, "split"),
+    ("past-the-budget-S65536-d128", (1, 65536, 8, 128), None, "split"),
+    ("tier1-tiny-dense", (8, 784, 2, 16), None, None),
+]
+
+
+@pytest.mark.parametrize("shape,value_dim,backward", [c[1:] for c in _BACKWARD],
+                         ids=[c[0] for c in _BACKWARD])
+def test_dispatch_plan_names_the_backward(shape, value_dim, backward):
+    assert pa.FUSED_DQ_MAX_BYTES == 4 * 32768 * 128 == 16 << 20
+    plan = pa.dispatch_plan(shape, causal=True, value_dim=value_dim)
+    assert plan["backward"] == backward
+    if backward is not None:
+        assert (4 * plan["seq_padded"] * shape[-1] <= pa.FUSED_DQ_MAX_BYTES) == (
+            backward == "fused") == pa.backward_fused(plan["seq_padded"], shape[-1])
+
+
 @pytest.mark.parametrize("widths", [32, (192, 128)], ids=["d32", "keys192-values128"])
 def test_dispatch_plan_is_what_the_dispatcher_runs(monkeypatch, widths):
     """The plan's block and padded length are the ones ``flash_attention`` is
@@ -238,7 +265,7 @@ def test_compile_event_reports_dense_for_a_tier1_sized_run(tmp_path, mesh):
     assert event["attention"] == {"impl": "dense", "score_bytes": 4 * 8 * 2 * 784 * 784
                                   // (1 if mesh else jax.device_count()),
                                   "seq_padded": None, "block": None,
-                                  "key_dim": 8, "value_dim": 8}
+                                  "key_dim": 8, "value_dim": 8, "backward": None}
 
 
 def test_compile_event_reports_flash_for_the_cells_shapes():
@@ -266,6 +293,8 @@ def test_compile_event_reports_flash_for_the_cells_shapes():
                             steps_per_call=32, attention=plan)
     assert event["attention"]["impl"] == "flash"
     assert event["attention"]["block"] == plan["block"]
+    assert event["attention"]["backward"] == latent["backward"] == "fused"
     kept = _attention_plan(config, 784, 4, dispatched=False)
     assert kept["impl"] == "dense" and kept["score_bytes"] == 314703872 // 4
+    assert kept["backward"] is None
     assert kept["block"] is None

@@ -117,7 +117,8 @@ def test_the_lfm2_step_keeps_its_flash_forward_and_fits_the_v5e(one_chip):
     (value, gradient, clip, AdamW; bf16, per-block recomputation on), compiled for the
     described chip: what recomputation keeps leaves arguments + temporaries under
     15.0 GB of the chip's 15.75, and the differentiated step calls ``flash_fwd``
-    once for its one attention layer, not again in the backward pass."""
+    once for its one attention layer, not again in the backward pass, which is the one
+    fused kernel (``flash_dkv``; no ``flash_dq``)."""
     import re
     from csed_514_project_distributed_training_using_pytorch_tpu import ops
     from csed_514_project_distributed_training_using_pytorch_tpu.models import hybrid_lm
@@ -151,7 +152,7 @@ def test_the_lfm2_step_keeps_its_flash_forward_and_fits_the_v5e(one_chip):
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes <= 15.0e9
     calls = lambda kernel: len(re.findall(rf"%{kernel}[.\d]* = ", compiled.as_text()))
-    assert (calls("flash_fwd"), calls("flash_dq"), calls("flash_dkv")) == (1, 1, 1)
+    assert (calls("flash_fwd"), calls("flash_dq"), calls("flash_dkv")) == (1, 0, 1)
 
 
 # NVIDIA-Nemotron-3-Super-120B-A12B, chip 0 of 64: 16 Mamba-2 heads of 64 in one group with
@@ -276,9 +277,10 @@ def test_a_delta_rule_layer_never_leaves_the_flat_layout(one_chip):
 
 
 def test_flash_kernels_compile_for_the_v5e_at_latent_attentions_widths(one_chip):
-    """``flash_fwd``, ``flash_dq`` and ``flash_dkv`` with keys of 192 channels and values
-    of 128 (a lane register and a half against one): the output and ``dv`` are 128 wide,
-    ``dq`` and ``dk`` 192, and nothing is padded to a common width."""
+    """``flash_fwd`` and the fused backward (``flash_dkv``, 6.3 MB of float32 dq resident a
+    (batch, head); no ``flash_dq``) with keys of 192 channels and values of 128 (a lane
+    register and a half against one): the output and ``dv`` are 128 wide, ``dq`` and
+    ``dk`` 192, and nothing is padded to a common width."""
     from csed_514_project_distributed_training_using_pytorch_tpu.ops import pallas_attention
     b, s, h = KDA["batch"], KDA["seq"], KDA["heads"]
     spec = lambda width: jax.ShapeDtypeStruct((b, s, h, width), jnp.bfloat16,
@@ -289,17 +291,40 @@ def test_flash_kernels_compile_for_the_v5e_at_latent_attentions_widths(one_chip)
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             spec(192), spec(192), spec(128)).compile()
     text = compiled.as_text()
-    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
-        assert f"%{name}" in text, name
+    assert pallas_attention.backward_fused(s, 192)
+    assert "%flash_fwd" in text and "%flash_dkv" in text and "%flash_dq" not in text
     widths = [x.shape[-1] for x in jax.tree.leaves(compiled.out_info)]
     assert widths == [192, 192, 128]       # dq, dk, dv
     assert f"bf16[{b * h},{s},256]" not in text
 
 
+@pytest.mark.parametrize("s,d,backward", [(32768, 128, "fused"), (32768, 192, "split")],
+                         ids=["budget-edge-fused", "past-the-budget-split"])
+def test_flash_backward_compiles_for_the_v5e_on_both_sides_of_the_budget(one_chip, s, d,
+                                                                          backward):
+    """The resident dq's budget, S x D x 4 <= 16 MiB, at its edge (the fused kernel holds
+    16 MiB of float32 dq, its bf16 output block twice and a block pair's tiles under the
+    100 MiB it asks for) and just past it (``flash_dq`` and ``flash_dkv`` apart)."""
+    from csed_514_project_distributed_training_using_pytorch_tpu.ops import pallas_attention
+    assert pallas_attention.backward_fused(s, d) == (backward == "fused")
+    block = pallas_attention.auto_block(s)
+    spec = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype,
+                                                                  sharding=one_chip)
+    stat = spec((2, s // block, 1, block), jnp.float32)
+    with lowering_for_the_chip(pallas_attention):
+        text = jax.jit(lambda *a: pallas_attention.flash_backward_blocks(
+            *a, causal=True, block=block)).lower(
+            spec((2, s, d)), spec((2, s, d)), spec((2, s, 128)), spec((2, s, 128)),
+            stat, stat).compile().as_text()
+    assert "%flash_dkv" in text
+    assert ("%flash_dq" in text) == (backward == "split")
+
+
 def test_eva_attention_kernels_compile_for_the_v5e_at_published_widths(one_chip):
     """One sequence of 32768 bytes, the 16 held heads of 128, windows of 2048 and chunks of
-    16: the local half's ``flash_*`` over ``[256, 2048, 128]`` and the remote half's
-    ``eva_fwd``, ``eva_dq``, ``eva_dkv`` over 2048 summaries, value and every gradient."""
+    16: the local half's ``flash_fwd`` and fused ``flash_dkv`` over ``[256, 2048, 128]`` and
+    the remote half's ``eva_fwd``, ``eva_dq``, ``eva_dkv`` over 2048 summaries, value and
+    every gradient."""
     from csed_514_project_distributed_training_using_pytorch_tpu.ops import (
         eva, pallas_attention,
     )
@@ -312,8 +337,9 @@ def test_eva_attention_kernels_compile_for_the_v5e_at_published_widths(one_chip)
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
             spec(s), spec(s), spec(s), spec(s // chunk), spec(s // chunk)).compile()
     text = compiled.as_text()
-    for name in ("flash_fwd", "flash_dq", "flash_dkv", "eva_fwd", "eva_dq", "eva_dkv"):
+    for name in ("flash_fwd", "flash_dkv", "eva_fwd", "eva_dq", "eva_dkv"):
         assert f"%{name}" in text, name
+    assert "%flash_dq" not in text
     assert [x.shape for x in jax.tree.leaves(compiled.out_info)] == \
         [(heads, s, d)] * 3 + [(heads, s // chunk, d)] * 2
 
@@ -345,8 +371,7 @@ def test_a_latent_attention_layer_rotates_the_shared_key_once(one_chip):
         hybrid_lm.mix(p, x, jnp.arange(s), "mla", model).astype(jnp.float32))
     with lowering_for_the_chip(pallas_attention):
         text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*on_chip((p, x))).compile().as_text()
-    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
-        assert f"%{name}" in text, name
+    assert "%flash_fwd" in text and "%flash_dkv" in text and "%flash_dq" not in text
     forward = re.findall(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* .*"
                          r'op_name="[^"]*/jvp\(mla_attention\)/rotary/dot_general"',
                          text, flags=re.M)

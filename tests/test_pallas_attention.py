@@ -1,9 +1,10 @@
 """Flash-attention Pallas kernels vs the dense oracle.
 
-Interpret-mode (CPU) tests pin exact numerics of the forward and the two-kernel
-recompute backward against ``ops.full_attention``; the TPU-gated test re-checks parity
-compiled through Mosaic on hardware (looser tolerance: TPU matmuls run f32 via bf16
-passes in both paths, so they differ from each other at ~1e-3).
+Interpret-mode (CPU) tests pin exact numerics of the forward and the recompute
+backward (its fused kernel, and the two split ones past its budget) against
+``ops.full_attention``; the TPU-gated test re-checks parity compiled through Mosaic on
+hardware (looser tolerance: TPU matmuls run f32 via bf16 passes in both paths, so they
+differ from each other at ~1e-3).
 """
 
 import jax
@@ -74,6 +75,44 @@ def test_gradients_match_dense(causal, window, head_dim):
     for name, a, b in zip("qkv", g_ref, g_flash):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    err_msg=name, **_tol(2e-4, 2e-5))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("s,block", [(256, 256), (512, 128)], ids=["one-block", "four-blocks"])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("head_dim", [64, 128, (192, 128)])
+def test_fused_backward_matches_dense_vjp_and_the_split_kernels(
+        monkeypatch, head_dim, causal, s, block, dtype):
+    """``flash_backward_blocks`` on its fused path (one kernel: dq resident in VMEM beside
+    dk and dv) against ``jax.vjp`` of the dense core on the same operands, and against
+    the two split kernels an oversize ``[S, D]`` still takes, to the last bit: the same
+    products on the same operands, each gradient's blocks summed in the same order."""
+    from csed_514_project_distributed_training_using_pytorch_tpu.ops import (
+        pallas_attention as pa,
+    )
+    q, k, v = (x.reshape(-1, s, x.shape[-1]).astype(dtype)
+               for x in _qkv(b=3, s=s, h=1, d=head_dim, seed=17))
+    g = jnp.asarray(np.random.default_rng(18).normal(size=v.shape), dtype)
+    assert pa.backward_fused(s, q.shape[-1])
+    out, lse = pa._flash_forward(q, k, v, causal=causal, block=block)
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1) \
+        .reshape(lse.shape)
+    backward = lambda: pa.flash_backward_blocks(q, k, v, g, lse, delta, causal=causal,
+                                                block=block)
+    fused = backward()
+    wide = lambda x: x.astype(jnp.float32)[:, :, None]          # [BH, S, 1, width]
+    _, vjp = jax.vjp(lambda q, k, v: full_attention(q, k, v, causal=causal),
+                     wide(q), wide(k), wide(v))
+    tol = _tol(2e-4, 2e-5) if dtype == "float32" else dict(rtol=0.1, atol=0.05)
+    for name, got, want in zip(("dq", "dk", "dv"), fused, vjp(wide(g))):
+        assert got.dtype == q.dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want[:, :, 0]),
+                                   err_msg=name, **tol)
+    monkeypatch.setattr(pa, "FUSED_DQ_MAX_BYTES", 4 * s * q.shape[-1] - 1)
+    assert not pa.backward_fused(s, q.shape[-1])
+    for name, got, want in zip(("dq", "dk", "dv"), fused, backward()):
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32), err_msg=name)
 
 
 def test_multi_block_sequence():
