@@ -70,7 +70,10 @@ predicts token ``t + 1``; there is no BOS id). With ``num_pred_heads`` P > 1 the
 one ``[d, P·vocab]`` matrix, head ``i`` of place ``t`` predicts token ``t + 1 + i``, and the
 loss is the mean over the heads and places that have a target. ``norm_unit_offset``
 reads a norm's leaf (``…_offset``, zero at the start) as ``1 + g``; ``fp32_residual`` keeps
-the residual stream in float32 between blocks whose matmuls run in ``dtype``.
+the residual stream in float32 between blocks whose matmuls run in ``dtype``, and each
+norm's output of such a stream stands behind an optimization barrier (``normed``: a
+row-tiled kernel pair for these norms was faster alone and 3.5 % slower in the step,
+``bench_results/hw_pr41/``).
 Parameters are a plain dict; ``init``
 and ``apply`` keep flax's calling convention so ``train/step.py`` builds the state
 as for any other model. ``remat`` recomputes each block in the backward pass from
@@ -234,12 +237,35 @@ class HybridLM:
         """A norm's leaf: its weight, or with ``norm_unit_offset`` what is added to one."""
         return "norm_offset" if self.norm_unit_offset else "norm_scale"
 
+    @property
+    def holds_norms(self) -> bool:
+        """Whether a norm's output is held behind a barrier: a float32 stream under
+        matmuls of a narrower ``dtype``."""
+        return self.fp32_residual and jnp.dtype(self.dtype).itemsize < 4
+
     def normed(self, x, p, which: str = ""):
         """``RMSNorm(x)`` by ``p``'s leaf ``<which>norm_scale`` (``<which>norm_offset``: weight
-        one plus the leaf), in ``dtype`` where the residual stream is float32."""
+        one plus the leaf), in ``dtype`` where the residual stream is float32. There the
+        output stands behind an optimization barrier, and so, by the barrier's transpose,
+        does its cotangent: left free, the compiler folded the norm's two backward
+        reductions over the float32 rows into the epilogue of the product that makes the
+        cotangent (14.4 ms where the product alone takes 8.2, six times a step of the
+        ``evabyte`` cell) and the scale and cast into the products that read the output
+        (PERF.md section 6, PR 41). A stream in ``dtype`` is left to the compiler, which
+        fuses its norms into their neighbours at 1-5 % of a step."""
         u = ops.rms_norm(x, p[which + self._norm_leaf], eps=self.norm_eps,
                          offset=1.0 if self.norm_unit_offset else 0.0)
-        return u.astype(self.dtype) if self.fp32_residual else u
+        if self.fp32_residual:
+            u = u.astype(self.dtype)
+        return jax.lax.optimization_barrier(u) if self.holds_norms else u
+
+    def norm_plan(self) -> dict:
+        """The ``compile`` event's ``norm`` field: how a step takes its norms of the
+        stream (``barrier``: each output, and its cotangent, materialised apart from the
+        products beside it; ``xla``: left to the compiler) and how many a forward pass."""
+        blocks = sum(kind in LAYER_KINDS for kind in self.layer_types)
+        return {"impl": "barrier" if self.holds_norms else "xla",
+                "calls": len(self.layer_types) + blocks + 1}
 
     def targets_per_seq(self, seq_len: int | None = None) -> int:
         """The (place, head) pairs of a sequence (of ``seq_len`` tokens) that have a

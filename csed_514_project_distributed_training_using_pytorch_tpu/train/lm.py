@@ -442,7 +442,7 @@ def main(config: LMConfig = LMConfig(), *,
                          recompute=model.recompute_plan(aot["jaxpr"]),
                          head_products=model.head_products(aot["jaxpr"], step_tokens),
                          ssm=model.ssm_plan(), kda=model.kda_plan(),
-                         eva=model.eva_plan()) if hybrid else {}
+                         eva=model.eva_plan(), norm=model.norm_plan()) if hybrid else {}
             tele.emit(T.compile_event("epoch", aot,
                                       steps_per_call=steps_per_epoch,
                                       attention=attention,

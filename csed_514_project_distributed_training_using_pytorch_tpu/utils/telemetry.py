@@ -355,7 +355,8 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
                   attention: dict | None = None, experts: dict | None = None,
                   recompute: dict | None = None, ssm: dict | None = None,
                   head_products: int | None = None, kda: dict | None = None,
-                  eva: dict | None = None, scopes: dict | None = None) -> dict:
+                  eva: dict | None = None, norm: dict | None = None,
+                  scopes: dict | None = None) -> dict:
     """The ``compile`` event for one AOT-timed program. ``attention``: which core the
     program's attention calls get and why (``ops.dispatch_plan``'s dict: ``impl``,
     ``score_bytes``, ``seq_padded``, ``block``, ``backward``), for the trainers that
@@ -375,7 +376,11 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
     kernels); None for a model with none. ``eva``: the same of each EVA layer
     (``ops.eva.attention_plan``: ``impl``, heads held, window and chunk, windows and
     summaries a sequence, the kernels' query and summary blocks, what recomputation
-    keeps); None for a model with none. ``head_products``:
+    keeps); None for a model with none. ``norm``: how a step takes its norms of the
+    residual stream (``HybridLM.norm_plan``: ``impl`` ``barrier`` where each output of a
+    float32 stream's norm, and its cotangent, stands behind an optimization barrier, else
+    ``xla``; ``calls`` a forward pass); None for a model that is not a ``HybridLM``.
+    ``head_products``:
     the matrix products of a step that touch the head's ``[T, vocab]`` logits
     (``HybridLM.head_products``: 3 when they are computed once); None for a model
     whose head is not counted. ``scopes``: where the table of each instruction's scope
@@ -401,6 +406,7 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
         "ssm": ssm,
         "kda": kda,
         "eva": eva,
+        "norm": norm,
         "head_products": head_products,
         "scopes": scopes,
         "scopes_s": _finite(aot.get("scopes_s")),

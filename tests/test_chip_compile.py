@@ -380,3 +380,39 @@ def test_a_latent_attention_layer_rotates_the_shared_key_once(one_chip):
     elements = [math.prod(map(int, shape.split(","))) for shape in forward]
     assert sorted(set(elements)) == [b * s * 64, b * s * h * 64], forward
     assert elements.count(b * s * 64) == elements.count(b * s * h * 64), forward
+
+
+def test_an_evabyte_blocks_norm_backward_is_in_no_products_epilogue(one_chip):
+    """One block of the benchmark's file at published widths (32768 bytes, 16 heads of 128,
+    5504 feed-forward columns), bfloat16 under a float32 stream, under ``jax.checkpoint``:
+    every norm's output stands behind a barrier, so no instruction that holds a product of
+    the backward pass also yields a norm's weight gradient ``f32[4096]`` (the parent's
+    ``fusion.2053``: the feed-forward norm's two reductions in the epilogue of the
+    ``[32768, 5504] x [5504, 4096]`` product, 14.4 ms on the chip for 8.2)."""
+    import re
+    from csed_514_project_distributed_training_using_pytorch_tpu import ops
+    from csed_514_project_distributed_training_using_pytorch_tpu.models import hybrid_lm
+    from csed_514_project_distributed_training_using_pytorch_tpu.ops import (
+        eva, pallas_attention,
+    )
+    s = 32768
+    config = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                          "benchmark", "configs", "evabyte-6.5b-tp2.json")
+    model = hybrid_lm.from_config_file(config, vocab_size=320, seq_len=s, dtype=jnp.bfloat16,
+                                       remat=True, attention_fn=ops.dispatch_attention)
+    assert model.norm_plan() == {"impl": "barrier", "calls": 13}
+    block = jax.checkpoint(
+        hybrid_lm.make_block(model, "eva", False),
+        policy=jax.checkpoint_policies.save_only_these_names(*model.kept))
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    p = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))["params"]["layer_0"]
+    x = jax.ShapeDtypeStruct((1, s, model.hidden_size), jnp.float32)
+    loss = lambda p, x: jnp.sum(block(p, x, jnp.arange(s))[0])
+    with lowering_for_the_chip(eva, pallas_attention):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*on_chip((p, x))).compile().as_text()
+    # an instruction's result (a fusion's may be a tuple) where its op_name is a product's
+    products = re.findall(r"^\s*(?:ROOT )?%\S+ = (.+?) (?:fusion|convolution)\(.*"
+                          r'op_name="[^"]*transpose\(jvp[^"]*/dot_general"', text, flags=re.M)
+    assert len(products) >= 10, "the backward pass's products carry their op_name"
+    assert not [result for result in products if "f32[4096]" in result], products

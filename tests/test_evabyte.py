@@ -216,6 +216,29 @@ def test_the_residual_stream_is_float32_under_bfloat16_matmuls():
     assert plain._blocks(params, ids)[0].dtype == jnp.bfloat16
 
 
+def test_a_float32_stream_under_bfloat16_holds_every_norms_output(monkeypatch):
+    """Two layers, bfloat16 under the float32 stream: five norms a forward pass, each
+    output behind an optimization barrier (and, in the gradient, each cotangent); the
+    head's own barriers aside, a float32 model has none. Loss and every gradient are those
+    of the same model with the barrier taken out: it moves when a value is written, not
+    what is written."""
+    config = tiny_config()
+    model, params = build(config, dtype=jnp.bfloat16, remat=True)
+    ids = tokens()
+    count = lambda fn, *a: str(jax.make_jaxpr(fn)(*a)).count("optimization_barrier")
+    blocks = lambda m: lambda p: jnp.sum(m._blocks(p, ids)[0])
+    assert model.norm_plan() == {"impl": "barrier", "calls": 5}
+    assert count(blocks(model), params) == 4                    # two a layer
+    assert count(blocks(build(config)[0]), params) == 0         # float32 matmuls: none
+    (loss, _), grads = jax.value_and_grad(model.loss, has_aux=True)(params, ids)
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+    assert count(blocks(model), params) == 0
+    (plain, _), plain_grads = jax.value_and_grad(model.loss, has_aux=True)(params, ids)
+    assert float(loss) == pytest.approx(float(plain), rel=1e-6)
+    assert worst(grads, plain_grads) < 1e-5
+    assert all(g.dtype == jnp.float32 for g in jax.tree_util.tree_leaves(grads))
+
+
 def test_a_norm_leaf_is_what_is_added_to_one():
     x = jax.random.normal(jax.random.PRNGKey(0), (3, 8))
     g = 0.1 * jax.random.normal(jax.random.PRNGKey(1), (8,))
@@ -330,7 +353,10 @@ def test_a_sequence_that_is_not_whole_windows_and_a_share_of_other_heads_are_ref
 # (e) through train.lm ----------------------------------------------------------------------
 
 
-def test_train_lm_trains_the_file_and_its_compile_event_carries_the_eva_plan(tmp_path):
+@pytest.mark.parametrize("bf16, norm", [(False, "xla"), (True, "barrier")],
+                         ids=["float32", "bfloat16-under-float32"])
+def test_train_lm_trains_the_file_and_its_compile_event_carries_the_eva_plan(tmp_path, bf16,
+                                                                             norm):
     from csed_514_project_distributed_training_using_pytorch_tpu.train import lm
     from csed_514_project_distributed_training_using_pytorch_tpu.utils.config import LMConfig
     config = tiny_config(vocab_size=256)        # the fixture corpus's ids
@@ -343,9 +369,11 @@ def test_train_lm_trains_the_file_and_its_compile_event_carries_the_eva_plan(tmp
         mesh="data=1", epochs=2, batch_size=8, eval_batch=19, telemetry=str(telemetry),
         results_dir="", images_dir=str(tmp_path / "images"), generate=0, remat=True,
         optimizer="adamw", learning_rate=3e-3,
-        clip_grad_norm=1.0))
+        clip_grad_norm=1.0, bf16=bf16))
     events = [json.loads(line) for line in telemetry.read_text().splitlines()]
     compiled = [e for e in events if e["event"] == "compile"][0]
+    # five norms a forward pass; held behind barriers where the float32 stream is the wider
+    assert compiled["norm"] == {"impl": norm, "calls": 5}
     assert compiled["eva"]["window"] == 16 and compiled["eva"]["kept"] == ["eva_out", "eva_lse"]
     assert compiled["experts"] is None and compiled["ssm"] is None and compiled["kda"] is None
     assert compiled["attention"] is None        # no mixer goes through the dispatcher

@@ -555,6 +555,8 @@ def test_the_compile_event_says_what_the_new_layers_ask(trained):
     assert event["experts"]["row_bound"] == 3 * 8 * 64 and event["experts"]["held"] == [0, 4]
     assert event["recompute"]["kept_bytes"] > 0 and "mla_latent" in event["recompute"]["kept"]
     assert event["head_products"] == 3      # the [T, vocab] logits: once a pass
+    # a stream in the model's dtype: three blocks' two norms and the last, the compiler's
+    assert event["norm"] == {"impl": "xla", "calls": 7}
     rate = event["experts"]["bias_update_rate"]
     # the selection's bias: out of AdamW, moved by the balancing rule alone, a rate a step
     steps = sum(e["steps"] for e in events if e["event"] == "epoch")
