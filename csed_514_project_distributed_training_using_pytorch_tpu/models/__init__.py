@@ -7,6 +7,9 @@ framework's sequence-parallel machinery (``parallel/ring_attention.py``); both s
 same call contract, so every trainer accepts either.
 """
 
+import dataclasses
+from typing import Callable
+
 from csed_514_project_distributed_training_using_pytorch_tpu.models.cnn import Net
 from csed_514_project_distributed_training_using_pytorch_tpu.models.transformer import (
     TransformerClassifier,
@@ -17,6 +20,25 @@ from csed_514_project_distributed_training_using_pytorch_tpu.models.transformer 
 import jax.numpy as jnp
 
 VALID_MODELS = ("cnn", "transformer")
+
+
+@dataclasses.dataclass(frozen=True)
+class Trainee:
+    """All that ``train/lm.py`` asks of a language model. The model builds it
+    (``TransformerLM.trainee``, ``HybridLM.trainee``); the trainer names no model class."""
+
+    loss: Callable      # (params, xs, ys, rng) -> the step's loss; (loss, aux) with ``has_aux``
+    eval_nll: Callable  # (params, batch) -> the batch's summed next-token NLL
+    targets_per_seq: int                    # a split's mean NLL is its sum over N times this
+    has_aux: bool = False                   # ``aux`` ends the epoch program's output
+    after_update: Callable | None = None    # ``train.step.make_train_step``'s, given ``aux``
+    is_frozen: Callable | None = None       # ``ops.optim.freeze``'s predicate; None: no wrapper
+    # (heads, head_dim, value_dim or None) of an ``attention_fn`` call, where a mixer makes
+    # one, and the model's own fields of the ``compile`` event's ``attention``
+    attention_shape: tuple | None = None
+    attention_fields: dict = dataclasses.field(default_factory=dict)
+    plans: Callable = lambda jaxpr, step_tokens: {}     # -> that event's model fields
+    expert_block: int | None = None         # the experts' row tile, ``epoch_event``'s
 
 
 def validate_model_config(name: str, *, remat: bool = False,
@@ -92,5 +114,5 @@ def build_model(name: str, *, bf16: bool = False, remat: bool = False,
                                  remat_policy=remat_policy, **kwargs)
 
 
-__all__ = ["Net", "TransformerClassifier", "build_model", "validate_model_config", "validate_remat_policy",
+__all__ = ["Net", "Trainee", "TransformerClassifier", "build_model", "validate_model_config", "validate_remat_policy",
            "VALID_MODELS"]

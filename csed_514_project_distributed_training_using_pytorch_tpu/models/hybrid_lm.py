@@ -100,6 +100,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from csed_514_project_distributed_training_using_pytorch_tpu import ops
+from csed_514_project_distributed_training_using_pytorch_tpu.models import Trainee
 from csed_514_project_distributed_training_using_pytorch_tpu.ops import eva, kda, moe, ssm
 from csed_514_project_distributed_training_using_pytorch_tpu.ops.rotary import (
     apply_rotary,
@@ -221,11 +222,6 @@ class HybridLM:
     @property
     def value_head_dim(self) -> int:
         return self.v_head_dim if "mla" in self.layer_types else self.head_dim
-
-    @property
-    def dispatches_attention(self) -> bool:
-        """Whether any mixer calls ``attention_fn`` (an EVA mixer has a core of its own)."""
-        return any(kind in ("full_attention", "attention", "mla") for kind in self.layer_types)
 
     def is_sparse(self, layer: int) -> bool:
         """Whether layer ``layer`` holds an expert feed-forward."""
@@ -378,6 +374,33 @@ class HybridLM:
         return sum(eqn.primitive.name == "dot_general"
                    and any(v.aval.shape == logits for v in (*eqn.invars, *eqn.outvars))
                    for eqn in _equations(getattr(jaxpr, "jaxpr", jaxpr)))
+
+    def plans(self, jaxpr, step_tokens: int) -> dict:
+        """The ``compile`` event's fields of this model, which ``telemetry.compile_event``
+        writes whole: the plans above, None each for a stack with no such layer, of a
+        program (``jaxpr``) that differentiates the loss once over ``step_tokens`` tokens."""
+        return {"experts": self.expert_plan(step_tokens),
+                "recompute": self.recompute_plan(jaxpr), "ssm": self.ssm_plan(),
+                "kda": self.kda_plan(), "eva": self.eva_plan(), "norm": self.norm_plan(),
+                "head_products": self.head_products(jaxpr, step_tokens)}
+
+    def trainee(self, *, deterministic: bool = True, label_smoothing: float = 0.0) -> Trainee:
+        """What ``train/lm.py`` trains and evaluates. The stack has no dropout and its loss
+        no smoothing, so the trainer refuses either knob for a model from a file."""
+        del deterministic, label_smoothing
+        # whether any mixer calls ``attention_fn`` (an EVA mixer has a core of its own)
+        dispatches = {"full_attention", "attention", "mla"} & set(self.layer_types)
+        return Trainee(
+            # (loss, rows that arrived at each held expert); the targets are the inputs
+            loss=lambda params, xs, ys, rng: self.loss(params, xs),
+            eval_nll=lambda params, batch: self.nll(params, batch)[0],
+            targets_per_seq=self.targets_per_seq(), has_aux=True,
+            after_update=self.rebalance if self.router_bias_update_rate else None,
+            is_frozen=is_frozen,
+            attention_shape=(self.num_attention_heads, self.head_dim, self.value_head_dim)
+            if dispatches else None,
+            attention_fields=self.rotary_plan(), plans=self.plans,
+            expert_block=(self.expert_plan(1) or {}).get("block"))
 
     def param_shapes(self) -> dict:
         d, hd = self.hidden_size, self.head_dim

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from csed_514_project_distributed_training_using_pytorch_tpu import ops
+from csed_514_project_distributed_training_using_pytorch_tpu.models import Trainee
 from csed_514_project_distributed_training_using_pytorch_tpu.ops import (
     quant as quant_ops,
 )
@@ -173,6 +174,22 @@ class TransformerLM(fnn.Module):
         ``vocab_size - 1``)."""
         bos = jnp.full((targets.shape[0], 1), self.vocab_size - 1, targets.dtype)
         return jnp.concatenate([bos, targets[:, :-1]], axis=1)
+
+    def trainee(self, *, deterministic: bool = True, label_smoothing: float = 0.0) -> Trainee:
+        """What ``train/lm.py`` trains and evaluates: ``next_token_loss`` under the trainer's
+        two knobs, and the summed NLL of the ``seq_len`` targets a sequence has."""
+
+        def eval_nll(params, batch):
+            log_probs = self.apply({"params": params}, self.shift_right(batch))
+            return -jnp.sum(jnp.take_along_axis(log_probs, batch[..., None], axis=-1))
+
+        return Trainee(
+            # the target stream IS the input stream, shifted inside the loss
+            loss=lambda params, xs, ys, rng: next_token_loss(
+                self, params, xs, rng, deterministic=deterministic,
+                label_smoothing=label_smoothing),
+            eval_nll=eval_nll, targets_per_seq=self.seq_len,
+            attention_shape=(self.num_heads, self.embed_dim // self.num_heads, None))
 
 
 def next_token_loss(model: TransformerLM, params, targets: jax.Array, rng,

@@ -69,31 +69,25 @@ from csed_514_project_distributed_training_using_pytorch_tpu.utils import (
 EPOCH_SPANS = ("data", "execute", "eval", "log", "emit", "guard", "checkpoint", "tick")
 
 
-def _attention_plan(config: LMConfig, seq_len: int, world: int, *,
-                    dispatched: bool, heads: int | None = None,
-                    head_dim: int | None = None, value_dim: int | None = None) -> dict:
+def _attention_plan(config: LMConfig, seq_len: int, world: int, shape: tuple, *,
+                    dispatched: bool) -> dict:
     """The ``compile`` event's ``attention`` field: what the step's attention call
     gets, by the dispatcher's own predicate on the per-device microbatch; where the
-    model keeps the dense core (``dispatched`` false) the same keys say so. ``heads``
-    and ``head_dim`` are the flags' unless the model came from a file, which may also
-    give values another width (``value_dim``)."""
-    heads = heads or config.num_heads
+    model keeps the dense core (``dispatched`` false) the same keys say so. ``shape``
+    is the model's ``Trainee.attention_shape``: heads, their width, their values'."""
+    heads, head_dim, value_dim = shape
     plan = ops.dispatch_plan(
-        (config.batch_size // world // config.grad_accum, seq_len, heads,
-         head_dim or config.embed_dim // heads),
+        (config.batch_size // world // config.grad_accum, seq_len, heads, head_dim),
         causal=True, window=config.attention_window, value_dim=value_dim)
     if not dispatched:
         plan.update(impl="dense", seq_padded=None, block=None, backward=None)
     return plan
 
 
-def make_eval_nll_fn(model, *, batch_size: int):
-    """``evaluate(params, tokens) -> sum_nll`` — summed next-token NLL over the split
-    (divide by ``N·S`` for the mean, ``N·targets_per_seq()`` for a ``HybridLM``, which has
-    no BOS;
+def make_eval_nll_fn(batch_nll, *, batch_size: int):
+    """``evaluate(params, tokens) -> sum_nll`` — summed next-token NLL over the split by
+    the model's ``Trainee.eval_nll`` a batch (divide by ``N·targets_per_seq`` for the mean;
     ``exp`` of that is perplexity), one scanned program like the classifier's eval."""
-
-    hybrid = isinstance(model, hybrid_lm.HybridLM)
 
     def evaluate(params, tokens):
         n = tokens.shape[0]
@@ -103,11 +97,7 @@ def make_eval_nll_fn(model, *, batch_size: int):
         xs = tokens.reshape((n // batch_size, batch_size) + tokens.shape[1:])
 
         def body(carry, batch):
-            if hybrid:
-                return carry + model.nll(params, batch)[0], None
-            log_probs = model.apply({"params": params}, model.shift_right(batch))
-            nll = -jnp.sum(jnp.take_along_axis(log_probs, batch[..., None], axis=-1))
-            return carry + nll, None
+            return carry + batch_nll(params, batch), None
 
         total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), xs)
         return total
@@ -262,6 +252,9 @@ def main(config: LMConfig = LMConfig(), *,
             # Pallas calls over a data axis, and no shard_map wraps them here yet.
             raise ValueError("--model-config trains on one device for now (--mesh "
                              "data=1), and takes no --attention-window")
+        if config.label_smoothing or config.dropout_rate:
+            raise ValueError("--model-config takes no --label-smoothing and no "
+                             "--dropout-rate: its stack and its loss have neither")
         model = hybrid_lm.from_config_file(
             config.model_config, vocab_size=vocab, seq_len=seq_len,
             dtype=jnp.bfloat16 if config.bf16 else jnp.float32, remat=config.remat,
@@ -277,9 +270,9 @@ def main(config: LMConfig = LMConfig(), *,
             dtype=jnp.bfloat16 if config.bf16 else jnp.float32, remat=config.remat,
             remat_policy=config.remat_policy,
             **lm_kwargs)
-    hybrid = isinstance(model, hybrid_lm.HybridLM)
-    # Targets a sequence: a HybridLM has no BOS, so its first token is context only.
-    targets_per_seq = model.targets_per_seq() if hybrid else seq_len
+    # all that is asked of the model from here on
+    view = model.trainee(deterministic=config.dropout_rate == 0.0,
+                         label_smoothing=config.label_smoothing)
     # Decoding is single-chip (host params): restore the default core, and the
     # window as a model field so the KV-cache decode mask applies the same band the
     # (possibly ring-windowed) training attention did — decode parity holds across
@@ -288,7 +281,8 @@ def main(config: LMConfig = LMConfig(), *,
                                 attention_window=config.attention_window)
                     if seq_size > 1 else model)
     M.log(f"LM training: mesh {dict(mesh.shape)} on {info.process_count} process(es), "
-          f"batch {config.batch_size}, vocab {vocab}{'' if hybrid else '+BOS'}, "
+          f"batch {config.batch_size}, vocab {vocab}"
+          f"{'+BOS' if model.vocab_size > vocab else ''}, "
           f"seq {seq_len}, data source: {data_source}")
     # Telemetry + resilience wiring live ABOVE the resume so the restore is recorded;
     # resilience hooks are flag-gated, host-side only (zero-cost when off).
@@ -312,8 +306,8 @@ def main(config: LMConfig = LMConfig(), *,
                                      learning_rate=config.learning_rate,
                                      momentum=config.momentum,
                                      weight_decay=config.weight_decay)
-    if hybrid:
-        optimizer = optim.freeze(optimizer, hybrid_lm.is_frozen)
+    if view.is_frozen is not None:
+        optimizer = optim.freeze(optimizer, view.is_frozen)
     state = create_train_state(model, jax.random.PRNGKey(config.seed),
                                sample_input_shape=(1, seq_len),
                                optimizer=optimizer, ema=config.ema_decay > 0,
@@ -375,29 +369,17 @@ def main(config: LMConfig = LMConfig(), *,
     # addresses every shard.
     gather = dp.gather_replicated(mesh)
 
-    deterministic = config.dropout_rate == 0.0
-
-    def lm_loss(params, xs, ys, rng):
-        del ys  # the target stream IS the input stream, shifted inside the loss
-        if hybrid:      # (loss, rows that arrived at each held expert)
-            return model.loss(params, xs)
-        return lm_mod.next_token_loss(model, params, xs, rng,
-                                      deterministic=deterministic,
-                                      label_smoothing=config.label_smoothing)
-
     health = config.health_stats
     step_fn = make_train_step(model, learning_rate=config.learning_rate,
                               momentum=config.momentum, grad_accum=config.grad_accum,
                               optimizer=optimizer, lr_schedule=lr_schedule,
                               clip_grad_norm=config.clip_grad_norm,
-                              ema_decay=config.ema_decay, loss_fn=lm_loss,
+                              ema_decay=config.ema_decay, loss_fn=view.loss,
                               with_metrics=health, guard=grt.spec,
-                              loss_has_aux=hybrid,
-                              after_update=model.rebalance if hybrid
-                              and model.router_bias_update_rate else None)
+                              loss_has_aux=view.has_aux, after_update=view.after_update)
     epoch_fn = compile_lm_epoch(make_epoch_from_step(step_fn, health=health,
-                                                     aux=hybrid))
-    eval_fn = jax.jit(make_eval_nll_fn(model, batch_size=eval_batch))
+                                                     aux=view.has_aux))
+    eval_fn = jax.jit(make_eval_nll_fn(view.eval_nll, batch_size=eval_batch))
 
     # Corpus mode: the device token array is REFILLED per epoch from the
     # streaming loader (same shape every epoch — the compiled program is
@@ -429,27 +411,17 @@ def main(config: LMConfig = LMConfig(), *,
             if aot.get("bytes_accessed"):
                 bytes_per_step = aot["bytes_accessed"] / steps_per_epoch
             # no plan for a stack none of whose mixers goes through ``attention_fn``
-            attention = None if seq_size > 1 or (
-                hybrid and not model.dispatches_attention) else _attention_plan(
-                config, seq_len, world, dispatched=mesh.size == 1,
-                heads=model.num_attention_heads if hybrid else None,
-                head_dim=model.head_dim if hybrid else None,
-                value_dim=model.value_head_dim if hybrid else None)
-            if hybrid and attention is not None:
-                attention.update(model.rotary_plan())
+            attention = None if seq_size > 1 or view.attention_shape is None else {
+                **_attention_plan(config, seq_len, world, view.attention_shape,
+                                  dispatched=mesh.size == 1), **view.attention_fields}
             step_tokens = config.batch_size // world // config.grad_accum * seq_len
-            plans = dict(experts=model.expert_plan(step_tokens),
-                         recompute=model.recompute_plan(aot["jaxpr"]),
-                         head_products=model.head_products(aot["jaxpr"], step_tokens),
-                         ssm=model.ssm_plan(), kda=model.kda_plan(),
-                         eva=model.eva_plan(), norm=model.norm_plan()) if hybrid else {}
             tele.emit(T.compile_event("epoch", aot,
                                       steps_per_call=steps_per_epoch,
                                       attention=attention,
                                       scopes=T.write_scope_table(
                                           config.telemetry, aot["scopes"],
                                           steps_per_call=steps_per_epoch),
-                                      **plans))
+                                      plans=view.plans(aot["jaxpr"], step_tokens)))
     history = M.MetricsHistory()
     saver = checkpoint.make_saver(config.async_checkpoint, tele=tele)
 
@@ -461,12 +433,10 @@ def main(config: LMConfig = LMConfig(), *,
     try:
         with profiling.maybe_profile(config.profile, config.profile_dir):
             state = _run_epochs(config, state, mesh, epoch_fn, eval_fn, tokens_d,
-                                zeros_d, test_d, dropout_rng, n_train, n_test,
-                                targets_per_seq, steps_per_epoch, start_epoch, history,
-                                watch, saver, ckpt_path, gather, tele, compile_s,
-                                flops_per_step, rt, bytes_per_step, grt, loader,
-                                (model.expert_plan(1) or {}).get("block") if hybrid
-                                else None)
+                                zeros_d, test_d, dropout_rng, n_train, n_test, view,
+                                steps_per_epoch, start_epoch, history, watch, saver,
+                                ckpt_path, gather, tele, compile_s, flops_per_step, rt,
+                                bytes_per_step, grt, loader)
     finally:
         # Drain the write-behind queue even on an exception/signal/preemption
         # mid-run — the queued per-epoch checkpoint is the resume artifact a killed
@@ -516,14 +486,11 @@ def main(config: LMConfig = LMConfig(), *,
 
 
 def _run_epochs(config, state, mesh, epoch_fn, eval_fn, tokens_d, zeros_d, test_d,
-                dropout_rng, n_train, n_test, targets_per_seq, steps_per_epoch,
-                start_epoch,
+                dropout_rng, n_train, n_test, view, steps_per_epoch, start_epoch,
                 history, watch, saver, ckpt_path, gather, tele, compile_s,
-                flops_per_step, rt, bytes_per_step=None, grt=None, loader=None,
-                expert_block=None):
+                flops_per_step, rt, bytes_per_step=None, grt=None, loader=None):
     """The LM trainer's epoch loop, split out so the caller can guarantee the
-    async-checkpoint flush in a ``finally`` regardless of where the loop fails.
-    ``expert_block``: the expert layers' row tile (None for a model with none)."""
+    async-checkpoint flush in a ``finally`` regardless of where the loop fails."""
     best_step_s = None
     ckpt_store = (os.path.join(config.results_dir, "checkpoints")
                   if config.results_dir else "")
@@ -578,7 +545,7 @@ def _run_epochs(config, state, mesh, epoch_fn, eval_fn, tokens_d, zeros_d, test_
                 out = out if isinstance(out, tuple) else (out,)
                 losses = out[0]
                 epoch_health = out[1] if config.health_stats else None
-                expert_counts = out[-1] if config.model_config else None
+                expert_counts = out[-1] if view.has_aux else None
                 with profiling.span("execute/wait"):
                     jax.block_until_ready(state.params)
                 with profiling.span("execute/loss_fetch"):
@@ -589,7 +556,7 @@ def _run_epochs(config, state, mesh, epoch_fn, eval_fn, tokens_d, zeros_d, test_
                 eval_params = state.ema if state.ema is not None else state.params
                 sum_nll = float(jax.device_get(eval_fn(eval_params, test_d)))
             with profiling.span("epoch/log"):
-                val_nll = sum_nll / (n_test * targets_per_seq)
+                val_nll = sum_nll / (n_test * view.targets_per_seq)
                 examples = (epoch + 1) * steps_per_epoch * config.batch_size
                 history.record_train(examples, train_loss)
                 history.record_test(examples, val_nll)
@@ -624,7 +591,7 @@ def _run_epochs(config, state, mesh, epoch_fn, eval_fn, tokens_d, zeros_d, test_
                         train_loss=train_loss, val_loss=val_nll,
                         mfu=T.estimate_mfu(flops_per_step, step_s)["mfu"],
                         expert_counts=expert_counts,
-                        expert_block=expert_block))
+                        expert_block=view.expert_block))
                     if epoch_health is not None:
                         tele.emit(T.health_event(epoch, health_host, steps_per_epoch,
                                                  param_norm=param_norm))

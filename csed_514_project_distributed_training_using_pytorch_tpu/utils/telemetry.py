@@ -352,40 +352,17 @@ def write_scope_table(telemetry_path: str, table: dict, *,
 
 
 def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
-                  attention: dict | None = None, experts: dict | None = None,
-                  recompute: dict | None = None, ssm: dict | None = None,
-                  head_products: int | None = None, kda: dict | None = None,
-                  eva: dict | None = None, norm: dict | None = None,
-                  scopes: dict | None = None) -> dict:
+                  attention: dict | None = None, scopes: dict | None = None,
+                  plans: dict | None = None) -> dict:
     """The ``compile`` event for one AOT-timed program. ``attention``: which core the
     program's attention calls get and why (``ops.dispatch_plan``'s dict: ``impl``,
     ``score_bytes``, ``seq_padded``, ``block``, ``backward``), for the trainers that
-    route through the dispatcher; for a model from a configuration file also
-    ``HybridLM.rotary_plan``'s ``rope_dim``, ``rope_pairing`` (``half_split`` or
-    ``interleaved``) and ``rope_theta``, None each where the attention has no positions. ``experts``: what a step asks of each sparse expert
-    layer (``ops.moe.expert_plan``: ``held``, ``row_bound``, ``rows_buffer``,
-    ``block``, ``rows_moved``). ``recompute``: what per-block recomputation keeps
-    between a step's forward and backward pass (``HybridLM.recompute_plan``: the
-    ``kept`` names and ``kept_bytes``); None when nothing is recomputed. ``ssm``: what a
-    step asks of each state-space layer (``ops.ssm.scan_plan``: heads and groups held,
-    head and state widths, the chunk, chunks and state bytes a sequence, what
-    recomputation keeps of the scan); None for a model with none. ``kda``: the same of
-    each delta-rule layer (``ops.kda.scan_plan``: heads, key and value widths, chunk and
-    sub-block, chunks, kept states and their bytes a sequence, what recomputation
-    keeps, and ``in_kernel``: the per-token, per-head scalars computed inside the
-    kernels); None for a model with none. ``eva``: the same of each EVA layer
-    (``ops.eva.attention_plan``: ``impl``, heads held, window and chunk, windows and
-    summaries a sequence, the kernels' query and summary blocks, what recomputation
-    keeps); None for a model with none. ``norm``: how a step takes its norms of the
-    residual stream (``HybridLM.norm_plan``: ``impl`` ``barrier`` where each output of a
-    float32 stream's norm, and its cotangent, stands behind an optimization barrier, else
-    ``xla``; ``calls`` a forward pass); None for a model that is not a ``HybridLM``.
-    ``head_products``:
-    the matrix products of a step that touch the head's ``[T, vocab]`` logits
-    (``HybridLM.head_products``: 3 when they are computed once); None for a model
-    whose head is not counted. ``scopes``: where the table of each instruction's scope
-    and pass was written and what it holds (``write_scope_table``); ``scopes_s``, what
-    building it added to the run's set-up, is the ``aot`` dict's."""
+    route through the dispatcher, with the model's own fields merged in
+    (``HybridLM.rotary_plan``). ``plans``: what the model says of the program, written
+    into the event whole, a field a key (``HybridLM.plans`` names the keys and what each
+    holds; a model that says nothing adds none). ``scopes``: where the table of each
+    instruction's scope and pass was written and what it holds (``write_scope_table``);
+    ``scopes_s``, what building it added to the run's set-up, is the ``aot`` dict's."""
     flops = aot.get("flops")
     return {
         "event": "compile",
@@ -401,13 +378,7 @@ def compile_event(fn_name: str, aot: dict, *, steps_per_call: int | None = None,
             aot["bytes_accessed"] / steps_per_call
             if aot.get("bytes_accessed") and steps_per_call else None),
         "attention": attention,
-        "experts": experts,
-        "recompute": recompute,
-        "ssm": ssm,
-        "kda": kda,
-        "eva": eva,
-        "norm": norm,
-        "head_products": head_products,
+        **(plans or {}),
         "scopes": scopes,
         "scopes_s": _finite(aot.get("scopes_s")),
     }

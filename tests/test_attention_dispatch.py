@@ -281,10 +281,9 @@ def test_compile_event_reports_flash_for_the_cells_shapes():
         telemetry as T,
     )
     config = LMConfig(batch_size=16, embed_dim=1024, num_heads=8, kv_heads=2)
-    plan = _attention_plan(config, 784, 1, dispatched=True)
+    plan = _attention_plan(config, 784, 1, (8, 128, None), dispatched=True)
     assert plan == pa.dispatch_plan((16, 784, 8, 128), causal=True)
-    latent = _attention_plan(LMConfig(batch_size=2), 8192, 1, dispatched=True, heads=32,
-                             head_dim=192, value_dim=128)
+    latent = _attention_plan(LMConfig(batch_size=2), 8192, 1, (32, 192, 128), dispatched=True)
     assert (latent["impl"], latent["key_dim"], latent["value_dim"], latent["seq_padded"]) == (
         "flash", 192, 128, 8192)
     assert (plan["impl"], plan["score_bytes"], plan["seq_padded"]) == (
@@ -294,7 +293,7 @@ def test_compile_event_reports_flash_for_the_cells_shapes():
     assert event["attention"]["impl"] == "flash"
     assert event["attention"]["block"] == plan["block"]
     assert event["attention"]["backward"] == latent["backward"] == "fused"
-    kept = _attention_plan(config, 784, 4, dispatched=False)
+    kept = _attention_plan(config, 784, 4, (8, 128, None), dispatched=False)
     assert kept["impl"] == "dense" and kept["score_bytes"] == 314703872 // 4
     assert kept["backward"] is None
     assert kept["block"] is None
