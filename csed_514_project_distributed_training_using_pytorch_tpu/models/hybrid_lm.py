@@ -1,6 +1,6 @@
 """Decoder LM built from a published configuration file: a stack of
-short-convolution, Mamba-2, delta-rule (KDA), GQA, latent-attention (MLA), EVA and expert
-layers.
+short-convolution, Mamba-2, delta-rule (KDA, gated delta net), GQA, latent-attention
+(MLA), EVA and expert layers.
 
 ``TransformerLM`` (``models/lm.py``) is the repo's own pixel decoder; this module is
 how a catalog architecture trains through ``train.lm``: the keys of the model's
@@ -15,7 +15,9 @@ first such file, ``NVIDIA-Nemotron-3-Super-120B-A12B`` (``nemotron_h``:
 every layer an EVA mixer and a dense feed-forward, no expert anywhere, eight prediction
 heads over 320 byte ids) the fourth, ``Kanana-2-30B-A3B`` (``deepseek_v3``: latent
 attention in every layer with a rotated shared key, ``first_k_dense_replace`` dense layers
-and then fine-grained experts beside shared ones) the fifth.
+and then fine-grained experts beside shared ones) the fifth, ``Qwen3-Next-80B-A3B``
+(``qwen3_next``: three gated delta-rule layers to one gated softmax attention, every layer
+with softmax-routed experts beside a sigmoid-gated shared one) the sixth.
 
 A layer is a block of two sublayers (``LAYER_KINDS``: its mixer) or one sublayer
 alone (``SUBLAYER_KINDS``). Each kind is written once, as a function of its
@@ -32,9 +34,17 @@ parameters, the normalized input and the positions:
                    (``ops/ssm.py``, heads of a group read its B, C; D is the leaf
                    ``D_scale``, one at the start as published);
                    W_out (w ⊙ RMSNorm_group(Y ⊙ silu(z)))
-    attention      q, k RMS-normed per head (or not) before RoPE (half-split pairing;
-                   or no positions at all), causal softmax(q·k/√D)·v in groups,
-                   through the pluggable ``attention_fn``
+    attention      q, k RMS-normed per head (or not) before RoPE (half-split pairing, over
+                   all of a head's channels or its first ``rope_dim``; or no positions
+                   at all), causal softmax(q·k/√D)·v in groups, through the pluggable
+                   ``attention_fn``; with ``attention_gate`` W_q is twice as wide and the
+                   heads' output is scaled by the sigmoid of its second half before W_o
+    gdn_mixer      [q̃ | k̃ | ṽ | z] = W_qkvz u, [b | a] = W_ba u;  (q̃, k̃, ṽ) = silu(conv(·));
+                   β = sigmoid(b), g = −exp(A_log) · softplus(a + dt_bias), one number a
+                   token and value head, float32. ``ops/kda.py``'s kernels at a scalar
+                   decay (``gdn_scan``): the unit norms, S_t = e^{g_t} S_{t−1} + β_t k_t
+                   (v_t − e^{g_t} S_{t−1}ᵀ k_t)ᵀ, value head h on key head h // rep, ô = o /
+                   rms_head(o). Then W_o (w ⊙ ô ⊙ silu(z)): normed, then gated
     kda_mixer      q̃, k̃, ṽ = silu(conv(W u)) each;  g = −exp(A_log) · softplus(W_f↑ W_f↓ u +
                    dt_bias) a channel, β = sigmoid(W_β u) a head, float32; all flat,
                    ``[B, S, H·128]``. In ``ops/kda.py``'s kernels, on a head's block:
@@ -53,12 +63,13 @@ parameters, the normalized input and the positions:
                    one float32 softmax over the keys of the query's own window up to the
                    query and the summaries of every chunk before that window; W_o
     dense_ff       W_2 (silu(W_1 u) ⊙ W_3 u), over the held columns of a share
-    sparse_ff      ``ops/moe.py``: sigmoid router over all experts, top-k of s + b,
+    sparse_ff      ``ops/moe.py``: sigmoid router over all experts, top-k of s + b (or a
+                   softmax router, top-k of p, no b),
                    the held experts' part of the result, dropless. Experts are gated
                    (three matrices) on the model's rows, or relu² (two) on a latent row
                    (W_fc2 Σ_e w_e W2_e relu(W1_e W_fc1 u)²), beside a shared expert
                    that every token passes, W_s2 relu(W_s1 u)² or the gated W_s2
-                   (silu(W_s1 u) ⊙ W_s3 u)
+                   (silu(W_s1 u) ⊙ W_s3 u), added as it is or times sigmoid(w_g · u)
 
 A share holds a mixer's heads as it holds experts: the out-projection sums over the
 held heads (of a Mamba-2 layer: whole groups, each with its own B, C and its own
@@ -107,7 +118,7 @@ from csed_514_project_distributed_training_using_pytorch_tpu.ops.rotary import (
 )
 
 # a block: this mixer, then a feed-forward
-LAYER_KINDS = ("conv", "full_attention", "kda", "mla", "eva")
+LAYER_KINDS = ("conv", "full_attention", "kda", "mla", "eva", "gdn")
 SUBLAYER_KINDS = ("mamba", "attention", "moe")  # a layer that is one sublayer
 # What ``remat`` keeps of a block between its forward and its backward pass, beside
 # the block's input: the names of ``jax.ad_checkpoint.checkpoint_name`` tags, set
@@ -125,6 +136,12 @@ EVA_KEPT = ("eva_out", "eva_lse")
 # name do not fit beside 11.0 GB of state: the two products run again (0.55 TFLOP a layer).
 MLA_KEPT = ("flash_out", "flash_lse", "mla_latent", "moe_route", "moe_sort", "mixer_out",
             "shared_hidden", "ff_gate")
+
+
+# What a ``qwen3_next`` stack keeps: all of ``KEPT`` that it tags, and the delta layers'
+# one projection (``[T, 12288]``, 0.40 GB a layer at 16,384 tokens: 0.82 TFLOP a layer not
+# run again), which fits beside 10.0 GB of state where the other stacks' would not.
+GDN_KEPT = KEPT + ("gdn_in_proj",)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -150,18 +167,23 @@ class HybridLM:
     norm_eps: float = 1e-5
     rope_theta: float | None = 1e6      # None: attention without positions
     rope_interleave: bool = False       # an MLA mixer's rotated channels 2i, 2i + 1 turn together
+    rope_dim: int | None = None         # a GQA head's leading channels that turn; None: all
     qk_norm: bool = True
+    attention_gate: bool = False        # W_q twice as wide: a head's output times sigmoid(gate)
     attention_head_dim: int | None = None   # None: hidden_size / num_attention_heads
     tied_head: bool = True
     routed_scaling_factor: float = 1.0
     router_eps: float = 1e-6            # beside the selected scores' sum
     router_bias_update_rate: float = 0.0    # of the selection's bias a step; 0: held fixed
+    router_scoring: str = "sigmoid"     # or "softmax", which has no selection bias
     gated_experts: bool = True          # swiglu over W1, W3; else relu² over W1
     moe_latent_size: int = 0            # the experts' row width, where not the model's
     shared_expert_size: int = 0         # held columns of the shared expert
     gated_shared_expert: bool = False   # swiglu over W_s1, W_s3; else relu² over W_s1
+    shared_expert_gate: bool = False    # the shared expert times sigmoid(w_g · u), a token
     kda_heads: int = 0                  # heads of a KDA mixer
-    kda_head_dim: int = 128             # their key width, and their value width
+    kda_head_dim: int = 128             # a KDA or gdn head's key width, and its value width
+    gdn_heads: tuple[int, int] = (0, 0)     # key heads, value heads of a gated delta layer
     kda_tiling: tuple[int, int, int] = (kda.CHUNK, kda.SUB, kda.GROUP)  # ops.kda's
     kv_lora_rank: int = 0               # the key/value latent of an MLA mixer
     qk_nope_head_dim: int = 128         # a head's key channels from the latent
@@ -195,6 +217,13 @@ class HybridLM:
                              f"{self.mamba_heads} heads of a mamba layer")
         if "kda" in self.layer_types and self.kda_heads < 1:
             raise ValueError("a kda layer needs kda_heads")
+        key_heads, value_heads = self.gdn_heads
+        if "gdn" in self.layer_types and (key_heads < 1 or value_heads % key_heads):
+            raise ValueError(f"a gdn layer needs key heads ({key_heads}) that divide its "
+                             f"value heads ({value_heads})")
+        if self.router_scoring not in moe.SCORINGS:
+            raise ValueError(f"router_scoring {self.router_scoring!r} is not of "
+                             f"{moe.SCORINGS}")
         if "mla" in self.layer_types and self.kv_lora_rank < 1:
             raise ValueError("an mla layer needs kv_lora_rank")
         if self.num_attention_heads % self.num_key_value_heads:
@@ -292,6 +321,16 @@ class HybridLM:
                              value_dim=self.kda_head_dim, seq_len=self.seq_len,
                              **self._kda_tiles, kept=self.kept if self.remat else ())
 
+    def gdn_plan(self) -> dict | None:
+        """What a step asks of each gated delta-rule layer (``ops.kda.scan_plan`` with key
+        heads: the decay a scalar), or None for a stack with none."""
+        if "gdn" not in self.layer_types:
+            return None
+        return kda.scan_plan(heads=self.gdn_heads[1], key_heads=self.gdn_heads[0],
+                             key_dim=self.kda_head_dim, value_dim=self.kda_head_dim,
+                             seq_len=self.seq_len, **self._kda_tiles,
+                             kept=self.kept if self.remat else ())
+
     def eva_plan(self) -> dict | None:
         """What a step asks of each EVA layer (``ops.eva.attention_plan``), or None for
         a stack with none."""
@@ -306,13 +345,15 @@ class HybridLM:
         """The ``compile`` event's ``rope_dim``, ``rope_pairing`` and ``rope_theta`` of its
         ``attention`` field: how many of a head's query and key channels turn by their
         position (a latent-attention head's shared ones; else all), in which pairing
-        and at which base. None each for attention without positions."""
+        and at which base. None each for attention without positions. With
+        ``attention_gate`` also ``output_gate``, what scales a head's output."""
+        gate = {"output_gate": "sigmoid"} if self.attention_gate else {}
         if self.rope_theta is None:
-            return dict.fromkeys(("rope_dim", "rope_pairing", "rope_theta"))
+            return dict(dict.fromkeys(("rope_dim", "rope_pairing", "rope_theta")), **gate)
         return {"rope_dim": self.qk_rope_head_dim if "mla" in self.layer_types
-                else self.head_dim,
+                else self.rope_dim or self.head_dim,
                 "rope_pairing": "interleaved" if self.rope_interleave else "half_split",
-                "rope_theta": self.rope_theta}
+                "rope_theta": self.rope_theta, **gate}
 
     @property
     def _kda_tiles(self) -> dict:
@@ -325,6 +366,8 @@ class HybridLM:
             return None
         plan = moe.expert_plan(tokens, top_k=self.num_experts_per_tok,
                                held=self.held_experts, block=self.expert_block)
+        if self.router_scoring != "sigmoid":
+            plan["scoring"] = self.router_scoring
         if self.router_bias_update_rate:
             plan["bias_update_rate"] = self.router_bias_update_rate
         return plan
@@ -381,7 +424,8 @@ class HybridLM:
         program (``jaxpr``) that differentiates the loss once over ``step_tokens`` tokens."""
         return {"experts": self.expert_plan(step_tokens),
                 "recompute": self.recompute_plan(jaxpr), "ssm": self.ssm_plan(),
-                "kda": self.kda_plan(), "eva": self.eva_plan(), "norm": self.norm_plan(),
+                "kda": self.kda_plan(), "gdn": self.gdn_plan(), "eva": self.eva_plan(),
+                "norm": self.norm_plan(),
                 "head_products": self.head_products(jaxpr, step_tokens)}
 
     def trainee(self, *, deterministic: bool = True, label_smoothing: float = 0.0) -> Trainee:
@@ -410,17 +454,19 @@ class HybridLM:
         tree = {"embed_tokens": (self.vocab_size, d), f"final_{norm}": (d,)}
         if not self.tied_head:
             tree["lm_head_kernel"] = (d, self.vocab_size * self.num_pred_heads)
-        attn = {"q_kernel": (d, heads * hd), "k_kernel": (d, kv * hd),
+        attn = {"q_kernel": (d, heads * hd * (2 if self.attention_gate else 1)),
+                "k_kernel": (d, kv * hd),
                 "v_kernel": (d, kv * hd), "out_kernel": (heads * hd, d)}
         if self.qk_norm:
-            attn.update(q_norm_scale=(hd,), k_norm_scale=(hd,))
+            attn.update({f"q_{norm}": (hd,), f"k_{norm}": (hd,)})
         # expert_bias_b: the selection's bias. Fixed (is_frozen): its gradient
         # is zero and no update rule is published.
         row = self.moe_latent_size or d
         experts = {"router_kernel": (d, self.router_experts),
-                   "expert_bias_b": (self.router_experts,),
                    "experts_w1_kernel": (row, held * f),
                    "experts_w2_kernel": (f, held * row)}
+        if self.router_scoring == "sigmoid":
+            experts["expert_bias_b"] = (self.router_experts,)
         if self.gated_experts:
             experts["experts_w3_kernel"] = (row, held * f)
         if self.moe_latent_size:
@@ -430,6 +476,8 @@ class HybridLM:
                            shared_w2_kernel=(self.shared_expert_size, d))
         if self.gated_shared_expert:
             experts["shared_w3_kernel"] = (d, self.shared_expert_size)
+        if self.shared_expert_gate:
+            experts["shared_gate_kernel"] = (d, 1)
         wide, low = self.kda_heads * self.kda_head_dim, self.kda_head_dim
         delta = {f"{name}_{leaf}": shape for name in "qkv" for leaf, shape in (
             ("kernel", (d, wide)), ("conv_kernel", (self.conv_kernel, wide)))}
@@ -443,10 +491,17 @@ class HybridLM:
                   "kv_b_kernel": (self.kv_lora_rank,
                                   heads * (self.qk_nope_head_dim + self.v_head_dim)),
                   "out_kernel": (heads * self.v_head_dim, d)}
+        keys, values = (n * self.kda_head_dim for n in self.gdn_heads)
+        gated_delta = {"qkvz_kernel": (d, 2 * keys + 2 * values),
+                       "conv_kernel": (self.conv_kernel, 2 * keys + values),
+                       "ba_kernel": (d, 2 * self.gdn_heads[1]),
+                       "A_log": (self.gdn_heads[1],), "dt_bias": (self.gdn_heads[1],),
+                       "o_norm_scale": (low,), "out_kernel": (values, d)}
         summarised = dict({name: shape for name, shape in attn.items() if "norm" not in name},
                           adaptive_phi=(heads, hd), adaptive_mu_k=(heads, hd))
         mixers = {"full_attention": ("attn", attn), "kda": ("kda", delta),
-                  "mla": ("mla", latent), "eva": ("eva", summarised)}
+                  "mla": ("mla", latent), "eva": ("eva", summarised),
+                  "gdn": ("gdn", gated_delta)}
         inner = self.mamba_heads * self.mamba_head_dim
         conv_width = inner + 2 * self.mamba_groups * self.ssm_state_size
         mamba = {"in_proj_kernel": (d, inner + conv_width + self.mamba_heads),
@@ -555,10 +610,7 @@ class HybridLM:
             u = self.normed(x, p)
         else:
             u = self.normed(mix(p, x, positions, kind, self), p, "ff_")
-        _, experts = moe.route(u.reshape(-1, u.shape[-1]), p["moe"]["router_kernel"],
-                               p["moe"]["expert_bias_b"],
-                               top_k=self.num_experts_per_tok)
-        return experts.reshape(*ids.shape, -1)
+        return routed(p["moe"], u.reshape(-1, u.shape[-1]), self)[1].reshape(*ids.shape, -1)
 
     def _head(self, params):
         """The head's leaf: the embedding ``[vocab, d]``, tied, or ``[d, vocab]``."""
@@ -675,7 +727,7 @@ head_nll.defvjp(_head_nll_fwd, _head_nll_bwd)
 # leaves unnamed only what escaped: device time is read by kind, never by layer index.
 MIXER_SCOPES = {"conv": "conv_mixer", "full_attention": "attention", "attention": "attention",
                 "kda": "kda_mixer", "mla": "mla_attention", "mamba": "mamba_mixer",
-                "eva": "eva_mixer"}
+                "eva": "eva_mixer", "gdn": "gdn_mixer"}
 
 
 def make_block(model: HybridLM, kind: str, sparse: bool):
@@ -718,6 +770,8 @@ def mix(p, x, positions, kind: str, model: HybridLM):
             mixed = conv_mixer(p["conv"], u)
         elif kind == "kda":
             mixed = kda_mixer(p["kda"], u, model)
+        elif kind == "gdn":
+            mixed = gdn_mixer(p["gdn"], u, model)
         elif kind == "mla":
             mixed = mla_mixer(p["mla"], u, positions, model)
         elif kind == "eva":
@@ -784,18 +838,33 @@ def attention_mixer(p, u, positions, model: HybridLM, core=None):
                      model.head_dim)
     # Named as the matmuls wrote them, not after the norm and the rotation: the
     # norm's backward pass reads its input, so its output kept spares no matmul.
+    # With ``attention_gate`` W_q's columns are every head's query and then every head's
+    # gate: the gates are the ``heads`` further "heads" of the projection.
     q, k, v = (checkpoint_name(_dense(u, p[f"{name}_kernel"]), "attn_proj")
-               .reshape(b, s, n, hd) for name, n in (("q", heads), ("k", kv), ("v", kv)))
-    def placed(x, scale):       # per-head norm, then the rotation; either or neither
-        if model.qk_norm:
-            x = ops.rms_norm(x, p[scale], eps=model.norm_eps)
-        if model.rope_theta is not None:
-            x = apply_rotary(x, positions, base=model.rope_theta)
-        return x
+               .reshape(b, s, n, hd) for name, n in (
+                   ("q", heads * (2 if model.attention_gate else 1)), ("k", kv), ("v", kv)))
+    if model.attention_gate:
+        q, gate = q[:, :, :heads], q[:, :, heads:]
 
-    q, k = placed(q, "q_norm_scale"), placed(k, "k_norm_scale")
+    def turned(x):      # the rotation, over the whole head or its first ``rope_dim`` channels
+        if not model.rope_dim:
+            return apply_rotary(x, positions, base=model.rope_theta)
+        with jax.named_scope("rotary"):
+            return jnp.concatenate(
+                [apply_rotary(x[..., :model.rope_dim], positions, base=model.rope_theta),
+                 x[..., model.rope_dim:]], axis=-1)
+
+    def placed(x, which):       # per-head norm, then the rotation; either or neither
+        if model.qk_norm:
+            x = ops.rms_norm(x, p[f"{which}_{model._norm_leaf}"], eps=model.norm_eps,
+                             offset=1.0 if model.norm_unit_offset else 0.0)
+        return x if model.rope_theta is None else turned(x)
+
+    q, k = placed(q, "q"), placed(k, "k")
     k, v = (jnp.repeat(x, heads // kv, axis=2) for x in (k, v))
     out = core(q, k, v) if core else model.attention_fn(q, k, v, causal=True)
+    if model.attention_gate:
+        out = out * jax.nn.sigmoid(gate)
     return _dense(out.reshape(b, s, heads * hd), p["out_kernel"])
 
 
@@ -820,6 +889,26 @@ def kda_mixer(p, u, model: HybridLM):
     scaled = normed.astype(f32) * jnp.tile(p["o_norm_scale"].astype(f32), heads)
     return _dense((scaled * jax.nn.sigmoid(low_rank("g"))).astype(u.dtype),
                   p["out_kernel"])
+
+
+def gdn_mixer(p, u, model: HybridLM):
+    """Flat as ``kda_mixer``: one projection to ``[q̃ | k̃ | ṽ | z]`` (the key heads' queries,
+    their keys, the value heads' values and gates, in that order of columns), one
+    convolution over the first three, and ``ops/kda.py``'s kernels at a scalar decay, in
+    which value head ``h`` reads key head ``h // rep``. The output is normed a head (inside
+    the kernels, the learned scale here) and THEN gated by ``silu(z)``."""
+    (key_heads, value_heads), hd = model.gdn_heads, model.kda_head_dim
+    keys, f32 = key_heads * hd, jnp.float32
+    qkv, z = jnp.split(checkpoint_name(_dense(u, p["qkvz_kernel"]), "gdn_in_proj"),
+                       [2 * keys + value_heads * hd], axis=-1)
+    q, k, v = jnp.split(jax.nn.silu(causal_depthwise_conv(qkv, p["conv_kernel"])),
+                        [keys, 2 * keys], axis=-1)
+    b, a = jnp.split(_dense(u, p["ba_kernel"]).astype(f32), 2, axis=-1)
+    decay = -jnp.exp(p["A_log"].astype(f32)) * jax.nn.softplus(a + p["dt_bias"].astype(f32))
+    normed = kda.gdn_scan(q, k, v, decay, jax.nn.sigmoid(b), key_heads=key_heads,
+                          eps=model.norm_eps, **model._kda_tiles)
+    scaled = normed.astype(f32) * jnp.tile(p["o_norm_scale"].astype(f32), value_heads)
+    return _dense((scaled * jax.nn.silu(z.astype(f32))).astype(u.dtype), p["out_kernel"])
 
 
 def mla_mixer(p, u, positions, model: HybridLM):
@@ -864,14 +953,19 @@ def dense_ff(p, u):
     return _dense(ops.swiglu(gate, _dense(u, p["w3_kernel"])), p["w2_kernel"])
 
 
+def routed(p, flat, model: HybridLM, load: bool = False):
+    """``ops.moe.route`` as this model's expert layers call it: ``sparse_ff`` and the
+    diagnostic ``HybridLM.router_choices`` route alike."""
+    return moe.route(flat, p["router_kernel"], p.get("expert_bias_b"),
+                     top_k=model.num_experts_per_tok, scaling=model.routed_scaling_factor,
+                     eps=model.router_eps, load=load, scoring=model.router_scoring)
+
+
 def sparse_ff(p, u, model: HybridLM):
     b, s, d = u.shape
     flat = u.reshape(b * s, d)
     balanced = bool(model.router_bias_update_rate)
-    weights, experts, *load = moe.route(flat, p["router_kernel"], p["expert_bias_b"],
-                                        top_k=model.num_experts_per_tok,
-                                        scaling=model.routed_scaling_factor,
-                                        eps=model.router_eps, load=balanced)
+    weights, experts, *load = routed(p, flat, model, load=balanced)
     rows = flat
     if model.moe_latent_size:
         with jax.named_scope("moe/latent"):
@@ -890,7 +984,10 @@ def sparse_ff(p, u, model: HybridLM):
             hidden = checkpoint_name(_dense(flat, p["shared_w1_kernel"]), "shared_hidden")
             hidden = (ops.swiglu(hidden, _dense(flat, p["shared_w3_kernel"]))
                       if model.gated_shared_expert else jnp.square(jax.nn.relu(hidden)))
-            out = out + _dense(hidden, p["shared_w2_kernel"])
+            shared = _dense(hidden, p["shared_w2_kernel"])
+            if model.shared_expert_gate:
+                shared = shared * jax.nn.sigmoid(_dense(flat, p["shared_gate_kernel"]))
+            out = out + shared
     return out.reshape(b, s, d), ((counts, *load) if balanced else counts)
 
 
@@ -1140,9 +1237,55 @@ def _deepseek_v3(config: dict) -> tuple[list, dict]:
         v_head_dim=int(config["v_head_dim"]), kept=MLA_KEPT)
 
 
+def _qwen3_next(config: dict) -> tuple[list, dict]:
+    """Layers numbered from 0: softmax attention where ``(i + 1) % full_attention_interval``
+    is 0 (grouped-query at ``head_dim``, an output gate in W_q's second half, ``1 + w`` head
+    norms, RoPE over the first ``partial_rotary_factor`` of a head's channels), else the
+    gated delta rule (``linear_*``: key heads shared by value heads, one decay a token and
+    value head); every layer with gated experts (softmax over all, ``num_experts_per_tok`` of
+    them renormalised, no selection bias) beside one shared expert scaled by a sigmoid
+    gate; every stream norm ``1 + w``; an untied head. What the file states and this module
+    does not compute is refused, not ignored."""
+    turned = int(config["head_dim"]) * float(config.get("partial_rotary_factor", 1.0))
+    key_heads, value_heads = (int(config[f"linear_num_{x}_heads"]) for x in ("key", "value"))
+    _refuse({
+        "rope_scaling not null": config.get("rope_scaling") is not None,
+        "use_sliding_window true": bool(config.get("use_sliding_window", False)),
+        "mlp_only_layers that is not empty": bool(config.get("mlp_only_layers")),
+        "decoder_sparse_step other than 1": config.get("decoder_sparse_step", 1) != 1,
+        "selected scores that are not normalised (norm_topk_prob false)":
+            not config.get("norm_topk_prob", True),
+        "hidden_act other than silu": config.get("hidden_act", "silu") != "silu",
+        "attention_bias true": bool(config.get("attention_bias", False)),
+        "a partial_rotary_factor whose rotated width is not an even number of channels":
+            turned != int(turned) or int(turned) % 2 != 0,
+        "linear_num_key_heads that do not divide linear_num_value_heads":
+            bool(value_heads % key_heads),
+        "linear_key_head_dim other than linear_value_head_dim":
+            config["linear_key_head_dim"] != config["linear_value_head_dim"],
+        "multi-token prediction (num_nextn_predict_layers or mtp_num_hidden_layers > 0: "
+        "the trainer has no such loss)": bool(config.get("num_nextn_predict_layers", 0))
+        or bool(config.get("mtp_num_hidden_layers", 0)),
+    })
+    depth = int(config.get("published", {}).get("num_hidden_layers",
+                                                config["num_hidden_layers"]))
+    every = int(config["full_attention_interval"])
+    return ["gdn" if (i + 1) % every else "full_attention" for i in range(depth)], dict(
+        _held_experts(config, "num_experts"), num_dense_layers=0,
+        norm_eps=float(config["rms_norm_eps"]), norm_unit_offset=True,
+        rope_theta=float(config["rope_theta"]), rope_dim=int(turned), qk_norm=True,
+        attention_head_dim=int(config["head_dim"]), attention_gate=True,
+        tied_head=bool(config.get("tie_word_embeddings", False)),
+        router_scoring="softmax", router_eps=0.0,
+        shared_expert_size=int(config["shared_expert_intermediate_size"]),
+        gated_shared_expert=True, shared_expert_gate=True,
+        gdn_heads=(key_heads, value_heads), kda_head_dim=int(config["linear_key_head_dim"]),
+        conv_kernel=int(config["linear_conv_kernel_dim"]), kept=GDN_KEPT)
+
+
 _FAMILIES = {"lfm2_moe": _lfm2_moe, "nemotron_h": _nemotron_h,
              "kimi_linear": _kimi_linear, "evabyte": _evabyte,
-             "deepseek_v3": _deepseek_v3}
+             "deepseek_v3": _deepseek_v3, "qwen3_next": _qwen3_next}
 
 
 def from_config_file(path: str, **kwargs) -> HybridLM:

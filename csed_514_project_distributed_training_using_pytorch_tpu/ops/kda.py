@@ -140,6 +140,24 @@ def _pair_scores(q, k, kb, cum, sub: int, dtype):
     return kk, qk
 
 
+def _scalar_pair_scores(q, k, kb, g, dtype):
+    """``_pair_scores`` where a token's decay is one number (``g [C, K]`` holds it on every
+    lane): ``exp(G_i − G_j)`` leaves the sum over channels, so both score matrices are ONE
+    product ``[βk; q] kᵀ`` times a ``[C, C]`` mask. ``G_i − G_j = Σ_{j<t≤i} g_t`` is itself a
+    product with the triangle of ones (float32 at ``highest``; no column of running sums
+    is turned into a row), at most zero where ``i >= j``: no factor passes one, a decay
+    too small for float32 reads zero, and ``exp(−G)`` is never formed."""
+    c = k.shape[0]
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+    after = jnp.where(row > col, jnp.broadcast_to(g[:, :1], (c, c)), 0.0)   # g_t, t > j
+    span = jax.lax.dot_general((row >= col).astype(jnp.float32), after, (NN, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+    decay = jnp.where(row >= col, jnp.exp(span), 0.0)
+    both = _dot(jnp.concatenate([kb, q]), k, NT, dtype)
+    return jnp.where(row > col, both[:c] * decay, 0.0), both[c:] * decay
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
 def _unit_lower_inverses(mats, sub: int, dtype):
     """``(I + a)⁻¹`` of every strictly lower triangular ``a [C, C]`` of the tuple ``mats``,
@@ -181,10 +199,11 @@ def _inverses_bwd(sub, dtype, inverses, ds):
 _unit_lower_inverses.defvjp(_inverses_fwd, _inverses_bwd)
 
 
-def _chunks(q, k, kb, vb, g, state, chunk: int, sub: int, dtype):
+def _chunks(q, k, kb, vb, g, state, chunk: int, sub: int, dtype, scalar: bool = False):
     """The chunks of one grid step of one head: ``(o [R, V] float32, the state after
     them)``. ``state`` is ``Sᵀ [V, K]``; ``q``, ``k``, ``kb = βk`` ``[R, K]``, ``vb = βv [R, V]`` and
-    ``g [R, K]`` float32; the products run in ``dtype``."""
+    ``g [R, K]`` float32; the products run in ``dtype``. ``scalar``: a row of ``g`` is one
+    number on every lane, and the pairs' scores are ``_scalar_pair_scores``'."""
     c = chunk
     ones = (_iota((c, c), 0) >= _iota((c, c), 1)).astype(jnp.float32)
     by_chunk = lambda x: [x[at:at + c] for at in range(0, x.shape[0], c)]
@@ -196,7 +215,8 @@ def _chunks(q, k, kb, vb, g, state, chunk: int, sub: int, dtype):
                                 precision=jax.lax.Precision.HIGHEST,
                                 preferred_element_type=jnp.float32) for x in by_chunk(g)]
     growns = [jnp.exp(cum) for cum in cums]
-    scores = [_pair_scores(*x, sub, dtype) for x in zip(qs, ks, kbs, cums)]
+    scores = ([_scalar_pair_scores(*x, dtype) for x in zip(qs, ks, kbs, by_chunk(g))]
+              if scalar else [_pair_scores(*x, sub, dtype) for x in zip(qs, ks, kbs, cums)])
     inverses = _unit_lower_inverses(tuple(a_kk for a_kk, _ in scores), sub, dtype)
     ws = [_dot(inverse, kb * grown, NN, dtype)
           for inverse, kb, grown in zip(inverses, kbs, growns)]
@@ -216,17 +236,21 @@ def _unit(x, scale: float = 1.0):
     return x * (jax.lax.rsqrt(jnp.sum(x * x, axis=1, keepdims=True) + 1e-6) * scale)
 
 
-def _group(q, k, v, g, beta, state, chunk: int, sub: int, eps: float):
+def _group(q, k, v, g, beta, state, chunk: int, sub: int, eps: float,
+           scalar: bool = False):
     """The chunks of one grid step, one after the other: ``(o, the state after)``, with
     everything that is one number a token of this head: ``q``, ``k`` ``[R, K]`` and ``v
     [R, V]`` as the projections wrote them (the model's dtype) are brought to unit
     length and to ``βv`` here, ``beta [R, 1]`` float32 a column that broadcasts along
-    the lanes, and a row of ``o`` leaves divided by its root mean square."""
+    the lanes, and a row of ``o`` leaves divided by its root mean square. ``scalar``: ``g``
+    is such a column too, one log-decay a token."""
     dtype = q.dtype
+    if scalar:
+        g = jnp.broadcast_to(g, k.shape)
     q = _unit(q.astype(jnp.float32), q.shape[1] ** -0.5)
     k = _unit(k.astype(jnp.float32))
     kb, vb = beta * k, beta * v.astype(jnp.float32)
-    o, state = _chunks(q, k, kb, vb, g, state, chunk, sub, dtype)
+    o, state = _chunks(q, k, kb, vb, g, state, chunk, sub, dtype, scalar)
     o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=1, keepdims=True) + eps)
     return o.astype(v.dtype), state
 
@@ -246,13 +270,19 @@ def _as_row(column, width: int):
         precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)[:1]
 
 
+def _decay(g_ref, static: dict):
+    """A program's log-decays: its ``[R, K]`` block, or with ``scalar`` its head's column of
+    the ``[R, H]`` block of every head."""
+    return _head_column(g_ref[...]) if static.get("scalar") else g_ref[...]
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, entered_ref, state, **static):
     @pl.when(pl.program_id(2) == 0)
     def _():
         state[...] = jnp.zeros_like(state)
 
     entered_ref[...] = state[...]
-    o_ref[...], state[...] = _group(q_ref[...], k_ref[...], v_ref[...], g_ref[...],
+    o_ref[...], state[...] = _group(q_ref[...], k_ref[...], v_ref[...], _decay(g_ref, static),
                                     _head_column(beta_ref[...]), state[...], **static)
 
 
@@ -265,23 +295,27 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, entered_ref, do_ref,
         dstate[...] = jnp.zeros_like(dstate)
 
     _, pull = jax.vjp(functools.partial(_group, **static),
-                      q_ref[...], k_ref[...], v_ref[...], g_ref[...],
+                      q_ref[...], k_ref[...], v_ref[...], _decay(g_ref, static),
                       _head_column(beta_ref[...]), entered_ref[...])
-    (dq_ref[...], dk_ref[...], dv_ref[...], dg_ref[...], dbeta,
-     dstate[...]) = pull((do_ref[...], dstate[...]))
+    dq_ref[...], dk_ref[...], dv_ref[...], dg, dbeta, handed = pull((do_ref[...], dstate[...]))
+    dg_ref[...] = _as_row(dg, q_ref.shape[1]) if static.get("scalar") else dg
+    dstate[...] = handed
     dbeta_ref[...] = _as_row(dbeta, q_ref.shape[1])
 
 
-def _specs(rows: int, heads: int, k: int, v: int, at):
+def _specs(rows: int, heads: int, k: int, v: int, at, rep: int = 1):
     """Block specs by operand; ``at(s)`` is the group that step ``s`` of the sequential
     grid axis works on (the backward pass walks them in reverse). A head's channels
     are a block of lanes of the ``[B, S, H·width]`` arrays: no operand is transposed.
     ``beta`` comes as the ``[R, H]`` block of every head, of which a program reads its
     column; its gradient leaves as a row of ``[B, H, S/R, 1, R]``, so that the programs
-    of one block of tokens write blocks of their own."""
+    of one block of tokens write blocks of their own. ``shared``: the queries and keys of
+    ``rep`` value heads are one key head's, ``[B, S, (H/rep)·K]``, and program ``h`` reads
+    block ``h // rep``."""
     tokens = lambda width: pl.BlockSpec((None, rows, width),
                                         lambda b, h, s: (b, at(s), h))
     return {"k": tokens(k), "v": tokens(v),
+            "shared": pl.BlockSpec((None, rows, k), lambda b, h, s: (b, at(s), h // rep)),
             "beta": pl.BlockSpec((None, rows, heads), lambda b, h, s: (b, at(s), 0)),
             "dbeta": pl.BlockSpec((None, None, None, 1, rows),
                                   lambda b, h, s: (b, h, at(s), 0, 0)),
@@ -294,14 +328,19 @@ def _params():
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
-def _scan_fwd(q, k, v, g, beta, chunk: int, sub: int, group: int, eps: float):
+def _scan_fwd(q, k, v, g, beta, chunk: int, sub: int, group: int, eps: float,
+              scalar: bool = False, rep: int = 1):
+    """``kda_fwd``; with ``scalar`` ``gdn_fwd``: ``g [B, S, H]`` like ``beta``, and ``q``, ``k`` of
+    ``H / rep`` key heads."""
     bsz, s, heads = beta.shape
-    dk, dv, rows = q.shape[2] // heads, v.shape[2] // heads, group * chunk
-    sp = _specs(rows, heads, dk, dv, lambda step: step)
+    dk, dv, rows = q.shape[2] // (heads // rep), v.shape[2] // heads, group * chunk
+    sp = _specs(rows, heads, dk, dv, lambda step: step, rep)
+    keys, decay = (sp["shared"], sp["beta"]) if scalar else (sp["k"], sp["k"])
+    static = dict(chunk=chunk, sub=sub, eps=eps, **({"scalar": True} if scalar else {}))
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, chunk=chunk, sub=sub, eps=eps), name="kda_fwd",
+        functools.partial(_fwd_kernel, **static), name="gdn_fwd" if scalar else "kda_fwd",
         interpret=_interpret(), grid=(bsz, heads, s // rows),
-        in_specs=[sp["k"], sp["k"], sp["v"], sp["k"], sp["beta"]],
+        in_specs=[keys, keys, sp["v"], decay, sp["beta"]],
         out_specs=[sp["v"], sp["state"]],
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct((bsz, s // rows, heads, dv, dk), jnp.float32)],
@@ -310,30 +349,48 @@ def _scan_fwd(q, k, v, g, beta, chunk: int, sub: int, group: int, eps: float):
     )(q, k, v, g, beta)
 
 
-def _scan_bwd(q, k, v, g, beta, entered, do, chunk: int, sub: int, group: int, eps: float):
+def _scan_bwd(q, k, v, g, beta, entered, do, chunk: int, sub: int, group: int, eps: float,
+              scalar: bool = False, rep: int = 1):
+    """``kda_bwd``; with ``scalar`` ``gdn_bwd``: ``dg`` leaves as ``dbeta`` does, and a value
+    head's program writes its own ``dq``, ``dk`` block, which the ``rep`` heads of a key head
+    sum outside (two programs may not write one block)."""
     bsz, s, heads = beta.shape
-    dk, dv, rows = q.shape[2] // heads, v.shape[2] // heads, group * chunk
+    dk, dv, rows = q.shape[2] // (heads // rep), v.shape[2] // heads, group * chunk
     groups = s // rows
-    sp = _specs(rows, heads, dk, dv, lambda step: groups - 1 - step)
-    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
-    *wide, dbeta = pl.pallas_call(
-        functools.partial(_bwd_kernel, chunk=chunk, sub=sub, eps=eps), name="kda_bwd",
+    sp = _specs(rows, heads, dk, dv, lambda step: groups - 1 - step, rep)
+    keys, decay, ddecay = ((sp["shared"], sp["beta"], sp["dbeta"]) if scalar
+                           else (sp["k"], sp["k"], sp["k"]))
+    like = lambda x, width=None: jax.ShapeDtypeStruct(
+        x.shape[:2] + (width or x.shape[2],), x.dtype)
+    row = jax.ShapeDtypeStruct((bsz, heads, groups, 1, rows), jnp.float32)
+    static = dict(chunk=chunk, sub=sub, eps=eps, **({"scalar": True} if scalar else {}))
+    dq, dk_, dv_, dg, dbeta = pl.pallas_call(
+        functools.partial(_bwd_kernel, **static), name="gdn_bwd" if scalar else "kda_bwd",
         interpret=_interpret(), grid=(bsz, heads, groups),
-        in_specs=[sp["k"], sp["k"], sp["v"], sp["k"], sp["beta"], sp["state"], sp["v"]],
-        out_specs=[sp["k"], sp["k"], sp["v"], sp["k"], sp["dbeta"]],
-        out_shape=[like(q), like(k), like(v), like(g),
-                   jax.ShapeDtypeStruct((bsz, heads, groups, 1, rows), jnp.float32)],
+        in_specs=[keys, keys, sp["v"], decay, sp["beta"], sp["state"], sp["v"]],
+        out_specs=[sp["k"], sp["k"], sp["v"], ddecay, sp["dbeta"]],
+        out_shape=[like(q, heads * dk), like(k, heads * dk), like(v),
+                   row if scalar else like(g), row],
         scratch_shapes=[pltpu.VMEM((dv, dk), jnp.float32)],
         compiler_params=_params(),
     )(q, k, v, g, beta, entered, do.astype(v.dtype))
-    return *wide, jnp.swapaxes(dbeta.reshape(bsz, heads, s), 1, 2)
+    by_token = lambda x: jnp.swapaxes(x.reshape(bsz, heads, s), 1, 2)
+    if scalar:
+        dg = by_token(dg)
+        if rep > 1:
+            dq, dk_ = (x.reshape(bsz, s, heads // rep, rep, dk).astype(jnp.float32).sum(3)
+                       .reshape(q.shape).astype(q.dtype) for x in (dq, dk_))
+    return dq, dk_, dv_, dg, by_token(dbeta)
 
 
 @functools.lru_cache(maxsize=None)
-def _make_op(chunk: int, sub: int, group: int, eps: float):
+def _make_op(chunk: int, sub: int, group: int, eps: float, scalar: bool = False,
+             rep: int = 1):
     # Jitted halves behind a cached factory, as ``ssm._make_op``: every KDA layer of a
     # model calls the same two functions, lowered once a program.
     kw = dict(chunk=chunk, sub=sub, group=group, eps=eps)
+    if scalar:
+        kw.update(scalar=True, rep=rep)
     forward = jax.jit(functools.partial(_scan_fwd, **kw))
     backward = jax.jit(functools.partial(_scan_bwd, **kw))
 
@@ -355,6 +412,21 @@ def _make_op(chunk: int, sub: int, group: int, eps: float):
     return op
 
 
+def _check_tiling(chunk: int, sub: int) -> None:
+    if chunk % sub or (chunk // sub) & (chunk // sub - 1) or sub & (sub - 1):
+        raise ValueError(f"sub-blocks of {sub} rows do not halve a chunk of {chunk}")
+
+
+def _padded_scan(scope: str, op, operands, s: int, rows: int):
+    """``op`` over ``operands [B, S, ·]`` padded at the end to whole groups of ``rows``
+    tokens (zeros decay nothing and write nothing), the result sliced back to ``S``."""
+    short = -s % rows
+    padded = [jnp.pad(x, ((0, 0), (0, short), (0, 0))) for x in operands]
+    with jax.named_scope(scope):
+        o = op(*padded)
+    return o[:, :s]
+
+
 def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array, *,
              eps: float, chunk: int = CHUNK, sub: int = SUB, group: int = GROUP) -> jax.Array:
     """``RMSNorm_head(o) [B, S, H·V]`` (no learned scale) of the recurrence above, on the
@@ -363,27 +435,46 @@ def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.A
     ``<= 0``) and ``beta [B, S, H]`` float32, whose last axis says how many heads there
     are. Differentiable in all five. Any ``S``: the tail of a sequence is padded to a
     whole group of chunks."""
-    s = q.shape[1]
-    if chunk % sub or (chunk // sub) & (chunk // sub - 1) or sub & (sub - 1):
-        raise ValueError(f"sub-blocks of {sub} rows do not halve a chunk of {chunk}")
-    short = -s % (group * chunk)
-    padded = [jnp.pad(x, ((0, 0), (0, short), (0, 0)))
-              for x in (q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32))]
-    with jax.named_scope("kda"):
-        o = _make_op(chunk, sub, group, eps)(*padded)
-    return o[:, :s]
+    _check_tiling(chunk, sub)
+    return _padded_scan("kda", _make_op(chunk, sub, group, eps),
+                        (q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32)),
+                        q.shape[1], group * chunk)
+
+
+def gdn_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array, *,
+             key_heads: int, eps: float, chunk: int = CHUNK, sub: int = SUB,
+             group: int = GROUP) -> jax.Array:
+    """``kda_scan`` for a decay that is one number a token and head (the gated delta rule:
+    ``Diag(exp g) = e^g I``), computed as such: ``g [B, S, H]`` like ``beta``, a chunk's scores
+    one product and a ``[C, C]`` mask (``_scalar_pair_scores``), everything else the same
+    kernels' (``gdn_fwd``, ``gdn_bwd`` on a trace). ``q``, ``k`` ``[B, S, key_heads·K]`` may hold
+    fewer heads than ``v [B, S, H·V]``: value head ``h`` reads key head ``h // (H / key_heads)``
+    through its block spec, and the key heads' gradients are summed over their value
+    heads outside the kernel."""
+    _check_tiling(chunk, sub)
+    heads = beta.shape[2]
+    if heads % key_heads or q.shape[2] % key_heads or q.shape != k.shape:
+        raise ValueError(f"{key_heads} key heads do not divide {heads} value heads, or "
+                         f"the channels of q {q.shape} and k {k.shape}")
+    return _padded_scan("gdn", _make_op(chunk, sub, group, eps, True, heads // key_heads),
+                        (q, k, v, g.astype(jnp.float32), beta.astype(jnp.float32)),
+                        q.shape[1], group * chunk)
 
 
 def scan_plan(*, heads: int, key_dim: int, value_dim: int, seq_len: int,
               chunk: int = CHUNK, sub: int = SUB, group: int = GROUP,
-              kept: tuple[str, ...] = ()) -> dict:
+              kept: tuple[str, ...] = (), key_heads: int | None = None) -> dict:
     """The ``compile`` event's ``kda`` field: what a KDA layer asks of a step. A state is
-    kept a group of chunks, not a chunk."""
+    kept a group of chunks, not a chunk. With ``key_heads`` the ``gdn`` field: ``heads``
+    value heads over that many key heads, the decay one number a token and head."""
     rows = group * chunk
     groups = -(-seq_len // rows)
-    return {"heads": heads, "key_dim": key_dim, "value_dim": value_dim, "chunk": chunk,
+    plan = {"heads": heads, "key_dim": key_dim, "value_dim": value_dim, "chunk": chunk,
             "sub_block": sub, "chunks_per_sequence": groups * group,
             "states_per_sequence": groups,
             "state_bytes_per_sequence": groups * heads * key_dim * value_dim * 4,
             "kept": [name for name in ("kda_out", "kda_state") if name in kept],
             "in_kernel": ["q_norm", "k_norm", "beta", "out_norm"]}
+    if key_heads is not None:
+        plan.update(key_heads=key_heads, group=group, decay="scalar")
+    return plan

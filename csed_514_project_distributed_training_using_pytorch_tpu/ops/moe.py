@@ -9,7 +9,8 @@ expert is computed, at any imbalance.
 
     route      s = sigmoid(u · W_r) in float32; the experts are the top-k of s + b
                (b enters the selection only); their weights are s_e / (Σ s_e + eps),
-               times the model's scaling
+               times the model's scaling. Or s = softmax(u · W_r) over all the
+               experts, the top-k of s, no b, the same renormalisation
     sort       a token can send a held expert at most one row, so of its k assignments
                at most min(k, n_held) land here: where k is the larger, each token's
                held assignments are moved to the front and the rest cut off (each takes
@@ -89,9 +90,12 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def route(u: jax.Array, router_kernel: jax.Array, select_bias: jax.Array, *,
-          top_k: int, scaling: float = 1.0, eps: float = 1e-6, load: bool = False
-          ) -> tuple[jax.Array, ...]:
+SCORINGS = ("sigmoid", "softmax")    # what ``route`` makes of the router's logits
+
+
+def route(u: jax.Array, router_kernel: jax.Array, select_bias: jax.Array | None, *,
+          top_k: int, scaling: float = 1.0, eps: float = 1e-6, load: bool = False,
+          scoring: str = "sigmoid") -> tuple[jax.Array, ...]:
     """``u [T, d]`` -> ``(weights [T, k] float32, experts [T, k] int32)`` over all the
     router's experts. Matmul (at ``highest``: one bf16 pass would move near-tied
     selections), sigmoid and top-k in float32; ``select_bias`` moves the selection
@@ -101,7 +105,9 @@ def route(u: jax.Array, router_kernel: jax.Array, select_bias: jax.Array, *,
     ``load=True`` adds a third result, ``[experts] int32``: the tokens that selected
     each of the router's experts, held here or not (what ``rebalanced_bias`` reads),
     counted as the biased scores at or over a token's ``top_k``-th: one pass over
-    ``[T, experts]``."""
+    ``[T, experts]``. ``scoring="softmax"``: the scores are the softmax over all the
+    router's logits (the selection is the logits' own; ``select_bias`` None: such a router
+    has none), and the selected ones are renormalised as the sigmoid's are."""
     with jax.named_scope("moe/route"):
         # Named: what a caller's ``jax.checkpoint`` may keep of the router (a policy
         # over names; an identity otherwise). The logits and not the scores, because
@@ -109,8 +115,10 @@ def route(u: jax.Array, router_kernel: jax.Array, select_bias: jax.Array, *,
         logits = checkpoint_name(jnp.dot(
             u.astype(jnp.float32), router_kernel.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST), "moe_route")
-        scores = jax.nn.sigmoid(logits)
-        biased = scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32))
+        scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+            else jax.nn.softmax(logits, axis=-1)
+        biased = scores if select_bias is None \
+            else scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32))
         top, experts = jax.lax.top_k(biased, top_k)
         experts = checkpoint_name(experts.astype(jnp.int32), "moe_route")
         picked = checkpoint_name(_pick(scores, experts), "moe_route")

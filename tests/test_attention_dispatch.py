@@ -105,6 +105,7 @@ _RECORDED = [
     ("classifier-B16-S2048-flash", (16, 2048, 8, 128), False, "flash"),
     ("tier1-tiny-dense", (8, 784, 2, 16), True, "dense"),
     ("kimi-cell-B2-H32-S8192-keys192-values128-flash", (2, 8192, 32, (192, 128)), True, "flash"),
+    ("qwen3-next-cell-B2-H16-S8192-d256-flash", (2, 8192, 16, 256), True, "flash"),
 ]
 
 
@@ -131,6 +132,7 @@ def test_dispatch_predicate_on_recorded_shapes(shape, causal, impl):
 _BACKWARD = [
     ("kanana2-and-kimi-S8192-keys192", (2, 8192, 32, 192), 128, "fused"),
     ("lfm2-S8192-d64", (4, 8192, 32, 64), None, "fused"),
+    ("qwen3-next-S8192-d256", (2, 8192, 16, 256), None, "fused"),
     ("nemotron-S8192-4-heads", (2, 8192, 4, 128), None, "fused"),
     ("lm-b16-S784-padded-896", (16, 784, 8, 128), None, "fused"),
     ("evabyte-windows-of-2048", (16, 2048, 16, 128), None, "fused"),

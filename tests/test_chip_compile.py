@@ -416,3 +416,53 @@ def test_an_evabyte_blocks_norm_backward_is_in_no_products_epilogue(one_chip):
                           r'op_name="[^"]*transpose\(jvp[^"]*/dot_general"', text, flags=re.M)
     assert len(products) >= 10, "the backward pass's products carry their op_name"
     assert not [result for result in products if "f32[4096]" in result], products
+
+
+# Qwen3-Next-80B-A3B, chip 0 of 16: 32 delta value heads on 16 key heads of 128 x 128, one decay
+# a token and head; 16 attention heads of 256 on 2 key/value heads; 2 x 8192 tokens
+GDN = dict(batch=2, seq=8192, key_heads=16, heads=32, head_dim=128, attention_heads=16,
+           attention_dim=256)
+
+
+def test_scalar_decay_scan_kernels_compile_for_the_v5e_at_published_widths(one_chip):
+    """``gdn_fwd`` and ``gdn_bwd`` at the cell's shapes: Mosaic takes the lane select of a
+    head's decay out of the ``[256, 32]`` block beside β's and its broadcast along the lanes,
+    the ``[64, 64]`` mask's product with the triangle of ones, the key head's block read by
+    two value heads' programs (``h // 2`` in the index map), and the row that ``dg`` leaves as;
+    the key heads' gradients leave a block a value head, ``[2, 8192, 4096]``, and are summed
+    to ``[2, 8192, 2048]`` outside."""
+    from csed_514_project_distributed_training_using_pytorch_tpu.ops import kda
+    b, s, kh, h, d = (GDN[k] for k in ("batch", "seq", "key_heads", "heads", "head_dim"))
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    keys, values = spec((b, s, kh * d), jnp.bfloat16), spec((b, s, h * d), jnp.bfloat16)
+    scalars = spec((b, s, h), jnp.float32)
+    loss = lambda *args: jnp.sum(kda.gdn_scan(*args, key_heads=kh, eps=1e-6).astype(jnp.float32))
+    with lowering_for_the_chip(kda):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            keys, keys, values, scalars, scalars).compile()
+    text = compiled.as_text()
+    assert "%gdn_fwd" in text and "%gdn_bwd" in text and "%kda_" not in text
+    rows = kda.GROUP * kda.CHUNK
+    assert f"f32[{b},{s // rows},{h},{d},{d}]" in text              # a state a group
+    assert text.count(f"f32[{b},{h},{s // rows},1,{rows}]") >= 2    # dβ and dg, rows a program
+    assert [x.shape for x in jax.tree.leaves(compiled.out_info)] == \
+        [(b, s, kh * d)] * 2 + [(b, s, h * d)] + [(b, s, h)] * 2
+
+
+def test_flash_kernels_compile_for_the_v5e_at_a_head_width_of_256(one_chip):
+    """``flash_fwd`` and the fused backward (``flash_dkv``, 8.4 MB of float32 dq resident a
+    (batch, head); no ``flash_dq``) at 256 / 256, two lane registers a head, at the blocks
+    ``_flash_plan`` picks for 8192 tokens."""
+    from csed_514_project_distributed_training_using_pytorch_tpu.ops import pallas_attention
+    b, s, h, d = (GDN[k] for k in ("batch", "seq", "attention_heads", "attention_dim"))
+    x = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    loss = lambda q, k, v: jnp.sum(pallas_attention.flash_attention(
+        q, k, v, causal=True).astype(jnp.float32))
+    with lowering_for_the_chip(pallas_attention):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x).compile()
+    text = compiled.as_text()
+    plan = pallas_attention.dispatch_plan((b, s, h, d), causal=True)
+    assert (plan["impl"], plan["backward"], plan["block"]) == ("flash", "fused", 1024)
+    assert pallas_attention.backward_fused(s, d)
+    assert "%flash_fwd" in text and "%flash_dkv" in text and "%flash_dq" not in text
+    assert [x.shape[-1] for x in jax.tree.leaves(compiled.out_info)] == [d, d, d]

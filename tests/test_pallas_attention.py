@@ -29,8 +29,9 @@ def _qkv(b=2, s=256, h=2, d=64, seed=0):
                  for width in (dk, dk, dv))
 
 
-# one width for queries, keys and values, and latent attention's (key, value) widths
-WIDTHS = [64, (192, 128)]
+# one width for queries, keys and values (256: ``qwen3_next_train_8k``'s gated attention, two
+# lane registers a head), and latent attention's (key, value) widths
+WIDTHS = [64, 256, (192, 128)]
 
 
 def _tol(tight_rtol, tight_atol):
@@ -53,11 +54,12 @@ def test_forward_matches_dense(causal, head_dim):
 
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("window", [None, 160])
-@pytest.mark.parametrize("head_dim", [64, 128, (192, 128)])
+@pytest.mark.parametrize("head_dim", [64, 128, 256, (192, 128)])
 def test_gradients_match_dense(causal, window, head_dim):
     """Forward and all three gradients against the dense oracle at the cells' head
     widths (64 is ``lfm2_moe_train_8k``'s, 128 ``lm_train_b16``'s: a whole lane
-    register, keys of 192 and values of 128 ``kimi_linear_train_8k``'s), with and without
+    register, 256 ``qwen3_next_train_8k``'s, keys of 192 and values of 128
+    ``kimi_linear_train_8k``'s), with and without
     a band that straddles the two 128-row blocks, over an odd number of heads."""
     q, k, v = _qkv(b=2, s=256, h=3, d=head_dim, seed=13)
     kw = dict(causal=causal, window=window)

@@ -37,7 +37,8 @@ FAMILIES = {"lfm2_moe": ("test_hybrid_lm", {}),
             "nemotron_h": ("test_nemotron_h", {"chunk_size": 16}),
             "kimi_linear": ("test_kimi_linear", {}),
             "deepseek_v3": ("test_deepseek_v3", {}),
-            "evabyte": ("test_evabyte", {"window_size": 16, "chunk_size": 4})}
+            "evabyte": ("test_evabyte", {"window_size": 16, "chunk_size": 4}),
+            "qwen3_next": ("test_qwen3_next", {})}
 TRAIN_LM = os.path.join(os.path.dirname(train_lm.__file__), "lm.py")
 
 
@@ -92,7 +93,7 @@ def test_the_compile_event_carries_the_models_plans_whole(family, tmp_path, monk
         tests = importlib.import_module(module)
         path = tmp_path / "tiny.json"
         path.write_text(json.dumps(dict(tests.tiny_config(vocab_size=256), **forced)))
-        if family == "kimi_linear":     # the tiling is no key of the file
+        if family in ("kimi_linear", "qwen3_next"):     # the tiling is no key of the file
             build = hybrid_lm.from_config
             monkeypatch.setattr(hybrid_lm, "from_config", lambda *a, **kw: build(
                 *a, **dict(kw, kda_tiling=tests.TILING)))
@@ -101,7 +102,8 @@ def test_the_compile_event_carries_the_models_plans_whole(family, tmp_path, monk
         tokens = 8 * model.seq_len
         said = {"experts": model.expert_plan(tokens), "recompute": model.recompute_plan(jaxpr),
                 "head_products": model.head_products(jaxpr, tokens), "ssm": model.ssm_plan(),
-                "kda": model.kda_plan(), "eva": model.eva_plan(), "norm": model.norm_plan()}
+                "kda": model.kda_plan(), "gdn": model.gdn_plan(), "eva": model.eva_plan(),
+                "norm": model.norm_plan()}
         assert model.plans(jaxpr, tokens) == said
         assert said["recompute"]["kept"] and said["head_products"] == 3
     view = model.trainee()
