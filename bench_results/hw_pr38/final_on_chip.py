@@ -9,5 +9,5 @@ from csed_514_project_distributed_training_using_pytorch_tpu.ops import kda
 
 if __name__ == "__main__":
     say, (operands, shape) = s0.recorder(sys.argv), s0.sizes()
-    for sub in (kda.SUB, 2, 4, 8, 16, kda.SUB):
-        s0.measure(f"the tree (SUB = {kda.SUB})", sub, say, operands, shape)
+    for sub in (kda.KDA_TILING.sub, 2, 4, 8, 16, kda.KDA_TILING.sub):
+        s0.measure(f"the tree (SUB = {kda.KDA_TILING.sub})", sub, say, operands, shape)

@@ -126,7 +126,7 @@ def timed(sub, operands, reps=5):
     forward = jax.jit(lambda *a: scan(*a))
     both = jax.jit(jax.grad(lambda *a: jnp.sum(scan(*a).astype(jnp.float32)),
                             argnums=(0, 1, 2, 3, 4)))
-    out, chunks = {}, b * h * s // kda.CHUNK
+    out, chunks = {}, b * h * s // kda.KDA_TILING.chunk
     for name, fn in (("kda_fwd", forward), ("kda_fwd+kda_bwd", both)):
         t0 = time.perf_counter(); jax.block_until_ready(fn(*operands))
         out[f"{name}_first_call_s"] = round(time.perf_counter() - t0, 2)

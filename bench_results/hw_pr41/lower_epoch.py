@@ -7,7 +7,8 @@ whose hashes agree run the same program in that cell but for locations and op_na
 (bench_results/hw_pr38/lower_epoch.py with the kanana cell beside the five, the forward pass alone
 (the loss, which is what the eval program and the checks' one-row programs run of the model) hashed
 beside the epoch program).
-usage: JAX_PLATFORMS=cpu python lower_epoch.py <repo root to import from> <lm|lfm2|nemotron|kimi|evabyte|kanana>"""
+usage: JAX_PLATFORMS=cpu python lower_epoch.py <repo root to import from> <lm|lfm2|nemotron|kimi|evabyte|kanana|qwen>
+(qwen: added by PR 44)"""
 import hashlib, json, os, re, sys
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 root, cell = os.path.realpath(sys.argv[1]), sys.argv[2]
@@ -40,7 +41,8 @@ else:
     file, vocab, B = {"kimi": ("kimi-linear-48b-a3b-ep32.json", 20480, 2), "lfm2": ("lfm2-24b-a2b-ep8.json", 8192, 4),
                       "nemotron": ("nemotron3-super-120b-tp8-ep64.json", 16384, 2),
                       "evabyte": ("evabyte-6.5b-tp2.json", 320, 1),
-                      "kanana": ("kanana-2-30b-a3b-ep8.json", 16032, 2)}[cell]
+                      "kanana": ("kanana-2-30b-a3b-ep8.json", 16032, 2),
+                      "qwen": ("qwen3-next-80b-a3b-ep16.json", 18992, 2)}[cell]
     S, STEPS = (32768, 4) if cell == "evabyte" else (8192, 8)
     model = hybrid_lm.from_config_file(f"{root}/benchmark/configs/{file}", vocab_size=vocab, seq_len=S,
                                        dtype=jnp.bfloat16, remat=True, attention_fn=ops.dispatch_attention)

@@ -184,7 +184,7 @@ class HybridLM:
     kda_heads: int = 0                  # heads of a KDA mixer
     kda_head_dim: int = 128             # a KDA or gdn head's key width, and its value width
     gdn_heads: tuple[int, int] = (0, 0)     # key heads, value heads of a gated delta layer
-    kda_tiling: tuple[int, int, int] = (kda.CHUNK, kda.SUB, kda.GROUP)  # ops.kda's
+    kda_tiling: tuple[int, int, int] | None = None  # None: ops.kda's own for the decay's kind
     kv_lora_rank: int = 0               # the key/value latent of an MLA mixer
     qk_nope_head_dim: int = 128         # a head's key channels from the latent
     qk_rope_head_dim: int = 64          # and those every head shares (rotated with rope_theta)
@@ -357,7 +357,7 @@ class HybridLM:
 
     @property
     def _kda_tiles(self) -> dict:
-        return dict(zip(("chunk", "sub", "group"), self.kda_tiling))
+        return dict(zip(("chunk", "sub", "group"), self.kda_tiling or ()))
 
     def expert_plan(self, tokens: int) -> dict | None:
         """What a step of ``tokens`` tokens asks of each sparse layer

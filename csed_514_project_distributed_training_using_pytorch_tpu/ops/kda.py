@@ -20,8 +20,8 @@ to 128 lanes and rewritten flat, with the heads moved between sublanes and lanes
 (PERF.md §6, PR 34). Their gradients are autodiff's, inside ``kda_bwd``; ``dβ`` leaves as
 a row a program.
 
-The scan walks a sequence in chunks of ``C`` tokens (``CHUNK``). With ``G`` the running
-sum of ``g`` from the chunk's start (inclusive) and ``P(a, b)_ij = Σ_c a_ic b_jc
+The scan walks a sequence in chunks of ``C`` tokens (a ``Tiling``'s ``chunk``). With ``G`` the
+running sum of ``g`` from the chunk's start (inclusive) and ``P(a, b)_ij = Σ_c a_ic b_jc
 exp(G_ic − G_jc)``::
 
     A = strict_lower(P(βk, k))        Ũ = (I + A)⁻¹ (βv − (βk ⊙ e^G) S)
@@ -30,11 +30,11 @@ exp(G_ic − G_jc)``::
 
 ``exp(−G)`` is never formed: a trained decay spans a few units a chunk, a seeded one
 hundreds, and ``exp(G_i − G_j)`` is wanted only where ``i >= j``, where it is at most
-one. ``P`` is built in sub-blocks of ``SUB`` rows (4: chosen on the chip, PERF.md §6, PR
+one. ``P`` is built in sub-blocks of ``sub`` rows (4: chosen on the chip, PERF.md §6, PR
 38). A pair inside one sub-block is computed exactly, ``Σ_c a_ic b_jc exp(G_ic − G_jc)``
 a diagonal of the sub-block at a time (the rows shifted by their distance, on the VPU:
 three diagonals a chunk). Every other pair is in the later half's rows against the
-earlier half's columns of one block of ``2·SUB``, ``4·SUB``, … ``C`` rows, and each doubling
+earlier half's columns of one block of ``2·sub``, ``4·sub``, … ``C`` rows, and each doubling
 is one product of two operands rescaled against the later half's first row ``m``,
 ``a ⊙ exp(G − G_m)`` below it and ``b ⊙ exp(G_m − G)`` above, both factors at most one,
 so a decay too small for float32 reads zero and never infinity.
@@ -42,23 +42,37 @@ so a decay too small for float32 reads zero and never infinity.
 ``(I − D)(I + D²)(I + D⁴)…`` (``D`` is nilpotent), then ``(I − N)(I + N²)…`` over the
 sub-blocks with ``N = (I + D)⁻¹(A − D)``, so no power of the whole ``A`` is taken.
 
-``kda_fwd`` takes ``GROUP`` chunks a grid step, the groups of one (batch, head) along a
+``kda_fwd`` takes ``group`` chunks a grid step, the groups of one (batch, head) along a
 sequential grid axis with the state carried in VMEM (held transposed, ``[V, K]``, so
-that a channel's decay is a lane's), and writes the state that entered every GROUP
-(``[B, S/(GROUP·C), H, V, K]`` float32: a state is 64 KiB at the published 128 x 128, so
-every chunk's would be 0.27 GB a layer and sequence of 8192, and a group's is a
-quarter of that). ``kda_bwd`` walks the groups in reverse carrying the state's
-gradient: a step runs its group's chunks again from the kept state and then their
+that a channel's decay is a lane's), and writes the state that entered every group
+(``[B, S/(group·C), H, V, K]`` float32: a state is 64 KiB at the published 128 x 128, so
+every 64 tokens' would be 0.27 GB a layer and sequence of 8192, and a group's of 256
+or 512 tokens is a quarter or an eighth of that). ``kda_bwd`` walks the groups in
+reverse carrying the state's gradient: a step runs its group's chunks again from the kept state and then their
 transpose, which is ``jax.vjp`` of the very function the forward kernel runs, traced
 into the kernel (so the two cannot drift apart). Within a grid step, what no state
 enters (running sums, ``P``, the inverses, ``(I + A)⁻¹βk e^G`` and ``(I + A)⁻¹βv``) is
-computed for all ``GROUP`` chunks first, the inverses' products a step at a time across
+computed for all ``group`` chunks first, the inverses' products a step at a time across
 the chunks, and the states follow: a chunk's small products wait for one another,
 and chunk by chunk the MXU stood idle in the waits. Decays, masks, the running sums
 (one float32 product with a triangle of ones, at ``highest``) and the state are
 float32; every other product runs on the MXU in the model's dtype.
 
-A sequence whose length is not a multiple of ``GROUP·C`` is padded at its end with
+What a grid step holds (``Tiling``: the tokens of a chunk, the rows of a sub-block, the
+chunks a step) changes no result beyond the products' rounding, since the chunked algorithm
+is the recurrence for any chunk, and is no key of any model's file: it is sized on the
+chip, one tiling a decay kind, because what bounds these kernels is the waits of small
+dependent products and those regroup with the tiling (alone at the cells' shapes, forward
++ backward of a layer, bench_results/hw_pr44/scan_tilings.jsonl: the scalar pair 26.5 ms at
+(64, 4, 4), 23.6 at (64, 4, 8), 22.4 at (128, 8, 2), 18.8 at (128, 8, 4): half the grid steps
+and the state walk's products at the MXU's full depth; the per-channel pair 25.5, 23.7 at
+(64, 4, 8), 24.9 at (128, 8, 2)). ``kda_scan`` and ``gdn_scan`` default to their own
+(``KDA_TILING``, ``GDN_TILING``). Both are held inside the 16 MiB of scoped fast memory Mosaic
+allows a kernel that asks for none: with 32 the same sweep read 17.1 ms at (128, 8, 8) for
+the scalar pair and 20.2 at (128, 4, 4) for the per-channel pair, which no cell has run
+yet (PERF.md §7); a chunk of 256 is slower (per-channel) or dies in the compiler (scalar).
+
+A sequence whose length is not a multiple of ``group·C`` is padded at its end with
 tokens that decay nothing and write nothing (``g = 0``, ``β = 0``, zero ``q̃``, ``k̃`` and ``v``,
 which the ``1e-6`` under the norms' roots leaves zeros), and the result sliced.
 """
@@ -66,6 +80,7 @@ which the ``1e-6`` under the norms' roots leaves zeros), and the result sliced.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -73,9 +88,19 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-CHUNK = 64      # tokens of a chunk: the published kernels', and what keeps (I + A)⁻¹ small
-SUB = 4         # rows of a sub-block: pairs inside one are computed exactly
-GROUP = 4       # chunks a grid step, and between two kept states
+
+class Tiling(NamedTuple):
+    """What one grid step of the scan holds. The recurrence is the same for any of them."""
+    chunk: int      # tokens of a chunk, and the side of (I + A)⁻¹
+    sub: int        # rows of a sub-block: pairs inside one are computed exactly
+    group: int      # chunks a grid step, side by side, and between two kept states
+
+
+# One tiling a decay kind, from its sweep on the chip at the cells' shapes: the fastest
+# forward + backward inside Mosaic's own 16 MiB of scoped fast memory that a cell has run
+# and checked (bench_results/hw_pr44/scan_tilings.jsonl; PERF.md §6, PR 44).
+KDA_TILING = Tiling(64, 4, 4)       # a decay a channel (``kda_fwd``, ``kda_bwd``)
+GDN_TILING = Tiling(128, 8, 4)      # one decay a token and head (``gdn_fwd``, ``gdn_bwd``)
 
 NN, NT, TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
 
@@ -428,7 +453,8 @@ def _padded_scan(scope: str, op, operands, s: int, rows: int):
 
 
 def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array, *,
-             eps: float, chunk: int = CHUNK, sub: int = SUB, group: int = GROUP) -> jax.Array:
+             eps: float, chunk: int = KDA_TILING.chunk, sub: int = KDA_TILING.sub,
+             group: int = KDA_TILING.group) -> jax.Array:
     """``RMSNorm_head(o) [B, S, H·V]`` (no learned scale) of the recurrence above, on the
     flat layout the projections write: ``q``, ``k`` ``[B, S, H·K]`` and ``v [B, S, H·V]`` in
     the model's dtype, ``q`` and ``k`` before their unit norms; ``g [B, S, H·K]`` (log-decays,
@@ -442,8 +468,8 @@ def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.A
 
 
 def gdn_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array, *,
-             key_heads: int, eps: float, chunk: int = CHUNK, sub: int = SUB,
-             group: int = GROUP) -> jax.Array:
+             key_heads: int, eps: float, chunk: int = GDN_TILING.chunk,
+             sub: int = GDN_TILING.sub, group: int = GDN_TILING.group) -> jax.Array:
     """``kda_scan`` for a decay that is one number a token and head (the gated delta rule:
     ``Diag(exp g) = e^g I``), computed as such: ``g [B, S, H]`` like ``beta``, a chunk's scores
     one product and a ``[C, C]`` mask (``_scalar_pair_scores``), everything else the same
@@ -462,19 +488,23 @@ def gdn_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.A
 
 
 def scan_plan(*, heads: int, key_dim: int, value_dim: int, seq_len: int,
-              chunk: int = CHUNK, sub: int = SUB, group: int = GROUP,
+              chunk: int | None = None, sub: int | None = None, group: int | None = None,
               kept: tuple[str, ...] = (), key_heads: int | None = None) -> dict:
     """The ``compile`` event's ``kda`` field: what a KDA layer asks of a step. A state is
     kept a group of chunks, not a chunk. With ``key_heads`` the ``gdn`` field: ``heads``
-    value heads over that many key heads, the decay one number a token and head."""
+    value heads over that many key heads, the decay one number a token and head. What of
+    the tiling is not given is the decay kind's own, as the scans default to it."""
+    own = KDA_TILING if key_heads is None else GDN_TILING
+    chunk, sub, group = (mine if given is None else given
+                         for given, mine in zip((chunk, sub, group), own))
     rows = group * chunk
     groups = -(-seq_len // rows)
     plan = {"heads": heads, "key_dim": key_dim, "value_dim": value_dim, "chunk": chunk,
-            "sub_block": sub, "chunks_per_sequence": groups * group,
+            "sub_block": sub, "group": group, "chunks_per_sequence": groups * group,
             "states_per_sequence": groups,
             "state_bytes_per_sequence": groups * heads * key_dim * value_dim * 4,
             "kept": [name for name in ("kda_out", "kda_state") if name in kept],
             "in_kernel": ["q_norm", "k_norm", "beta", "out_norm"]}
     if key_heads is not None:
-        plan.update(key_heads=key_heads, group=group, decay="scalar")
+        plan.update(key_heads=key_heads, decay="scalar")
     return plan

@@ -214,9 +214,9 @@ KDA = dict(batch=2, seq=8192, heads=32, head_dim=128)
 
 
 def test_delta_rule_kernels_compile_for_the_v5e_at_published_widths(one_chip):
-    """``kda_fwd`` and ``kda_bwd`` at the cell's shapes, on the flat layout (chunks of 64 in
-    sub-blocks of ``kda.SUB``, four chunks a grid step side by side before their states, a
-    128 x 128 state): Mosaic takes the sublane rolls of the exact diagonals, the far
+    """``kda_fwd`` and ``kda_bwd`` at the cell's shapes, on the flat layout at the per-channel
+    branch's committed tiling (``kda.KDA_TILING``: chunks of 64 in sub-blocks of 4, four chunks
+    a grid step side by side before their states, a 128 x 128 state): Mosaic takes the sublane rolls of the exact diagonals, the far
     pairs' product a doubling of the block, the four triangular inverses' products, the lane
     select of a head's β out of the ``[256, 32]`` block, the norms' lane reductions, the
     row that ``dβ`` leaves as, and the transpose of the whole group that ``jax.vjp`` traces
@@ -232,10 +232,13 @@ def test_delta_rule_kernels_compile_for_the_v5e_at_published_widths(one_chip):
             x, x, x, g, beta).compile()
     text = compiled.as_text()
     assert "%kda_fwd" in text and "%kda_bwd" in text
-    assert kda.scan_plan(heads=h, key_dim=d, value_dim=d, seq_len=s)["sub_block"] == kda.SUB
-    rows = kda.GROUP * kda.CHUNK
+    chunk, sub, group = kda.KDA_TILING
+    plan = kda.scan_plan(heads=h, key_dim=d, value_dim=d, seq_len=s)
+    assert (plan["chunk"], plan["sub_block"], plan["group"]) == (chunk, sub, group)
+    rows = group * chunk
+    assert plan["states_per_sequence"] == s // rows
     kept = f"f32[{b},{s // rows},{h},{d},{d}]"      # a state a group
-    assert kept in text and f"f32[{b},{s // kda.CHUNK},{h},{d},{d}]" not in text
+    assert kept in text and f"f32[{b},{s // chunk},{h},{d},{d}]" not in text
     assert f"f32[{b},{h},{s // rows},1,{rows}]" in text         # dβ, a row a program
     assert [x.shape for x in jax.tree.leaves(compiled.out_info)] == \
         [(b, s, h * d)] * 4 + [(b, s, h)]
@@ -260,7 +263,10 @@ def test_a_delta_rule_layer_never_leaves_the_flat_layout(one_chip):
                                        dtype=jnp.bfloat16, remat=True)
     assert (model.kda_heads, model.kda_head_dim) == (h, d)
     # the record says what the kernels ran: the ``compile`` event's ``kda`` field is this plan
-    assert model.kda_plan()["sub_block"] == kda.SUB == model.kda_tiling[1]
+    # and the tiling is the kernels' own for this stack's decay kind, which the file does not name
+    plan = model.kda_plan()
+    assert model.kda_tiling is None
+    assert (plan["chunk"], plan["sub_block"], plan["group"]) == kda.KDA_TILING
     on_chip = lambda tree: jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
     p = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))["params"]["layer_0"]["kda"]
@@ -425,10 +431,11 @@ GDN = dict(batch=2, seq=8192, key_heads=16, heads=32, head_dim=128, attention_he
 
 
 def test_scalar_decay_scan_kernels_compile_for_the_v5e_at_published_widths(one_chip):
-    """``gdn_fwd`` and ``gdn_bwd`` at the cell's shapes: Mosaic takes the lane select of a
-    head's decay out of the ``[256, 32]`` block beside β's and its broadcast along the lanes,
-    the ``[64, 64]`` mask's product with the triangle of ones, the key head's block read by
-    two value heads' programs (``h // 2`` in the index map), and the row that ``dg`` leaves as;
+    """``gdn_fwd`` and ``gdn_bwd`` at the cell's shapes and the scalar branch's committed tiling
+    (``kda.GDN_TILING``: four chunks of 128 a grid step, inside Mosaic's own 16 MiB): Mosaic takes
+    the lane select of a head's decay out of the ``[512, 32]`` block beside β's and its broadcast
+    along the lanes, the ``[128, 128]`` mask's product with the triangle of ones, the key
+    head's block read by two value heads' programs (``h // 2`` in the index map), and the row that ``dg`` leaves as;
     the key heads' gradients leave a block a value head, ``[2, 8192, 4096]``, and are summed
     to ``[2, 8192, 2048]`` outside."""
     from csed_514_project_distributed_training_using_pytorch_tpu.ops import kda
@@ -442,11 +449,45 @@ def test_scalar_decay_scan_kernels_compile_for_the_v5e_at_published_widths(one_c
             keys, keys, values, scalars, scalars).compile()
     text = compiled.as_text()
     assert "%gdn_fwd" in text and "%gdn_bwd" in text and "%kda_" not in text
-    rows = kda.GROUP * kda.CHUNK
+    chunk, sub, group = kda.GDN_TILING
+    plan = kda.scan_plan(heads=h, key_heads=kh, key_dim=d, value_dim=d, seq_len=s)
+    assert (plan["chunk"], plan["sub_block"], plan["group"]) == (chunk, sub, group)
+    rows = group * chunk
+    assert plan["states_per_sequence"] == s // rows
     assert f"f32[{b},{s // rows},{h},{d},{d}]" in text              # a state a group
     assert text.count(f"f32[{b},{h},{s // rows},1,{rows}]") >= 2    # dβ and dg, rows a program
     assert [x.shape for x in jax.tree.leaves(compiled.out_info)] == \
         [(b, s, kh * d)] * 2 + [(b, s, h * d)] + [(b, s, h)] * 2
+
+
+def test_a_gated_delta_layer_keeps_the_states_its_plan_counts(one_chip):
+    """``gdn_mixer`` of the published file, value and every gradient, at the cell's shapes: the
+    file names no tiling, the model passes none (``kda_tiling`` None), and the states the
+    compiled program keeps between ``gdn_fwd`` and ``gdn_bwd`` are the ``compile`` event's
+    ``gdn`` plan's: one a group of the scalar branch's own tiling, ``f32[b, s // rows, h, d, d]``.
+    A tiling Mosaic refuses for fast memory fails here, without a chip."""
+    from csed_514_project_distributed_training_using_pytorch_tpu.models import hybrid_lm
+    from csed_514_project_distributed_training_using_pytorch_tpu.ops import kda
+    b, s, h, d = (GDN[k] for k in ("batch", "seq", "heads", "head_dim"))
+    config = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                          "benchmark", "configs", "qwen3-next-80b-a3b-ep16.json")
+    model = hybrid_lm.from_config_file(config, vocab_size=18992, seq_len=s,
+                                       dtype=jnp.bfloat16, remat=True)
+    plan = model.gdn_plan()
+    assert model.kda_tiling is None and model.kda_plan() is None
+    assert (plan["chunk"], plan["sub_block"], plan["group"]) == kda.GDN_TILING
+    assert (plan["heads"], plan["key_heads"], plan["key_dim"]) == (h, GDN["key_heads"], d)
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+    p = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))["params"]["layer_0"]["gdn"]
+    u = jax.ShapeDtypeStruct((b, s, model.hidden_size), jnp.bfloat16)
+    loss = lambda p, u: jnp.sum(hybrid_lm.gdn_mixer(p, u, model).astype(jnp.float32))
+    with lowering_for_the_chip(kda):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*on_chip((p, u))).compile().as_text()
+    assert "%gdn_fwd" in text and "%gdn_bwd" in text and "%kda_" not in text
+    kept = f"f32[{b},{plan['states_per_sequence']},{h},{d},{d}]"
+    assert plan["state_bytes_per_sequence"] == plan["states_per_sequence"] * h * d * d * 4
+    assert kept in text and f"f32[{b},{s // plan['chunk']},{h},{d},{d}]" not in text
 
 
 def test_flash_kernels_compile_for_the_v5e_at_a_head_width_of_256(one_chip):

@@ -147,6 +147,8 @@ def test_the_references_recurrence_is_the_definition():
     np.testing.assert_allclose(got, token_by_token(q, k, v, g, beta)[0], atol=1e-5)
 
 
+KDA_CHUNK = kda.KDA_TILING.chunk
+KDA_ROWS = KDA_CHUNK * kda.KDA_TILING.group     # tokens of a grid step
 # (batch, S, heads, K, V, chunk, sub, group, decay): a chunk of 16 tokens at decay 8 sums
 # log-decays of −8 · softplus(N(0, 1)) a token: G passes −100 inside it
 SCAN_SIZES = {
@@ -158,11 +160,17 @@ SCAN_SIZES = {
     "one sub-block a chunk": (1, 32, 2, 8, 8, 8, 8, 2, 1.0),
     "published tile: chunk 64, sub-block 16, 128 x 128": (1, 128, 1, 128, 128, 64, 16, 2, 1.0),
     "published tile: chunk 64, sub-block 8, 128 x 128": (1, 128, 1, 128, 128, 64, 8, 2, 1.0),
-    "published tile: chunk 64, sub-block 4 (ops.kda.SUB), 128 x 128":
-        (1, 128, 1, 128, 128, kda.CHUNK, kda.SUB, 2, 1.0),
+    "published tile: the committed chunk and sub-block (ops.kda.KDA_TILING), 128 x 128":
+        (1, 2 * KDA_CHUNK, 1, 128, 128, KDA_CHUNK, kda.KDA_TILING.sub, 2, 1.0),
     # every pair that is not inside a sub-block of 4 is a product of two rescaled operands
     "published tile, steep: G passes -100 inside a chunk":
-        (1, 128, 1, 128, 128, kda.CHUNK, kda.SUB, 2, 8.0),
+        (1, 2 * KDA_CHUNK, 1, 128, 128, KDA_CHUNK, kda.KDA_TILING.sub, 2, 8.0),
+    # the whole committed tiling, its group too: a kept state enters a second group, whose
+    # tail is padding (no multiple of the rows of a grid step)
+    "committed tiling, 128 x 128: a second, padded group":
+        (1, KDA_ROWS + KDA_CHUNK + 6, 1, 128, 128, *kda.KDA_TILING, 1.0),
+    "committed tiling, 128 x 128, steep: a second, padded group":
+        (1, KDA_ROWS + KDA_CHUNK + 6, 1, 128, 128, *kda.KDA_TILING, 8.0),
 }
 
 
@@ -242,11 +250,41 @@ def test_the_scan_refuses_sub_blocks_that_do_not_halve_a_chunk():
 def test_the_scan_plan_counts_the_states_a_sequence_keeps():
     plan = kda.scan_plan(heads=32, key_dim=128, value_dim=128, seq_len=8192,
                          kept=hybrid_lm.KEPT)
-    assert plan == {"heads": 32, "key_dim": 128, "value_dim": 128, "chunk": 64,
-                    "sub_block": 4, "chunks_per_sequence": 128, "states_per_sequence": 32,
-                    "state_bytes_per_sequence": 32 * 32 * 128 * 128 * 4,
+    chunk, sub, group = kda.KDA_TILING
+    assert plan == {"heads": 32, "key_dim": 128, "value_dim": 128, "chunk": chunk,
+                    "sub_block": sub, "group": group, "chunks_per_sequence": 8192 // chunk,
+                    "states_per_sequence": 8192 // KDA_ROWS,
+                    "state_bytes_per_sequence": 8192 // KDA_ROWS * 32 * 128 * 128 * 4,
                     "kept": ["kda_out", "kda_state"],
                     "in_kernel": ["q_norm", "k_norm", "beta", "out_norm"]}
+
+
+@pytest.mark.parametrize("tiling", [None, (16, 4, 2)], ids=["the kernels' own", "a triple"])
+def test_the_kda_plan_reads_the_tiling_the_kernels_are_built_with(monkeypatch, tiling):
+    """The published file names no tiling. What ``kda_mixer`` hands ``ops.kda`` to build
+    ``kda_fwd`` / ``kda_bwd`` with is what ``kda_plan`` (the ``compile`` event's ``kda``) reports:
+    the per-channel branch's own, or the ``kda_tiling`` triple the caller passed."""
+    with open(CONFIG_FILE) as fh:
+        config = json.load(fh)
+    model = hybrid_lm.from_config(config, vocab_size=config["vocab_size"], seq_len=8192,
+                                  dtype=jnp.bfloat16, **({"kda_tiling": tiling} if tiling else {}))
+    built = []
+
+    def make_op(chunk, sub, group, eps, scalar=False, rep=1):
+        built.append(((chunk, sub, group), scalar))
+        return lambda q, k, v, g, beta: v
+
+    monkeypatch.setattr(kda, "_make_op", make_op)
+    number = config["linear_attn_config"]["kda_layers"][0] - 1      # the file counts from 1
+    shapes = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))["params"]
+    p = shapes[f"layer_{number}"]["kda"]
+    u = jax.ShapeDtypeStruct((2, 8192, model.hidden_size), jnp.bfloat16)
+    jax.eval_shape(lambda p, u: hybrid_lm.kda_mixer(p, u, model), p, u)
+    plan = model.kda_plan()
+    assert built == [((plan["chunk"], plan["sub_block"], plan["group"]), False)]
+    assert built[0][0] == (tiling or kda.KDA_TILING)
+    assert plan["states_per_sequence"] == 8192 // (plan["chunk"] * plan["group"])
+    assert model.gdn_plan() is None
 
 
 # (b) latent attention through the flash kernels ---------------------------------------------
@@ -471,7 +509,7 @@ def test_the_configuration_is_one_period_of_one_chips_share():
     assert model.expert_plan(2 * 8192)["row_bound"] == 8 * 2 * 8192
     assert model.expert_plan(2 * 8192)["bias_update_rate"] == \
         config["moe_router_bias_update_rate"]
-    assert model.kda_plan()["states_per_sequence"] == 32
+    assert model.kda_plan()["states_per_sequence"] == 8192 // KDA_ROWS
     assert (model.rope_theta, model.qk_norm, model.tied_head, model.head_dim,
             model.value_head_dim, model.routed_scaling_factor) == \
         (None, False, False, 192, 128, 2.446)
@@ -544,7 +582,7 @@ def test_the_compile_event_says_what_the_new_layers_ask(trained):
     state, events = trained
     event = [e for e in events if e["event"] == "compile"][0]
     assert event["kda"] == {"heads": 4, "key_dim": 8, "value_dim": 8, "chunk": 8,
-                            "sub_block": 4, "chunks_per_sequence": 8,
+                            "sub_block": 4, "group": 2, "chunks_per_sequence": 8,
                             "states_per_sequence": 4,
                             "state_bytes_per_sequence": 4 * 4 * 8 * 8 * 4,
                             "kept": ["kda_out", "kda_state"],

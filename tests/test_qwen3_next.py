@@ -122,12 +122,20 @@ def operands(s, key_heads, heads, d, steep, seed=0, batch=2):
     return (q, k, v, g, jax.nn.sigmoid(jax.random.normal(keys[5], (batch, s, heads)))), w
 
 
+GDN_ROWS = kda.GDN_TILING.chunk * kda.GDN_TILING.group     # tokens of a grid step
 # (tokens, key heads, value heads, width, (chunk, sub, group), how steep the decay is)
 SCANS = {
     "chunks, groups and a padded tail": (40, 2, 4, 8, (8, 4, 2), 1.0),
     "decays near zero": (37, 2, 2, 8, (8, 4, 2), 1e-3),
     "past -50 a chunk": (48, 1, 2, 8, (8, 2, 2), 15.0),
     "one chunk a group, sub-block a chunk": (24, 1, 3, 16, (8, 8, 1), 0.3),
+    # the scalar branch's committed tiling (ops.kda.GDN_TILING) at the published 128 x 128 head
+    "committed tiling, published head: a tail padded inside the first group":
+        (GDN_ROWS // 4 + 2, 1, 2, 128, kda.GDN_TILING, 1.0),
+    "committed tiling, published head: a kept state enters a second, padded group":
+        (GDN_ROWS + kda.GDN_TILING.chunk + 6, 1, 2, 128, kda.GDN_TILING, 0.3),
+    "committed tiling, published head: whole rows, decays near zero":
+        (GDN_ROWS, 1, 1, 128, kda.GDN_TILING, 1e-3),
 }
 
 
@@ -191,10 +199,46 @@ def test_the_scan_refuses_key_heads_that_do_not_divide_the_value_heads():
 
 def test_the_plan_says_the_decay_is_a_scalar():
     plan = kda.scan_plan(heads=32, key_heads=16, key_dim=128, value_dim=128, seq_len=8192)
-    assert (plan["decay"], plan["key_heads"], plan["heads"], plan["group"]) == \
-        ("scalar", 16, 32, kda.GROUP)
-    assert plan["states_per_sequence"] == 8192 // (kda.GROUP * kda.CHUNK)
-    assert "decay" not in kda.scan_plan(heads=32, key_dim=128, value_dim=128, seq_len=8192)
+    assert (plan["decay"], plan["key_heads"], plan["heads"]) == ("scalar", 16, 32)
+    assert (plan["chunk"], plan["sub_block"], plan["group"]) == kda.GDN_TILING
+    assert plan["states_per_sequence"] == 8192 // GDN_ROWS
+    assert plan["chunks_per_sequence"] == 8192 // kda.GDN_TILING.chunk
+    assert plan["state_bytes_per_sequence"] == 8192 // GDN_ROWS * 32 * 128 * 128 * 4
+    channel = kda.scan_plan(heads=32, key_dim=128, value_dim=128, seq_len=8192)
+    assert "decay" not in channel       # and a decay a channel has a tiling of its own
+    assert (channel["chunk"], channel["sub_block"], channel["group"]) == kda.KDA_TILING
+
+
+def test_a_tiling_given_in_part_keeps_the_rest_of_the_decay_kinds_own():
+    plan = kda.scan_plan(heads=4, key_heads=2, key_dim=8, value_dim=8, seq_len=100, group=1)
+    assert (plan["chunk"], plan["sub_block"], plan["group"]) == \
+        (kda.GDN_TILING.chunk, kda.GDN_TILING.sub, 1)
+    assert plan["states_per_sequence"] == -(-100 // kda.GDN_TILING.chunk)
+
+
+@pytest.mark.parametrize("tiling", [None, (16, 4, 2)], ids=["the kernels' own", "a triple"])
+def test_the_gdn_plan_reads_the_tiling_the_kernels_are_built_with(monkeypatch, tiling):
+    """The published file names no tiling. What ``gdn_mixer`` hands ``ops.kda`` to build
+    ``gdn_fwd`` / ``gdn_bwd`` with is what ``gdn_plan`` (the ``compile`` event's ``gdn``) reports:
+    the scalar branch's own, or the ``kda_tiling`` triple the caller passed."""
+    with open(CONFIG_FILE) as fh:
+        config = json.load(fh)
+    model = hybrid_lm.from_config(config, vocab_size=config["vocab_size"], seq_len=8192,
+                                  dtype=jnp.bfloat16, **({"kda_tiling": tiling} if tiling else {}))
+    built = []
+
+    def make_op(chunk, sub, group, eps, scalar=False, rep=1):
+        built.append(((chunk, sub, group), scalar, rep))
+        return lambda q, k, v, g, beta: v
+
+    monkeypatch.setattr(kda, "_make_op", make_op)
+    p = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0)))["params"]["layer_0"]["gdn"]
+    u = jax.ShapeDtypeStruct((2, 8192, model.hidden_size), jnp.bfloat16)
+    jax.eval_shape(lambda p, u: hybrid_lm.gdn_mixer(p, u, model), p, u)
+    plan = model.gdn_plan()
+    assert built == [((plan["chunk"], plan["sub_block"], plan["group"]), True, 2)]
+    assert built[0][0] == (tiling or kda.GDN_TILING)
+    assert plan["states_per_sequence"] == 8192 // (plan["chunk"] * plan["group"])
 
 
 # (b) the model against the reference -----------------------------------------------------
@@ -416,7 +460,7 @@ def test_the_configuration_is_one_period_of_one_chips_share():
     gdn = model.gdn_plan()
     assert (gdn["heads"], gdn["key_heads"], gdn["key_dim"], gdn["value_dim"], gdn["chunk"],
             gdn["sub_block"], gdn["group"], gdn["decay"]) == \
-        (32, 16, 128, 128, kda.CHUNK, kda.SUB, kda.GROUP, "scalar")
+        (32, 16, 128, 128, *kda.GDN_TILING, "scalar")
     assert model.kda_plan() is None
     assert sorted(config["reduced"]) == sorted(config["published"]) == \
         ["num_experts", "num_hidden_layers", "vocab_size"]
