@@ -46,8 +46,8 @@ sub-blocks with ``N = (I + D)⁻¹(A − D)``, so no power of the whole ``A`` is
 sequential grid axis with the state carried in VMEM (held transposed, ``[V, K]``, so
 that a channel's decay is a lane's), and writes the state that entered every group
 (``[B, S/(group·C), H, V, K]`` float32: a state is 64 KiB at the published 128 x 128, so
-every 64 tokens' would be 0.27 GB a layer and sequence of 8192, and a group's of 256
-or 512 tokens is a quarter or an eighth of that). ``kda_bwd`` walks the groups in
+every 64 tokens' would be 0.27 GB a layer and sequence of 8192, and a group's of 512
+or 1024 tokens is an eighth or a sixteenth of that). ``kda_bwd`` walks the groups in
 reverse carrying the state's gradient: a step runs its group's chunks again from the kept state and then their
 transpose, which is ``jax.vjp`` of the very function the forward kernel runs, traced
 into the kernel (so the two cannot drift apart). Within a grid step, what no state
@@ -63,14 +63,21 @@ chunks a step) changes no result beyond the products' rounding, since the chunke
 is the recurrence for any chunk, and is no key of any model's file: it is sized on the
 chip, one tiling a decay kind, because what bounds these kernels is the waits of small
 dependent products and those regroup with the tiling (alone at the cells' shapes, forward
-+ backward of a layer, bench_results/hw_pr44/scan_tilings.jsonl: the scalar pair 26.5 ms at
-(64, 4, 4), 23.6 at (64, 4, 8), 22.4 at (128, 8, 2), 18.8 at (128, 8, 4): half the grid steps
-and the state walk's products at the MXU's full depth; the per-channel pair 25.5, 23.7 at
-(64, 4, 8), 24.9 at (128, 8, 2)). ``kda_scan`` and ``gdn_scan`` default to their own
-(``KDA_TILING``, ``GDN_TILING``). Both are held inside the 16 MiB of scoped fast memory Mosaic
-allows a kernel that asks for none: with 32 the same sweep read 17.1 ms at (128, 8, 8) for
-the scalar pair and 20.2 at (128, 4, 4) for the per-channel pair, which no cell has run
-yet (PERF.md §7); a chunk of 256 is slower (per-channel) or dies in the compiler (scalar).
++ backward of a layer, bench_results/hw_pr44/ and hw_pr45/scan_tilings.jsonl: the scalar pair
+26.5 ms at (64, 4, 4), 18.7 at (128, 8, 4), 17.1 at (128, 8, 8): half the grid steps and the
+state walk's products at the MXU's full depth; the per-channel pair 25.5, 23.7 at (64, 4, 8),
+21.7 at (128, 8, 4), 20.2 at (128, 4, 4), 18.3 at (128, 4, 8), which compiles in 43 s for 27
+and was left for that, 20.5 at (128, 4, 16); a chunk of 256 is slower (per-channel) or dies
+in the compiler (scalar)). ``kda_scan`` and ``gdn_scan`` default to their own (``KDA_TILING``,
+``GDN_TILING``). The backward kernel holds every chunk's ``[C, 128]`` and ``[C, C]``
+intermediates of a step for ``jax.vjp``, 17.15 MiB at the per-channel tiling and 17.61 at the
+scalar one, where Mosaic gives a kernel that asks for none 16 MiB of scoped fast memory and
+refuses the rest at compile time: ``_params`` asks for ``VMEM_LIMIT``, one limit for both
+kinds (the limit alone moves no kernel's time: 18.748 ms for 18.752), and ``scan_plan``
+reports it. In the cells, parent and change in one call (bench_results/hw_pr45/cells_tpu.jsonl,
+PERF.md §6, PR 45): ``kda_fwd`` + ``kda_bwd`` 105.3 -> 82.9 ms a step and 3.577 -> 3.717
+examples/s in ``kimi_linear_train_8k`` (+3.9 % on three seeds, every check ``correct``);
+``gdn_fwd`` + ``gdn_bwd`` 48.3 -> 43.5 ms a step in ``qwen3_next_train_8k``.
 
 A sequence whose length is not a multiple of ``group·C`` is padded at its end with
 tokens that decay nothing and write nothing (``g = 0``, ``β = 0``, zero ``q̃``, ``k̃`` and ``v``,
@@ -97,10 +104,13 @@ class Tiling(NamedTuple):
 
 
 # One tiling a decay kind, from its sweep on the chip at the cells' shapes: the fastest
-# forward + backward inside Mosaic's own 16 MiB of scoped fast memory that a cell has run
-# and checked (bench_results/hw_pr44/scan_tilings.jsonl; PERF.md §6, PR 44).
-KDA_TILING = Tiling(64, 4, 4)       # a decay a channel (``kda_fwd``, ``kda_bwd``)
-GDN_TILING = Tiling(128, 8, 4)      # one decay a token and head (``gdn_fwd``, ``gdn_bwd``)
+# forward + backward of each kind that compiles in little more time than the one before it
+# (bench_results/hw_pr44/scan_tilings.jsonl, bench_results/hw_pr45/; PERF.md §6, PR 44 and 45).
+KDA_TILING = Tiling(128, 4, 4)      # a decay a channel (``kda_fwd``, ``kda_bwd``)
+GDN_TILING = Tiling(128, 8, 8)      # one decay a token and head (``gdn_fwd``, ``gdn_bwd``)
+# The scoped fast memory both kinds' kernels ask Mosaic for (its own 16 MiB refuse either
+# tiling's backward kernel; the chip has 128).
+VMEM_LIMIT = 32 << 20
 
 NN, NT, TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
 
@@ -350,7 +360,8 @@ def _specs(rows: int, heads: int, k: int, v: int, at, rep: int = 1):
 
 def _params():
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=VMEM_LIMIT)
 
 
 def _scan_fwd(q, k, v, g, beta, chunk: int, sub: int, group: int, eps: float,
@@ -504,7 +515,8 @@ def scan_plan(*, heads: int, key_dim: int, value_dim: int, seq_len: int,
             "states_per_sequence": groups,
             "state_bytes_per_sequence": groups * heads * key_dim * value_dim * 4,
             "kept": [name for name in ("kda_out", "kda_state") if name in kept],
-            "in_kernel": ["q_norm", "k_norm", "beta", "out_norm"]}
+            "in_kernel": ["q_norm", "k_norm", "beta", "out_norm"],
+            "vmem_limit_bytes": _params().vmem_limit_bytes}
     if key_heads is not None:
         plan.update(key_heads=key_heads, decay="scalar")
     return plan

@@ -9,6 +9,7 @@ library once, inside the fixture, after collection.
 """
 
 import contextlib
+import dataclasses
 import os
 
 import jax
@@ -44,6 +45,21 @@ def lowering_for_the_chip(*modules):
         for module, interpret in zip(modules, interprets):
             module._interpret = interpret
         jax.config.update("jax_enable_compilation_cache", cache)
+
+
+@contextlib.contextmanager
+def mosaics_own_limit(kda):
+    """Inside, the scan kernels of ``ops/kda.py`` ask for no scoped fast memory, which leaves
+    them Mosaic's own 16 MiB (``_make_op`` caches the jitted halves, so the cache is cleared on
+    the way in and out)."""
+    params = kda._params
+    kda._params = lambda: dataclasses.replace(params(), vmem_limit_bytes=None)
+    kda._make_op.cache_clear()
+    try:
+        yield
+    finally:
+        kda._params = params
+        kda._make_op.cache_clear()
 
 
 KERNELS = ("moe_ffn_fwd", "moe_ffn_bwd", "moe_ffn_dw",
@@ -215,21 +231,26 @@ KDA = dict(batch=2, seq=8192, heads=32, head_dim=128)
 
 def test_delta_rule_kernels_compile_for_the_v5e_at_published_widths(one_chip):
     """``kda_fwd`` and ``kda_bwd`` at the cell's shapes, on the flat layout at the per-channel
-    branch's committed tiling (``kda.KDA_TILING``: chunks of 64 in sub-blocks of 4, four chunks
+    branch's committed tiling (``kda.KDA_TILING``: chunks of 128 in sub-blocks of 4, four chunks
     a grid step side by side before their states, a 128 x 128 state): Mosaic takes the sublane rolls of the exact diagonals, the far
     pairs' product a doubling of the block, the four triangular inverses' products, the lane
-    select of a head's β out of the ``[256, 32]`` block, the norms' lane reductions, the
+    select of a head's β out of the ``[512, 32]`` block, the norms' lane reductions, the
     row that ``dβ`` leaves as, and the transpose of the whole group that ``jax.vjp`` traces
-    into the backward kernel."""
+    into the backward kernel, with the scoped fast memory ``kda._params`` asks for
+    (``kda.VMEM_LIMIT``) and not without it: ``kda_bwd`` holds 17.15 MiB, over Mosaic's own 16."""
     from csed_514_project_distributed_training_using_pytorch_tpu.ops import kda
     b, s, h, d = (KDA[k] for k in ("batch", "seq", "heads", "head_dim"))
     spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     x, g, beta = (spec((b, s, h * d), jnp.bfloat16), spec((b, s, h * d), jnp.float32),
                   spec((b, s, h), jnp.float32))
     loss = lambda *args: jnp.sum(kda.kda_scan(*args, eps=1e-5).astype(jnp.float32))
+    compile_pair = lambda: jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        x, x, x, g, beta).compile()
+    assert kda._params().vmem_limit_bytes == kda.VMEM_LIMIT
     with lowering_for_the_chip(kda):
-        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-            x, x, x, g, beta).compile()
+        compiled = compile_pair()
+        with mosaics_own_limit(kda), pytest.raises(Exception, match="exceeded scoped vmem limit"):
+            compile_pair()
     text = compiled.as_text()
     assert "%kda_fwd" in text and "%kda_bwd" in text
     chunk, sub, group = kda.KDA_TILING
@@ -432,8 +453,10 @@ GDN = dict(batch=2, seq=8192, key_heads=16, heads=32, head_dim=128, attention_he
 
 def test_scalar_decay_scan_kernels_compile_for_the_v5e_at_published_widths(one_chip):
     """``gdn_fwd`` and ``gdn_bwd`` at the cell's shapes and the scalar branch's committed tiling
-    (``kda.GDN_TILING``: four chunks of 128 a grid step, inside Mosaic's own 16 MiB): Mosaic takes
-    the lane select of a head's decay out of the ``[512, 32]`` block beside β's and its broadcast
+    (``kda.GDN_TILING``: eight chunks of 128 a grid step, whose ``gdn_bwd`` holds 17.61 MiB of
+    scoped fast memory: compiled with the limit ``kda._params`` asks for, ``kda.VMEM_LIMIT``, and
+    refused at Mosaic's own 16 MiB): Mosaic takes
+    the lane select of a head's decay out of the ``[1024, 32]`` block beside β's and its broadcast
     along the lanes, the ``[128, 128]`` mask's product with the triangle of ones, the key
     head's block read by two value heads' programs (``h // 2`` in the index map), and the row that ``dg`` leaves as;
     the key heads' gradients leave a block a value head, ``[2, 8192, 4096]``, and are summed
@@ -444,9 +467,13 @@ def test_scalar_decay_scan_kernels_compile_for_the_v5e_at_published_widths(one_c
     keys, values = spec((b, s, kh * d), jnp.bfloat16), spec((b, s, h * d), jnp.bfloat16)
     scalars = spec((b, s, h), jnp.float32)
     loss = lambda *args: jnp.sum(kda.gdn_scan(*args, key_heads=kh, eps=1e-6).astype(jnp.float32))
+    compile_pair = lambda: jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        keys, keys, values, scalars, scalars).compile()
+    assert kda._params().vmem_limit_bytes == kda.VMEM_LIMIT
     with lowering_for_the_chip(kda):
-        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-            keys, keys, values, scalars, scalars).compile()
+        compiled = compile_pair()
+        with mosaics_own_limit(kda), pytest.raises(Exception, match="exceeded scoped vmem limit"):
+            compile_pair()
     text = compiled.as_text()
     assert "%gdn_fwd" in text and "%gdn_bwd" in text and "%kda_" not in text
     chunk, sub, group = kda.GDN_TILING

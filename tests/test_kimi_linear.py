@@ -171,6 +171,12 @@ SCAN_SIZES = {
         (1, KDA_ROWS + KDA_CHUNK + 6, 1, 128, 128, *kda.KDA_TILING, 1.0),
     "committed tiling, 128 x 128, steep: a second, padded group":
         (1, KDA_ROWS + KDA_CHUNK + 6, 1, 128, 128, *kda.KDA_TILING, 8.0),
+    # the tiling committed until PR 45, by value: a chunk of 64 stays held to the recurrence
+    # whatever the constant becomes
+    "chunk 64, sub-block 4, group 4, 128 x 128: a second, padded group":
+        (1, 256 + 64 + 6, 1, 128, 128, 64, 4, 4, 1.0),
+    "chunk 64, sub-block 4, group 4, 128 x 128, steep: a second, padded group":
+        (1, 256 + 64 + 6, 1, 128, 128, 64, 4, 4, 8.0),
 }
 
 
@@ -256,7 +262,17 @@ def test_the_scan_plan_counts_the_states_a_sequence_keeps():
                     "states_per_sequence": 8192 // KDA_ROWS,
                     "state_bytes_per_sequence": 8192 // KDA_ROWS * 32 * 128 * 128 * 4,
                     "kept": ["kda_out", "kda_state"],
-                    "in_kernel": ["q_norm", "k_norm", "beta", "out_norm"]}
+                    "in_kernel": ["q_norm", "k_norm", "beta", "out_norm"],
+                    "vmem_limit_bytes": kda.VMEM_LIMIT}
+
+
+@pytest.mark.parametrize("key_heads", [None, 16], ids=["a decay a channel", "a scalar decay"])
+def test_the_scan_plan_reports_the_fast_memory_the_kernels_ask_for(key_heads):
+    """The ``compile`` event's ``kda`` / ``gdn`` field says what scoped fast memory the kernels
+    were built with: what ``_params`` hands ``pallas_call``, one limit for both decay kinds."""
+    plan = kda.scan_plan(heads=32, key_dim=128, value_dim=128, seq_len=8192, key_heads=key_heads)
+    assert plan["vmem_limit_bytes"] == kda._params().vmem_limit_bytes == kda.VMEM_LIMIT
+    assert 16 << 20 < plan["vmem_limit_bytes"] <= 48 << 20
 
 
 @pytest.mark.parametrize("tiling", [None, (16, 4, 2)], ids=["the kernels' own", "a triple"])
@@ -587,7 +603,9 @@ def test_the_compile_event_says_what_the_new_layers_ask(trained):
                             "state_bytes_per_sequence": 4 * 4 * 8 * 8 * 4,
                             "kept": ["kda_out", "kda_state"],
                             # the per-head scalars the kernels compute on a head's block
-                            "in_kernel": ["q_norm", "k_norm", "beta", "out_norm"]}
+                            "in_kernel": ["q_norm", "k_norm", "beta", "out_norm"],
+                            # the scoped fast memory the kernels ask Mosaic for
+                            "vmem_limit_bytes": kda.VMEM_LIMIT}
     assert event["ssm"] is None
     assert (event["attention"]["key_dim"], event["attention"]["value_dim"]) == (12, 8)
     assert event["experts"]["row_bound"] == 3 * 8 * 64 and event["experts"]["held"] == [0, 4]

@@ -582,6 +582,7 @@ def test_the_compile_event_says_what_the_new_layers_ask(trained):
     assert (gdn["heads"], gdn["key_heads"], gdn["key_dim"], gdn["value_dim"], gdn["decay"],
             gdn["chunk"], gdn["sub_block"], gdn["group"]) == (4, 2, 8, 8, "scalar", *TILING)
     assert gdn["kept"] == ["kda_out", "kda_state"]
+    assert gdn["vmem_limit_bytes"] == kda.VMEM_LIMIT    # what the kernels ask Mosaic for
     assert event["ssm"] is None and event["kda"] is None and event["eva"] is None
     assert event["experts"]["row_bound"] == 3 * 8 * 64 and event["experts"]["held"] == [0, 4]
     assert event["experts"]["scoring"] == "softmax"
