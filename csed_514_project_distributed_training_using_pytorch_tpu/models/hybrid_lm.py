@@ -1,6 +1,6 @@
 """Decoder LM built from a published configuration file: a stack of
 short-convolution, Mamba-2, delta-rule (KDA, gated delta net), GQA, latent-attention
-(MLA), EVA and expert layers.
+(MLA), EVA, parallel (Mamba-2 beside GQA) and expert layers.
 
 ``TransformerLM`` (``models/lm.py``) is the repo's own pixel decoder; this module is
 how a catalog architecture trains through ``train.lm``: the keys of the model's
@@ -17,7 +17,10 @@ heads over 320 byte ids) the fourth, ``Kanana-2-30B-A3B`` (``deepseek_v3``: late
 attention in every layer with a rotated shared key, ``first_k_dense_replace`` dense layers
 and then fine-grained experts beside shared ones) the fifth, ``Qwen3-Next-80B-A3B``
 (``qwen3_next``: three gated delta-rule layers to one gated softmax attention, every layer
-with softmax-routed experts beside a sigmoid-gated shared one) the sixth.
+with softmax-routed experts beside a sigmoid-gated shared one) the sixth,
+``Falcon-H1-34B`` (``falcon_h1``: every block a Mamba-2 mixer and a rotated GQA attention
+that read ONE normed input side by side, a dense feed-forward, and the family's fourteen
+forward multipliers on the activations) the seventh.
 
 A layer is a block of two sublayers (``LAYER_KINDS``: its mixer) or one sublayer
 alone (``SUBLAYER_KINDS``). Each kind is written once, as a function of its
@@ -62,6 +65,14 @@ parameters, the normalized input and the positions:
                    ``ops/eva.py``: a summary (k̃, ṽ) a chunk by the head's learned φ, μ;
                    one float32 softmax over the keys of the query's own window up to the
                    query and the summaries of every chunk before that window; W_o
+    parallel       u = RMSNorm(x) once;  x + m_ssm_out · mamba_mixer(u) + m_attn_out ·
+                   attention(m_attn_in · u): two mixers of different kinds on one input.
+                   With ``multipliers`` (``Multipliers``: a ``falcon_h1`` file's fourteen) the
+                   embedding's rows, the logits, the mamba mixer's input and the five segments
+                   [z | x | B | C | dt] of its in-projection's output, the attention's keys
+                   before they turn, the feed-forward's gate and its result are each scaled
+                   where the published code scales them, as activations: folded into a
+                   weight, a multiplier would change that weight's gradient and AdamW's step
     dense_ff       W_2 (silu(W_1 u) ⊙ W_3 u), over the held columns of a share
     sparse_ff      ``ops/moe.py``: sigmoid router over all experts, top-k of s + b (or a
                    softmax router, top-k of p, no b),
@@ -118,7 +129,7 @@ from csed_514_project_distributed_training_using_pytorch_tpu.ops.rotary import (
 )
 
 # a block: this mixer, then a feed-forward
-LAYER_KINDS = ("conv", "full_attention", "kda", "mla", "eva", "gdn")
+LAYER_KINDS = ("conv", "full_attention", "kda", "mla", "eva", "gdn", "parallel")
 SUBLAYER_KINDS = ("mamba", "attention", "moe")  # a layer that is one sublayer
 # What ``remat`` keeps of a block between its forward and its backward pass, beside
 # the block's input: the names of ``jax.ad_checkpoint.checkpoint_name`` tags, set
@@ -142,6 +153,29 @@ MLA_KEPT = ("flash_out", "flash_lse", "mla_latent", "moe_route", "moe_sort", "mi
 # one projection (``[T, 12288]``, 0.40 GB a layer at 16,384 tokens: 0.82 TFLOP a layer not
 # run again), which fits beside 10.0 GB of state where the other stacks' would not.
 GDN_KEPT = KEPT + ("gdn_in_proj",)
+
+
+# What a ``falcon_h1`` stack keeps: all of ``KEPT`` that it tags, and the feed-forward's
+# up-projection (``[T, 5376]``, 88 MB a layer at 8,192 tokens: 0.45 TFLOP a layer not run
+# again), which fits beside 9.2 GB of state at one sequence a step.
+H1_KEPT = KEPT + ("ff_up",)
+
+
+@dataclasses.dataclass(frozen=True)
+class Multipliers:
+    """A ``falcon_h1`` file's forward multipliers, each by the file's key (``ssm``:
+    ``ssm_multipliers``, one a segment ``[z | x | B | C | dt]`` of the Mamba-2 in-projection's
+    output; ``mlp``: ``mlp_multipliers``, the feed-forward's gate and its result)."""
+
+    embedding: float = 1.0
+    lm_head: float = 1.0
+    attention_in: float = 1.0
+    attention_out: float = 1.0
+    key: float = 1.0
+    ssm_in: float = 1.0
+    ssm_out: float = 1.0
+    ssm: tuple[float, float, float, float, float] = (1.0,) * 5
+    mlp: tuple[float, float] = (1.0, 1.0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -205,13 +239,14 @@ class HybridLM:
     norm_unit_offset: bool = False      # a norm's weight is 1 + its leaf
     fp32_residual: bool = False         # the residual stream in float32 whatever ``dtype``
     kept: tuple[str, ...] = KEPT        # what ``remat`` keeps of a block
+    multipliers: Multipliers | None = None  # None: no activation is scaled, nothing is traced
 
     def __post_init__(self):
         odd = sorted(set(self.layer_types) - set(LAYER_KINDS + SUBLAYER_KINDS))
         if odd:
             raise ValueError(f"layer_types {odd} are not of "
                              f"{LAYER_KINDS + SUBLAYER_KINDS}")
-        if "mamba" in self.layer_types and (
+        if self._has_ssm and (
                 self.mamba_heads < 1 or self.mamba_heads % self.mamba_groups):
             raise ValueError(f"{self.mamba_groups} groups do not divide the "
                              f"{self.mamba_heads} heads of a mamba layer")
@@ -251,6 +286,11 @@ class HybridLM:
     @property
     def value_head_dim(self) -> int:
         return self.v_head_dim if "mla" in self.layer_types else self.head_dim
+
+    @property
+    def _has_ssm(self) -> bool:
+        """Whether any layer holds a Mamba-2 mixer, alone or beside an attention."""
+        return bool({"mamba", "parallel"} & set(self.layer_types))
 
     def is_sparse(self, layer: int) -> bool:
         """Whether layer ``layer`` holds an expert feed-forward."""
@@ -305,7 +345,7 @@ class HybridLM:
     def ssm_plan(self) -> dict | None:
         """What a step asks of each state-space layer (``ops.ssm.scan_plan``), or None
         for a stack with none."""
-        if "mamba" not in self.layer_types:
+        if not self._has_ssm:
             return None
         return ssm.scan_plan(heads=self.mamba_heads, groups=self.mamba_groups,
                              head_dim=self.mamba_head_dim, state=self.ssm_state_size,
@@ -426,6 +466,7 @@ class HybridLM:
                 "recompute": self.recompute_plan(jaxpr), "ssm": self.ssm_plan(),
                 "kda": self.kda_plan(), "gdn": self.gdn_plan(), "eva": self.eva_plan(),
                 "norm": self.norm_plan(),
+                "multipliers": self.multipliers and dataclasses.asdict(self.multipliers),
                 "head_products": self.head_products(jaxpr, step_tokens)}
 
     def trainee(self, *, deterministic: bool = True, label_smoothing: float = 0.0) -> Trainee:
@@ -433,7 +474,8 @@ class HybridLM:
         no smoothing, so the trainer refuses either knob for a model from a file."""
         del deterministic, label_smoothing
         # whether any mixer calls ``attention_fn`` (an EVA mixer has a core of its own)
-        dispatches = {"full_attention", "attention", "mla"} & set(self.layer_types)
+        dispatches = {"full_attention", "attention", "mla", "parallel"} \
+            & set(self.layer_types)
         return Trainee(
             # (loss, rows that arrived at each held expert); the targets are the inputs
             loss=lambda params, xs, ys, rng: self.loss(params, xs),
@@ -521,6 +563,8 @@ class HybridLM:
                 layer["conv"] = {"in_proj_kernel": (d, 3 * d),
                                  "conv_kernel": (self.conv_L_cache, d),
                                  "out_proj_kernel": (d, d)}
+            elif kind == "parallel":
+                layer.update(mamba=dict(mamba), attn=dict(attn))
             else:
                 group, leaves = mixers[kind]
                 layer[group] = dict(leaves)
@@ -573,8 +617,10 @@ class HybridLM:
         ids = ids.astype(jnp.int32)
         positions = jnp.arange(ids.shape[1])
         with jax.named_scope("embed"):
-            x = params["embed_tokens"].astype(
-                jnp.float32 if self.fp32_residual else self.dtype)[ids]
+            x = ops.embedding_rows(params["embed_tokens"].astype(
+                jnp.float32 if self.fp32_residual else self.dtype), ids)
+            if self.multipliers:
+                x = x * self.multipliers.embedding
         counts = []
         for i, kind in enumerate(self.layer_types[:layers]):
             fn = make_block(self, kind, self.is_sparse(i))
@@ -625,6 +671,8 @@ class HybridLM:
         head = head.astype(self.dtype)
         flat = jnp.matmul(rows, head.T if self.tied_head else head,
                           preferred_element_type=jnp.float32)
+        if self.multipliers:
+            flat = flat * self.multipliers.lm_head
         return flat.reshape(b, s, -1)
 
     def nll(self, params, tokens) -> tuple[jax.Array, jax.Array | None]:
@@ -727,7 +775,7 @@ head_nll.defvjp(_head_nll_fwd, _head_nll_bwd)
 # leaves unnamed only what escaped: device time is read by kind, never by layer index.
 MIXER_SCOPES = {"conv": "conv_mixer", "full_attention": "attention", "attention": "attention",
                 "kda": "kda_mixer", "mla": "mla_attention", "mamba": "mamba_mixer",
-                "eva": "eva_mixer", "gdn": "gdn_mixer"}
+                "eva": "eva_mixer", "gdn": "gdn_mixer", "parallel": "parallel_mixer"}
 
 
 def make_block(model: HybridLM, kind: str, sparse: bool):
@@ -757,7 +805,7 @@ def make_block(model: HybridLM, kind: str, sparse: bool):
             return experts(p, h, "ff_")
         with jax.named_scope("dense_ff"):
             u = model.normed(h, p, "ff_")
-            return h + dense_ff(p["ff"], u), None
+            return h + dense_ff(p["ff"], u, model.multipliers and model.multipliers.mlp), None
 
     return block
 
@@ -776,6 +824,8 @@ def mix(p, x, positions, kind: str, model: HybridLM):
             mixed = mla_mixer(p["mla"], u, positions, model)
         elif kind == "eva":
             mixed = eva_mixer(p["eva"], u, positions, model)
+        elif kind == "parallel":
+            mixed = parallel_mixers(p, u, positions, model)
         else:
             mixed = attention_mixer(p["attn"], u, positions, model)
         return checkpoint_name(x + mixed, "mixer_out")
@@ -805,9 +855,15 @@ def mamba_mixer(p, u, model: HybridLM):
     heads, groups = model.mamba_heads, model.mamba_groups
     hd, n = model.mamba_head_dim, model.ssm_state_size
     inner, bc = heads * hd, groups * n
-    z, xbc, dt = jnp.split(
-        checkpoint_name(_dense(u, p["in_proj_kernel"]), "mamba_in_proj"),
-        [inner, 2 * inner + 2 * bc], axis=-1)
+    scaled = model.multipliers
+    if scaled:
+        u = u * scaled.ssm_in
+    projected = checkpoint_name(_dense(u, p["in_proj_kernel"]), "mamba_in_proj")
+    if scaled:      # one number a segment [z | x | B | C | dt] of the columns
+        projected = projected * jnp.concatenate(
+            [jnp.full((width,), value, projected.dtype)
+             for width, value in zip((inner, inner, bc, bc, heads), scaled.ssm)])
+    z, xbc, dt = jnp.split(projected, [inner, 2 * inner + 2 * bc], axis=-1)
     xbc = jax.nn.silu(causal_depthwise_conv(xbc, p["conv_kernel"])
                       + p["conv_bias"].astype(xbc.dtype))
     x, b_in, c_out = jnp.split(xbc, [inner, inner + bc], axis=-1)
@@ -843,13 +899,15 @@ def attention_mixer(p, u, positions, model: HybridLM, core=None):
     q, k, v = (checkpoint_name(_dense(u, p[f"{name}_kernel"]), "attn_proj")
                .reshape(b, s, n, hd) for name, n in (
                    ("q", heads * (2 if model.attention_gate else 1)), ("k", kv), ("v", kv)))
+    if model.multipliers:
+        k = k * model.multipliers.key
     if model.attention_gate:
         q, gate = q[:, :, :heads], q[:, :, heads:]
 
     def turned(x):      # the rotation, over the whole head or its first ``rope_dim`` channels
-        if not model.rope_dim:
-            return apply_rotary(x, positions, base=model.rope_theta)
         with jax.named_scope("rotary"):
+            if not model.rope_dim:
+                return apply_rotary(x, positions, base=model.rope_theta)
             return jnp.concatenate(
                 [apply_rotary(x[..., :model.rope_dim], positions, base=model.rope_theta),
                  x[..., model.rope_dim:]], axis=-1)
@@ -946,11 +1004,28 @@ def eva_mixer(p, u, positions, model: HybridLM):
         window=model.eva_window, chunk=model.eva_chunk))
 
 
-def dense_ff(p, u):
+def parallel_mixers(p, u, positions, model: HybridLM):
+    """What a ``parallel`` block's two mixers add to the stream: both read the block's one
+    normed input ``u``, and neither waits for the other."""
+    scaled = model.multipliers or Multipliers()
+    with jax.named_scope("ssm"):
+        state_space = mamba_mixer(p["mamba"], u, model)
+    with jax.named_scope("attention"):
+        attended = attention_mixer(p["attn"], u * scaled.attention_in, positions, model)
+    return scaled.ssm_out * state_space + scaled.attention_out * attended
+
+
+def dense_ff(p, u, multipliers: tuple[float, float] | None = None):
+    """``multipliers``: what scales the gate's product before its silu and the result."""
     # ``W1 u`` is kept and ``W3 u`` recomputed: beside the cell's state the chip
-    # has room for one ``[T, intermediate]`` array more, not for two (PERF.md §6).
+    # has room for one ``[T, intermediate]`` array more, not for two (PERF.md §6);
+    # a stack that has room for both keeps ``ff_up`` too (``H1_KEPT``).
     gate = checkpoint_name(_dense(u, p["w1_kernel"]), "ff_gate")
-    return _dense(ops.swiglu(gate, _dense(u, p["w3_kernel"])), p["w2_kernel"])
+    if multipliers:
+        gate = gate * multipliers[0]
+    up = checkpoint_name(_dense(u, p["w3_kernel"]), "ff_up")
+    out = _dense(ops.swiglu(gate, up), p["w2_kernel"])
+    return out * multipliers[1] if multipliers else out
 
 
 def routed(p, flat, model: HybridLM, load: bool = False):
@@ -1283,9 +1358,60 @@ def _qwen3_next(config: dict) -> tuple[list, dict]:
         conv_kernel=int(config["linear_conv_kernel_dim"]), kept=GDN_KEPT)
 
 
+def _falcon_h1(config: dict) -> tuple[list, dict]:
+    """Every layer a ``parallel`` block (a Mamba-2 mixer and a GQA attention rotated over a
+    head's whole width, on one normed input) and a dense gated feed-forward; no expert layer;
+    an untied head; the fourteen forward multipliers as ``Multipliers``. A share holds
+    ``num_attention_heads`` query heads on ``num_key_value_heads`` key/value heads,
+    ``mamba_n_heads`` heads in ``mamba_n_groups`` whole groups (``share.mamba_channels`` of the
+    whole model's ``mamba_d_ssm`` channels, which like ``intermediate_size`` keeps its published
+    value) and ``share.mlp_columns`` of the feed-forward's ``intermediate_size`` columns; a group
+    of the gated norm is a held group's channels. What the file states and this module does not
+    compute is refused, not ignored."""
+    share, published = config.get("share", {}), config.get("published", {})
+    heads, width = int(config["mamba_n_heads"]), int(config["mamba_d_head"])
+    whole = int(published.get("mamba_n_heads", heads)) * width
+    _refuse({
+        "attn_layer_indices not null": config.get("attn_layer_indices") is not None,
+        "mamba_use_mlp false": not config.get("mamba_use_mlp", True),
+        "mamba_norm_before_gate true": bool(config.get("mamba_norm_before_gate", False)),
+        "mamba_rms_norm false": not config.get("mamba_rms_norm", True),
+        "a bias on a projection": any(config.get(k) for k in (
+            "attention_bias", "mamba_proj_bias", "mlp_bias", "projectors_bias")),
+        "a convolution without bias (mamba_conv_bias false)":
+            not config.get("mamba_conv_bias", True),
+        "rope_scaling not null": config.get("rope_scaling") is not None,
+        "tie_word_embeddings true": bool(config.get("tie_word_embeddings", False)),
+        "hidden_act other than silu": config.get("hidden_act", "silu") != "silu",
+        "mamba_d_ssm other than the whole model's mamba_n_heads x mamba_d_head":
+            int(config.get("mamba_d_ssm") or whole) != whole,
+        "share.mamba_channels other than mamba_n_heads x mamba_d_head":
+            int(share.get("mamba_channels", heads * width)) != heads * width,
+        "ssm_multipliers that are not five, or mlp_multipliers that are not two":
+            (len(config["ssm_multipliers"]), len(config["mlp_multipliers"])) != (5, 2),
+    })
+    depth = int(published.get("num_hidden_layers", config["num_hidden_layers"]))
+    return ["parallel"] * depth, dict(
+        num_dense_layers=depth, router_experts=0, held_experts=(0, 0),
+        num_experts_per_tok=0, moe_intermediate_size=0,
+        intermediate_size=int(share.get("mlp_columns", config["intermediate_size"])),
+        norm_eps=float(config["rms_norm_eps"]), rope_theta=float(config["rope_theta"]),
+        qk_norm=False, tied_head=False, attention_head_dim=int(config["head_dim"]),
+        mamba_heads=heads, mamba_groups=int(config["mamba_n_groups"]), mamba_head_dim=width,
+        ssm_state_size=int(config["mamba_d_state"]), conv_kernel=int(config["mamba_d_conv"]),
+        chunk_size=int(config["mamba_chunk_size"]), kept=H1_KEPT,
+        multipliers=Multipliers(
+            ssm=tuple(map(float, config["ssm_multipliers"])),
+            mlp=tuple(map(float, config["mlp_multipliers"])),
+            **{name: float(config[f"{name}_multiplier"]) for name in (
+                "embedding", "lm_head", "attention_in", "attention_out", "key",
+                "ssm_in", "ssm_out")}))
+
+
 _FAMILIES = {"lfm2_moe": _lfm2_moe, "nemotron_h": _nemotron_h,
              "kimi_linear": _kimi_linear, "evabyte": _evabyte,
-             "deepseek_v3": _deepseek_v3, "qwen3_next": _qwen3_next}
+             "deepseek_v3": _deepseek_v3, "qwen3_next": _qwen3_next,
+             "falcon_h1": _falcon_h1}
 
 
 def from_config_file(path: str, **kwargs) -> HybridLM:

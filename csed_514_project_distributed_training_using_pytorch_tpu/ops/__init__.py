@@ -18,6 +18,7 @@ from csed_514_project_distributed_training_using_pytorch_tpu.ops.nn import (
     dropout2d,
     layer_norm,
     rms_norm,
+    embedding_rows,
     swiglu,
     gelu,
 )
@@ -46,6 +47,7 @@ __all__ = [
     "dropout2d",
     "layer_norm",
     "rms_norm",
+    "embedding_rows",
     "swiglu",
     "gelu",
     "full_attention",

@@ -149,6 +149,28 @@ def rms_norm(x: jax.Array, gamma: jax.Array, *, eps: float = 1e-5,
     return (normed * weight).astype(x.dtype)
 
 
+# The widest rows one gather of an embedding takes: a choice for the v5e, from eight timings
+# of the backward scatter-add alone (8192 or 16384 rows into the table's bfloat16 copy:
+# ``bench_results/hw_pr47/embed_on_chip.py`` / ``embed_on_chip.jsonl``, PERF.md section 6,
+# PR 47). Rows of 5120 channels scatter back in 59.3 ms, of 4096 in 3.8 and of 2304 in 3.5;
+# 5120 as 4096 + 1024 side by side in 4.7, as five of 1024 in 5.2, the same sums to the last
+# bit. NOT a rule of "wide rows are slow": 5120 as two of 2560 takes 28.0 ms, so a row of
+# 2560 is still some four times slower than one of 4096, and the cause is not known
+# (PERF.md section 7). Only 4096 and 1024 are shown fast as a block's width.
+EMBED_COLUMNS = 4096
+
+
+def embedding_rows(table: jax.Array, ids: jax.Array) -> jax.Array:
+    """``table[ids]``, ``[..., d]``, gathered in column blocks of ``EMBED_COLUMNS`` side by
+    side (one block, the plain gather, up to that width), so that the backward pass scatters
+    rows no wider than that: the same rows and the same gradient to the last bit."""
+    d = table.shape[1]
+    if d <= EMBED_COLUMNS:
+        return table[ids]
+    return jnp.concatenate([table[:, c:c + EMBED_COLUMNS][ids]
+                            for c in range(0, d, EMBED_COLUMNS)], axis=-1)
+
+
 def swiglu(gate: jax.Array, up: jax.Array) -> jax.Array:
     """The gated feed-forward's nonlinearity, ``silu(gate) · up``."""
     return jax.nn.silu(gate) * up

@@ -177,12 +177,18 @@ SSM = dict(batch=2, seq=8192, heads=16, head_dim=64, groups=1, state=128)
 LATENT, EXPERT_F, ROUTER_512, K_22, TOKENS = 1024, 2688, 512, 22, 2 * 8192
 
 
-def test_scan_kernels_compile_for_the_v5e_at_published_widths(one_chip):
-    """``ssd_fwd`` and ``ssd_bwd`` at the cell's shapes (chunk 128, P 64, N 128, sixteen
-    heads a grid step): Mosaic takes the per-head column slices, the transposed-operand
-    products and the carried state."""
+# the ``falcon_h1`` cell's: one sequence, a head's state 128 x 256, four times the above
+SSM_WIDE = dict(batch=1, seq=8192, heads=8, head_dim=128, groups=1, state=256)
+
+
+@pytest.mark.parametrize("sizes", [SSM, SSM_WIDE], ids=["P64-N128-H16", "P128-N256-H8"])
+def test_scan_kernels_compile_for_the_v5e_at_published_widths(one_chip, sizes):
+    """``ssd_fwd`` and ``ssd_bwd`` at each cell's shapes (chunk 128; P 64, N 128, sixteen
+    heads a grid step; P 128, N 256, eight): Mosaic takes the per-head column slices, the
+    transposed-operand products and the carried state, and the scoped fast memory either
+    size asks for is under its limit: an overflow fails here and not on the chip."""
     from csed_514_project_distributed_training_using_pytorch_tpu.ops import ssm
-    b, s, h, p, g, n = (SSM[k] for k in ("batch", "seq", "heads", "head_dim", "groups", "state"))
+    b, s, h, p, g, n = (sizes[k] for k in ("batch", "seq", "heads", "head_dim", "groups", "state"))
     spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     x, step = spec((b, s, h, p), jnp.bfloat16), spec((b, s, h), jnp.float32)
     bc = spec((b, s, g, n), jnp.bfloat16)

@@ -103,7 +103,9 @@ def test_the_references_recurrence_is_the_definition():
 SCAN_SIZES = {"two groups, whole chunks": (2, 32, 4, 8, 2, 16, 8),
               "a ragged tail is padded": (1, 21, 2, 8, 1, 8, 8),
               "shorter than a chunk": (1, 5, 2, 8, 2, 8, 8),
-              "published tile: chunk 128, P 64, N 128": (1, 256, 4, 64, 1, 128, 128)}
+              "published tile: chunk 128, P 64, N 128": (1, 256, 4, 64, 1, 128, 128),
+              # a ``falcon_h1`` share's grid step: eight heads on one group, a state of 128 x 256
+              "fourfold state: chunk 128, P 128, N 256, 8 heads": (1, 256, 8, 128, 1, 256, 128)}
 
 
 @pytest.mark.parametrize("size", SCAN_SIZES)

@@ -2,6 +2,7 @@
 on op names and on the three families' compiled epoch programs, and the benchmark's
 ``reducers/scope_time.py`` on a hand-worked trace (PR 35)."""
 
+import functools
 import json
 import os
 import sys
@@ -218,16 +219,17 @@ def test_a_warm_cache_serves_an_older_trees_names_and_the_table_says_so(tmp_path
     assert out.stdout.splitlines() == ["older_tree ['older_tree']", "this_tree ['older_tree']"]
 
 
-# -- (c) the three families' epoch programs -------------------------------------------------
+# -- (c) the families' epoch programs -------------------------------------------------
 
 FAMILIES = {"lfm2_moe": "test_hybrid_lm", "nemotron_h": "test_nemotron_h",
-            "kimi_linear": "test_kimi_linear"}
+            "kimi_linear": "test_kimi_linear", "falcon_h1": "test_falcon_h1"}
 # Of a CPU program's instructions the share with a scope (0.69-0.79 read: the rest are
 # copies and loop plumbing the compiler placed, with no op_name), and of those that carry
 # an op_name (0.970-0.986 read: the feed's gather and the loop's counters have none).
 FLOOR, FLOOR_OF_NAMED_OPS = 0.6, 0.95
 
 
+@functools.lru_cache(maxsize=None)     # a family's program compiles once a run of this file
 def _epoch_table(family: str, remat: bool) -> tuple[hybrid_lm.HybridLM, dict]:
     """The family's tiny model (its own test file's), two steps of batch 2 as one scanned
     epoch program built as ``train/lm.py`` builds it, compiled on the CPU."""
@@ -269,6 +271,8 @@ def _scopes_of(model: hybrid_lm.HybridLM) -> set[str]:
     for mixer, scan in (("mamba_mixer", "ssd"), ("kda_mixer", "kda")):
         if mixer in want:       # the scan's own scope, opened in ops/ssm.py and ops/kda.py
             want.add(f"{mixer}/{scan}")
+    if "parallel_mixer" in want:    # its two branches, the scan and the rotation under them
+        want |= {"parallel_mixer/ssm/ssd", "parallel_mixer/attention/rotary"}
     return want
 
 
@@ -290,6 +294,22 @@ def test_an_epoch_program_names_every_scope_and_pass(family):
     assert named / len(ops) > FLOOR, named / len(ops)
     with_op_name = sum(1 for _, which in ops.values() if which)
     assert named / with_op_name > FLOOR_OF_NAMED_OPS, named / with_op_name
+
+
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_the_parallel_blocks_scopes_are_its_own_stacks_alone(family):
+    """``parallel_mixer`` and its branches ``ssm`` and ``attention`` name a ``falcon_h1``
+    stack's mixers, which have no ``mamba_mixer`` or top-level ``attention`` of their own;
+    no other family's table holds them."""
+    _, table = _epoch_table(family, remat=True)
+    found = {scope for scope, _ in table["ops"].values() if scope}
+    branches = {s for s in found if s.split("/")[0] == "parallel_mixer"}
+    if family == "falcon_h1":
+        assert {"/".join(s.split("/")[:2]) for s in branches} >= {
+            "parallel_mixer", "parallel_mixer/ssm", "parallel_mixer/attention"}
+        assert not any(s.split("/")[0] in ("mamba_mixer", "attention") for s in found)
+    else:
+        assert not branches
 
 
 @pytest.mark.parametrize("family", list(FAMILIES))

@@ -4,6 +4,7 @@ carries the model's ``plans`` whole. Nothing here compiles or trains: ``train.lm
 runs with no epoch and its ahead-of-time compile replaced by a trace."""
 
 import ast
+import dataclasses
 import importlib
 import json
 import os
@@ -38,7 +39,8 @@ FAMILIES = {"lfm2_moe": ("test_hybrid_lm", {}),
             "kimi_linear": ("test_kimi_linear", {}),
             "deepseek_v3": ("test_deepseek_v3", {}),
             "evabyte": ("test_evabyte", {"window_size": 16, "chunk_size": 4}),
-            "qwen3_next": ("test_qwen3_next", {})}
+            "qwen3_next": ("test_qwen3_next", {}),
+            "falcon_h1": ("test_falcon_h1", {})}
 TRAIN_LM = os.path.join(os.path.dirname(train_lm.__file__), "lm.py")
 
 
@@ -103,7 +105,10 @@ def test_the_compile_event_carries_the_models_plans_whole(family, tmp_path, monk
         said = {"experts": model.expert_plan(tokens), "recompute": model.recompute_plan(jaxpr),
                 "head_products": model.head_products(jaxpr, tokens), "ssm": model.ssm_plan(),
                 "kda": model.kda_plan(), "gdn": model.gdn_plan(), "eva": model.eva_plan(),
-                "norm": model.norm_plan()}
+                "norm": model.norm_plan(),
+                # the forward multipliers the program applied, by name: a ``falcon_h1`` file's
+                "multipliers": model.multipliers and dataclasses.asdict(model.multipliers)}
+        assert (said["multipliers"] is None) == (family != "falcon_h1")
         assert model.plans(jaxpr, tokens) == said
         assert said["recompute"]["kept"] and said["head_products"] == 3
     view = model.trainee()
