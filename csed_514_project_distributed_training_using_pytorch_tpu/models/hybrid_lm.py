@@ -126,6 +126,7 @@ from csed_514_project_distributed_training_using_pytorch_tpu.models import Train
 from csed_514_project_distributed_training_using_pytorch_tpu.ops import eva, kda, moe, ssm
 from csed_514_project_distributed_training_using_pytorch_tpu.ops.rotary import (
     apply_rotary,
+    rotation_form,
 )
 
 # a block: this mixer, then a feed-forward
@@ -382,18 +383,27 @@ class HybridLM:
                                   kept=self.kept if self.remat else ())
 
     def rotary_plan(self) -> dict:
-        """The ``compile`` event's ``rope_dim``, ``rope_pairing`` and ``rope_theta`` of its
-        ``attention`` field: how many of a head's query and key channels turn by their
-        position (a latent-attention head's shared ones; else all), in which pairing
-        and at which base. None each for attention without positions. With
-        ``attention_gate`` also ``output_gate``, what scales a head's output."""
+        """The ``compile`` event's ``rope_dim``, ``rope_pairing``, ``rope_theta`` and
+        ``rotation`` of its ``attention`` field: how many of a head's query and key
+        channels turn by their position (a latent-attention head's shared ones; else
+        all), in which pairing, at which base, and which form of ``ops/rotary.py`` turns
+        a query of this model's shape (``rotary.rotation_form``). None each for attention
+        without positions. With ``attention_gate`` also ``output_gate``, what scales a
+        head's output."""
         gate = {"output_gate": "sigmoid"} if self.attention_gate else {}
         if self.rope_theta is None:
-            return dict(dict.fromkeys(("rope_dim", "rope_pairing", "rope_theta")), **gate)
-        return {"rope_dim": self.qk_rope_head_dim if "mla" in self.layer_types
-                else self.rope_dim or self.head_dim,
+            return dict(dict.fromkeys(
+                ("rope_dim", "rope_pairing", "rope_theta", "rotation")), **gate)
+        latent = "mla" in self.layer_types
+        width = self.qk_nope_head_dim + self.qk_rope_head_dim if latent else self.head_dim
+        turning = self.qk_rope_head_dim if latent else self.rope_dim or self.head_dim
+        return {"rope_dim": turning,
                 "rope_pairing": "interleaved" if self.rope_interleave else "half_split",
-                "rope_theta": self.rope_theta, **gate}
+                "rope_theta": self.rope_theta,
+                "rotation": rotation_form(
+                    (1, self.seq_len, self.num_attention_heads, width), self.dtype,
+                    interleaved=self.rope_interleave,
+                    channels=(width - turning if latent else 0, turning)), **gate}
 
     @property
     def _kda_tiles(self) -> dict:
@@ -906,11 +916,8 @@ def attention_mixer(p, u, positions, model: HybridLM, core=None):
 
     def turned(x):      # the rotation, over the whole head or its first ``rope_dim`` channels
         with jax.named_scope("rotary"):
-            if not model.rope_dim:
-                return apply_rotary(x, positions, base=model.rope_theta)
-            return jnp.concatenate(
-                [apply_rotary(x[..., :model.rope_dim], positions, base=model.rope_theta),
-                 x[..., model.rope_dim:]], axis=-1)
+            return apply_rotary(x, positions, base=model.rope_theta,
+                                channels=(0, model.rope_dim) if model.rope_dim else None)
 
     def placed(x, which):       # per-head norm, then the rotation; either or neither
         if model.qk_norm:
@@ -988,7 +995,7 @@ def mla_mixer(p, u, positions, model: HybridLM):
             turn = functools.partial(apply_rotary, positions=positions,
                                      base=model.rope_theta,
                                      interleaved=model.rope_interleave)
-            q = jnp.concatenate([q[..., :nope], turn(q[..., nope:])], axis=-1)
+            q = turn(q, channels=(nope, q.shape[-1] - nope))
             shared_key = turn(shared_key)
     k = jnp.concatenate([own_key, jnp.broadcast_to(
         shared_key, (b, s, heads, shared_key.shape[-1]))], axis=-1)

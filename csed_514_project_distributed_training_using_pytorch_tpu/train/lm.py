@@ -410,10 +410,11 @@ def main(config: LMConfig = LMConfig(), *,
                 flops_per_step = aot["flops"] / steps_per_epoch
             if aot.get("bytes_accessed"):
                 bytes_per_step = aot["bytes_accessed"] / steps_per_epoch
-            # no plan for a stack none of whose mixers goes through ``attention_fn``
-            attention = None if seq_size > 1 or view.attention_shape is None else {
-                **_attention_plan(config, seq_len, world, view.attention_shape,
-                                  dispatched=mesh.size == 1), **view.attention_fields}
+            # no plan for a stack none of whose mixers goes through ``attention_fn``:
+            # the model's own fields alone (how its mixers turn q and k)
+            plan = {} if view.attention_shape is None else _attention_plan(
+                config, seq_len, world, view.attention_shape, dispatched=mesh.size == 1)
+            attention = None if seq_size > 1 else {**plan, **view.attention_fields} or None
             step_tokens = config.batch_size // world // config.grad_accum * seq_len
             tele.emit(T.compile_event("epoch", aot,
                                       steps_per_call=steps_per_epoch,

@@ -382,8 +382,9 @@ def test_a_latent_attention_layer_rotates_the_shared_key_once(one_chip):
     the cell's shapes (2 x 8192 tokens, 32 heads of 128 + 64 / 128): the flash kernels take
     keys of 192 channels, and in the forward pass the rotation's product (``mla_attention/
     rotary``, a signed permutation of the 64 shared channels) runs twice: over the 32 heads'
-    query channels ``[2, 8192, 32, 64]`` and over the one shared key ``[2, 8192, 64]``, before
-    it is handed to the heads, not over 32 copies of it."""
+    queries, whole and in place since PR 48 (``[2, 8192, 32, 192]``, the permutation's other
+    128 columns zero), and over the one shared key ``[2, 8192, 64]``, before it is handed to
+    the heads, not over 32 copies of it."""
     import math
     import re
     from csed_514_project_distributed_training_using_pytorch_tpu import ops
@@ -408,11 +409,10 @@ def test_a_latent_attention_layer_rotates_the_shared_key_once(one_chip):
     forward = re.findall(r"^\s*(?:ROOT )?%\S+ = \w+\[([\d,]+)\]\S* .*"
                          r'op_name="[^"]*/jvp\(mla_attention\)/rotary/dot_general"',
                          text, flags=re.M)
-    # a fusion and the product inside it both carry the name: as many at the key's own
-    # shape as at the queries', and none at any other
+    # a fusion and the product inside it both carry the name, at the key's own shape and at
+    # the queries' whole heads, and at no other: none over the key handed to 32 heads
     elements = [math.prod(map(int, shape.split(","))) for shape in forward]
-    assert sorted(set(elements)) == [b * s * 64, b * s * h * 64], forward
-    assert elements.count(b * s * 64) == elements.count(b * s * h * 64), forward
+    assert sorted(set(elements)) == [b * s * 64, b * s * h * 192], forward
 
 
 def test_an_evabyte_blocks_norm_backward_is_in_no_products_epilogue(one_chip):

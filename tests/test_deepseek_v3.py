@@ -116,7 +116,7 @@ def test_a_kimi_linear_file_still_builds_a_model_with_no_rotation():
     assert model.rope_theta is None and "mla" in model.layer_types
     assert model.kept == hybrid_lm.KEPT
     assert model.rotary_plan() == {"rope_dim": None, "rope_pairing": None,
-                                   "rope_theta": None}
+                                   "rope_theta": None, "rotation": None}
     rotating, params = build(tiny_config())
     small, p = dataclasses.replace(rotating, rope_theta=None), params["layer_0"]["mla"]
     u = jax.random.normal(jax.random.PRNGKey(1), (1, SEQ, 64))
@@ -319,7 +319,7 @@ def test_the_configuration_is_one_chips_share_of_the_first_stage():
             model.norm_eps, model.shared_expert_size, model.gated_shared_expert) == \
         (1e6, True, False, False, 192, 128, 2.448, 1e-6, 1536, True)
     assert model.rotary_plan() == {"rope_dim": 64, "rope_pairing": "interleaved",
-                                   "rope_theta": 1e6}
+                                   "rope_theta": 1e6, "rotation": "permutation"}
     assert sorted(config["reduced"]) == sorted(config["published"]) == \
         ["n_routed_experts", "num_hidden_layers", "vocab_size"]
     for key, value in config["published"].items():
@@ -404,8 +404,8 @@ def test_the_compile_event_says_what_the_new_layers_ask(trained):
     event = [e for e in events if e["event"] == "compile"][0]
     attention = event["attention"]
     assert (attention["key_dim"], attention["value_dim"]) == (24, 16)
-    assert (attention["rope_dim"], attention["rope_pairing"], attention["rope_theta"]) == \
-        (8, "interleaved", 1e6)
+    assert (attention["rope_dim"], attention["rope_pairing"], attention["rope_theta"],
+            attention["rotation"]) == (8, "interleaved", 1e6, "permutation")
     assert event["ssm"] is None and event["kda"] is None and event["eva"] is None
     assert event["experts"]["row_bound"] == 3 * 8 * 64 and event["experts"]["held"] == [0, 4]
     assert event["recompute"]["kept"] == list(hybrid_lm.MLA_KEPT)

@@ -218,8 +218,9 @@ def test_the_residual_stream_is_float32_under_bfloat16_matmuls():
 
 def test_a_float32_stream_under_bfloat16_holds_every_norms_output(monkeypatch):
     """Two layers, bfloat16 under the float32 stream: five norms a forward pass, each
-    output behind an optimization barrier (and, in the gradient, each cotangent); the
-    head's own barriers aside, a float32 model has none. Loss and every gradient are those
+    output behind an optimization barrier (and, in the gradient, each cotangent), as is
+    each bfloat16 q and k on its way into the rotation; the head's own barriers aside, a
+    float32 model has none. Loss and every gradient are those
     of the same model with the barrier taken out: it moves when a value is written, not
     what is written."""
     config = tiny_config()
@@ -228,7 +229,8 @@ def test_a_float32_stream_under_bfloat16_holds_every_norms_output(monkeypatch):
     count = lambda fn, *a: str(jax.make_jaxpr(fn)(*a)).count("optimization_barrier")
     blocks = lambda m: lambda p: jnp.sum(m._blocks(p, ids)[0])
     assert model.norm_plan() == {"impl": "barrier", "calls": 5}
-    assert count(blocks(model), params) == 4                    # two a layer
+    # two norms a layer, and the two operands of its rotation (``ops/rotary.py``)
+    assert count(blocks(model), params) == 4 + 4
     assert count(blocks(build(config)[0]), params) == 0         # float32 matmuls: none
     (loss, _), grads = jax.value_and_grad(model.loss, has_aux=True)(params, ids)
     monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
@@ -376,7 +378,10 @@ def test_train_lm_trains_the_file_and_its_compile_event_carries_the_eva_plan(tmp
     assert compiled["norm"] == {"impl": norm, "calls": 5}
     assert compiled["eva"]["window"] == 16 and compiled["eva"]["kept"] == ["eva_out", "eva_lse"]
     assert compiled["experts"] is None and compiled["ssm"] is None and compiled["kda"] is None
-    assert compiled["attention"] is None        # no mixer goes through the dispatcher
+    # no mixer goes through the dispatcher: no ``impl``, the mixers' rotation alone
+    assert compiled["attention"] == {"rope_dim": 16, "rope_pairing": "half_split",
+                                     "rope_theta": config["rope_theta"],
+                                     "rotation": "permutation"}
     assert compiled["head_products"] == 3
     assert compiled["recompute"]["kept"] == ["eva_out", "eva_lse"]
     epochs = [e for e in events if e["event"] == "epoch"]

@@ -176,7 +176,7 @@ def test_the_multipliers_are_the_files_by_key_and_the_plan_lists_them():
     assert (plans["ssm"]["head_dim"], plans["ssm"]["state"], plans["ssm"]["chunk"]) == \
         (128, 256, 128)
     assert model.rotary_plan() == {"rope_dim": 128, "rope_pairing": "half_split",
-                                   "rope_theta": 1e11}
+                                   "rope_theta": 1e11, "rotation": "permutation"}
     with open(NEMOTRON_FILE) as fh:
         other = json.load(fh)
     other = hybrid_lm.from_config(other, vocab_size=other["vocab_size"], seq_len=8192)
@@ -447,8 +447,8 @@ def test_the_compile_event_says_what_the_new_block_asks(trained):
     event = [e for e in events if e["event"] == "compile"][0]
     attention = event["attention"]
     assert (attention["key_dim"], attention["value_dim"]) == (16, 16)
-    assert (attention["rope_dim"], attention["rope_pairing"], attention["rope_theta"]) == \
-        (16, "half_split", 1e11)
+    assert (attention["rope_dim"], attention["rope_pairing"], attention["rope_theta"],
+            attention["rotation"]) == (16, "half_split", 1e11, "permutation")
     ssm = event["ssm"]
     assert (ssm["heads"], ssm["groups"], ssm["head_dim"], ssm["state"], ssm["chunk"]) == \
         (4, 2, 8, 16, 16)

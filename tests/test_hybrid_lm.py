@@ -544,6 +544,7 @@ def test_the_events_carry_the_expert_layers_fields(trained):
                                         "rows_buffer": (8 + 4) * 256, "block": 256,
                                         "rows_moved": "arrived"}
     assert compile_event["attention"]["impl"] == "dense"
+    assert compile_event["attention"]["rotation"] == "permutation"
     for event in (e for e in events if e["event"] == "epoch"):
         rows = np.asarray(event["expert_rows"])
         assert rows.shape == (event["steps"], 4)          # [steps, sparse layers]

@@ -456,7 +456,8 @@ def test_the_configuration_is_one_period_of_one_chips_share():
             model.conv_kernel, model.num_dense_layers) == \
         (1e7, 64, True, True, False, 256, 256, 1e-6, True, 512, True, True, (16, 32), 128, 4, 0)
     assert model.rotary_plan() == {"rope_dim": 64, "rope_pairing": "half_split",
-                                   "rope_theta": 1e7, "output_gate": "sigmoid"}
+                                   "rope_theta": 1e7, "rotation": "permutation",
+                                   "output_gate": "sigmoid"}
     gdn = model.gdn_plan()
     assert (gdn["heads"], gdn["key_heads"], gdn["key_dim"], gdn["value_dim"], gdn["chunk"],
             gdn["sub_block"], gdn["group"], gdn["decay"]) == \
@@ -527,7 +528,7 @@ def test_the_other_families_keep_their_routers_and_norms():
     assert "expert_bias_b" in shapes[f"layer_{sparse}"]["moe"]
     attn = [v["attn"] for v in shapes.values() if isinstance(v, dict) and "attn" in v][0]
     assert "q_norm_scale" in attn and attn["q_kernel"][1] == attn["out_kernel"][0]
-    assert model.rotary_plan().keys() == {"rope_dim", "rope_pairing", "rope_theta"}
+    assert model.rotary_plan().keys() == {"rope_dim", "rope_pairing", "rope_theta", "rotation"}
     assert model.plans(jax.make_jaxpr(lambda: 0)(), 8)["gdn"] is None
     assert "scoring" not in model.expert_plan(8)
     assert moe.SCORINGS == ("sigmoid", "softmax")
@@ -577,7 +578,8 @@ def test_the_compile_event_says_what_the_new_layers_ask(trained):
     attention = event["attention"]
     assert (attention["key_dim"], attention["value_dim"]) == (16, 16)
     assert (attention["rope_dim"], attention["rope_pairing"], attention["rope_theta"],
-            attention["output_gate"]) == (4, "half_split", 1e7, "sigmoid")
+            attention["rotation"], attention["output_gate"]) == \
+        (4, "half_split", 1e7, "permutation", "sigmoid")
     gdn = event["gdn"]
     assert (gdn["heads"], gdn["key_heads"], gdn["key_dim"], gdn["value_dim"], gdn["decay"],
             gdn["chunk"], gdn["sub_block"], gdn["group"]) == (4, 2, 8, 8, "scalar", *TILING)

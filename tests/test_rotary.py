@@ -181,3 +181,128 @@ def test_interleaved_scalar_position_matches_indexed_row():
     full = apply_rotary(x, jnp.arange(8), base=1e6, interleaved=True)
     row = apply_rotary(x[:, 5], jnp.asarray(5, jnp.int32), base=1e6, interleaved=True)
     np.testing.assert_allclose(np.asarray(row), np.asarray(full[:, 5]), rtol=1e-6, atol=1e-6)
+
+
+# -- the lane-whole form against the formula it replaced (PR 48) -----------------------------
+
+
+def _sliced_rotary(x, positions, *, base=10000.0, interleaved=False, channels=None):
+    """The plain reference: ``ops/rotary.py`` as it stood until PR 48 and its call sites'
+    split and rejoin around a part of a head, written out. The turning channels are cut
+    out of the head, their halves (or their pairs' two lanes) are arrays of their own,
+    and ``jnp.concatenate`` joins the products and the channels that do not turn."""
+    first, width = channels or (0, x.shape[-1])
+    part = x[..., first:first + width].astype(jnp.float32)
+    inv_freq = base ** (-jnp.arange(0, width, 2, dtype=jnp.float32) / width)
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq
+    if positions.ndim:
+        ang = ang[..., :, None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if interleaved:
+        x1, x2 = part[..., 0::2], part[..., 1::2]
+        turned = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).reshape(part.shape)
+    else:
+        x1, x2 = part[..., :width // 2], part[..., width // 2:]
+        turned = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return jnp.concatenate([x[..., :first], turned.astype(x.dtype),
+                            x[..., first + width:]], axis=-1)
+
+
+def _part(which: str, width: int):
+    """The cells' three cases: a whole head; ``qwen3_next``'s leading quarter (64 of 256);
+    ``kanana2``'s trailing third (64 of 192)."""
+    turning = min(64, width // 2)
+    return {"whole": None, "leading": (0, turning), "trailing": (width - turning, turning)}[which]
+
+
+@pytest.mark.parametrize("positions", ["row", "scalar"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("width", [64, 128, 192, 256])
+@pytest.mark.parametrize("which", ["whole", "leading", "trailing"])
+@pytest.mark.parametrize("interleaved", [False, True], ids=["half-split", "interleaved"])
+def test_lane_whole_form_is_the_sliced_formula(interleaved, which, width, dtype, positions):
+    """Values, and the gradient of a weighted sum, against the reference: the same two
+    products and one sum a channel in float32 and one rounding, so float32 agrees to its
+    rounding and bfloat16 to one step of its own."""
+    rng = np.random.default_rng(width + 7 * interleaved)
+    shape = (2, 6, 3, width) if positions == "row" else (2, 3, width)
+    pos = jnp.arange(6) + 11 if positions == "row" else jnp.asarray(13, jnp.int32)
+    x = jnp.asarray(rng.normal(size=shape).astype(np.float32)).astype(dtype)
+    w = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    kw = dict(base=1e6, interleaved=interleaved, channels=_part(which, width))
+    tol = dict(rtol=1e-6, atol=1e-6) if dtype == jnp.float32 else dict(rtol=8e-3, atol=8e-3)
+    for fn in (lambda turn: turn(x, pos, **kw),
+               lambda turn: jax.grad(lambda x: jnp.sum(
+                   turn(x, pos, **kw).astype(jnp.float32) * w))(x)):
+        got, want = fn(apply_rotary), fn(_sliced_rotary)
+        assert got.dtype == dtype and got.shape == shape
+        np.testing.assert_allclose(np.asarray(got.astype(jnp.float32)),
+                                   np.asarray(want.astype(jnp.float32)), **tol)
+
+
+def _cuts_of_the_last_axis(jaxpr) -> list[str]:
+    """Every equation, in ``jaxpr`` or under it, that slices, pads or joins an array of
+    three axes or more along its last axis."""
+    found = []
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _cuts_of_the_last_axis(sub)
+        shape = eqn.invars[0].aval.shape if eqn.invars else ()
+        if len(shape) < 3:
+            continue
+        name, last = eqn.primitive.name, len(shape) - 1
+        if (name == "slice" and (eqn.params["start_indices"][last] != 0
+                                 or eqn.params["limit_indices"][last] != shape[last])) \
+                or (name == "concatenate" and eqn.params["dimension"] == last) \
+                or (name == "pad" and tuple(eqn.params["padding_config"][last]) != (0, 0, 0)) \
+                or (name in ("dynamic_slice", "dynamic_update_slice")):
+            found.append(str(eqn)[:200])
+    return found
+
+
+@pytest.mark.parametrize("shape,kw", [
+    ((1, 256, 16, 128), {}),                                           # evabyte, falcon_h1, lm
+    ((2, 256, 8, 64), {}),                                             # lfm2
+    ((2, 256, 16, 256), {"channels": (0, 64)}),                        # qwen3_next
+    ((2, 256, 32, 192), {"channels": (128, 64), "interleaved": True}),  # kanana2
+    ((2, 256, 1, 64), {"interleaved": True}),                          # its one shared key
+    ((4, 8, 128), {}),                                                 # serving: [B, H, D], one position a row
+], ids=["128", "64", "first-64-of-256", "last-64-of-192-interleaved", "shared-key", "decode"])
+def test_no_slice_or_join_of_the_last_axis_in_either_pass(shape, kw):
+    """The copies cannot come back unseen: neither the rotation's jaxpr nor its
+    gradient's cuts a head's channels apart or joins them (on the chip a slice at half a
+    lane tile is an array of its own, and the transpose of slice-and-concatenate is
+    pad-and-add). The reference's jaxpr holds both, which is what the walk looks for."""
+    x = jnp.zeros(shape, jnp.bfloat16)
+    pos = jnp.arange(shape[-3])
+    value = lambda turn: lambda x: turn(x, pos, **kw)
+    grad = lambda turn: jax.grad(lambda x: jnp.sum(turn(x, pos, **kw).astype(jnp.float32)))
+    for make in (value, grad):
+        assert _cuts_of_the_last_axis(jax.make_jaxpr(make(apply_rotary))(x).jaxpr) == []
+        assert _cuts_of_the_last_axis(jax.make_jaxpr(make(_sliced_rotary))(x).jaxpr)
+
+
+def test_backward_is_the_rotation_at_the_negated_angle():
+    """A rotation's transpose is its inverse: the cotangent turned back by the same
+    angles, and turning forward again returns it; nothing but ``positions`` is held."""
+    rng = np.random.default_rng(48)
+    x = jnp.asarray(rng.normal(size=(2, 8, 2, 192)).astype(np.float32))
+    g = jnp.asarray(rng.normal(size=(2, 8, 2, 192)).astype(np.float32))
+    pos = jnp.arange(8) + 40
+    kw = dict(base=1e6, interleaved=True, channels=(128, 64))
+    out, pull = jax.vjp(lambda x: apply_rotary(x, pos, **kw), x)
+    (back,) = pull(g)
+    np.testing.assert_allclose(np.asarray(apply_rotary(back, pos, **kw)), np.asarray(g),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(back[..., :128]), np.asarray(g[..., :128]))
+    np.testing.assert_array_equal(np.asarray(out[..., :128]), np.asarray(x[..., :128]))
+    residuals = jax.make_jaxpr(lambda x: jax.vjp(lambda x: apply_rotary(x, pos, **kw), x)[1])(x)
+    assert all(v.aval.shape == pos.shape for v in residuals.jaxpr.outvars)
+
+
+def test_channels_outside_the_head_are_refused():
+    with pytest.raises(ValueError, match="do not lie in a head"):
+        apply_rotary(jnp.zeros((1, 4, 2, 16)), jnp.arange(4), channels=(12, 8))
+    with pytest.raises(ValueError, match="even head dim"):
+        apply_rotary(jnp.zeros((1, 4, 2, 16)), jnp.arange(4), channels=(0, 7))

@@ -117,8 +117,8 @@ def test_the_compile_event_carries_the_models_plans_whole(family, tmp_path, monk
     assert set(event) == fixed | set(said) and not fixed & set(said)
     assert {key: event[key] for key in said} == json.loads(json.dumps(said))
     # the attention entry is the model's shape through the dispatcher, and its own fields
-    if view.attention_shape is None:
-        assert event["attention"] is None
+    if view.attention_shape is None:        # no dispatch plan: the model's own fields alone
+        assert event["attention"] == (view.attention_fields or None)
     else:
         heads, head_dim, value_dim = view.attention_shape
         assert event["attention"] == {**ops.dispatch_plan(
@@ -150,7 +150,7 @@ def test_both_views_answer_the_same_questions_and_the_pixel_path_stays_plain():
     assert held.after_update is None and held.expert_block == 8     # no bias update rate
     assert held.targets_per_seq == tests.SEQ - 1 and held.attention_shape == (4, 8, 8)
     assert held.attention_fields == {"rope_dim": 8, "rope_pairing": "half_split",
-                                     "rope_theta": 1e6}
+                                     "rope_theta": 1e6, "rotation": "permutation"}
 
 
 def test_the_trainer_names_no_model_class():
